@@ -18,7 +18,7 @@ operand byte. Everything elementwise fuses into one read + one write pass
 (XLA fusion).
 
 The op's **model time** is total modeled traffic / peak HBM bandwidth; the
-**%membw** column of BENCH_TPU.md is ``model_time / measured_time`` — the
+**%membw** column of the bench output is ``model_time / measured_time`` — the
 fraction of the algorithm's own bandwidth bound the implementation
 achieves. A low %membw means dispatch overhead or unfused overhead; a high
 %membw with a slow op means the *algorithm* is the cost (too many passes)
@@ -229,7 +229,8 @@ def _walk(jaxpr, rep: Report) -> None:
             w = (in_bytes + out_bytes) * GATHER_PASS_EQ
             rep.scatter_bytes += w
             rep.by_prim[prim] = rep.by_prim.get(prim, 0.0) + w
-        elif prim in ("all_to_all", "all_gather", "psum", "ppermute",
+        elif prim in ("all_to_all", "all_gather", "all_gather_invariant",
+                      "psum", "psum_invariant", "ppermute",
                       "reduce_scatter"):
             rep.collective_bytes += in_bytes
             rep.collective_count += 1

@@ -40,15 +40,11 @@ def main():
 
     import __graft_entry__ as ge
 
-    use_cpu = args.cpu
-    if not use_cpu:
+    if not args.cpu:
         import bench as _b
 
-        use_cpu = not _b.probe_tpu(
-            float(os.environ.get("BENCH_INIT_TIMEOUT", 120)),
-            int(os.environ.get("BENCH_INIT_TRIES", 2)),
-        )
-    if use_cpu:
+        _b.require_tpu()  # --cpu is the only way onto the CPU
+    else:
         ge._force_cpu_mesh(1)
         args.rows = min(args.rows, 1_000_000)
 

@@ -71,8 +71,14 @@ class CollectiveContract:
         pairs = [
             ("collectives", self.collectives, census.total),
             ("all_to_all", self.all_to_all, census.counts.get("all_to_all", 0)),
-            ("all_gather", self.all_gather, census.counts.get("all_gather", 0)),
-            ("psum", self.psum, census.counts.get("psum", 0)),
+            # jax files a collective whose operand is replicated under the
+            # ``*_invariant`` name; the contract counts the operation
+            ("all_gather", self.all_gather,
+             census.counts.get("all_gather", 0)
+             + census.counts.get("all_gather_invariant", 0)),
+            ("psum", self.psum,
+             census.counts.get("psum", 0)
+             + census.counts.get("psum_invariant", 0)),
         ]
         for label, want, got in pairs:
             w = _eval(want, k)

@@ -1,76 +1,20 @@
-"""JAX version compatibility shims.
+"""The JAX names this package uses that have moved between releases.
 
-The ONE place version skew between JAX releases is absorbed. Today that is
-``shard_map``: promoted to ``jax.shard_map`` (with the ``check_rep`` knob
-renamed ``check_vma``) in newer releases, but living at
-``jax.experimental.shard_map.shard_map`` on the 0.4.x line this image ships.
-Every module that wraps a kernel in shard_map imports :func:`shard_map` from
-here instead of touching ``jax.shard_map`` directly.
+One installation is supported (the jax 0.9 line ``pyproject.toml`` names),
+so nothing here branches on the version: these are the plain spellings,
+kept in one module so that the next move is a one-file change.
 """
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-    VMA_NATIVE = True
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-    VMA_NATIVE = False
-
-
-if hasattr(jax, "enable_x64"):
-    enable_x64 = jax.enable_x64
-else:  # jax <= 0.4.x keeps the context manager under experimental
-    from jax.experimental import enable_x64  # noqa: F401
-
-
-def distributed_is_initialized() -> bool:
-    """``jax.distributed.is_initialized()``, absent on the 0.4.x line —
-    there the singleton client's presence is the same signal."""
-    if hasattr(jax.distributed, "is_initialized"):
-        return bool(jax.distributed.is_initialized())
-    try:
-        from jax._src.distributed import global_state
-
-        return global_state.client is not None
-    except Exception:
-        return False
+shard_map = jax.shard_map  # (f, mesh=, in_specs=, out_specs=, check_vma=)
+enable_x64 = jax.enable_x64
+distributed_is_initialized = jax.distributed.is_initialized
 
 
 def pvary(x, axis_name: str):
-    """Mark a replicated value as varying over the mesh axis (vma system of
-    newer JAX). Old releases have no vma tracking at all, so the identity is
-    the correct no-op there."""
-    try:
-        return jax.lax.pcast(x, (axis_name,), to="varying")
-    except (AttributeError, TypeError):
-        pass
-    try:
-        return jax.lax.pvary(x, (axis_name,))
-    except AttributeError:
-        return x
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with the stable keyword surface used repo-wide.
-
-    ``check_vma`` maps onto the old API's ``check_rep`` — both toggle the
-    same replication/varying-axes checker that pallas_call-embedding kernels
-    need off. On the 0.4.x line the checker itself is incomplete (rep rules
-    returning None for e.g. sorted-method searchsorted, untypable scan
-    carries), so it is forced off there — it is a debugging aid, not a
-    semantics change.
-    """
-    if not VMA_NATIVE:
-        check_vma = False
-    return _shard_map(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        **{_CHECK_KW: check_vma},
-    )
+    """Mark a replicated value as varying over the mesh axis, so a scan
+    carry initialised from a constant types like the body's outputs under
+    ``shard_map``'s varying-axes checker."""
+    return jax.lax.pcast(x, (axis_name,), to="varying")

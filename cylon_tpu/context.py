@@ -10,6 +10,7 @@ synchronizing, so an explicit barrier is rarely needed).
 from __future__ import annotations
 
 import itertools
+import os
 import threading
 from typing import Any, Dict, Optional
 
@@ -21,41 +22,43 @@ from .config import CommConfig, CommType, LocalConfig, TPUConfig
 
 _compile_cache_set = False
 
+#: the compile cache of accelerator contexts when JAX_COMPILATION_CACHE_DIR
+#: is not set: a fixed directory inside the checkout (the path is part of
+#: the cache's key, so it must not move between runs); .gitignore lists it
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
+
 
 def _enable_compile_cache(platform: str) -> None:
-    """Persistent XLA compilation cache, on by default on accelerators
-    (opt out with CYLON_TPU_COMPILE_CACHE=0; redirect with
-    CYLON_TPU_COMPILE_CACHE=<dir>; set a dir to force-enable on CPU).
+    """Persistent XLA compilation cache, on by default on accelerators.
 
     The reference compiles its kernels AOT to native code once at build time;
     the XLA analog is this cache — every (program, shapes) combination
-    compiles once per machine, not once per process. On TPU the big fused
-    programs cost minutes to compile cold, so this is a product-level fix,
-    not just a bench convenience. CPU is excluded by default: XLA:CPU AOT
-    reloads warn (and may SIGILL) across host-feature drift, and CPU
-    compiles are cheap anyway."""
+    compiles once per machine, not once per process. On TPU the big
+    programs cost about a minute each to compile cold, so this is a
+    product-level fix, not just a bench convenience.
+
+    One location: where ``JAX_COMPILATION_CACHE_DIR`` is set jax already
+    caches there and this function touches nothing; otherwise accelerator
+    contexts use :data:`DEFAULT_COMPILE_CACHE`. ``CYLON_TPU_COMPILE_CACHE=0``
+    opts out of the default. CPU contexts set no cache: XLA:CPU AOT reloads
+    warn (and may SIGILL) across host-feature drift, and CPU compiles are
+    cheap anyway."""
     global _compile_cache_set
     if _compile_cache_set:
         return
     _compile_cache_set = True
-    import os
-
     from .utils import envgate as _envgate
 
-    loc = _envgate.COMPILE_CACHE.get()
-    if loc == "0":
+    if (
+        platform == "cpu"
+        or os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        or _envgate.COMPILE_CACHE.get() == "0"
+    ):
         return
-    if platform == "cpu" and not loc:
-        return
-    if not loc:
-        loc = os.path.join(
-            os.path.expanduser("~"), ".cache", "cylon_tpu", "xla_cache"
-        )
-    try:
-        jax.config.update("jax_compilation_cache_dir", loc)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:
-        pass  # older jax without the knobs: in-process caching still applies
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 
 class CylonContext:
@@ -160,6 +163,13 @@ class CylonContext:
 
     def is_distributed(self) -> bool:
         return self.mesh.size > 1
+
+    @property
+    def platform(self) -> str:
+        """Platform of the mesh's devices ("tpu", "cpu", ...). Programs for
+        this context lower for it — which is not always the process's
+        default backend (a TPU host may drive a CPU-device mesh)."""
+        return self.mesh.devices.flat[0].platform
 
     # -- config KV (reference AddConfig/GetConfig, cylon_context.hpp:60-69) --
     def add_config(self, key: str, value: str) -> None:

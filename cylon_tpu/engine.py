@@ -18,6 +18,8 @@ one XLA program per (shapes, statics) combination, compiled once and reused.
 """
 from __future__ import annotations
 
+import contextvars
+import functools
 import threading
 from typing import Any, Callable, NamedTuple, Tuple
 
@@ -94,6 +96,42 @@ def record_dispatch(fn, *args, key=None) -> None:
     _KERNEL_RECORD.append((key, fn, spec))
 
 
+# platform of the devices the kernel being traced is for (None outside
+# a kernel trace)
+_MESH_PLATFORM: contextvars.ContextVar = contextvars.ContextVar(
+    "cylon_tpu_mesh_platform", default=None
+)
+
+
+def mesh_platform() -> str:
+    """Platform of the devices the program being traced will run on.
+
+    Inside a kernel wrapped by :func:`on_mesh` (every ``get_kernel``
+    program and the fused-join steps) this is the context mesh's platform:
+    a lowering for TPU devices is a Mosaic lowering wherever the process
+    runs, and a CPU mesh driven from a TPU host interprets. Outside one (a
+    bare ``jax.jit`` of an op, no mesh in hand) jit places the program on
+    the default device, so that device's platform is the answer."""
+    return _MESH_PLATFORM.get() or jax.devices()[0].platform
+
+
+def on_mesh(mesh, kernel: Callable) -> Callable:
+    """Wrap ``kernel`` so its body sees ``mesh_platform()`` of ``mesh``.
+    The body of a jitted function runs only while it is traced, so the
+    wrapper costs nothing per dispatch."""
+    platform = mesh.devices.flat[0].platform
+
+    @functools.wraps(kernel)
+    def traced(*args):
+        token = _MESH_PLATFORM.set(platform)
+        try:
+            return kernel(*args)
+        finally:
+            _MESH_PLATFORM.reset(token)
+
+    return traced
+
+
 def round_cap(n: int, minimum: int = 8) -> int:
     """Round a capacity up to a power of two (>= minimum)."""
     n = max(int(n), minimum)
@@ -121,9 +159,13 @@ def get_kernel(
     with unvarying iotas trips the checker).
 
     ``use_shard_map=False`` jits the kernel directly (caller guarantees a
-    1-device mesh, where shard_map is a no-op): compiled ``pallas_call``
-    under jit(shard_map) hits an unbounded-recursion jax bug on TPU.
-    Caching and kernel recording behave identically either way."""
+    1-device mesh, where shard_map is a no-op). Caching and kernel
+    recording behave identically either way.
+
+    The kernel body runs with :func:`mesh_platform` set to the platform of
+    the context's devices, so code deep inside it (the sort engine's
+    Pallas tier) can pick interpret mode from the MESH, not from the
+    process's default backend."""
     cache = ctx.__dict__.get("_jit_cache")
     if cache is None:
         with cache_lock(ctx):
@@ -138,7 +180,7 @@ def get_kernel(
         with cache_lock(ctx):
             fn = cache.get(key)  # double-check: lost the build race
             if fn is None:
-                kernel = builder()
+                kernel = on_mesh(ctx.mesh, builder())
                 if use_shard_map:
                     fn = jax.jit(
                         shard_map(

@@ -89,20 +89,42 @@ def _prune_stale(keep: str, prefix: str) -> None:
                 pass
 
 
+def _compile(cmd: list, so: str, prefix: str) -> bool:
+    """Run ``cmd + ["-o", <tmp>]`` and move the result to ``so``.
+
+    The temporary name is this process's own: several processes (pytest
+    workers, ranks of one job) may find the library missing at once, and a
+    shared name lets one process rename the file another is still linking.
+    ``os.replace`` is atomic and every builder produces the same bytes (the
+    path carries the source hash), so whoever lands last changes nothing;
+    a build that fails after another process placed the library is a
+    success too."""
+    tmp = f"{so}.{os.getpid()}.tmp"
+    if _asan():
+        asan = ["-fsanitize=address", "-fno-omit-frame-pointer", "-g"]
+        cmd = cmd[:1] + asan + cmd[1:]
+    try:
+        subprocess.run(
+            cmd + ["-o", tmp], check=True, capture_output=True, timeout=300
+        )
+        os.replace(tmp, so)
+    except (subprocess.CalledProcessError, FileNotFoundError,
+            subprocess.TimeoutExpired):
+        try:
+            os.unlink(tmp)
+        except OSError:
+            pass
+        return os.path.exists(so)
+    _prune_stale(so, prefix)
+    return True
+
+
 def _build(so: str) -> bool:
     cmd = [
         "g++", "-std=c++20", "-O3", "-fPIC", "-shared", "-pthread",
-        _SRC, _SRC_RT, "-o", so + ".tmp",
+        _SRC, _SRC_RT,
     ]
-    if _asan():
-        cmd[1:1] = ["-fsanitize=address", "-fno-omit-frame-pointer", "-g"]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=300)
-    except (subprocess.CalledProcessError, FileNotFoundError, subprocess.TimeoutExpired):
-        return False
-    os.replace(so + ".tmp", so)
-    _prune_stale(so, "_cylon_native")
-    return True
+    return _compile(cmd, so, "_cylon_native")
 
 
 def build_capi() -> Optional[str]:
@@ -118,18 +140,9 @@ def build_capi() -> Optional[str]:
     ver = sysconfig.get_config_var("LDVERSION") or sysconfig.get_python_version()
     cmd = [
         "g++", "-std=c++20", "-O2", "-fPIC", "-shared", "-pthread",
-        f"-I{inc}", _SRC_CAPI, "-o", so + ".tmp",
-        f"-L{libdir}", f"-lpython{ver}",
+        f"-I{inc}", _SRC_CAPI, f"-L{libdir}", f"-lpython{ver}",
     ]
-    if _asan():
-        cmd[1:1] = ["-fsanitize=address", "-fno-omit-frame-pointer", "-g"]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=300)
-    except (subprocess.CalledProcessError, FileNotFoundError, subprocess.TimeoutExpired):
-        return None
-    os.replace(so + ".tmp", so)
-    _prune_stale(so, "_cylon_capi")
-    return so
+    return so if _compile(cmd, so, "_cylon_capi") else None
 
 
 def _bind(lib):

@@ -584,9 +584,7 @@ def emit_impl_kwargs(ctx) -> Tuple[str, dict]:
     trigger."""
     from ..utils import envgate as _eg
 
-    impl = emit_impl_for(
-        ctx.world_size, ctx.mesh.devices.flat[0].platform
-    )
+    impl = emit_impl_for(ctx.world_size, ctx.platform)
     if not impl.startswith("windowed"):
         return impl, {}
     # CYLON_TPU_FORCE_SHARD_MAP=1 keeps shard_map on a 1-device mesh: the

@@ -46,17 +46,32 @@ Implementation selection mirrors the sort engine's lattice
 2. ``CYLON_TPU_CODEC_IMPL`` in {xla, pallas} forces.
 3. The autopilot's per-shape ``Decisions.codec_impl``
    (plan/feedback.py), visible through the applying() contextvar.
-4. Default ``auto``: pallas wherever the structural predicates accept
-   (``pack_supported`` / ``compact_supported``) — each kernel declines
-   independently and per-stage fallback is exact, so mixed-impl rounds
-   are sound.
+4. Default ``auto``: the XLA lowering of both stages, on every
+   platform (CPU tests and the chip take the same path). The default
+   selects no kernel the TPU compiler refuses, and Mosaic (jax 0.9.0 /
+   libtpu 0.0.34, compiled for v5e) refuses the pack kernel twice over:
+   its ``(1, TILE)`` blocks over ``[n_tiles, TILE]`` operands ("the
+   last two dimensions of your block shape are divisible by 8 and 128
+   respectively, or be equal to the respective dimensions of the
+   overall array") and, behind that, its body ("Unimplemented primitive
+   in Pallas TPU lowering: cumsum", plus lane->sublane transposes of
+   the pid vector). The compact kernel does compile for v5e
+   (tests/test_tpu_compile.py keeps that true) but has not run on a
+   chip, so it follows the same default until a run on the four-chip
+   mesh shows it matching the XLA compact and winning. Forcing
+   ``pallas`` engages each kernel wherever its structural predicate
+   accepts (``pack_supported`` / ``compact_supported``; declines are
+   per stage and exact); on a TPU mesh a forced kernel that does not
+   lower raises the compiler's error — it never declines to XLA or to
+   interpret mode there.
 
 ``impl_tag()`` is the cache-key carrier: every shuffle-family kernel
 key appends it, so a knob (or tuned-decision) flip recompiles exactly
 once and never aliases a stale program. ``gate_state()`` is the plan-
-fingerprint component (plan/lazy.py). interpret=True on CPU meshes,
-raw functions only — no nested jit (see ops/pallas_gather.py tail
-note).
+fingerprint component (plan/lazy.py). interpret=True only where the
+MESH's devices are CPUs (the call sites in table.py decide from the
+context's mesh, not from the process's backend), raw functions only —
+no nested jit (see ops/pallas_gather.py tail note).
 
 Deviation from the plan of record, stated plainly: the pack kernel
 emits ``dest`` + histogram and the ONE collision-free lane-buffer
@@ -143,9 +158,9 @@ def codec_available() -> bool:
 def resolved_impl() -> str:
     """The selected codec impl for the CURRENT trace: kill switch, then
     the forcing env, then the autopilot's applied per-shape decision,
-    then the ``auto`` default (pallas where the structural predicates
-    accept). Host env/contextvar reads only — shape-static, cache-key
-    safe."""
+    then the ``auto`` default — XLA on every platform (the pack kernel
+    does not lower for TPU; see the module doc). Host env/contextvar
+    reads only — shape-static, cache-key safe."""
     if not enabled() or pl is None:
         return "xla"
     forced = _eg.CODEC_IMPL.get()
@@ -156,7 +171,7 @@ def resolved_impl() -> str:
     tuned = _fb.tuned_codec_impl()
     if tuned in IMPLS:
         return tuned
-    return "pallas"
+    return "xla"
 
 
 def impl_tag() -> tuple:

@@ -89,8 +89,13 @@ def range_partition_ids(
     lo = jnp.min(jnp.where(ok, x, big))
     hi = jnp.max(jnp.where(ok, x, -big))
     if axis_name is not None:
-        lo = jax.lax.pmin(lo, axis_name)
-        hi = jax.lax.pmax(hi, axis_name)
+        # one all_gather of the per-shard extrema, reduced locally, instead
+        # of pmin + pmax: lo/hi are float64 under x64, and the TPU compiler
+        # lowers a 64-bit all-reduce only for sums ("UNIMPLEMENTED:
+        # Supported lowering only of Sum all reduce"). Same values, one
+        # collective fewer.
+        ends = jax.lax.all_gather(jnp.stack([lo, hi]), axis_name)  # [P, 2]
+        lo, hi = jnp.min(ends[:, 0]), jnp.max(ends[:, 1])
     span = jnp.maximum(hi - lo, 1e-300)
     # local histogram over num_bins equal-width bins
     b = jnp.clip(((x - lo) / span * num_bins).astype(jnp.int32), 0, num_bins - 1)

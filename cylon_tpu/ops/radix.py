@@ -381,13 +381,15 @@ def _dispatch_pass(
         from . import pallas_radix as _pr
 
         if _pr.pass_supported(enc, perm.shape[0]):
-            # interpret on CPU backends, same rule as the windowed emit;
-            # radix_pallas is force/tuned-only, so the TPU-host-driving-
-            # a-CPU-mesh mismatch the emit path guards against cannot be
-            # reached by default
+            # interpret only in programs for CPU devices — the mesh being
+            # traced for decides (engine.mesh_platform), same rule as the
+            # windowed emit. On a TPU mesh the forced tier compiles or
+            # raises Mosaic's error; it never declines to XLA there
+            from .. import engine as _engine
+
             return _pr.radix_pass_pallas(
                 enc, perm, shift, bits,
-                interpret=jax.default_backend() == "cpu",
+                interpret=_engine.mesh_platform() == "cpu",
             )
         # 64-bit lanes / non-tile-divisible caps: per-pass XLA fallback
         # (stability makes mixed-tier chains exact)

@@ -36,7 +36,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec
 
-from ..compat import shard_map
+from ..compat import pvary, shard_map
+from ..engine import on_mesh
 from ..ops import join as _j
 from ..ops import partition as _p
 from ..ops.sort import KeyCol
@@ -373,37 +374,12 @@ def make_distributed_join_step(
         # the carry must match the body outputs' varying-manual-axes type
         # under shard_map: mark the unvarying zero initializers as varying
         # over the mesh axis
-        from ..compat import VMA_NATIVE, pvary
-
-        def _vary(x):
-            return pvary(x, axis_name)
-
-        if VMA_NATIVE:
-            (ov_shuffle, ov_join), (ds, vs, ns) = jax.lax.scan(
-                slice_body,
-                (_vary(jnp.int32(0)), _vary(jnp.int32(0))),
-                jnp.arange(num_slices, dtype=jnp.int32),
-            )
-        else:
-            # old-API shard_map mis-lowers the collectives inside a scanned
-            # body (measured: rows silently lost/duplicated per slice on
-            # jax 0.4.x CPU) — unroll the K slice rounds instead. Program
-            # size grows O(K), results match the scan on current JAX.
-            carry = (jnp.int32(0), jnp.int32(0))
-            ys_all = []
-            for s in range(num_slices):
-                carry, ys = slice_body(carry, jnp.int32(s))
-                ys_all.append(ys)
-            ov_shuffle, ov_join = carry
-            ds = tuple(
-                jnp.stack([y[0][ci] for y in ys_all])
-                for ci in range(len(ys_all[0][0]))
-            )
-            vs = tuple(
-                jnp.stack([y[1][vi] for y in ys_all])
-                for vi in range(len(ys_all[0][1]))
-            )
-            ns = jnp.stack([y[2] for y in ys_all])
+        zero = pvary(jnp.int32(0), axis_name)
+        (ov_shuffle, ov_join), (ds, vs, ns) = jax.lax.scan(
+            slice_body,
+            (zero, zero),
+            jnp.arange(num_slices, dtype=jnp.int32),
+        )
         # reassemble the [K, join_cap]-stacked outputs into flat columns and
         # compact the K live prefixes into ONE (a segment mask + one stable
         # sort + one packed gather — the only output-sized cost of slicing)
@@ -428,7 +404,7 @@ def make_distributed_join_step(
 
     return jax.jit(
         shard_map(
-            step,
+            on_mesh(mesh, step),
             mesh=mesh,
             in_specs=(PartitionSpec(axis_name), PartitionSpec()),
             out_specs=PartitionSpec(axis_name),
@@ -531,7 +507,7 @@ def make_join_groupby_step(
 
     return jax.jit(
         shard_map(
-            step,
+            on_mesh(mesh, step),
             mesh=mesh,
             in_specs=(PartitionSpec(axis_name), PartitionSpec()),
             out_specs=PartitionSpec(axis_name),

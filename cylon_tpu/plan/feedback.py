@@ -138,9 +138,10 @@ class Decisions(NamedTuple):
     #: back to the XLA pack/compact lowerings when its journaled codec
     #: dispatch clocks show the fused Pallas kernels not beating them
     #: (the same beat-your-lowering rule as sort_impl); ``"pallas"``
-    #: pins the fused tier. None = the static default (pallas where the
-    #: structural predicates accept). Policy only: the codec is
-    #: bit-lossless on non-quant lanes and the CYLON_TPU_NO_PALLAS_CODEC
+    #: pins the fused tier. None = the static default (XLA; the
+    #: proposer below only ever walks back to it, so the autopilot never
+    #: selects a kernel the chip's compiler refuses). Policy only: the
+    #: codec is bit-lossless on non-quant lanes and the CYLON_TPU_NO_PALLAS_CODEC
     #: oracle pins exact equality — only milliseconds move.
     codec_impl: Optional[str] = None
 
@@ -320,7 +321,7 @@ def effective_decisions(p: Dict[str, Any]) -> tuple:
         si = None
     ci = dec.get("codec_impl")
     if ci == STATIC:
-        # decided: the fused pallas codec holds up, keep the static default
+        # decided: nothing to walk back, keep the static default
         ci = None
     return (
         dec.get("shuffle_budget"),
@@ -674,10 +675,13 @@ def _codec_impl_proposal(
     alt_row_passes_sum]}`` — the sort_impl proposal's shape, two-way
     xla|pallas.
 
-    Both impls measured: propose the faster by the margin — "xla" when
-    the XLA lowerings win (the auto-default walk-back), STATIC when the
-    fused kernels hold (decision MADE: keep the default, stop
-    re-judging). One impl measured: model the other through the row-pass
+    Both impls measured: "xla" when the XLA lowerings win by the
+    margin, STATIC when the fused kernels hold (decision MADE: keep the
+    resolver's static default and stop re-judging). That default is XLA
+    too (ops/pallas_codec.py: the pack kernel does not lower for TPU),
+    so today both outcomes resolve to XLA; the proposer never pins
+    "pallas", because a decision learned on one mesh must not select it
+    on another. One impl measured: model the other through the row-pass
     ratio the observation carried (a pallas round knows the 3-pass XLA
     pack its shape would have paid, and vice versa). Returns
     ``(None, None)`` when the evidence floor is not met."""

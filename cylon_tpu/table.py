@@ -1715,23 +1715,6 @@ class Table:
         spec_join, never a wrong answer."""
         if how != "inner":
             raise ValueError("algorithm='pallas_pk' supports how='inner' only")
-        if (
-            self.ctx.world_size > 1
-            and self.ctx.mesh.devices.flat[0].platform != "cpu"
-        ):
-            # compiled (non-interpret) pallas_call under jit(shard_map) hits
-            # an unbounded-recursion jax bug on TPU; on a multi-chip
-            # accelerator mesh the hint path cannot run, so take the exact
-            # sort join directly (same result, just no speculation) BEFORE
-            # paying dictionary unification / key promotion / flattening
-            return self.join(
-                other,
-                on=l_names if l_names == r_names else None,
-                left_on=l_names if l_names != r_names else None,
-                right_on=r_names if l_names != r_names else None,
-                how=how,
-                suffixes=suffixes,
-            )
         left, right = _unify_dict_pair(self, other, l_names, r_names)
         left, right = _promote_key_pair(left, right, l_names, r_names)
         lk = left._flat_cols(l_names)
@@ -1754,7 +1737,7 @@ class Table:
         # a static exact bound -> single dispatch, ONE host sync
         cap_out = left.shard_cap
         B = 256
-        interp = self.ctx.mesh.devices.flat[0].platform == "cpu"
+        interp = self.ctx.platform == "cpu"
         key = (
             "pallas_pk_join", len(lflat), len(rflat), cap_out, B, interp,
         )
@@ -1773,10 +1756,12 @@ class Table:
 
         with span("join.pallas_pk", rows=self._rows_hint()):
             args = (lk, rk, lflat, rflat, left.counts_dev, right.counts_dev)
-            # world==1: shard_map is a no-op AND its compiled-pallas
-            # recursion bug is avoided (use_shard_map=False). Multi-device
-            # reaches here only in interpret mode (CPU mesh), which traces
-            # clean; check_vma=False because pallas_call output vma
+            # world==1: shard_map is a no-op, so the kernel is jitted
+            # directly. Multi-device meshes run the pallas_call per shard
+            # under shard_map, compiled on an accelerator mesh and
+            # interpreted on a CPU one (a kernel the chip's compiler
+            # refuses raises here; it is never swapped for another
+            # algorithm); check_vma=False because pallas_call output vma
             # interplay with unvarying iotas trips shard_map's checker
             out, stats = get_kernel(
                 self.ctx, key, build, check_vma=False,
@@ -3486,12 +3471,12 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
                     words, valids, hv = _codec.hash_operands(list(kcols))
                     dest, cnt = _codec.fused_pack_dest(
                         words, valids, hv, n, rnd, world, bc,
-                        interpret=jax.default_backend() == "cpu",
+                        interpret=ctx.platform == "cpu",
                     )
                 else:
                     dest, cnt = _codec.fused_pack_dest(
                         [], [], (), n, rnd, world, bc, pid=pid,
-                        interpret=jax.default_backend() == "cpu",
+                        interpret=ctx.platform == "cpu",
                     )
             else:
                 cnt = _sh.bucket_counts(pid, world)
@@ -3785,7 +3770,7 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
                     )
                 moved, total = _codec.fused_compact_move(
                     jnp.concatenate(parts, axis=1), recv_counts, world, bc,
-                    interpret=jax.default_backend() == "cpu",
+                    interpret=ctx.platform == "cpu",
                 )
                 nw = lane_rows.shape[1]
                 word_lanes = [moved[:, j] for j in range(nw)]

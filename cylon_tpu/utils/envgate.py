@@ -334,7 +334,9 @@ COMPILE_EFFORT = EnvKnob(
 )
 COMPILE_CACHE = EnvKnob(
     "CYLON_TPU_COMPILE_CACHE", "", kind="startup",
-    note="persistent XLA compile cache location (context init)",
+    note="=0 opts accelerator contexts out of the default persistent "
+    "compile cache (<checkout>/.jax_cache); JAX_COMPILATION_CACHE_DIR "
+    "places the cache, this knob never does",
 )
 
 # -- self-tuning execution (obs/store.py + plan/feedback.py; the

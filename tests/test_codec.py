@@ -298,11 +298,15 @@ def test_f64_passthrough_lane_vs_oracle(ctx4, rng):
 def test_resolver_ladder_and_tag():
     os.environ.pop("CYLON_TPU_CODEC_IMPL", None)
     os.environ.pop("CYLON_TPU_NO_PALLAS_CODEC", None)
-    assert pc.resolved_impl() == "pallas"
+    # the auto default selects no kernel the TPU compiler refuses: XLA on
+    # every platform until a pack kernel lowers (ops/pallas_codec.py)
+    assert pc.resolved_impl() == "xla"
+    assert pc.kernel_kwargs() == {}
     os.environ["CYLON_TPU_CODEC_IMPL"] = "xla"
     assert pc.resolved_impl() == "xla"
     tag_x = pc.impl_tag()
     os.environ["CYLON_TPU_CODEC_IMPL"] = "pallas"
+    assert pc.resolved_impl() == "pallas"
     tag_p = pc.impl_tag()
     assert tag_x != tag_p and tag_x[0] == "codec_impl"
     os.environ.pop("CYLON_TPU_CODEC_IMPL", None)

@@ -43,8 +43,11 @@ def ctx4(devices):
 def _norm(df):
     out = df.copy()
     for c in out.columns:
-        if out[c].dtype == object:
-            out[c] = out[c].map(lambda v: "\x00null" if v is None else str(v))
+        # pandas 3 gives string columns the ``str`` dtype, older ones ``object``
+        if out[c].dtype == object or pd.api.types.is_string_dtype(out[c].dtype):
+            out[c] = out[c].astype(object).map(
+                lambda v: "\x00null" if pd.isna(v) else str(v)
+            )
         else:
             out[c] = out[c].astype(np.float64)
     out = out.fillna(-1e30)

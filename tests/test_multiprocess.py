@@ -107,18 +107,6 @@ def _free_port() -> int:
     return p
 
 
-@pytest.mark.xfail(
-    reason=(
-        "jax 0.4.37's CPU backend cannot run multi-process collectives: "
-        "worker ranks fail with 'Multiprocess computations aren't "
-        "implemented on the CPU backend'. Fixed upstream by the "
-        "cross-host CPU collectives (Gloo) work in newer jax; on real "
-        "TPU pods the same code path is exercised by the MULTICHIP "
-        "dryruns. Pre-seed failure, unchanged since PR 1 — xfail so "
-        "tier-1 reports fully green and real regressions are unmissable."
-    ),
-    strict=False,
-)
 def test_two_process_distributed_ops(tmp_path):
     port = _free_port()
     env = dict(os.environ)

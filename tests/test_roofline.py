@@ -1,5 +1,5 @@
 """benchmarks/roofline.py analyzer sanity: primitive counting and traffic
-math on known-shape programs (the model feeds BENCH_TPU.md's %membw column,
+math on known-shape programs (the model feeds the bench's %membw column,
 so its bookkeeping needs a regression net)."""
 import os
 import sys

@@ -10,6 +10,7 @@ generation still queryable and the state arena rolled back.
 import os
 
 import numpy as np
+import pandas as pd
 import pytest
 
 import cylon_tpu as ct
@@ -57,10 +58,15 @@ def _batch(rng, n, null_p=0.1):
     }
 
 
+def _is_str(col):
+    # pandas 3 gives string columns the ``str`` dtype, older ones ``object``
+    return col.dtype == object or pd.api.types.is_string_dtype(col.dtype)
+
+
 def _canon(t):
     df = t.to_pandas()
     for c in df.columns:
-        if df[c].dtype == object:
+        if _is_str(df[c]):
             df[c] = df[c].fillna("\x00<null>")
     return df.sort_values(list(df.columns)).reset_index(drop=True)
 
@@ -74,7 +80,7 @@ def _assert_equal(got, want):
     assert len(a) == len(b), f"{len(a)} rows != oracle {len(b)}"
     for c in a.columns:
         av, bv = a[c].to_numpy(), b[c].to_numpy()
-        if a[c].dtype == object:
+        if _is_str(a[c]):
             assert (av == bv).all(), f"column {c} mismatch"
         else:
             np.testing.assert_array_equal(av, bv, err_msg=f"column {c}")

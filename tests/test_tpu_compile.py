@@ -1,0 +1,272 @@
+"""Compiles of the main path for a described TPU v5e, without the chip.
+
+The TPU's compiler is installed beside the CPU backend and compiles for a
+topology that is described, not attached. Nothing here runs: a compile that
+passes says the chip's compiler takes the program, never that it is right
+or fast (``chip_smoke.py`` on the chip says that). These tests guard what
+the bring-up established:
+
+- every program the default path is made of lowers for v5e, on one chip and
+  on the 2x2 mesh;
+- the fused compact kernel lowers as a Mosaic kernel; the fused pack kernel
+  and the Pallas radix pass are refused, with the compiler's own message —
+  which is why ``auto`` resolves both codec stages to XLA
+  (ops/pallas_codec.py) and why a forced kernel raises on a TPU mesh.
+
+This is the only file that describes a TPU topology, and it does so only
+inside the module-scoped ``topo`` fixture: one process at a time may load
+the TPU's library, so the call must not run while any module is imported
+(each pytest worker imports every test file) and the compiles run in this
+process, never in a child.
+"""
+from functools import partial
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from cylon_tpu.ops import groupby as _g
+from cylon_tpu.ops import join as _j
+from cylon_tpu.ops import pallas_codec as _codec
+from cylon_tpu.ops import pallas_radix as _pr
+from cylon_tpu.ops import partition as _p
+from cylon_tpu.ops import radix as _radix
+from cylon_tpu.ops import sort as _sort
+from cylon_tpu.parallel import shuffle as _sh
+from cylon_tpu.parallel.pipeline import make_distributed_join_step
+
+#: rows of the single-kernel compiles (the issue's real width)
+ROWS = 1 << 20
+#: rows a shard of the whole-program compiles: the smallest capacity that
+#: keeps every stage of the program (sorts, scans, gathers, collectives)
+STEP_ROWS = 1 << 13
+WORLD = 4
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The described v5e 2x2 host, with the persistent compile cache off:
+    a compile for described devices is written to the cache but cannot be
+    read back without a chip, so the next run would warn and recompile."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler here: nothing to guard
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh1(topo):
+    return Mesh(np.array(topo.devices[:1]), ("dp",))
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.array(topo.devices[:WORLD]), ("dp",))
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *specs):
+    return jax.jit(fn).lower(*specs).compile()
+
+
+# ----------------------------------------------------------------------
+# (a) the fused compact kernel lowers as a Mosaic kernel
+# ----------------------------------------------------------------------
+
+def test_fused_compact_move_lowers_for_tpu(one_chip):
+    bc = 4096
+    fn = partial(
+        _codec.fused_compact_move, world=WORLD, bucket_cap=bc, interpret=False
+    )
+    compiled = _compile(
+        fn,
+        _spec((WORLD * bc, 3), jnp.int32, one_chip),
+        _spec((WORLD,), jnp.int32, one_chip),
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ----------------------------------------------------------------------
+# (b) the XLA pack chain the default shuffle sends with
+# ----------------------------------------------------------------------
+
+def test_xla_pack_chain_compiles_for_tpu(one_chip):
+    bc = ROWS // WORLD
+
+    def pack(key, n, rnd):
+        pid = _p.hash_partition_ids([(key, None)], n, WORLD)
+        cnt = _sh.bucket_counts(pid, WORLD)
+        dest, leftover = _sh.build_send_slots_round(pid, cnt, WORLD, bc, rnd)
+        return dest, cnt, leftover
+
+    compiled = _compile(
+        pack,
+        _spec((ROWS,), jnp.int32, one_chip),
+        _spec((), jnp.int32, one_chip),
+        _spec((), jnp.int32, one_chip),
+    )
+    # the default pack is plain XLA: no Pallas kernel, interpreted or not
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+# ----------------------------------------------------------------------
+# (c) the two sort engines
+# ----------------------------------------------------------------------
+
+def test_radix_pass_compiles_for_tpu(one_chip):
+    fn = partial(_radix.radix_pass, shift=0, bits=_radix.RADIX_BITS)
+    _compile(
+        fn,
+        _spec((ROWS,), jnp.uint32, one_chip),
+        _spec((ROWS,), jnp.int32, one_chip),
+    )
+
+
+def test_bitonic_lexsort_compiles_for_tpu(one_chip):
+    def lexsort(key):
+        with _radix.disabled():  # the chained lax.sort path
+            return _sort.lexsort_indices([key], ROWS)
+
+    _compile(lexsort, _spec((ROWS,), jnp.int32, one_chip))
+
+
+# ----------------------------------------------------------------------
+# (d), (e) the local sort join at both dtype widths; (g) the distributed
+# join step on the four-chip mesh
+# ----------------------------------------------------------------------
+
+def _join_step_specs(mesh, key_dtype, val_dtype):
+    world = mesh.size
+    rows = NamedSharding(mesh, PartitionSpec("dp"))
+    cols = [
+        (_spec((world * STEP_ROWS,), key_dtype, rows), None),
+        (_spec((world * STEP_ROWS,), val_dtype, rows), None),
+    ]
+    counts = _spec((world,), jnp.int32, rows)
+    return (cols, counts, cols, counts), ()
+
+
+@pytest.mark.parametrize(
+    "key_dtype,val_dtype",
+    [(jnp.int32, jnp.float32), (jnp.int64, jnp.float64)],
+    ids=["int32-float32", "int64-float64"],
+)
+def test_local_sort_join_compiles_for_tpu(mesh1, key_dtype, val_dtype):
+    step = make_distributed_join_step(
+        mesh1, "dp", (0,), (0,), _j.INNER,
+        bucket_cap=STEP_ROWS, join_cap=2 * STEP_ROWS,
+    )
+    compiled = step.lower(*_join_step_specs(mesh1, key_dtype, val_dtype)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_distributed_join_step_compiles_for_four_chips(mesh4):
+    step = make_distributed_join_step(
+        mesh4, "dp", (0,), (0,), _j.INNER,
+        bucket_cap=STEP_ROWS, join_cap=2 * STEP_ROWS,
+    )
+    compiled = step.lower(
+        *_join_step_specs(mesh4, jnp.int32, jnp.float32)
+    ).compile()
+    text = compiled.as_text()
+    assert "all-to-all" in text  # the rows cross chips
+    assert "tpu_custom_call" not in text
+    mem = compiled.memory_analysis()
+    assert 0 < mem.temp_size_in_bytes < 16 * 2**30  # a v5e chip's HBM
+
+
+def test_range_partition_compiles_for_four_chips(mesh4):
+    """The sample-sort's partitioner (distributed_sort, world > 1): its
+    extrema cross chips as float64 under x64, and the TPU compiler lowers
+    a 64-bit all-reduce only for sums — pmin/pmax there were refused."""
+    rows = NamedSharding(mesh4, PartitionSpec("dp"))
+
+    def kern(val, counts):
+        return _p.range_partition_ids(
+            (val, None), counts[0], WORLD, axis_name="dp"
+        )
+
+    step = jax.jit(jax.shard_map(
+        kern, mesh=mesh4, in_specs=PartitionSpec("dp"),
+        out_specs=PartitionSpec("dp"),
+    ))
+    step.lower(
+        _spec((WORLD * ROWS,), jnp.float32, rows),
+        _spec((WORLD,), jnp.int32, rows),
+    ).compile()
+
+
+# ----------------------------------------------------------------------
+# (f) group-by: factorize + segment-sum
+# ----------------------------------------------------------------------
+
+def test_groupby_segment_sum_compiles_for_tpu(one_chip):
+    def groupby_sum(key, val, n):
+        ids, n_groups = _g.group_ids([(key, None)], n, ROWS)
+        out, _valid = _g.aggregate_column(
+            _g.agg_op_id("sum"), val, None, ids, n_groups, ROWS
+        )
+        return out, n_groups
+
+    _compile(
+        groupby_sum,
+        _spec((ROWS,), jnp.int32, one_chip),
+        _spec((ROWS,), jnp.float32, one_chip),
+        _spec((), jnp.int32, one_chip),
+    )
+
+
+# ----------------------------------------------------------------------
+# (h) a forced Pallas kernel that does not lower raises the compiler's
+# error for a TPU device: the call sites pass interpret=False on a TPU
+# mesh (engine.mesh_platform / the context's mesh), so forcing never
+# declines to XLA or to interpret mode there
+# ----------------------------------------------------------------------
+
+def test_forced_pallas_pack_raises_for_tpu(one_chip):
+    def pack(pid, n):
+        return _codec.fused_pack_dest(
+            [], [], (), n, 0, WORLD, ROWS // WORLD, pid=pid, interpret=False
+        )
+
+    with pytest.raises(Exception, match="block shape"):
+        _compile(
+            pack,
+            _spec((ROWS,), jnp.int32, one_chip),
+            _spec((), jnp.int32, one_chip),
+        )
+
+
+def test_forced_pallas_radix_pass_raises_for_tpu(one_chip):
+    fn = partial(
+        _pr.radix_pass_pallas, shift=0, bits=_radix.PALLAS_RADIX_BITS,
+        interpret=False,
+    )
+    with pytest.raises(Exception, match="block shape"):
+        _compile(
+            fn,
+            _spec((ROWS,), jnp.uint32, one_chip),
+            _spec((ROWS,), jnp.int32, one_chip),
+        )

@@ -6,7 +6,8 @@ script is the equivalent of the reference's build step: run it once on a
 fresh machine (or bake it into an image) and the hot op set — the
 speculative join, the two-phase probe/emit, fused join, sort, set ops,
 groupby — is already in the persistent cache
-(`~/.cache/cylon_tpu/xla_cache`, context.py) for every pow2 capacity
+(`JAX_COMPILATION_CACHE_DIR`, else `<checkout>/.jax_cache`; context.py)
+for every pow2 capacity
 bucket requested, so first user calls compile-warm.
 
 Capacities are pow2-rounded by the engine (shape bucketing), so warming
