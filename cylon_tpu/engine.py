@@ -20,8 +20,9 @@ from __future__ import annotations
 
 import contextvars
 import functools
+import re
 import threading
-from typing import Any, Callable, NamedTuple, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import numpy as np
@@ -29,6 +30,7 @@ from jax.sharding import PartitionSpec
 
 from .compat import shard_map
 from .context import CylonContext
+from .obs import stages as _stages
 from .utils.tracing import bump
 
 # kernel-invocation recording for roofline analysis (benchmarks/roofline.py):
@@ -82,15 +84,12 @@ def record_dispatch(fn, *args, key=None) -> None:
     Records SHAPES, not the live arrays: pinning every dispatched kernel's
     inputs for a whole op chain would hold intermediates XLA otherwise
     frees, inflating peak HBM exactly on the big TPU runs the recorder
-    exists to model."""
+    exists to model. The spec (with shardings and weak types, so that it
+    lowers to the program that ran) is built by ``obs.stages.arg_spec``,
+    the rule's one copy, shared with the stage table's registry."""
     if _KERNEL_RECORD is None:
         return
-    spec = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
-        if hasattr(x, "shape") and hasattr(x, "dtype")
-        else x,
-        args,
-    )
+    spec = _stages.arg_spec(args)
     # lint: guarded=gil -- list.append is GIL-atomic and the recorder is a
     # single-threaded bench/analysis harness, never enabled while serving
     _KERNEL_RECORD.append((key, fn, spec))
@@ -115,10 +114,25 @@ def mesh_platform() -> str:
     return _MESH_PLATFORM.get() or jax.devices()[0].platform
 
 
-def on_mesh(mesh, kernel: Callable) -> Callable:
-    """Wrap ``kernel`` so its body sees ``mesh_platform()`` of ``mesh``.
-    The body of a jitted function runs only while it is traced, so the
-    wrapper costs nothing per dispatch."""
+_NOT_IDENT = re.compile(r"[^A-Za-z0-9_]")
+
+
+def program_name(key: Tuple, name: Optional[str] = None) -> str:
+    """What a program is called in a trace (``jit_<name>`` on the device's
+    ``XLA Modules`` line, ``PjitFunction(<name>)`` on the host): ``name``,
+    or the cache key's first string part, cut to ``[A-Za-z0-9_]``. The
+    name is also part of the persistent compile cache's key."""
+    if name is None:
+        name = next((p for p in key if isinstance(p, str)), "kern")
+    return _NOT_IDENT.sub("_", name) or "kern"
+
+
+def on_mesh(mesh, kernel: Callable, name: Optional[str] = None) -> Callable:
+    """Wrap ``kernel`` so its body sees ``mesh_platform()`` of ``mesh``,
+    and call the wrapper ``name`` (``jax.jit`` names a program after the
+    function it is given; every builder's is ``kern``). The body of a
+    jitted function runs only while it is traced, so the wrapper costs
+    nothing per dispatch."""
     platform = mesh.devices.flat[0].platform
 
     @functools.wraps(kernel)
@@ -129,6 +143,8 @@ def on_mesh(mesh, kernel: Callable) -> Callable:
         finally:
             _MESH_PLATFORM.reset(token)
 
+    if name is not None:
+        traced.__name__ = traced.__qualname__ = name
     return traced
 
 
@@ -151,8 +167,12 @@ def get_kernel(
     builder: Callable[[], Callable],
     check_vma: bool = True,
     use_shard_map: bool = True,
+    name: Optional[str] = None,
 ) -> Callable:
     """Fetch (or build+jit) the shard_map-wrapped kernel for this context.
+
+    ``name`` is what the program does (``join_spec``, ``shuffle_pack``);
+    the default is the key's first string part (:func:`program_name`).
 
     ``check_vma=False`` disables shard_map's varying-mesh-axes checker —
     needed by kernels embedding ``pallas_call`` (its output vma interplay
@@ -165,7 +185,12 @@ def get_kernel(
     The kernel body runs with :func:`mesh_platform` set to the platform of
     the context's devices, so code deep inside it (the sort engine's
     Pallas tier) can pick interpret mode from the MESH, not from the
-    process's default backend."""
+    process's default backend.
+
+    On a cache MISS the callable handed back is a one-shot wrapper that
+    registers the first call's argument spec for the device stage table
+    (``obs.stages.register_dispatch``); every later call gets the bare
+    jitted function, so a warm dispatch pays nothing."""
     cache = ctx.__dict__.get("_jit_cache")
     if cache is None:
         with cache_lock(ctx):
@@ -176,11 +201,14 @@ def get_kernel(
     # the hot path stays lock-cheap: a dict read is GIL-atomic, and an
     # entry is published only AFTER it is fully built (under the lock)
     fn = cache.get(key)
+    missed = False
     if fn is None:
         with cache_lock(ctx):
             fn = cache.get(key)  # double-check: lost the build race
             if fn is None:
-                kernel = on_mesh(ctx.mesh, builder())
+                kernel = on_mesh(
+                    ctx.mesh, builder(), program_name(key, name)
+                )
                 if use_shard_map:
                     fn = jax.jit(
                         shard_map(
@@ -197,14 +225,17 @@ def get_kernel(
                 else:
                     fn = jax.jit(kernel)
                 cache[key] = fn
-    if _KERNEL_RECORD is None:
+                missed = True
+    if _KERNEL_RECORD is None and not missed:
         return fn
 
-    def recording(*args, _fn=fn, _key=key):
+    def noting(*args, _fn=fn, _key=key):
+        if missed:
+            _stages.register_dispatch(ctx, _key, _fn, args)
         record_dispatch(_fn, *args, key=_key)
         return _fn(*args)
 
-    return recording
+    return noting
 
 
 def run(ctx: CylonContext, key: Tuple, builder, dp_args, rep_args=()):

@@ -44,7 +44,7 @@ pre-existing call site (``span``/``bump``/``gauge``/``report``/...)
 keeps working, and the process-global rollup keeps feeding the
 graft-lint plan registry (``analysis/plans.py``) unchanged.
 """
-from . import export, metrics, prof, resource, slo, store, trace  # noqa: F401
+from . import export, metrics, prof, resource, slo, stages, store, trace  # noqa: F401
 from .metrics import (  # noqa: F401
     fingerprint_key,
     latency_quantiles,

@@ -48,6 +48,8 @@ import time
 from contextvars import ContextVar
 from typing import Any, Dict, Iterator, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from ..utils import envgate as _eg
 from . import export as _export
 from . import metrics as _metrics
@@ -236,36 +238,31 @@ def span(name: str, rows: Optional[int] = None, **attrs) -> Iterator[Optional[Sp
     per-op-chain trace at the outermost span) also records a tree node
     and yields it so the caller can attach attrs."""
     q = _ACTIVE.get()
-    if q is None and not tracing_active():
-        # disabled fast path: rollup only, nothing allocated
-        t0 = time.perf_counter()
-        try:
-            yield None
-        finally:
-            dt = time.perf_counter() - t0
-            _metrics.rollup_span(name, dt, rows)
-            if trace_enabled():
-                extra = f" rows={rows}" if rows is not None else ""
-                print(
-                    f"[cylon_tpu] {name}: {dt * 1e3:.2f} ms{extra}",
-                    file=sys.stderr,
-                )
-        return
-    token = None
-    if q is None:
+    token = sp = None
+    if q is None and tracing_active():
         # outermost span of an eager op chain: implicit per-chain trace
         q = QueryTrace(name, kind="op")
         token = _ACTIVE.set(q)
-    sp = q._open(name, rows, attrs)
+    if q is not None:
+        sp = q._open(name, rows, attrs)
+    t0 = time.perf_counter()
     try:
-        yield sp
+        # inside a jax.profiler session the span is a host event on the
+        # device trace's clock; outside one a TraceMe is a flag test
+        with TraceAnnotation(name):
+            yield sp
     finally:
-        q._close(sp)
-        _metrics.rollup_span(name, sp.dur_s(), rows)
+        if sp is None:
+            # disabled fast path: rollup only, nothing allocated
+            dt = time.perf_counter() - t0
+        else:
+            q._close(sp)
+            dt = sp.dur_s()
+        _metrics.rollup_span(name, dt, rows)
         if trace_enabled():
             extra = f" rows={rows}" if rows is not None else ""
             print(
-                f"[cylon_tpu] {name}: {sp.dur_s() * 1e3:.2f} ms{extra}",
+                f"[cylon_tpu] {name}: {dt * 1e3:.2f} ms{extra}",
                 file=sys.stderr,
             )
         if token is not None:

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import stages as _stages
 from .factorize import factorize
 from .sort import KeyCol, rows_differ, wide_float, wide_int, lexsort_indices
 
@@ -99,15 +100,22 @@ def _masked(values: jax.Array, valid: Optional[jax.Array], fill) -> jax.Array:
 
 
 def _seg_sum(vals, ids, cap_out):
-    return jnp.zeros((cap_out,), vals.dtype).at[ids].add(vals, mode="drop")
+    with jax.named_scope(_stages.GROUPBY_SEGMENT_SUM):
+        return jnp.zeros((cap_out,), vals.dtype).at[ids].add(vals, mode="drop")
 
 
 def _seg_min(vals, ids, cap_out, init):
-    return jnp.full((cap_out,), init, vals.dtype).at[ids].min(vals, mode="drop")
+    with jax.named_scope(_stages.GROUPBY_SEGMENT_SUM):
+        return jnp.full((cap_out,), init, vals.dtype).at[ids].min(
+            vals, mode="drop"
+        )
 
 
 def _seg_max(vals, ids, cap_out, init):
-    return jnp.full((cap_out,), init, vals.dtype).at[ids].max(vals, mode="drop")
+    with jax.named_scope(_stages.GROUPBY_SEGMENT_SUM):
+        return jnp.full((cap_out,), init, vals.dtype).at[ids].max(
+            vals, mode="drop"
+        )
 
 
 def _type_extrema(dtype):

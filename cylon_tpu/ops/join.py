@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import stages as _stages
 from . import radix as _radix
 from .factorize import factorize_two
 from .sort import KeyCol
@@ -93,33 +94,34 @@ def _merged_counts(
         sentinel_compact,
     )
 
-    keys = jnp.concatenate([r_ids, l_ids])  # rights FIRST (tie order matters)
-    pay = jnp.arange(cap_r + cap_l, dtype=jnp.int32)
-    skey, spay = _radix.kv_sort(keys, pay, _ids_hint(keys, cap_r + cap_l))
-    is_r_live = spay < nr
-    is_l = spay >= cap_r
-    rl = is_r_live.astype(jnp.int32)
-    r_excl = jnp.cumsum(rl) - rl  # live rights strictly before each position
-    new_run = jnp.concatenate([jnp.ones((1,), bool), skey[1:] != skey[:-1]])
-    lo_run = run_start_broadcast(new_run, r_excl)  # r_excl @ run start
-    cnt_p = run_count_upto(new_run, is_r_live)  # live rights in run up to p
-    big = jnp.int32(2**31 - 1)
-    lo_c, cnt_c = sentinel_compact(
-        jnp.where(is_l, spay, big), [lo_run, cnt_p]
-    )
-    idx_l = jnp.arange(cap_l, dtype=jnp.int32)
-    lo = lo_c[:cap_l]
-    cnt = jnp.where(idx_l < nl, cnt_c[:cap_l], 0)
-    if not need_rcnt:
-        return lo, cnt, jnp.zeros((cap_r,), jnp.int32)
-    # lefts come after rights within a run, so counting "at/after me" from
-    # a right position sees exactly the run's live lefts
-    is_l_live = is_l & (spay < cap_r + nl)
-    rcnt_p = run_count_from(new_run, is_l_live)
-    (rcnt_c,) = sentinel_compact(jnp.where(~is_l, spay, big), [rcnt_p])
-    idx_r = jnp.arange(cap_r, dtype=jnp.int32)
-    r_cnt = jnp.where(idx_r < nr, rcnt_c[:cap_r], 0)
-    return lo, cnt, r_cnt
+    with jax.named_scope(_stages.JOIN_PROBE):
+        keys = jnp.concatenate([r_ids, l_ids])  # rights FIRST (tie order matters)
+        pay = jnp.arange(cap_r + cap_l, dtype=jnp.int32)
+        skey, spay = _radix.kv_sort(keys, pay, _ids_hint(keys, cap_r + cap_l))
+        is_r_live = spay < nr
+        is_l = spay >= cap_r
+        rl = is_r_live.astype(jnp.int32)
+        r_excl = jnp.cumsum(rl) - rl  # live rights strictly before each position
+        new_run = jnp.concatenate([jnp.ones((1,), bool), skey[1:] != skey[:-1]])
+        lo_run = run_start_broadcast(new_run, r_excl)  # r_excl @ run start
+        cnt_p = run_count_upto(new_run, is_r_live)  # live rights in run up to p
+        big = jnp.int32(2**31 - 1)
+        lo_c, cnt_c = sentinel_compact(
+            jnp.where(is_l, spay, big), [lo_run, cnt_p]
+        )
+        idx_l = jnp.arange(cap_l, dtype=jnp.int32)
+        lo = lo_c[:cap_l]
+        cnt = jnp.where(idx_l < nl, cnt_c[:cap_l], 0)
+        if not need_rcnt:
+            return lo, cnt, jnp.zeros((cap_r,), jnp.int32)
+        # lefts come after rights within a run, so counting "at/after me" from
+        # a right position sees exactly the run's live lefts
+        is_l_live = is_l & (spay < cap_r + nl)
+        rcnt_p = run_count_from(new_run, is_l_live)
+        (rcnt_c,) = sentinel_compact(jnp.where(~is_l, spay, big), [rcnt_p])
+        idx_r = jnp.arange(cap_r, dtype=jnp.int32)
+        r_cnt = jnp.where(idx_r < nr, rcnt_c[:cap_r], 0)
+        return lo, cnt, r_cnt
 
 
 def _key_order_emit(
@@ -159,45 +161,47 @@ def _key_order_emit(
     from .gather import pack_gather
     from .sort import run_count_upto, run_start_broadcast
 
-    cap_cat = cap_r + cap_l
-    keys = jnp.concatenate([r_ids, l_ids])  # rights FIRST (tie order matters)
-    pay = jnp.arange(cap_cat, dtype=jnp.int32)
-    skey, spay = _radix.kv_sort(keys, pay, _ids_hint(keys, cap_cat))
-    is_l = spay >= cap_r
-    is_l_live = is_l & (spay < cap_r + nl)
-    is_r_live = (~is_l) & (spay < nr)
-    rl = is_r_live.astype(jnp.int32)
-    r_excl = jnp.cumsum(rl) - rl
-    new_run = jnp.concatenate([jnp.ones((1,), bool), skey[1:] != skey[:-1]])
-    lo_run = run_start_broadcast(new_run, r_excl)
-    cnt_p = run_count_upto(new_run, is_r_live)
-    cnt = jnp.where(is_l_live, cnt_p, 0)
-    shadow = jnp.sum(cnt.astype(jnp.float32))
-    if how == LEFT:
-        cnt_adj = jnp.where(is_l_live & (cnt == 0), 1, cnt)
-    else:
-        cnt_adj = cnt
-    ends = jnp.cumsum(cnt_adj)
-    offs = ends - cnt_adj
-    total = ends[-1].astype(jnp.int32)
-    base = lo_run - offs
+    with jax.named_scope(_stages.JOIN_PROBE):
+        cap_cat = cap_r + cap_l
+        keys = jnp.concatenate([r_ids, l_ids])  # rights FIRST (tie order matters)
+        pay = jnp.arange(cap_cat, dtype=jnp.int32)
+        skey, spay = _radix.kv_sort(keys, pay, _ids_hint(keys, cap_cat))
+        is_l = spay >= cap_r
+        is_l_live = is_l & (spay < cap_r + nl)
+        is_r_live = (~is_l) & (spay < nr)
+        rl = is_r_live.astype(jnp.int32)
+        r_excl = jnp.cumsum(rl) - rl
+        new_run = jnp.concatenate([jnp.ones((1,), bool), skey[1:] != skey[:-1]])
+        lo_run = run_start_broadcast(new_run, r_excl)
+        cnt_p = run_count_upto(new_run, is_r_live)
+        cnt = jnp.where(is_l_live, cnt_p, 0)
+        shadow = jnp.sum(cnt.astype(jnp.float32))
+        if how == LEFT:
+            cnt_adj = jnp.where(is_l_live & (cnt == 0), 1, cnt)
+        else:
+            cnt_adj = cnt
+        ends = jnp.cumsum(cnt_adj)
+        offs = ends - cnt_adj
+        total = ends[-1].astype(jnp.int32)
+        base = lo_run - offs
 
-    li = _repeat_ss(ends, cap_out)  # sorted-space position per output row
-    out_pos = jnp.arange(cap_out, dtype=jnp.int32)
-    in_out = out_pos < total
-    li = jnp.where(in_out, li, -1)
-    safe_li = jnp.clip(li, 0, cap_cat - 1)
-    book = jnp.stack(
-        [base, cnt, spay - jnp.int32(cap_r)], axis=1
-    )[safe_li]  # one narrow [cap_out, 3] gather
-    base_g, cnt_g, orig_g = book[:, 0], book[:, 1], book[:, 2]
-    orig_li = jnp.where(li >= 0, orig_g, -1)
-    out_l, _ = pack_gather(l_cols, orig_li, all_valid=True)
+    with jax.named_scope(_stages.JOIN_EMIT):
+        li = _repeat_ss(ends, cap_out)  # sorted-space position per output row
+        out_pos = jnp.arange(cap_out, dtype=jnp.int32)
+        in_out = out_pos < total
+        li = jnp.where(in_out, li, -1)
+        safe_li = jnp.clip(li, 0, cap_cat - 1)
+        book = jnp.stack(
+            [base, cnt, spay - jnp.int32(cap_r)], axis=1
+        )[safe_li]  # one narrow [cap_out, 3] gather
+        base_g, cnt_g, orig_g = book[:, 0], book[:, 1], book[:, 2]
+        orig_li = jnp.where(li >= 0, orig_g, -1)
+        out_l, _ = pack_gather(l_cols, orig_li, all_valid=True)
 
-    has_match = in_out & (cnt_g > 0)
-    rpos = jnp.where(has_match, jnp.clip(base_g + out_pos, 0, cap_r - 1), -1)
-    out_r, _ = pack_gather(r_sorted_cols, rpos)
-    return list(out_l) + list(out_r), total, shadow
+        has_match = in_out & (cnt_g > 0)
+        rpos = jnp.where(has_match, jnp.clip(base_g + out_pos, 0, cap_r - 1), -1)
+        out_r, _ = pack_gather(r_sorted_cols, rpos)
+        return list(out_l) + list(out_r), total, shadow
 
 
 def impl_tag() -> tuple:
@@ -308,41 +312,53 @@ def _canonical_ids(
     ``fuse``: stats-driven sort-word fusion plan for the factorize lanes
     (Table.join derives it from both sides' merged range stats); the
     single-uint32-key fast path is already one lane and ignores it."""
-    idx_l = jnp.arange(cap_l, dtype=jnp.int32)
-    idx_r = jnp.arange(cap_r, dtype=jnp.int32)
-    # promote key dtypes to a common type first: orderable_key lanes are only
-    # comparable within one dtype (int32 vs uint32 canonicalize differently)
-    if (
-        len(l_key_cols) == 1
-        and len(r_key_cols) == 1
-        and l_key_cols[0][0].dtype != r_key_cols[0][0].dtype
-    ):
-        from ..dtypes import promote_key_dtypes
+    with jax.named_scope(_stages.JOIN_KEY_IDS):
+        idx_l = jnp.arange(cap_l, dtype=jnp.int32)
+        idx_r = jnp.arange(cap_r, dtype=jnp.int32)
+        # promote key dtypes to a common type first: orderable_key lanes are only
+        # comparable within one dtype (int32 vs uint32 canonicalize differently)
+        if (
+            len(l_key_cols) == 1
+            and len(r_key_cols) == 1
+            and l_key_cols[0][0].dtype != r_key_cols[0][0].dtype
+        ):
+            from ..dtypes import promote_key_dtypes
 
-        common = promote_key_dtypes(l_key_cols[0][0].dtype, r_key_cols[0][0].dtype)
-        l_key_cols = [(l_key_cols[0][0].astype(common), l_key_cols[0][1])]
-        r_key_cols = [(r_key_cols[0][0].astype(common), r_key_cols[0][1])]
-    if _fast_path_ok(l_key_cols) and _fast_path_ok(r_key_cols):
-        # Single <=32-bit key, no nulls: stay entirely in uint32 (no int64
-        # emulation on TPU). Padding rows take the value UINT32_MAX; because
-        # tables are front-packed (padding indices >= n) and the merged sort
-        # is stable, live rows with a real MAX key still sort BEFORE padding
-        # inside the equal run, and _merged_counts counts live rights only.
-        from .sort import orderable_key
+            common = promote_key_dtypes(l_key_cols[0][0].dtype, r_key_cols[0][0].dtype)
+            l_key_cols = [(l_key_cols[0][0].astype(common), l_key_cols[0][1])]
+            r_key_cols = [(r_key_cols[0][0].astype(common), r_key_cols[0][1])]
+        if _fast_path_ok(l_key_cols) and _fast_path_ok(r_key_cols):
+            # Single <=32-bit key, no nulls: stay entirely in uint32 (no int64
+            # emulation on TPU). Padding rows take the value UINT32_MAX; because
+            # tables are front-packed (padding indices >= n) and the merged sort
+            # is stable, live rows with a real MAX key still sort BEFORE padding
+            # inside the equal run, and _merged_counts counts live rights only.
+            from .sort import orderable_key
 
-        MAXU = np.uint32(0xFFFFFFFF)
-        lk = orderable_key(l_key_cols[0][0])
-        rk = orderable_key(r_key_cols[0][0])
-        l_ids = jnp.where(idx_l < nl, lk, MAXU)
-        r_ids = jnp.where(idx_r < nr, rk, MAXU)
-    else:
-        l_ids, r_ids, _ = factorize_two(
-            l_key_cols, r_key_cols, nl, nr, cap_l, cap_r, fuse=fuse
-        )
-        big = jnp.int32(cap_l + cap_r)  # sorts after every live dense id
-        l_ids = jnp.where(idx_l < nl, l_ids, big)
-        r_ids = jnp.where(idx_r < nr, r_ids, big)
-    return l_ids, r_ids
+            MAXU = np.uint32(0xFFFFFFFF)
+            lk = orderable_key(l_key_cols[0][0])
+            rk = orderable_key(r_key_cols[0][0])
+            l_ids = jnp.where(idx_l < nl, lk, MAXU)
+            r_ids = jnp.where(idx_r < nr, rk, MAXU)
+        else:
+            l_ids, r_ids, _ = factorize_two(
+                l_key_cols, r_key_cols, nl, nr, cap_l, cap_r, fuse=fuse
+            )
+            big = jnp.int32(cap_l + cap_r)  # sorts after every live dense id
+            l_ids = jnp.where(idx_l < nl, l_ids, big)
+            r_ids = jnp.where(idx_r < nr, r_ids, big)
+        return l_ids, r_ids
+
+
+def _right_order(r_ids: jax.Array, cap_cat: int) -> jax.Array:
+    """Stable argsort of the canonical right ids: radix where the lane is
+    eligible, else the native sort."""
+    with jax.named_scope(_stages.JOIN_RIGHT_SORT):
+        r_order = _radix.argsort_perm(r_ids, _ids_hint(r_ids, cap_cat))
+        if r_order is None:
+            with jax.named_scope(_stages.SORT_ENGINE):
+                r_order = jnp.argsort(r_ids, stable=True).astype(jnp.int32)
+        return r_order
 
 
 def _probe(
@@ -358,9 +374,7 @@ def _probe(
     l_ids, r_ids = _canonical_ids(
         l_key_cols, r_key_cols, nl, nr, cap_l, cap_r, fuse=fuse
     )
-    r_order = _radix.argsort_perm(r_ids, _ids_hint(r_ids, cap_l + cap_r))
-    if r_order is None:
-        r_order = jnp.argsort(r_ids, stable=True).astype(jnp.int32)
+    r_order = _right_order(r_ids, cap_l + cap_r)
     lo, cnt, r_cnt = _merged_counts(
         l_ids, r_ids, nl, nr, cap_l, cap_r, need_rcnt
     )
@@ -398,15 +412,16 @@ def probe_arrays(
 
 
 def count_from_probe(cnt, r_cnt, nl, nr, how: int) -> jax.Array:
-    cap_l = cnt.shape[0]
-    cap_r = r_cnt.shape[0]
-    inner = jnp.sum(cnt)
-    total = inner
-    if how in (LEFT, FULL_OUTER):
-        total = total + jnp.sum((cnt == 0) & (jnp.arange(cap_l) < nl))
-    if how in (RIGHT, FULL_OUTER):
-        total = total + jnp.sum((r_cnt == 0) & (jnp.arange(cap_r) < nr))
-    return total.astype(jnp.int32)
+    with jax.named_scope(_stages.JOIN_PROBE):
+        cap_l = cnt.shape[0]
+        cap_r = r_cnt.shape[0]
+        inner = jnp.sum(cnt)
+        total = inner
+        if how in (LEFT, FULL_OUTER):
+            total = total + jnp.sum((cnt == 0) & (jnp.arange(cap_l) < nl))
+        if how in (RIGHT, FULL_OUTER):
+            total = total + jnp.sum((r_cnt == 0) & (jnp.arange(cap_r) < nr))
+        return total.astype(jnp.int32)
 
 
 def count_overflow_check(cnt, r_cnt) -> jax.Array:
@@ -415,48 +430,50 @@ def count_overflow_check(cnt, r_cnt) -> jax.Array:
     keeps the right magnitude, so ``shadow > 2^31`` (or a negative int32
     total) detects the wrap. Outputs that large can't be allocated anyway —
     callers raise."""
-    return jnp.sum(cnt.astype(jnp.float32))
+    with jax.named_scope(_stages.JOIN_PROBE):
+        return jnp.sum(cnt.astype(jnp.float32))
 
 
 def emit_from_probe(
     lo, cnt, r_order, r_cnt, nl, nr, how: int, cap_out: int
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Phase-2: join row indices from the phase-1 probe state."""
-    cap_l = lo.shape[0]
-    cap_r = r_order.shape[0]
-    idx_l = jnp.arange(cap_l, dtype=jnp.int32)
-    live_l = idx_l < nl
-    if how in (LEFT, FULL_OUTER):
-        cnt_adj = jnp.where(live_l & (cnt == 0), 1, cnt)
-    else:
-        cnt_adj = cnt
-    ends = jnp.cumsum(cnt_adj)
-    offs = ends - cnt_adj
-    total_l = ends[-1].astype(jnp.int32)
+    with jax.named_scope(_stages.JOIN_EMIT):
+        cap_l = lo.shape[0]
+        cap_r = r_order.shape[0]
+        idx_l = jnp.arange(cap_l, dtype=jnp.int32)
+        live_l = idx_l < nl
+        if how in (LEFT, FULL_OUTER):
+            cnt_adj = jnp.where(live_l & (cnt == 0), 1, cnt)
+        else:
+            cnt_adj = cnt
+        ends = jnp.cumsum(cnt_adj)
+        offs = ends - cnt_adj
+        total_l = ends[-1].astype(jnp.int32)
 
-    li = _repeat_ss(ends, cap_out)
-    # rpos = lo[li] + (k - offs[li]) = (lo - offs)[li] + k: one gather of the
-    # precombined base instead of a second repeat + a second gather
-    base = lo - offs
-    has_match = cnt[li] > 0
-    rpos = jnp.clip(base[li] + jnp.arange(cap_out, dtype=jnp.int32), 0, cap_r - 1)
-    ri = jnp.where(has_match, r_order[rpos], -1)
-    out_pos = jnp.arange(cap_out, dtype=jnp.int32)
-    in_left_part = out_pos < total_l
-    li = jnp.where(in_left_part, li, -1)
-    ri = jnp.where(in_left_part, ri, -1)
+        li = _repeat_ss(ends, cap_out)
+        # rpos = lo[li] + (k - offs[li]) = (lo - offs)[li] + k: one gather of the
+        # precombined base instead of a second repeat + a second gather
+        base = lo - offs
+        has_match = cnt[li] > 0
+        rpos = jnp.clip(base[li] + jnp.arange(cap_out, dtype=jnp.int32), 0, cap_r - 1)
+        ri = jnp.where(has_match, r_order[rpos], -1)
+        out_pos = jnp.arange(cap_out, dtype=jnp.int32)
+        in_left_part = out_pos < total_l
+        li = jnp.where(in_left_part, li, -1)
+        ri = jnp.where(in_left_part, ri, -1)
 
-    n_out = total_l
-    if how in (RIGHT, FULL_OUTER):
-        idx_r = jnp.arange(cap_r, dtype=jnp.int32)
-        r_un = (r_cnt == 0) & (idx_r < nr)
-        r_un_rank = jnp.cumsum(r_un.astype(jnp.int32)) - 1
-        n_r_un = jnp.sum(r_un).astype(jnp.int32)
-        dest = jnp.where(r_un, total_l + r_un_rank, cap_out)
-        ri = ri.at[dest].set(idx_r, mode="drop")
-        li = li.at[dest].set(-1, mode="drop")
-        n_out = total_l + n_r_un
-    return li, ri, n_out.astype(jnp.int32)
+        n_out = total_l
+        if how in (RIGHT, FULL_OUTER):
+            idx_r = jnp.arange(cap_r, dtype=jnp.int32)
+            r_un = (r_cnt == 0) & (idx_r < nr)
+            r_un_rank = jnp.cumsum(r_un.astype(jnp.int32)) - 1
+            n_r_un = jnp.sum(r_un).astype(jnp.int32)
+            dest = jnp.where(r_un, total_l + r_un_rank, cap_out)
+            ri = ri.at[dest].set(idx_r, mode="drop")
+            li = li.at[dest].set(-1, mode="drop")
+            n_out = total_l + n_r_un
+        return li, ri, n_out.astype(jnp.int32)
 
 
 def join_count(
@@ -521,22 +538,24 @@ def emit_gather(
     from .gather import pack_gather
 
     if how in (RIGHT, FULL_OUTER):
-        li, ri, n_out = emit_from_probe(
-            lo, cnt, r_order, r_cnt, nl, nr, how, cap_out
-        )
-        out_l, _ = pack_gather(l_cols, li)
-        out_r, _ = pack_gather(r_cols, ri)
-        return out_l + out_r, n_out
+        with jax.named_scope(_stages.JOIN_EMIT):
+            li, ri, n_out = emit_from_probe(
+                lo, cnt, r_order, r_cnt, nl, nr, how, cap_out
+            )
+            out_l, _ = pack_gather(l_cols, li)
+            out_r, _ = pack_gather(r_cols, ri)
+            return out_l + out_r, n_out
 
     # permute right payload into key-sorted order once (cap_r rows).
     # r_order is a permutation (all indices >= 0), so columns that had no
     # validity mask stay mask-free — don't let the all-True ok lane ride
     # through the second (hot, cap_out-sized) gather.
-    r_sorted_cols, _ = pack_gather(r_cols, r_order)
-    r_sorted_cols = [
-        (d, None if rv is None else v)
-        for (d, v), (_, rv) in zip(r_sorted_cols, r_cols)
-    ]
+    with jax.named_scope(_stages.JOIN_RIGHT_SORT):
+        r_sorted_cols, _ = pack_gather(r_cols, r_order)
+        r_sorted_cols = [
+            (d, None if rv is None else v)
+            for (d, v), (_, rv) in zip(r_sorted_cols, r_cols)
+        ]
     return _emit_inner_left(
         lo, cnt, l_cols, r_sorted_cols, nl, how, cap_out, r_order.shape[0],
         emit_impl,
@@ -633,27 +652,28 @@ def _emit_inner_left(
             )
     from .gather import pack_gather
 
-    cap_l = lo.shape[0]
-    idx_l = jnp.arange(cap_l, dtype=jnp.int32)
-    live_l = idx_l < nl
-    if how == LEFT:
-        cnt_adj = jnp.where(live_l & (cnt == 0), 1, cnt)
-    else:
-        cnt_adj = cnt
-    ends = jnp.cumsum(cnt_adj)
-    offs = ends - cnt_adj
-    total_l = ends[-1].astype(jnp.int32)
-    base = lo - offs
+    with jax.named_scope(_stages.JOIN_EMIT):
+        cap_l = lo.shape[0]
+        idx_l = jnp.arange(cap_l, dtype=jnp.int32)
+        live_l = idx_l < nl
+        if how == LEFT:
+            cnt_adj = jnp.where(live_l & (cnt == 0), 1, cnt)
+        else:
+            cnt_adj = cnt
+        ends = jnp.cumsum(cnt_adj)
+        offs = ends - cnt_adj
+        total_l = ends[-1].astype(jnp.int32)
+        base = lo - offs
 
-    li = _repeat_ss(ends, cap_out)
-    out_pos = jnp.arange(cap_out, dtype=jnp.int32)
-    li = jnp.where(out_pos < total_l, li, -1)
-    out_l, (base_g, cnt_g) = pack_gather(l_cols, li, extra_lanes=[base, cnt])
+        li = _repeat_ss(ends, cap_out)
+        out_pos = jnp.arange(cap_out, dtype=jnp.int32)
+        li = jnp.where(out_pos < total_l, li, -1)
+        out_l, (base_g, cnt_g) = pack_gather(l_cols, li, extra_lanes=[base, cnt])
 
-    has_match = (li >= 0) & (cnt_g > 0)
-    rpos = jnp.where(has_match, jnp.clip(base_g + out_pos, 0, cap_r - 1), -1)
-    out_r, _ = pack_gather(r_sorted_cols, rpos)
-    return list(out_l) + list(out_r), total_l
+        has_match = (li >= 0) & (cnt_g > 0)
+        rpos = jnp.where(has_match, jnp.clip(base_g + out_pos, 0, cap_r - 1), -1)
+        out_r, _ = pack_gather(r_sorted_cols, rpos)
+        return list(out_l) + list(out_r), total_l
 
 
 def _emit_inner_left_windowed(
@@ -679,67 +699,68 @@ def _emit_inner_left_windowed(
     from .gather import pack_cols, pack_gather, unpack_cols
     from .pallas_gather import expand_rows_raw
 
-    impl = _eg.EXPAND_GATHER.get()
-    cap_l = lo.shape[0]
-    idx_l = jnp.arange(cap_l, dtype=jnp.int32)
-    live_l = idx_l < nl
-    if how == LEFT:
-        cnt_adj = jnp.where(live_l & (cnt == 0), 1, cnt)
-    else:
-        cnt_adj = cnt
-    emitting = live_l & (cnt_adj > 0)
-    em32 = emitting.astype(jnp.int32)
-    slot = jnp.cumsum(em32) - em32  # dense compaction slot (order-preserving)
-    dest = jnp.where(emitting, slot, cap_l)
+    with jax.named_scope(_stages.JOIN_EMIT):
+        impl = _eg.EXPAND_GATHER.get()
+        cap_l = lo.shape[0]
+        idx_l = jnp.arange(cap_l, dtype=jnp.int32)
+        live_l = idx_l < nl
+        if how == LEFT:
+            cnt_adj = jnp.where(live_l & (cnt == 0), 1, cnt)
+        else:
+            cnt_adj = cnt
+        emitting = live_l & (cnt_adj > 0)
+        em32 = emitting.astype(jnp.int32)
+        slot = jnp.cumsum(em32) - em32  # dense compaction slot (order-preserving)
+        dest = jnp.where(emitting, slot, cap_l)
 
-    plan, lanes, passthrough = pack_cols(l_cols)
-    n_payload = len(lanes)
-    lanes = list(lanes) + [lo, cnt, cnt_adj.astype(jnp.int32), idx_l]
-    packed = jnp.stack(lanes, axis=1)  # [cap_l, LA]
-    LA = packed.shape[1]
-    packed_c = jnp.zeros((cap_l, LA), jnp.int32).at[dest].set(
-        packed.astype(jnp.int32), mode="drop"
-    )
+        plan, lanes, passthrough = pack_cols(l_cols)
+        n_payload = len(lanes)
+        lanes = list(lanes) + [lo, cnt, cnt_adj.astype(jnp.int32), idx_l]
+        packed = jnp.stack(lanes, axis=1)  # [cap_l, LA]
+        LA = packed.shape[1]
+        packed_c = jnp.zeros((cap_l, LA), jnp.int32).at[dest].set(
+            packed.astype(jnp.int32), mode="drop"
+        )
 
-    cnt_adj_c = packed_c[:, n_payload + 2]
-    ends_c = jnp.cumsum(cnt_adj_c)
-    total = ends_c[-1].astype(jnp.int32)
-    offs_c = (ends_c - cnt_adj_c).astype(jnp.int32)
-    li_c = _repeat_ss(ends_c, cap_out)  # raw non-decreasing (no -1 masking)
+        cnt_adj_c = packed_c[:, n_payload + 2]
+        ends_c = jnp.cumsum(cnt_adj_c)
+        total = ends_c[-1].astype(jnp.int32)
+        offs_c = (ends_c - cnt_adj_c).astype(jnp.int32)
+        li_c = _repeat_ss(ends_c, cap_out)  # raw non-decreasing (no -1 masking)
 
-    srcT = jnp.concatenate(
-        [packed_c.T, offs_c[None, :]], axis=0
-    )  # [LA+1, cap_l]
-    # unjitted on purpose: this call site is always inside the engine's
-    # jit / jit(shard_map); wrapping the pallas_call in its own jit was the
-    # round-3 unbounded-recursion trigger under shard_map on compiled TPU
-    outT = expand_rows_raw(srcT, li_c, impl=impl, interpret=interpret)
-    g_lanes = [outT[j] for j in range(LA + 1)]
-    out_pos = jnp.arange(cap_out, dtype=jnp.int32)
-    in_out = out_pos < total
-    lo_g = g_lanes[n_payload]
-    cnt_g = g_lanes[n_payload + 1]
-    orig_g = g_lanes[n_payload + 3]
-    offs_g = g_lanes[LA]
+        srcT = jnp.concatenate(
+            [packed_c.T, offs_c[None, :]], axis=0
+        )  # [LA+1, cap_l]
+        # unjitted on purpose: this call site is always inside the engine's
+        # jit / jit(shard_map); wrapping the pallas_call in its own jit was the
+        # round-3 unbounded-recursion trigger under shard_map on compiled TPU
+        outT = expand_rows_raw(srcT, li_c, impl=impl, interpret=interpret)
+        g_lanes = [outT[j] for j in range(LA + 1)]
+        out_pos = jnp.arange(cap_out, dtype=jnp.int32)
+        in_out = out_pos < total
+        lo_g = g_lanes[n_payload]
+        cnt_g = g_lanes[n_payload + 1]
+        orig_g = g_lanes[n_payload + 3]
+        offs_g = g_lanes[LA]
 
-    def make_valid(lane):
-        return in_out if lane is None else (in_out & lane.astype(jnp.bool_))
+        def make_valid(lane):
+            return in_out if lane is None else (in_out & lane.astype(jnp.bool_))
 
-    out_l, _ = unpack_cols(
-        plan,
-        g_lanes[:n_payload],
-        # f64 columns have no int32 lane route: gather them by the expanded
-        # original row id (their validity lane rode the expand)
-        lambda ci: passthrough[ci][jnp.clip(orig_g, 0, cap_l - 1)],
-        make_valid,
-    )
+        out_l, _ = unpack_cols(
+            plan,
+            g_lanes[:n_payload],
+            # f64 columns have no int32 lane route: gather them by the expanded
+            # original row id (their validity lane rode the expand)
+            lambda ci: passthrough[ci][jnp.clip(orig_g, 0, cap_l - 1)],
+            make_valid,
+        )
 
-    has_match = in_out & (cnt_g > 0)
-    rpos = jnp.where(
-        has_match, jnp.clip(lo_g - offs_g + out_pos, 0, cap_r - 1), -1
-    )
-    out_r, _ = pack_gather(r_sorted_cols, rpos)
-    return list(out_l) + list(out_r), total
+        has_match = in_out & (cnt_g > 0)
+        rpos = jnp.where(
+            has_match, jnp.clip(lo_g - offs_g + out_pos, 0, cap_r - 1), -1
+        )
+        out_r, _ = pack_gather(r_sorted_cols, rpos)
+        return list(out_l) + list(out_r), total
 
 
 def spec_join(
@@ -796,33 +817,37 @@ def spec_join(
         from .gather import pack_gather
         from .sort import merge_ride_cols, split_ride_cols
 
-        if r_presorted:
-            # sorted-run reuse: the rows ARE the key-sorted payload
-            r_sorted = list(r_cols)
-        else:
-            ride, payloads, heavy = split_ride_cols(r_cols)
-            perm = _radix.argsort_perm(r_ids, _ids_hint(r_ids, cap_l + cap_r))
-            if perm is not None:
-                # radix: one gather per column by the final perm replaces
-                # riding every bitonic pass
-                spays = [p[perm] for p in payloads]
-                heavy_sorted = pack_gather(heavy, perm)[0] if heavy else []
-            elif heavy:
-                # carry the order only when something needs gathering by it
-                iota = jnp.arange(cap_r, dtype=jnp.int32)
-                sorted_ops = jax.lax.sort(
-                    tuple([r_ids] + payloads + [iota]),
-                    num_keys=1, is_stable=True,
-                )
-                spays = list(sorted_ops[1:-1])
-                heavy_sorted = pack_gather(heavy, sorted_ops[-1])[0]
+        with jax.named_scope(_stages.JOIN_RIGHT_SORT):
+            if r_presorted:
+                # sorted-run reuse: the rows ARE the key-sorted payload
+                r_sorted = list(r_cols)
             else:
-                sorted_ops = jax.lax.sort(
-                    tuple([r_ids] + payloads), num_keys=1, is_stable=True
-                )
-                spays = list(sorted_ops[1:])
-                heavy_sorted = []
-            r_sorted = merge_ride_cols(r_cols, ride, spays, heavy_sorted)
+                ride, payloads, heavy = split_ride_cols(r_cols)
+                perm = _radix.argsort_perm(r_ids, _ids_hint(r_ids, cap_l + cap_r))
+                if perm is not None:
+                    # radix: one gather per column by the final perm replaces
+                    # riding every bitonic pass
+                    spays = [p[perm] for p in payloads]
+                    heavy_sorted = pack_gather(heavy, perm)[0] if heavy else []
+                elif heavy:
+                    # carry the order only when something needs gathering by it
+                    iota = jnp.arange(cap_r, dtype=jnp.int32)
+                    with jax.named_scope(_stages.SORT_ENGINE):
+                        sorted_ops = jax.lax.sort(
+                            tuple([r_ids] + payloads + [iota]),
+                            num_keys=1, is_stable=True,
+                        )
+                    spays = list(sorted_ops[1:-1])
+                    heavy_sorted = pack_gather(heavy, sorted_ops[-1])[0]
+                else:
+                    with jax.named_scope(_stages.SORT_ENGINE):
+                        sorted_ops = jax.lax.sort(
+                            tuple([r_ids] + payloads),
+                            num_keys=1, is_stable=True,
+                        )
+                    spays = list(sorted_ops[1:])
+                    heavy_sorted = []
+                r_sorted = merge_ride_cols(r_cols, ride, spays, heavy_sorted)
         if emit_key_order:
             # probe + emit in one sorted-space pass, no compaction sort
             out_cols, total, shadow = _key_order_emit(
@@ -847,11 +872,7 @@ def spec_join(
         if r_presorted:
             r_order = jnp.arange(cap_r, dtype=jnp.int32)
         else:
-            r_order = _radix.argsort_perm(
-                r_ids, _ids_hint(r_ids, cap_l + cap_r)
-            )
-            if r_order is None:
-                r_order = jnp.argsort(r_ids, stable=True).astype(jnp.int32)
+            r_order = _right_order(r_ids, cap_l + cap_r)
         out_cols, n_out = emit_gather(
             lo, cnt, r_order, r_cnt, l_cols, r_cols, nl, nr, how, cap_out,
             emit_impl,

@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import stages as _stages
 from ..utils import envgate as _eg
 from ..utils.envgate import env_gate
 
@@ -351,27 +352,28 @@ def lexsort_perm(
         impl = resolved_impl()
     if impl == "bitonic":
         return None
-    planned = plan_lanes(lanes, hints)
-    if planned is None:
+    with jax.named_scope(_stages.SORT_ENGINE):
+        planned = plan_lanes(lanes, hints)
+        if planned is None:
+            from ..obs import metrics as _metrics
+
+            _metrics.rollup_count("radix.declined")
+            return None
+        perm = jnp.arange(cap, dtype=jnp.int32)
+        r = PALLAS_RADIX_BITS if impl == "radix_pallas" else RADIX_BITS
+        n_passes = 0
+        for enc, lo, hi in planned:
+            shift = lo
+            while shift < hi:
+                bits = min(r, hi - shift)
+                perm = _dispatch_pass(enc, perm, shift, bits, impl)
+                n_passes += 1
+                shift += bits
         from ..obs import metrics as _metrics
 
-        _metrics.rollup_count("radix.declined")
-        return None
-    perm = jnp.arange(cap, dtype=jnp.int32)
-    r = PALLAS_RADIX_BITS if impl == "radix_pallas" else RADIX_BITS
-    n_passes = 0
-    for enc, lo, hi in planned:
-        shift = lo
-        while shift < hi:
-            bits = min(r, hi - shift)
-            perm = _dispatch_pass(enc, perm, shift, bits, impl)
-            n_passes += 1
-            shift += bits
-    from ..obs import metrics as _metrics
-
-    # trace-time census (one bump per compile, not per execution)
-    _metrics.rollup_count("radix.trace_passes", rows=n_passes)
-    return perm
+        # trace-time census (one bump per compile, not per execution)
+        _metrics.rollup_count("radix.trace_passes", rows=n_passes)
+        return perm
 
 
 def _dispatch_pass(
@@ -412,7 +414,8 @@ def kv_sort(
 ) -> Tuple[jax.Array, jax.Array]:
     """Stable 1-key kv-sort (the join probe's merged sort): radix when
     eligible, else the native ``jax.lax.sort``. Returns (skey, spay)."""
-    perm = argsort_perm(keys, hint)
-    if perm is not None:
-        return keys[perm], pay[perm]
-    return jax.lax.sort((keys, pay), num_keys=1, is_stable=True)
+    with jax.named_scope(_stages.SORT_ENGINE):
+        perm = argsort_perm(keys, hint)
+        if perm is not None:
+            return keys[perm], pay[perm]
+        return jax.lax.sort((keys, pay), num_keys=1, is_stable=True)

@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import stages as _stages
 from ..utils.envgate import env_gate
 from .hash import hash_columns
 from .sort import KeyCol, orderable_key
@@ -221,42 +222,43 @@ def build_local(
     module doc), then [max_enc, min_enc] of the range lane. Per-shard code
     (runs under shard_map); combine across shards with
     :func:`combine_pair`."""
-    cap = cols[0][0].shape[0]
-    W = bits // 32
-    live = jnp.arange(cap, dtype=jnp.int32) < n
-    ok = live
-    word, positions = _word_and_bits(cols, W)
-    # build through a bit ARRAY (scatter-set of PROBE_BITS indices per row,
-    # duplicates harmless), then pack to words — the scatter is once per
-    # shuffle on the build side; the probe path stays scatter-free
-    base = word * jnp.int32(32)
-    idxs = [
-        jnp.where(ok, base + pos.astype(jnp.int32), jnp.int32(bits))
-        for pos in positions
-    ]
-    flat = jnp.concatenate(idxs)
-    bitarr = jnp.zeros((bits,), jnp.bool_).at[flat].set(True, mode="drop")
-    words = jnp.sum(
-        bitarr.reshape(W, 32).astype(jnp.uint32)
-        << jnp.arange(32, dtype=jnp.uint32)[None, :],
-        axis=1,
-        dtype=jnp.uint32,
-    )
-    if use_range:
-        enc = _range_enc(cols[0])
-        max_enc = jnp.max(jnp.where(ok, enc, jnp.uint32(0)))
-        min_enc = jnp.min(jnp.where(ok, enc, _NULL_ENC))
-    else:
-        # disabled range: the widest possible window passes every probe
-        max_enc = _NULL_ENC
-        min_enc = jnp.uint32(0)
-    # an EMPTY build shard contributes max=0 < min=0xFFFFFFFF — after the
-    # max/min fold an empty build SIDE keeps that inverted window and the
-    # range check prunes everything (correct: nothing can match). An
-    # all-NULL shard is different: its rows are live and encode as the
-    # 0xFFFFFFFF sentinel, so it contributes max=min=0xFFFFFFFF and
-    # probe-side nulls still pass (null == null must survive)
-    return jnp.concatenate([words, max_enc[None], min_enc[None]])
+    with jax.named_scope(_stages.SEMI_SKETCH):
+        cap = cols[0][0].shape[0]
+        W = bits // 32
+        live = jnp.arange(cap, dtype=jnp.int32) < n
+        ok = live
+        word, positions = _word_and_bits(cols, W)
+        # build through a bit ARRAY (scatter-set of PROBE_BITS indices per row,
+        # duplicates harmless), then pack to words — the scatter is once per
+        # shuffle on the build side; the probe path stays scatter-free
+        base = word * jnp.int32(32)
+        idxs = [
+            jnp.where(ok, base + pos.astype(jnp.int32), jnp.int32(bits))
+            for pos in positions
+        ]
+        flat = jnp.concatenate(idxs)
+        bitarr = jnp.zeros((bits,), jnp.bool_).at[flat].set(True, mode="drop")
+        words = jnp.sum(
+            bitarr.reshape(W, 32).astype(jnp.uint32)
+            << jnp.arange(32, dtype=jnp.uint32)[None, :],
+            axis=1,
+            dtype=jnp.uint32,
+        )
+        if use_range:
+            enc = _range_enc(cols[0])
+            max_enc = jnp.max(jnp.where(ok, enc, jnp.uint32(0)))
+            min_enc = jnp.min(jnp.where(ok, enc, _NULL_ENC))
+        else:
+            # disabled range: the widest possible window passes every probe
+            max_enc = _NULL_ENC
+            min_enc = jnp.uint32(0)
+        # an EMPTY build shard contributes max=0 < min=0xFFFFFFFF — after the
+        # max/min fold an empty build SIDE keeps that inverted window and the
+        # range check prunes everything (correct: nothing can match). An
+        # all-NULL shard is different: its rows are live and encode as the
+        # 0xFFFFFFFF sentinel, so it contributes max=min=0xFFFFFFFF and
+        # probe-side nulls still pass (null == null must survive)
+        return jnp.concatenate([words, max_enc[None], min_enc[None]])
 
 
 def combine_pair(local: jax.Array, axis_name: str, world: int) -> jax.Array:
@@ -265,15 +267,16 @@ def combine_pair(local: jax.Array, axis_name: str, world: int) -> jax.Array:
     small sketch collective — both sides of a pair ride it together), then
     the fold is local: bitwise OR over the bloom words, max/min over the
     range tail. The unrolled fold is over the STATIC world size."""
-    g = jax.lax.all_gather(local, axis_name)  # [P, S, L]
-    L = local.shape[-1]
-    W = L - RANGE_WORDS
-    bloom = g[0, :, :W]
-    for p in range(1, world):
-        bloom = bloom | g[p, :, :W]
-    max_enc = jnp.max(g[:, :, W], axis=0)
-    min_enc = jnp.min(g[:, :, W + 1], axis=0)
-    return jnp.concatenate([bloom, max_enc[:, None], min_enc[:, None]], axis=1)
+    with jax.named_scope(_stages.SEMI_SKETCH):
+        g = jax.lax.all_gather(local, axis_name)  # [P, S, L]
+        L = local.shape[-1]
+        W = L - RANGE_WORDS
+        bloom = g[0, :, :W]
+        for p in range(1, world):
+            bloom = bloom | g[p, :, :W]
+        max_enc = jnp.max(g[:, :, W], axis=0)
+        min_enc = jnp.min(g[:, :, W + 1], axis=0)
+        return jnp.concatenate([bloom, max_enc[:, None], min_enc[:, None]], axis=1)
 
 
 def probe(
@@ -287,14 +290,15 @@ def probe(
     provably partnerless. One lane-aligned uint32 gather per row + bitwise
     tests; a null-key row survives exactly when the other side may hold a
     null (null == null — module doc)."""
-    L = sketch.shape[0]
-    W = L - RANGE_WORDS
-    words = sketch[:W]
-    word, positions = _word_and_bits(cols, W)
-    pattern = _pattern(positions)
-    got = words[word]  # THE probe gather: one uint32 block per row
-    hit = (got & pattern) == pattern
-    if use_range:
-        enc = _range_enc(cols[0])
-        hit = hit & (enc >= sketch[W + 1]) & (enc <= sketch[W])
-    return hit
+    with jax.named_scope(_stages.SEMI_SKETCH):
+        L = sketch.shape[0]
+        W = L - RANGE_WORDS
+        words = sketch[:W]
+        word, positions = _word_and_bits(cols, W)
+        pattern = _pattern(positions)
+        got = words[word]  # THE probe gather: one uint32 block per row
+        hit = (got & pattern) == pattern
+        if use_range:
+            enc = _range_enc(cols[0])
+            hit = hit & (enc >= sketch[W + 1]) & (enc <= sketch[W])
+        return hit

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import stages as _stages
+
 KeyCol = Tuple[jax.Array, Optional[jax.Array]]  # (data, valid-or-None)
 
 
@@ -143,30 +145,31 @@ def lexsort_with_payload(
 
     Returns (sorted_lanes | None, sorted_payloads).
     """
-    k = len(lanes)
-    if not keep_lanes:
-        pending = list(lanes)  # least-significant first; index 0 keys next
-        carry = list(payloads)
-        for _ in range(k):
-            key, *pending = pending
-            out = jax.lax.sort(
-                tuple([key] + pending + carry), num_keys=1, is_stable=True
-            )
-            pending = list(out[1 : 1 + len(pending)])
-            carry = list(out[1 + len(pending) :])
-        return None, carry
-    ops = list(lanes) + list(payloads)
-    for i in range(k):  # least significant first
-        rest = [ops[j] for j in range(len(ops)) if j != i]
-        out = jax.lax.sort(tuple([ops[i]] + rest), num_keys=1, is_stable=True)
-        ops = [None] * len(ops)
-        ops[i] = out[0]
-        rj = 1
-        for j in range(len(ops)):
-            if ops[j] is None:
-                ops[j] = out[rj]
-                rj += 1
-    return ops[:k], ops[k:]
+    with jax.named_scope(_stages.SORT_ENGINE):
+        k = len(lanes)
+        if not keep_lanes:
+            pending = list(lanes)  # least-significant first; index 0 keys next
+            carry = list(payloads)
+            for _ in range(k):
+                key, *pending = pending
+                out = jax.lax.sort(
+                    tuple([key] + pending + carry), num_keys=1, is_stable=True
+                )
+                pending = list(out[1 : 1 + len(pending)])
+                carry = list(out[1 + len(pending) :])
+            return None, carry
+        ops = list(lanes) + list(payloads)
+        for i in range(k):  # least significant first
+            rest = [ops[j] for j in range(len(ops)) if j != i]
+            out = jax.lax.sort(tuple([ops[i]] + rest), num_keys=1, is_stable=True)
+            ops = [None] * len(ops)
+            ops[i] = out[0]
+            rj = 1
+            for j in range(len(ops)):
+                if ops[j] is None:
+                    ops[j] = out[rj]
+                    rj += 1
+        return ops[:k], ops[k:]
 
 
 def lexsort_indices(
@@ -181,12 +184,13 @@ def lexsort_indices(
     so the result is bit-identical to the chained bitonic path."""
     from . import radix as _radix
 
-    perm = _radix.lexsort_perm(lanes, cap, hints)
-    if perm is not None:
-        return perm
-    iota = jnp.arange(cap, dtype=jnp.int32)
-    _, pays = lexsort_with_payload(lanes, [iota], keep_lanes=False)
-    return pays[0]
+    with jax.named_scope(_stages.SORT_PERM):
+        perm = _radix.lexsort_perm(lanes, cap, hints)
+        if perm is not None:
+            return perm
+        iota = jnp.arange(cap, dtype=jnp.int32)
+        _, pays = lexsort_with_payload(lanes, [iota], keep_lanes=False)
+        return pays[0]
 
 
 # ---------------------------------------------------------------------------
@@ -290,47 +294,48 @@ def fused_key_words(
     null rows order by their masked payload) the field is exact;
     ``zero_null_values=True`` reproduces canonical_row_lanes' zeroed
     value-under-null (null == null runs)."""
-    fields = []
-    bits_list = []
-    for kind, pos, bits, asc in plan.fields:
-        if kind == "pad":
-            v = jnp.where(
-                live, jnp.uint32(0), np.uint32((1 << bits) - 1)
-            )
-        elif kind == "prefix":
-            v = jnp.clip(
-                prefix_lane, 0, (1 << bits) - 1
-            ).astype(jnp.uint32)
-        elif kind == "null":
-            _data, valid = key_cols[pos]
-            flag = ~valid if nulls_last else valid
-            v = flag.astype(jnp.uint32)
-        else:  # value
-            data, valid = key_cols[pos]
-            enc = orderable_key(data)
-            fdt = enc.dtype
-            if bits == 0:
-                v = jnp.zeros(data.shape, jnp.uint32)
-            else:
-                from .stats import mask_of
-
-                wide = fdt == jnp.uint64
-                maxf = mask_of(min(bits, 64 if wide else 32), fdt)
-                enc_max = mask_of(64 if wide else 32, fdt)
-                if asc:
-                    base = jnp.min(jnp.where(live, enc, enc_max))
-                    v = jnp.minimum(enc - base, maxf)
+    with jax.named_scope(_stages.SORT_KEYS):
+        fields = []
+        bits_list = []
+        for kind, pos, bits, asc in plan.fields:
+            if kind == "pad":
+                v = jnp.where(
+                    live, jnp.uint32(0), np.uint32((1 << bits) - 1)
+                )
+            elif kind == "prefix":
+                v = jnp.clip(
+                    prefix_lane, 0, (1 << bits) - 1
+                ).astype(jnp.uint32)
+            elif kind == "null":
+                _data, valid = key_cols[pos]
+                flag = ~valid if nulls_last else valid
+                v = flag.astype(jnp.uint32)
+            else:  # value
+                data, valid = key_cols[pos]
+                enc = orderable_key(data)
+                fdt = enc.dtype
+                if bits == 0:
+                    v = jnp.zeros(data.shape, jnp.uint32)
                 else:
-                    zero = np.uint64(0) if wide else np.uint32(0)
-                    top = jnp.max(jnp.where(live, enc, zero))
-                    v = jnp.minimum(top - enc, maxf)
-            if zero_null_values and valid is not None:
-                v = jnp.where(valid, v, jnp.zeros_like(v))
-        fields.append(v)
-        bits_list.append(bits)
-    from .stats import assemble_words, layout_words
+                    from .stats import mask_of
 
-    return assemble_words(fields, layout_words(bits_list, plan.allow64))
+                    wide = fdt == jnp.uint64
+                    maxf = mask_of(min(bits, 64 if wide else 32), fdt)
+                    enc_max = mask_of(64 if wide else 32, fdt)
+                    if asc:
+                        base = jnp.min(jnp.where(live, enc, enc_max))
+                        v = jnp.minimum(enc - base, maxf)
+                    else:
+                        zero = np.uint64(0) if wide else np.uint32(0)
+                        top = jnp.max(jnp.where(live, enc, zero))
+                        v = jnp.minimum(top - enc, maxf)
+                if zero_null_values and valid is not None:
+                    v = jnp.where(valid, v, jnp.zeros_like(v))
+            fields.append(v)
+            bits_list.append(bits)
+        from .stats import assemble_words, layout_words
+
+        return assemble_words(fields, layout_words(bits_list, plan.allow64))
 
 
 # ---------------------------------------------------------------------------
@@ -485,8 +490,9 @@ def sentinel_compact(key: jax.Array, payloads: Sequence[jax.Array]) -> list:
     ordering key (e.g. their original index), dropped rows a BIG sentinel
     that pushes them past every kept row. The scatter-free compaction used
     by the join probe and every set-op emit."""
-    out = jax.lax.sort(tuple([key] + list(payloads)), num_keys=1, is_stable=True)
-    return list(out[1:])
+    with jax.named_scope(_stages.SORT_ENGINE):
+        out = jax.lax.sort(tuple([key] + list(payloads)), num_keys=1, is_stable=True)
+        return list(out[1:])
 
 
 def lexsort_rows(
@@ -549,42 +555,38 @@ def lexsort_rows_payload(
         # radix over fused words: the layout's live widths bound the digit
         # spans (the least-significant word additionally skips its
         # constant-zero bottom tie padding)
-        perm = _radix.lexsort_perm(
-            lanes, cap, _radix.fuse_word_hints(fuse)
-        )
-        if perm is not None:
+        hints = _radix.fuse_word_hints(fuse)
+    else:
+        lanes = []  # least-significant first (lexsort convention)
+        hints = []  # per-lane radix digit spans, same order
+        with jax.named_scope(_stages.SORT_KEYS):
+            pad = row_class(n, cap, None)
+            for (data, valid), asc in zip(
+                reversed(list(key_cols)), list(reversed(list(ascending)))
+            ):
+                lanes.append(_norm_key(data, asc))
+                hints.append(None)  # dtype-default span (floats decline)
+                if valid is not None:
+                    null_lane = (~valid).astype(jnp.int8)
+                    if not nulls_last:
+                        null_lane = -null_lane
+                    lanes.append(null_lane)
+                    hints.append(_radix.bias_hint(1, 2))  # {-1,0,1} nulls
+        if prefix_lane is not None:
+            lanes.append(prefix_lane)
+            hints.append(_radix.bound_hint(cap + 1))  # run ids + padding id
+        lanes.append(pad)  # most significant: padding always last
+        hints.append(_radix.bias_hint(1, 2))  # {-1,0,1,2} row classes
+    with jax.named_scope(_stages.SORT_PERM):
+        perm = _radix.lexsort_perm(lanes, cap, hints)
+    if perm is not None:
+        with jax.named_scope(_stages.SORT_GATHER):
             return perm, [p[perm] for p in payloads]
-        iota = jnp.arange(cap, dtype=jnp.int32)
+    iota = jnp.arange(cap, dtype=jnp.int32)
+    with jax.named_scope(_stages.SORT_PERM):
         _, pays = lexsort_with_payload(
             lanes, list(payloads) + [iota], keep_lanes=False
         )
-        return pays[-1], pays[:-1]
-    lanes = []  # least-significant first (lexsort convention)
-    hints = []  # per-lane radix digit spans, same order
-    pad = row_class(n, cap, None)
-    for (data, valid), asc in zip(
-        reversed(list(key_cols)), list(reversed(list(ascending)))
-    ):
-        lanes.append(_norm_key(data, asc))
-        hints.append(None)  # dtype-default span (floats decline radix)
-        if valid is not None:
-            null_lane = (~valid).astype(jnp.int8)
-            if not nulls_last:
-                null_lane = -null_lane
-            lanes.append(null_lane)
-            hints.append(_radix.bias_hint(1, 2))  # {-1,0,1} null classes
-    if prefix_lane is not None:
-        lanes.append(prefix_lane)
-        hints.append(_radix.bound_hint(cap + 1))  # run ids + padding id
-    lanes.append(pad)  # most significant: padding always last
-    hints.append(_radix.bias_hint(1, 2))  # {-1,0,1,2} row classes
-    perm = _radix.lexsort_perm(lanes, cap, hints)
-    if perm is not None:
-        return perm, [p[perm] for p in payloads]
-    iota = jnp.arange(cap, dtype=jnp.int32)
-    _, pays = lexsort_with_payload(
-        lanes, list(payloads) + [iota], keep_lanes=False
-    )
     return pays[-1], pays[:-1]
 
 

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import stages as _stages
 from ..ops.gather import (
     lane_plan,
     pack_cols,
@@ -84,8 +85,9 @@ def bucket_counts(pid: jax.Array, num_partitions: int) -> jax.Array:
 def exchange_counts(counts: jax.Array, axis_name: str) -> jax.Array:
     """all_to_all the [P] send-counts -> [P] receive-counts (entry s = rows
     arriving from source shard s)."""
-    return jax.lax.all_to_all(
-        counts.reshape(-1, 1), axis_name, split_axis=0, concat_axis=0, tiled=False
+    with jax.named_scope(_stages.SHUFFLE_ALL_TO_ALL):
+        return jax.lax.all_to_all(
+            counts.reshape(-1, 1), axis_name, split_axis=0, concat_axis=0, tiled=False
     ).reshape(-1)
 
 
@@ -101,7 +103,8 @@ def shuffle_gather_order(pid: jax.Array, num_partitions: int) -> jax.Array:
     order = _radix.argsort_perm(pid, _radix.bound_hint(num_partitions))
     if order is not None:
         return order
-    return jnp.argsort(pid, stable=True).astype(jnp.int32)
+    with jax.named_scope(_stages.SORT_ENGINE):
+        return jnp.argsort(pid, stable=True).astype(jnp.int32)
 
 
 def build_send_slots_round(
@@ -129,25 +132,26 @@ def build_send_slots_round(
     them through the host relay (:func:`relay_send_slots`) instead of
     padding the cap up to the hottest bucket.
     """
-    cap = pid.shape[0]
-    order = shuffle_gather_order(pid, num_partitions)
-    spid = pid[order]
-    starts = jnp.cumsum(counts) - counts  # exclusive prefix per partition
-    safe_pid = jnp.clip(spid, 0, num_partitions - 1)
-    pos = jnp.arange(cap, dtype=jnp.int32) - starts[safe_pid]  # pos in bucket
-    r = jnp.asarray(round_idx, jnp.int32)
-    slot = pos - r * bucket_cap
-    ok = (spid < num_partitions) & (slot >= 0) & (slot < bucket_cap)
-    dest_sorted = jnp.where(
-        ok, safe_pid * bucket_cap + slot, num_partitions * bucket_cap
-    )
-    dest = jnp.full((cap,), num_partitions * bucket_cap, jnp.int32).at[order].set(
-        dest_sorted
-    )
-    leftover = jnp.sum(
-        (spid < num_partitions) & (pos >= (r + 1) * bucket_cap)
-    ).astype(jnp.int32)
-    return dest, leftover
+    with jax.named_scope(_stages.SHUFFLE_PACK):
+        cap = pid.shape[0]
+        order = shuffle_gather_order(pid, num_partitions)
+        spid = pid[order]
+        starts = jnp.cumsum(counts) - counts  # exclusive prefix per partition
+        safe_pid = jnp.clip(spid, 0, num_partitions - 1)
+        pos = jnp.arange(cap, dtype=jnp.int32) - starts[safe_pid]  # pos in bucket
+        r = jnp.asarray(round_idx, jnp.int32)
+        slot = pos - r * bucket_cap
+        ok = (spid < num_partitions) & (slot >= 0) & (slot < bucket_cap)
+        dest_sorted = jnp.where(
+            ok, safe_pid * bucket_cap + slot, num_partitions * bucket_cap
+        )
+        dest = jnp.full((cap,), num_partitions * bucket_cap, jnp.int32).at[order].set(
+            dest_sorted
+        )
+        leftover = jnp.sum(
+            (spid < num_partitions) & (pos >= (r + 1) * bucket_cap)
+        ).astype(jnp.int32)
+        return dest, leftover
 
 
 class SlicePlan(NamedTuple):
@@ -372,9 +376,10 @@ def scatter_send(
 ) -> jax.Array:
     """Scatter one column into its padded [P * bucket_cap, *trailing] send
     buffer (the pack phase of an un-headered exchange)."""
-    trailing = data.shape[1:]
-    return jnp.zeros((num_partitions * bucket_cap, *trailing), data.dtype).at[
-        dest
+    with jax.named_scope(_stages.SHUFFLE_PACK):
+        trailing = data.shape[1:]
+        return jnp.zeros((num_partitions * bucket_cap, *trailing), data.dtype).at[
+            dest
     ].set(data, mode="drop")
 
 
@@ -421,41 +426,43 @@ def pack_lane_buffer(
     destination (lane 0) followed by ``header_extra`` — [P, E] int32
     per-chunk metadata (the quantized tier's bitcast block scales) —
     wrapped across ``n_header`` rows (the fused count/scale exchange)."""
-    packed = jnp.stack(lanes, axis=1)  # [cap, L]
-    L = packed.shape[1]
-    rows = bucket_cap + n_header
-    buf = jnp.zeros((num_partitions * rows, L), jnp.int32)
-    if header_extra is None and n_header == 1:
-        buf = buf.at[
-            jnp.arange(num_partitions, dtype=jnp.int32) * rows, 0
-        ].set(counts_round.astype(jnp.int32))
-    else:
-        hv = jnp.zeros((num_partitions, n_header * L), jnp.int32)
-        hv = hv.at[:, 0].set(counts_round.astype(jnp.int32))
-        if header_extra is not None:
-            E = header_extra.shape[1]
-            hv = hv.at[:, 1 : 1 + E].set(header_extra.astype(jnp.int32))
-        hidx = (
-            jnp.arange(num_partitions, dtype=jnp.int32)[:, None] * rows
-            + jnp.arange(n_header, dtype=jnp.int32)[None, :]
-        ).reshape(-1)
-        buf = buf.at[hidx].set(hv.reshape(num_partitions * n_header, L))
-    return buf.at[
-        header_slots(dest, num_partitions, bucket_cap, n_header)
+    with jax.named_scope(_stages.SHUFFLE_PACK):
+        packed = jnp.stack(lanes, axis=1)  # [cap, L]
+        L = packed.shape[1]
+        rows = bucket_cap + n_header
+        buf = jnp.zeros((num_partitions * rows, L), jnp.int32)
+        if header_extra is None and n_header == 1:
+            buf = buf.at[
+                jnp.arange(num_partitions, dtype=jnp.int32) * rows, 0
+            ].set(counts_round.astype(jnp.int32))
+        else:
+            hv = jnp.zeros((num_partitions, n_header * L), jnp.int32)
+            hv = hv.at[:, 0].set(counts_round.astype(jnp.int32))
+            if header_extra is not None:
+                E = header_extra.shape[1]
+                hv = hv.at[:, 1 : 1 + E].set(header_extra.astype(jnp.int32))
+            hidx = (
+                jnp.arange(num_partitions, dtype=jnp.int32)[:, None] * rows
+                + jnp.arange(n_header, dtype=jnp.int32)[None, :]
+            ).reshape(-1)
+            buf = buf.at[hidx].set(hv.reshape(num_partitions * n_header, L))
+        return buf.at[
+            header_slots(dest, num_partitions, bucket_cap, n_header)
     ].set(packed, mode="drop")
 
 
 def exchange_buffer(buf: jax.Array, num_partitions: int, axis_name: str) -> jax.Array:
     """all_to_all a [P * rows, *trailing] send buffer; chunk s of the output
     holds what source shard s sent."""
-    trailing = buf.shape[1:]
-    rows = buf.shape[0] // num_partitions
-    return jax.lax.all_to_all(
-        buf.reshape(num_partitions, rows, *trailing),
-        axis_name,
-        split_axis=0,
-        concat_axis=0,
-        tiled=False,
+    with jax.named_scope(_stages.SHUFFLE_ALL_TO_ALL):
+        trailing = buf.shape[1:]
+        rows = buf.shape[0] // num_partitions
+        return jax.lax.all_to_all(
+            buf.reshape(num_partitions, rows, *trailing),
+            axis_name,
+            split_axis=0,
+            concat_axis=0,
+            tiled=False,
     ).reshape(num_partitions * rows, *trailing)
 
 
@@ -701,19 +708,20 @@ def compact_received_lanes(
     (plus one per f64 passthrough column), then unpack. The chunked
     engine's compact phase uses this instead of :func:`compact_received`,
     which would re-pack rows that arrived packed."""
-    order = jnp.argsort(~mask, stable=True).astype(jnp.int32)
-    out_lanes: List[jax.Array] = []
-    if lane_rows is not None and lane_rows.shape[1]:
-        g = lane_rows[order]
-        out_lanes = [g[:, j] for j in range(g.shape[1])]
-    sorted_pt = {ci: d[order] for ci, d in pt_cols.items()}
-    out, _ = unpack_cols(
-        plan,
-        out_lanes,
-        lambda ci: sorted_pt[ci],
-        lambda lane: None if lane is None else lane.astype(jnp.bool_),
-    )
-    return out
+    with jax.named_scope(_stages.SHUFFLE_COMPACT):
+        order = jnp.argsort(~mask, stable=True).astype(jnp.int32)
+        out_lanes: List[jax.Array] = []
+        if lane_rows is not None and lane_rows.shape[1]:
+            g = lane_rows[order]
+            out_lanes = [g[:, j] for j in range(g.shape[1])]
+        sorted_pt = {ci: d[order] for ci, d in pt_cols.items()}
+        out, _ = unpack_cols(
+            plan,
+            out_lanes,
+            lambda ci: sorted_pt[ci],
+            lambda lane: None if lane is None else lane.astype(jnp.bool_),
+        )
+        return out
 
 
 def compact_received_wire(
@@ -731,19 +739,20 @@ def compact_received_wire(
     of the quantized fields (broadcast from the headers BEFORE this
     permutation — they ride the same gather so each row dequantizes with
     its own source chunk's scale)."""
-    order = jnp.argsort(~mask, stable=True).astype(jnp.int32)
-    g = lane_rows[order]
-    word_lanes = [g[:, j] for j in range(g.shape[1])]
-    sorted_pt = {ci: d[order] for ci, d in pt_cols.items()}
-    qsc = None if qscale_rows is None else qscale_rows[order]
-    return wire_unpack_cols(
-        word_lanes,
-        wire,
-        bases,
-        lambda ci: sorted_pt[ci],
-        lambda lane: None if lane is None else lane.astype(jnp.bool_),
-        qscales=qsc,
-    )
+    with jax.named_scope(_stages.SHUFFLE_COMPACT):
+        order = jnp.argsort(~mask, stable=True).astype(jnp.int32)
+        g = lane_rows[order]
+        word_lanes = [g[:, j] for j in range(g.shape[1])]
+        sorted_pt = {ci: d[order] for ci, d in pt_cols.items()}
+        qsc = None if qscale_rows is None else qscale_rows[order]
+        return wire_unpack_cols(
+            word_lanes,
+            wire,
+            bases,
+            lambda ci: sorted_pt[ci],
+            lambda lane: None if lane is None else lane.astype(jnp.bool_),
+            qscales=qsc,
+        )
 
 
 def compact_received(
@@ -752,11 +761,12 @@ def compact_received(
 ) -> List[Tuple[jax.Array, Optional[jax.Array]]]:
     """Front-pack received rows (stable), restoring the live-prefix
     invariant. All columns ride ONE packed row gather (see ops/gather)."""
-    order = jnp.argsort(~mask, stable=True).astype(jnp.int32)
-    gathered, _ = pack_gather(cols, order)
-    # pack_gather merges ok=order>=0 (always True here) into validity; keep
-    # mask-free columns mask-free
-    return [
-        (d, None if ov is None else v)
-        for (d, v), (_, ov) in zip(gathered, cols)
-    ]
+    with jax.named_scope(_stages.SHUFFLE_COMPACT):
+        order = jnp.argsort(~mask, stable=True).astype(jnp.int32)
+        gathered, _ = pack_gather(cols, order)
+        # pack_gather merges ok=order>=0 (always True here) into validity; keep
+        # mask-free columns mask-free
+        return [
+            (d, None if ov is None else v)
+            for (d, v), (_, ov) in zip(gathered, cols)
+        ]

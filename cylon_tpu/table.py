@@ -59,6 +59,7 @@ from .parallel import spill as _spill
 from .parallel import topo as _topo
 from .obs import prof as _prof
 from .obs import resource as _obsres
+from .obs import stages as _stages
 from .obs import store as _obsstore
 from .obs import trace as _obstrace
 from .plan import feedback as _feedback
@@ -99,13 +100,17 @@ def _fetch(arr) -> np.ndarray:
     a global array's remote shards are not addressable from this host, so
     ``np.asarray`` alone would raise — allgather across processes first
     (the reference's equivalent host boundary is each rank owning only its
-    partition, table.cpp:791-829)."""
-    if jax.process_count() > 1 and hasattr(arr, "is_fully_addressable"):
-        if not arr.is_fully_addressable:
-            from jax.experimental import multihost_utils
+    partition, table.cpp:791-829). Inside a ``jax.profiler`` session the
+    wait is a host event named ``host_sync`` on the device trace's clock."""
+    with jax.profiler.TraceAnnotation("host_sync"):
+        if jax.process_count() > 1 and hasattr(arr, "is_fully_addressable"):
+            if not arr.is_fully_addressable:
+                from jax.experimental import multihost_utils
 
-            return np.asarray(multihost_utils.process_allgather(arr, tiled=True))
-    return np.asarray(arr)
+                return np.asarray(
+                    multihost_utils.process_allgather(arr, tiled=True)
+                )
+        return np.asarray(arr)
 
 
 class Row:
@@ -1236,13 +1241,14 @@ class Table:
                 n = counts[0]
                 cap = cols[0][0].shape[0]
                 keys = [cols[i] for i in key_idx[m:]]
-                prefix_lane = (
-                    _sort_mod.prefix_run_lane(
-                        [cols[i] for i in key_idx[:m]], n, cap
+                with jax.named_scope(_stages.SORT_KEYS):
+                    prefix_lane = (
+                        _sort_mod.prefix_run_lane(
+                            [cols[i] for i in key_idx[:m]], n, cap
+                        )
+                        if m
+                        else None
                     )
-                    if m
-                    else None
-                )
                 # <=32-bit columns RIDE the sort as payload operands (a lane
                 # per pass instead of a random row gather); 64-bit columns
                 # fall back to one packed gather by the order (the int32
@@ -1252,10 +1258,13 @@ class Table:
                     keys, n, cap, payloads, ascending=list(asc[m:]),
                     prefix_lane=prefix_lane, fuse=fuse,
                 )
-                heavy_out = (
-                    _g_pack.pack_gather(heavy, order)[0] if heavy else []
-                )
-                return _sort_mod.merge_ride_cols(cols, ride, spays, heavy_out)
+                with jax.named_scope(_stages.SORT_GATHER):
+                    heavy_out = (
+                        _g_pack.pack_gather(heavy, order)[0] if heavy else []
+                    )
+                    return _sort_mod.merge_ride_cols(
+                        cols, ride, spays, heavy_out
+                    )
 
             return kern
 
@@ -1599,7 +1608,8 @@ class Table:
 
             with span("join.speculative", rows=self._rows_hint()):
                 out, stats = get_kernel(
-                    self.ctx, key + ("spec",), build_spec, **emit_kw
+                    self.ctx, key + ("spec",), build_spec, name="join_spec",
+                    **emit_kw,
                 )(
                     (lflat_k, rflat_k, lflat, rflat, left.counts_dev, right.counts_dev),
                     (jnp.zeros((spec_cap,), jnp.int8),),
@@ -1655,7 +1665,7 @@ class Table:
             return kern
 
         lo, cnt, r_order, r_cnt, pstats = get_kernel(
-            self.ctx, key + ("probe",), build_probe
+            self.ctx, key + ("probe",), build_probe, name="join_probe"
         )((lflat_k, rflat_k, left.counts_dev, right.counts_dev), ())
         bump("host_sync")
         pstats = _fetch(pstats).reshape(-1, 2)
@@ -1680,7 +1690,8 @@ class Table:
             return kern
 
         out, _nout = get_kernel(
-            self.ctx, key + ("emit",), build_emit, **emit_kw
+            self.ctx, key + ("emit",), build_emit, name="join_emit",
+            **emit_kw,
         )(
             (lo, cnt, r_order, r_cnt, lflat, rflat, left.counts_dev, right.counts_dev),
             (jnp.zeros((cap_out,), jnp.int8),),
@@ -3399,26 +3410,27 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
 
     def build_count():
         def kern(dp, rep):
-            if semi:
-                # flat [2P + 4S]: unfiltered counts ++ filtered counts ++
-                # per-statable-column range words — the host reads counts,
-                # exact selectivity AND global column bounds in its ONE
-                # existing count fetch
-                (cols, kcols, counts, sk) = dp
-                n = counts[0]
-                pid = compute_pid(cols, kcols, n)
-                pid_f = jnp.where(probe_ok(cols, sk), pid, world)
-                parts = [
-                    _sh.bucket_counts(pid, world),
-                    _sh.bucket_counts(pid_f, world),
-                ]
-            else:
-                (cols, kcols, counts) = dp
-                n = counts[0]
-                pid = compute_pid(cols, kcols, n)
-                parts = [_sh.bucket_counts(pid, world)]
-            parts += [_st.stat_words(cols[ci], n) for ci in stat_cols]
-            return jnp.concatenate(parts)
+            with jax.named_scope(_stages.SHUFFLE_COUNT):
+                if semi:
+                    # flat [2P + 4S]: unfiltered counts ++ filtered counts ++
+                    # per-statable-column range words — the host reads counts,
+                    # exact selectivity AND global column bounds in its ONE
+                    # existing count fetch
+                    (cols, kcols, counts, sk) = dp
+                    n = counts[0]
+                    pid = compute_pid(cols, kcols, n)
+                    pid_f = jnp.where(probe_ok(cols, sk), pid, world)
+                    parts = [
+                        _sh.bucket_counts(pid, world),
+                        _sh.bucket_counts(pid_f, world),
+                    ]
+                else:
+                    (cols, kcols, counts) = dp
+                    n = counts[0]
+                    pid = compute_pid(cols, kcols, n)
+                    parts = [_sh.bucket_counts(pid, world)]
+                parts += [_st.stat_words(cols[ci], n) for ci in stat_cols]
+                return jnp.concatenate(parts)
 
         return kern
 
@@ -3428,99 +3440,100 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
         # dispatch key appends st["wire"], so each decision compiles its
         # own program and the builders read the decided state at build time
         def kern(dp, rep):
-            wire = st["wire"]
-            if semi:
-                (cols, kcols, counts, sk) = dp
+            with jax.named_scope(_stages.SHUFFLE_PACK):
+                wire = st["wire"]
+                if semi:
+                    (cols, kcols, counts, sk) = dp
+                    if wire is not None:
+                        (dummy, rnd, usef, bases) = rep
+                    else:
+                        (dummy, rnd, usef) = rep
+                        bases = None
+                    n = counts[0]
+                    pid = compute_pid(cols, kcols, n)
+                    # the adaptive gate's decision rides in as a traced scalar
+                    # so ONE compiled pack program serves both outcomes
+                    pid = jnp.where(
+                        (usef != 0) & ~probe_ok(cols, sk), world, pid
+                    )
+                else:
+                    (cols, kcols, counts) = dp
+                    if wire is not None:
+                        (dummy, rnd, bases) = rep
+                    else:
+                        (dummy, rnd) = rep
+                        bases = None
+                    n = counts[0]
+                    pid = compute_pid(cols, kcols, n)
+                bc = dummy.shape[0]
+                n_header = (
+                    _sh.wire_header_rows(wire) if wire is not None
+                    else _sh.HEADER_ROWS
+                )
+                if _codec.pack_engaged(kind, semi, has_lanes, n_header, world):
+                    # fused hash→partition→slot kernel (ops/pallas_codec):
+                    # dest/cnt come out of ONE VMEM pass over the key words;
+                    # the collision-free lane-buffer scatter below is shared
+                    # with the XLA path, so `head` is bit-identical by
+                    # construction. Range/task/semi packs can't replay the
+                    # pid in Mosaic — the XLA pid lane (incl. the semi probe
+                    # rewrite above) feeds the same kernel and histogram +
+                    # rank + slot still fuse; in hash mode `pid` above is
+                    # dead and DCE'd.
+                    if _codec.pack_fuses_hash(kind, semi):
+                        words, valids, hv = _codec.hash_operands(list(kcols))
+                        dest, cnt = _codec.fused_pack_dest(
+                            words, valids, hv, n, rnd, world, bc,
+                            interpret=ctx.platform == "cpu",
+                        )
+                    else:
+                        dest, cnt = _codec.fused_pack_dest(
+                            [], [], (), n, rnd, world, bc, pid=pid,
+                            interpret=ctx.platform == "cpu",
+                        )
+                else:
+                    cnt = _sh.bucket_counts(pid, world)
+                    dest, _leftover = _sh.build_send_slots_round(
+                        pid, cnt, world, bc, rnd
+                    )
+                rc = _sh.round_counts(cnt, bc, rnd)
+                hx = None
                 if wire is not None:
-                    (dummy, rnd, usef, bases) = rep
+                    # bit-width-adaptive wire narrowing: lanes are the packed
+                    # words of the stats-driven wire plan (validity at 1
+                    # bit/row, values at measured width, global rebase words
+                    # riding in as the tiny replicated `bases` operand).
+                    # Quantized 'q8' fields additionally compute one block
+                    # scale per destination chunk here and ship it in the
+                    # (widened) header rows beside the counts (n_header above).
+                    qrows = None
+                    if _g_pack.wire_q8_cols(wire):
+                        scales = _sh.quant_chunk_scales(
+                            cols, wire, dest, world, bc
+                        )
+                        qrows = _sh.send_row_scales(scales, dest, bc)
+                        hx = jax.lax.bitcast_convert_type(scales, jnp.int32)
+                    lanes, passthrough = _g_pack.wire_pack_cols(
+                        list(cols), wire, bases, qscales=qrows
+                    )
+                    pt_eff = _g_pack.wire_pt_order(wire, pt_order)
                 else:
-                    (dummy, rnd, usef) = rep
-                    bases = None
-                n = counts[0]
-                pid = compute_pid(cols, kcols, n)
-                # the adaptive gate's decision rides in as a traced scalar
-                # so ONE compiled pack program serves both outcomes
-                pid = jnp.where(
-                    (usef != 0) & ~probe_ok(cols, sk), world, pid
-                )
-            else:
-                (cols, kcols, counts) = dp
-                if wire is not None:
-                    (dummy, rnd, bases) = rep
-                else:
-                    (dummy, rnd) = rep
-                    bases = None
-                n = counts[0]
-                pid = compute_pid(cols, kcols, n)
-            bc = dummy.shape[0]
-            n_header = (
-                _sh.wire_header_rows(wire) if wire is not None
-                else _sh.HEADER_ROWS
-            )
-            if _codec.pack_engaged(kind, semi, has_lanes, n_header, world):
-                # fused hash→partition→slot kernel (ops/pallas_codec):
-                # dest/cnt come out of ONE VMEM pass over the key words;
-                # the collision-free lane-buffer scatter below is shared
-                # with the XLA path, so `head` is bit-identical by
-                # construction. Range/task/semi packs can't replay the
-                # pid in Mosaic — the XLA pid lane (incl. the semi probe
-                # rewrite above) feeds the same kernel and histogram +
-                # rank + slot still fuse; in hash mode `pid` above is
-                # dead and DCE'd.
-                if _codec.pack_fuses_hash(kind, semi):
-                    words, valids, hv = _codec.hash_operands(list(kcols))
-                    dest, cnt = _codec.fused_pack_dest(
-                        words, valids, hv, n, rnd, world, bc,
-                        interpret=ctx.platform == "cpu",
+                    _plan, lanes, passthrough = _g_pack.pack_cols(list(cols))
+                    pt_eff = pt_order
+                if lanes:
+                    # the fused count/payload exchange: this round's per-
+                    # destination send counts ride the lane buffer's header row
+                    head = _sh.pack_lane_buffer(
+                        lanes, dest, rc, world, bc,
+                        header_extra=hx, n_header=n_header,
                     )
                 else:
-                    dest, cnt = _codec.fused_pack_dest(
-                        [], [], (), n, rnd, world, bc, pid=pid,
-                        interpret=ctx.platform == "cpu",
-                    )
-            else:
-                cnt = _sh.bucket_counts(pid, world)
-                dest, _leftover = _sh.build_send_slots_round(
-                    pid, cnt, world, bc, rnd
+                    head = rc  # pure-f64 table: dedicated count lane
+                pts = tuple(
+                    _sh.scatter_send(passthrough[ci], dest, world, bc)
+                    for ci in pt_eff
                 )
-            rc = _sh.round_counts(cnt, bc, rnd)
-            hx = None
-            if wire is not None:
-                # bit-width-adaptive wire narrowing: lanes are the packed
-                # words of the stats-driven wire plan (validity at 1
-                # bit/row, values at measured width, global rebase words
-                # riding in as the tiny replicated `bases` operand).
-                # Quantized 'q8' fields additionally compute one block
-                # scale per destination chunk here and ship it in the
-                # (widened) header rows beside the counts (n_header above).
-                qrows = None
-                if _g_pack.wire_q8_cols(wire):
-                    scales = _sh.quant_chunk_scales(
-                        cols, wire, dest, world, bc
-                    )
-                    qrows = _sh.send_row_scales(scales, dest, bc)
-                    hx = jax.lax.bitcast_convert_type(scales, jnp.int32)
-                lanes, passthrough = _g_pack.wire_pack_cols(
-                    list(cols), wire, bases, qscales=qrows
-                )
-                pt_eff = _g_pack.wire_pt_order(wire, pt_order)
-            else:
-                _plan, lanes, passthrough = _g_pack.pack_cols(list(cols))
-                pt_eff = pt_order
-            if lanes:
-                # the fused count/payload exchange: this round's per-
-                # destination send counts ride the lane buffer's header row
-                head = _sh.pack_lane_buffer(
-                    lanes, dest, rc, world, bc,
-                    header_extra=hx, n_header=n_header,
-                )
-            else:
-                head = rc  # pure-f64 table: dedicated count lane
-            pts = tuple(
-                _sh.scatter_send(passthrough[ci], dest, world, bc)
-                for ci in pt_eff
-            )
-            return head, pts
+                return head, pts
 
         return kern
 
@@ -3530,25 +3543,26 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
         # count fetch; the dispatch key carries its full tuple, so each
         # decision compiles its own program
         def kern(dp, rep):
-            (head, pts) = dp
-            tp = st["topo_plan"]
-            if tp is not None:
-                # two-hop exchange: inner grouped all_to_all, dense
-                # count-informed cross-outer repack, outer grouped
-                # all_to_all — the pack output rides in UNCHANGED
-                bc = head.shape[0] // world - tp.n_header
-                return _topo.two_hop_exchange(
-                    head, pts, _topo.Topology(tp.outer, tp.inner),
-                    bc, tp.cap_o, tp.n_header, ax,
-                )
-            # a decided wire plan guarantees word lanes even when the
-            # plain codec had none (pure-f64 quantized tables)
-            if has_lanes or st["wire"] is not None:
-                out_head = _sh.exchange_buffer(head, world, ax)
-            else:
-                out_head = _sh.exchange_counts(head, ax)
-            out_pts = tuple(_sh.exchange_buffer(p, world, ax) for p in pts)
-            return out_head, out_pts
+            with jax.named_scope(_stages.SHUFFLE_ALL_TO_ALL):
+                (head, pts) = dp
+                tp = st["topo_plan"]
+                if tp is not None:
+                    # two-hop exchange: inner grouped all_to_all, dense
+                    # count-informed cross-outer repack, outer grouped
+                    # all_to_all — the pack output rides in UNCHANGED
+                    bc = head.shape[0] // world - tp.n_header
+                    return _topo.two_hop_exchange(
+                        head, pts, _topo.Topology(tp.outer, tp.inner),
+                        bc, tp.cap_o, tp.n_header, ax,
+                    )
+                # a decided wire plan guarantees word lanes even when the
+                # plain codec had none (pure-f64 quantized tables)
+                if has_lanes or st["wire"] is not None:
+                    out_head = _sh.exchange_buffer(head, world, ax)
+                else:
+                    out_head = _sh.exchange_counts(head, ax)
+                out_pts = tuple(_sh.exchange_buffer(p, world, ax) for p in pts)
+                return out_head, out_pts
 
         return kern
 
@@ -3571,48 +3585,49 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
             else:
                 (cols, kcols, counts) = dp
                 (dummy, quota) = rep
-            n = counts[0]
-            pid = compute_pid(cols, kcols, n)
-            if semi:
-                pid = jnp.where(
-                    (usef != 0) & ~probe_ok(cols, sk), world, pid
-                )
-            rc = dummy.shape[0]
-            cnt = _sh.bucket_counts(pid, world)
-            sel = None
-            if st["relay_mode"] == "inter":
-                # two-hop relay split: same-outer-group tails left this
-                # kernel for the device ppermute ring (build_ring); only
-                # cross-outer tails still cross the host
-                inner = st["topo_plan"].inner
-                o_self = jax.lax.axis_index(ax) // inner
-                sel = (jnp.arange(world, dtype=jnp.int32) // inner) != o_self
-            dest = _sh.relay_send_slots(pid, cnt, world, quota, rc, sel=sel)
-            if relay_qcols:
-                lanes, passthrough, qcodes, qscales = (
-                    _g_pack.pack_cols_quant(
-                        list(cols), relay_qplan, relay_qcols,
-                        live=dest < rc,
+            with jax.named_scope(_stages.SHUFFLE_PACK):
+                n = counts[0]
+                pid = compute_pid(cols, kcols, n)
+                if semi:
+                    pid = jnp.where(
+                        (usef != 0) & ~probe_ok(cols, sk), world, pid
                     )
+                rc = dummy.shape[0]
+                cnt = _sh.bucket_counts(pid, world)
+                sel = None
+                if st["relay_mode"] == "inter":
+                    # two-hop relay split: same-outer-group tails left this
+                    # kernel for the device ppermute ring (build_ring); only
+                    # cross-outer tails still cross the host
+                    inner = st["topo_plan"].inner
+                    o_self = jax.lax.axis_index(ax) // inner
+                    sel = (jnp.arange(world, dtype=jnp.int32) // inner) != o_self
+                dest = _sh.relay_send_slots(pid, cnt, world, quota, rc, sel=sel)
+                if relay_qcols:
+                    lanes, passthrough, qcodes, qscales = (
+                        _g_pack.pack_cols_quant(
+                            list(cols), relay_qplan, relay_qcols,
+                            live=dest < rc,
+                        )
+                    )
+                else:
+                    _plan2, lanes, passthrough = _g_pack.pack_cols(list(cols))
+                if lanes:
+                    mat = _sh.scatter_send(
+                        jnp.stack(lanes, axis=1), dest, 1, rc
+                    )
+                else:
+                    mat = jnp.zeros((rc, 0), jnp.int32)
+                pts = tuple(
+                    _sh.scatter_send(passthrough[ci], dest, 1, rc)
+                    for ci in pt_order
+                    if not relay_qcols or relay_qsig[ci] != "q8"
                 )
-            else:
-                _plan2, lanes, passthrough = _g_pack.pack_cols(list(cols))
-            if lanes:
-                mat = _sh.scatter_send(
-                    jnp.stack(lanes, axis=1), dest, 1, rc
-                )
-            else:
-                mat = jnp.zeros((rc, 0), jnp.int32)
-            pts = tuple(
-                _sh.scatter_send(passthrough[ci], dest, 1, rc)
-                for ci in pt_order
-                if not relay_qcols or relay_qsig[ci] != "q8"
-            )
-            if relay_qcols:
-                pts = pts + (
-                    _sh.scatter_send(qcodes, dest, 1, rc), qscales
-                )
-            return mat, pts
+                if relay_qcols:
+                    pts = pts + (
+                        _sh.scatter_send(qcodes, dest, 1, rc), qscales
+                    )
+                return mat, pts
 
         return kern
 
@@ -3632,40 +3647,42 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
             else:
                 (cols, kcols, counts) = dp
                 (dummy, quota) = rep
-            n = counts[0]
-            pid = compute_pid(cols, kcols, n)
-            if semi:
-                pid = jnp.where(
-                    (usef != 0) & ~probe_ok(cols, sk), world, pid
+            with jax.named_scope(_stages.SHUFFLE_PACK):
+                n = counts[0]
+                pid = compute_pid(cols, kcols, n)
+                if semi:
+                    pid = jnp.where(
+                        (usef != 0) & ~probe_ok(cols, sk), world, pid
+                    )
+                rc = dummy.shape[0]
+                cnt = _sh.bucket_counts(pid, world)
+                tp = st["topo_plan"]
+                o_self = jax.lax.axis_index(ax) // tp.inner
+                sel = (
+                    jnp.arange(world, dtype=jnp.int32) // tp.inner
+                ) == o_self
+                dest = _sh.relay_send_slots(
+                    pid, cnt, world, quota, rc, sel=sel
                 )
-            rc = dummy.shape[0]
-            cnt = _sh.bucket_counts(pid, world)
-            tp = st["topo_plan"]
-            o_self = jax.lax.axis_index(ax) // tp.inner
-            sel = (
-                jnp.arange(world, dtype=jnp.int32) // tp.inner
-            ) == o_self
-            dest = _sh.relay_send_slots(
-                pid, cnt, world, quota, rc, sel=sel
-            )
-            _plan2, lanes, passthrough = _g_pack.pack_cols(list(cols))
-            if lanes:
-                mat = _sh.scatter_send(
-                    jnp.stack(lanes, axis=1), dest, 1, rc
+                _plan2, lanes, passthrough = _g_pack.pack_cols(list(cols))
+                if lanes:
+                    mat = _sh.scatter_send(
+                        jnp.stack(lanes, axis=1), dest, 1, rc
+                    )
+                else:
+                    mat = jnp.zeros((rc, 0), jnp.int32)
+                pidl = jnp.full((rc,), -1, jnp.int32).at[dest].set(
+                    pid, mode="drop"
                 )
-            else:
-                mat = jnp.zeros((rc, 0), jnp.int32)
-            pidl = jnp.full((rc,), -1, jnp.int32).at[dest].set(
-                pid, mode="drop"
-            )
-            pts = tuple(
-                _sh.scatter_send(passthrough[ci], dest, 1, rc)
-                for ci in pt_order
-            )
-            lanes_all, mask_all, pts_all = _topo.ring_relay(
-                mat, pidl, pts,
-                _topo.Topology(tp.outer, tp.inner), ax,
-            )
+                pts = tuple(
+                    _sh.scatter_send(passthrough[ci], dest, 1, rc)
+                    for ci in pt_order
+                )
+            with jax.named_scope(_stages.SHUFFLE_ALL_TO_ALL):
+                lanes_all, mask_all, pts_all = _topo.ring_relay(
+                    mat, pidl, pts,
+                    _topo.Topology(tp.outer, tp.inner), ax,
+                )
             out = _sh.compact_received_lanes(
                 list(plan_sig),
                 lanes_all if has_lanes else None,
@@ -3678,142 +3695,143 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
 
     def build_compact():
         def kern(dp, rep):
-            wire = st["wire"]
-            tp = st["topo_plan"]
-            if tp is not None:
-                # two-hop receive: same-group rows (final after hop 1)
-                # fuse with the combined cross-outer chunks into ONE
-                # front-pack — the self chunk of the outer hop arrived
-                # empty by construction, so its mask is all dead
-                (got2, self_rows, self_cnt, pts2, ptsS) = dp
-                bc = self_rows.shape[0] // tp.inner
-                lane_rows, mask, total = _topo.two_hop_received(
-                    got2, self_rows, self_cnt,
-                    _topo.Topology(tp.outer, tp.inner),
-                    bc, tp.cap_o, tp.n_header,
-                )
-                pt_eff = (
-                    _g_pack.wire_pt_order(wire, pt_order)
-                    if wire is not None
-                    else pt_order
-                )
-                pt_cols = {
-                    ci: jnp.concatenate([ps, p2], axis=0)
-                    for ci, ps, p2 in zip(pt_eff, ptsS, pts2)
-                }
+            with jax.named_scope(_stages.SHUFFLE_COMPACT):
+                wire = st["wire"]
+                tp = st["topo_plan"]
+                if tp is not None:
+                    # two-hop receive: same-group rows (final after hop 1)
+                    # fuse with the combined cross-outer chunks into ONE
+                    # front-pack — the self chunk of the outer hop arrived
+                    # empty by construction, so its mask is all dead
+                    (got2, self_rows, self_cnt, pts2, ptsS) = dp
+                    bc = self_rows.shape[0] // tp.inner
+                    lane_rows, mask, total = _topo.two_hop_received(
+                        got2, self_rows, self_cnt,
+                        _topo.Topology(tp.outer, tp.inner),
+                        bc, tp.cap_o, tp.n_header,
+                    )
+                    pt_eff = (
+                        _g_pack.wire_pt_order(wire, pt_order)
+                        if wire is not None
+                        else pt_order
+                    )
+                    pt_cols = {
+                        ci: jnp.concatenate([ps, p2], axis=0)
+                        for ci, ps, p2 in zip(pt_eff, ptsS, pts2)
+                    }
+                    if wire is not None:
+                        (bases,) = rep
+                        out = _sh.compact_received_wire(
+                            wire, bases, lane_rows, pt_cols, mask
+                        )
+                    else:
+                        out = _sh.compact_received_lanes(
+                            list(plan_sig), lane_rows, pt_cols, mask
+                        )
+                    return out, _scalar(total)
+                (head, pts) = dp
+                qsc_rows = None
+                if wire is not None:
+                    n_header = _sh.wire_header_rows(wire)
+                    lane_rows, recv_counts = _sh.split_header(
+                        head, world, n_header
+                    )
+                    bc = lane_rows.shape[0] // world
+                    nq8 = len(_g_pack.wire_q8_cols(wire))
+                    if nq8:
+                        # each received row dequantizes with its SOURCE
+                        # chunk's block scale, broadcast from the header rows
+                        # before the compaction permutes anything
+                        qsc_rows = _sh.recv_row_scales(
+                            _sh.split_header_scales(
+                                head, world, n_header, nq8
+                            ),
+                            world, bc,
+                        )
+                    pt_cols = dict(
+                        zip(_g_pack.wire_pt_order(wire, pt_order), pts)
+                    )
+                elif has_lanes:
+                    lane_rows, recv_counts = _sh.split_header(head, world)
+                    bc = lane_rows.shape[0] // world
+                    pt_cols = dict(zip(pt_order, pts))
+                else:
+                    lane_rows, recv_counts = None, head
+                    bc = pts[0].shape[0] // world
+                    pt_cols = dict(zip(pt_order, pts))
+                nml = 0
+                if lane_rows is not None:
+                    nml = (
+                        lane_rows.shape[1]
+                        + (qsc_rows.shape[1] if qsc_rows is not None else 0)
+                        + (1 if pt_cols else 0)
+                    )
+                if _codec.compact_engaged(
+                    lane_rows is not None, False, world, bc, nml
+                ):
+                    # fused front-pack (ops/pallas_codec): ONE masked block-
+                    # copy pass replaces the liveness mask + stable argsort +
+                    # 400x-priced row gather. q8 scale rows ride the move
+                    # matrix bitcast; f64 passthrough columns (no i32 lane
+                    # route on TPU) gather by a carried row-index lane that
+                    # equals the argsort order bit-for-bit, dead rows included
+                    parts = [lane_rows]
+                    if qsc_rows is not None:
+                        parts.append(
+                            jax.lax.bitcast_convert_type(qsc_rows, jnp.int32)
+                        )
+                    if pt_cols:
+                        parts.append(
+                            jnp.arange(
+                                world * bc, dtype=jnp.int32
+                            ).reshape(-1, 1)
+                        )
+                    moved, total = _codec.fused_compact_move(
+                        jnp.concatenate(parts, axis=1), recv_counts, world, bc,
+                        interpret=ctx.platform == "cpu",
+                    )
+                    nw = lane_rows.shape[1]
+                    word_lanes = [moved[:, j] for j in range(nw)]
+                    qsc = None
+                    if qsc_rows is not None:
+                        nq8 = qsc_rows.shape[1]
+                        qsc = jax.lax.bitcast_convert_type(
+                            moved[:, nw : nw + nq8], jnp.float32
+                        )
+                        nw += nq8
+                    if pt_cols:
+                        order = moved[:, nw]
+                        sorted_pt = {ci: d[order] for ci, d in pt_cols.items()}
+                    else:
+                        sorted_pt = {}
+                    mk_valid = (
+                        lambda lane: None if lane is None
+                        else lane.astype(jnp.bool_)
+                    )
+                    if wire is not None:
+                        (bases,) = rep
+                        out = _g_pack.wire_unpack_cols(
+                            word_lanes, wire, bases,
+                            lambda ci: sorted_pt[ci], mk_valid, qscales=qsc,
+                        )
+                    else:
+                        out, _ = _g_pack.unpack_cols(
+                            list(plan_sig), word_lanes,
+                            lambda ci: sorted_pt[ci], mk_valid,
+                        )
+                    return out, _scalar(total)
+                mask, total = _sh.received_row_mask(recv_counts, world, bc)
                 if wire is not None:
                     (bases,) = rep
                     out = _sh.compact_received_wire(
-                        wire, bases, lane_rows, pt_cols, mask
+                        wire, bases, lane_rows, pt_cols, mask,
+                        qscale_rows=qsc_rows,
                     )
                 else:
                     out = _sh.compact_received_lanes(
                         list(plan_sig), lane_rows, pt_cols, mask
                     )
                 return out, _scalar(total)
-            (head, pts) = dp
-            qsc_rows = None
-            if wire is not None:
-                n_header = _sh.wire_header_rows(wire)
-                lane_rows, recv_counts = _sh.split_header(
-                    head, world, n_header
-                )
-                bc = lane_rows.shape[0] // world
-                nq8 = len(_g_pack.wire_q8_cols(wire))
-                if nq8:
-                    # each received row dequantizes with its SOURCE
-                    # chunk's block scale, broadcast from the header rows
-                    # before the compaction permutes anything
-                    qsc_rows = _sh.recv_row_scales(
-                        _sh.split_header_scales(
-                            head, world, n_header, nq8
-                        ),
-                        world, bc,
-                    )
-                pt_cols = dict(
-                    zip(_g_pack.wire_pt_order(wire, pt_order), pts)
-                )
-            elif has_lanes:
-                lane_rows, recv_counts = _sh.split_header(head, world)
-                bc = lane_rows.shape[0] // world
-                pt_cols = dict(zip(pt_order, pts))
-            else:
-                lane_rows, recv_counts = None, head
-                bc = pts[0].shape[0] // world
-                pt_cols = dict(zip(pt_order, pts))
-            nml = 0
-            if lane_rows is not None:
-                nml = (
-                    lane_rows.shape[1]
-                    + (qsc_rows.shape[1] if qsc_rows is not None else 0)
-                    + (1 if pt_cols else 0)
-                )
-            if _codec.compact_engaged(
-                lane_rows is not None, False, world, bc, nml
-            ):
-                # fused front-pack (ops/pallas_codec): ONE masked block-
-                # copy pass replaces the liveness mask + stable argsort +
-                # 400x-priced row gather. q8 scale rows ride the move
-                # matrix bitcast; f64 passthrough columns (no i32 lane
-                # route on TPU) gather by a carried row-index lane that
-                # equals the argsort order bit-for-bit, dead rows included
-                parts = [lane_rows]
-                if qsc_rows is not None:
-                    parts.append(
-                        jax.lax.bitcast_convert_type(qsc_rows, jnp.int32)
-                    )
-                if pt_cols:
-                    parts.append(
-                        jnp.arange(
-                            world * bc, dtype=jnp.int32
-                        ).reshape(-1, 1)
-                    )
-                moved, total = _codec.fused_compact_move(
-                    jnp.concatenate(parts, axis=1), recv_counts, world, bc,
-                    interpret=ctx.platform == "cpu",
-                )
-                nw = lane_rows.shape[1]
-                word_lanes = [moved[:, j] for j in range(nw)]
-                qsc = None
-                if qsc_rows is not None:
-                    nq8 = qsc_rows.shape[1]
-                    qsc = jax.lax.bitcast_convert_type(
-                        moved[:, nw : nw + nq8], jnp.float32
-                    )
-                    nw += nq8
-                if pt_cols:
-                    order = moved[:, nw]
-                    sorted_pt = {ci: d[order] for ci, d in pt_cols.items()}
-                else:
-                    sorted_pt = {}
-                mk_valid = (
-                    lambda lane: None if lane is None
-                    else lane.astype(jnp.bool_)
-                )
-                if wire is not None:
-                    (bases,) = rep
-                    out = _g_pack.wire_unpack_cols(
-                        word_lanes, wire, bases,
-                        lambda ci: sorted_pt[ci], mk_valid, qscales=qsc,
-                    )
-                else:
-                    out, _ = _g_pack.unpack_cols(
-                        list(plan_sig), word_lanes,
-                        lambda ci: sorted_pt[ci], mk_valid,
-                    )
-                return out, _scalar(total)
-            mask, total = _sh.received_row_mask(recv_counts, world, bc)
-            if wire is not None:
-                (bases,) = rep
-                out = _sh.compact_received_wire(
-                    wire, bases, lane_rows, pt_cols, mask,
-                    qscale_rows=qsc_rows,
-                )
-            else:
-                out = _sh.compact_received_lanes(
-                    list(plan_sig), lane_rows, pt_cols, mask
-                )
-            return out, _scalar(total)
 
         return kern
 
@@ -3885,7 +3903,8 @@ def _shuffle_many(specs: Sequence["_ShuffleSpec"]) -> List["Table"]:
             dp = dp + (spec.sketch,)
         with span("shuffle.count", rows=st["t"]._rows_hint()):
             st["counts_fut"] = get_kernel(
-                st["ctx"], st["key"] + ("count",), st["build_count"]
+                st["ctx"], st["key"] + ("count",), st["build_count"],
+                name="shuffle_count",
             )(dp, ())
     for st in states:
         bump("host_sync")
@@ -4370,7 +4389,7 @@ def _shuffle_many_rounds(states, rows_total) -> List["Table"]:
                 ):
                     st["ring_out"] = get_kernel(
                         st["ctx"], st["key"] + ("relay", "ring"),
-                        st["build_ring"],
+                        st["build_ring"], name="shuffle_ring",
                     )(dp, (jnp.zeros((cap_ri,), jnp.int8), quota) + usef)
                 if st["relay_inter"] is None:
                     continue
@@ -4383,7 +4402,7 @@ def _shuffle_many_rounds(states, rows_total) -> List["Table"]:
             )
             with span("shuffle.round.relay", rows=st["sched"].relay_rows()):
                 st["relay_out"] = get_kernel(
-                    st["ctx"], rkey, st["build_relay"]
+                    st["ctx"], rkey, st["build_relay"], name="shuffle_relay"
                 )(dp, rep)
         for r in range(max(st["n_rounds"] for st in states)):
             for st in states:
@@ -4406,7 +4425,8 @@ def _shuffle_many_rounds(states, rows_total) -> List["Table"]:
                 with span("shuffle.round.pack"):
                     head, pts = get_kernel(
                         ctx, st["key"] + ("pack", st["wire"]),
-                        st["build_pack"], **_codec.kernel_kwargs(),
+                        st["build_pack"], name="shuffle_pack",
+                        **_codec.kernel_kwargs(),
                     )(dp, rep)
                 t_pk1 = _time.perf_counter()
                 # the two-hop plan joins both dispatch keys: its cap_o /
