@@ -351,8 +351,8 @@ def _canonical_ids(
 
 
 def _right_order(r_ids: jax.Array, cap_cat: int) -> jax.Array:
-    """Stable argsort of the canonical right ids: radix where the lane is
-    eligible, else the native sort."""
+    """Stable argsort of the canonical right ids: the native sort, or
+    radix passes where a radix tier is selected (ops/radix.py)."""
     with jax.named_scope(_stages.JOIN_RIGHT_SORT):
         r_order = _radix.argsort_perm(r_ids, _ids_hint(r_ids, cap_cat))
         if r_order is None:

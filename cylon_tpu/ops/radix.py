@@ -1,15 +1,24 @@
-"""Width-adaptive LSD radix sort engine.
+"""Sort engine selection, and the width-adaptive LSD radix engine.
 
 Every ordering in this codebase bottoms out in chained stable 1-key
-``jax.lax.sort`` passes (ops/sort.py) — XLA lowers each to a bitonic
-network of ~log2(n)*(log2(n)+1)/2 compare-exchange sweeps over HBM. A
-comparison sort cannot use the one thing the lane-packing stats engine
-(ops/stats.py, PR 5) already measures: the LIVE BIT WIDTH of every sort
-lane. A d-bit key radix-sorts in ceil(d/r) stable histogram ->
-exclusive-scan -> scatter passes (r-bit digits), and per-pass STABILITY
-makes the multi-lane lexsort just a pass sequence — the payload-ride
-machinery (split_ride_cols / merge_ride_cols) is unchanged, payloads are
-gathered ONCE by the final permutation instead of riding every sweep.
+``jax.lax.sort`` passes (ops/sort.py), the chip's native sort, which the
+code calls ``"bitonic"``. That is the default: on a v5e a pass of the
+radix engine below pays per element for its gathers and its scatter
+(about 17 ns a row a pass; PERF.md, PR 25), so seven passes over
+4,194,304 rows cost 914 ms where the native sort of the same fused word
+takes 5.6 ms (PERF.md, PR 26). The radix engine stays selectable by
+force (and by an applied ``Decisions.sort_impl``, which no proposal
+sets to a radix tier); nothing selects it by default.
+
+The radix engine: a comparison sort cannot use the one thing the
+lane-packing stats engine (ops/stats.py, PR 5) measures: the LIVE BIT
+WIDTH of every sort lane. A d-bit key radix-sorts in ceil(d/r) stable
+histogram -> exclusive-scan -> scatter passes (r-bit digits), and
+per-pass STABILITY makes the multi-lane lexsort just a pass sequence —
+the payload-ride machinery (split_ride_cols / merge_ride_cols) is
+unchanged, payloads are gathered ONCE by the final permutation instead
+of riding every sweep. The pass COUNT is small; the time of a pass on
+the chip is not.
 
 The XLA tier (:func:`radix_pass`) carries a permutation, not the data:
 per pass it gathers the keyed lane through the current perm, builds the
@@ -32,13 +41,14 @@ resolved impl is sound inside kernel cache keys):
 
 1. ``CYLON_TPU_NO_RADIX=1`` — kill switch, everything bitonic. Its
    ``disabled()`` context manager IS the differential oracle the tests
-   and the fuzz radix profile diff against.
+   and the fuzz radix profile diff a FORCED radix run against.
 2. ``CYLON_TPU_SORT_IMPL`` in {bitonic, radix, radix_pallas} forces.
 3. The autopilot's per-shape ``Decisions.sort_impl`` (plan/feedback.py),
    visible through the applying() contextvar during plan execution.
-4. Default ``auto``: radix wherever the lane plan is eligible (no float
-   lanes — the f64 total-order lane has no integer digit decomposition,
-   so those sorts decline to bitonic at trace time).
+4. Default ``auto``: ``bitonic``, the native sort, for every lane plan.
+   (Under a radix tier a stack with a float lane still declines to it
+   at trace time: the f64 total-order lane has no integer digit
+   decomposition.)
 
 ``impl_tag()`` is the cache-key carrier: every sort-family kernel key
 appends it, so a mid-process flip of either knob (or a tuned decision
@@ -70,20 +80,22 @@ PALLAS_RADIX_BITS = 8
 IMPLS = ("bitonic", "radix", "radix_pallas")
 
 # kill switch + differential oracle (CYLON_TPU_NO_RADIX=1 -> bitonic
-# everywhere; tests diff exact emitted order against it)
+# everywhere, over any force; tests diff a forced radix run's exact
+# emitted order against it)
 enabled, disabled = env_gate(
     "CYLON_TPU_NO_RADIX",
     keyed_via="ops.radix.impl_tag appended to every sort-family kernel "
     "cache key; plan fingerprints carry ops.radix.gate_state",
-    note="=1 disables the radix sort engine (bitonic everywhere) — the "
-    "differential oracle for exact emitted-order tests",
+    note="=1 disables the radix sort engine over any force or tuned "
+    "decision (bitonic, the default, everywhere) — the differential "
+    "oracle for exact emitted-order tests",
 )
 
 
 def resolved_impl() -> str:
     """The selected sort impl for the CURRENT trace: kill switch, then
     the forcing env, then the autopilot's applied per-shape decision,
-    then the ``auto`` default (radix where the lane plan is eligible).
+    then the ``auto`` default: ``"bitonic"``, the chip's native sort.
     Host env/contextvar reads only — shape-static, cache-key safe."""
     if not enabled():
         return "bitonic"
@@ -95,7 +107,7 @@ def resolved_impl() -> str:
     tuned = _fb.tuned_sort_impl()
     if tuned in IMPLS:
         return tuned
-    return "radix"
+    return "bitonic"
 
 
 def impl_tag() -> tuple:
@@ -412,8 +424,9 @@ def kv_sort(
     pay: jax.Array,
     hint: Optional[Hint] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Stable 1-key kv-sort (the join probe's merged sort): radix when
-    eligible, else the native ``jax.lax.sort``. Returns (skey, spay)."""
+    """Stable 1-key kv-sort (the join probe's merged sort): the native
+    ``jax.lax.sort``, or radix passes where a radix tier is selected and
+    the lane eligible. Returns (skey, spay)."""
     with jax.named_scope(_stages.SORT_ENGINE):
         perm = argsort_perm(keys, hint)
         if perm is not None:

@@ -130,9 +130,10 @@ class Decisions(NamedTuple):
     #: show radix not beating the bitonic lowering (the ROADMAP's "a
     #: kernel must beat its XLA lowering to merge" rule, enforced at
     #: runtime per fingerprint); ``"radix"``/``"radix_pallas"`` pin a
-    #: tier. None = the static default (radix where the lane plan is
-    #: eligible). Policy only: the stable lexsort permutation is unique,
-    #: so every impl is bit-exact — only milliseconds move.
+    #: tier. None = the static default (the native sort, which is what
+    #: ``"bitonic"`` names; no proposal ever pins a radix tier). Policy
+    #: only: the stable lexsort permutation is unique, so every impl is
+    #: bit-exact — only milliseconds move.
     sort_impl: Optional[str] = None
     #: shuffle codec impl (ops/pallas_codec.py): ``"xla"`` walks a shape
     #: back to the XLA pack/compact lowerings when its journaled codec
@@ -317,7 +318,7 @@ def effective_decisions(p: Dict[str, Any]) -> tuple:
         sm = None
     si = dec.get("sort_impl")
     if si == STATIC:
-        # decided: radix holds up, keep the static default
+        # decided: nothing to walk back, keep the static default
         si = None
     ci = dec.get("codec_impl")
     if ci == STATIC:
@@ -808,7 +809,7 @@ def describe(base: tuple) -> list:
         )
         lines.append(
             f"sort_impl tuned: {d.sort_impl} "
-            f"(was radix-where-eligible, n={n_sort})"
+            f"(was the static default, n={n_sort})"
         )
     if d.codec_impl is not None:
         n_codec = sum(

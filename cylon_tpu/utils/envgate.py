@@ -177,7 +177,7 @@ SORT_IMPL = EnvKnob(
     "CYLON_TPU_SORT_IMPL", "auto", kind="impl",
     keyed_via="ops.radix.impl_tag appended to every sort-family cache "
     "key; plan fingerprints carry ops.radix.gate_state",
-    note="sort engine: 'auto' (radix where the lane plan is eligible), "
+    note="sort engine: 'auto' (= 'bitonic', the chip's native sort), "
     "'bitonic', 'radix', 'radix_pallas'",
 )
 CODEC_IMPL = EnvKnob(

@@ -14,6 +14,12 @@ Layers, mirroring test_lane_pack.py:
   3. selection — the impl tag recompiles (never aliases) across
      CYLON_TPU_SORT_IMPL flips, and the forced Pallas tier (interpret
      mode on CPU) emits the same permutation.
+
+The default engine is the native sort, which is also the oracle's, so
+the subject side of every test here runs under an explicit
+``CYLON_TPU_SORT_IMPL=radix`` (the ``radix_forced`` fixture); the kill
+switch inside ``_oracle`` wins over that force. The default's own
+tests are in test_sort_default.py.
 """
 import os
 import sys
@@ -29,6 +35,12 @@ import jax.numpy as jnp
 
 import cylon_tpu as ct
 from cylon_tpu.ops import radix as rx
+
+
+@pytest.fixture(autouse=True)
+def radix_forced(monkeypatch):
+    """Every test's subject side is the radix engine, by force."""
+    monkeypatch.setenv("CYLON_TPU_SORT_IMPL", "radix")
 
 
 @pytest.fixture(scope="module")

@@ -137,7 +137,7 @@ def test_no_hot_path_program_is_called_kern(dispatched):
 
 
 @pytest.mark.parametrize("key,name,want", [
-    (("join", 0, (0,), "radix", "spec"), "join_spec", "join_spec"),
+    (("join", 0, (0,), "bitonic", "spec"), "join_spec", "join_spec"),
     (("shuffle", "hash", (0,), "pack"), None, "shuffle"),
     (("semi_sketch", ((("int64", False),),), 12), None, "semi_sketch"),
     ((3, "task split/sort"), None, "task_split_sort"),
@@ -206,6 +206,7 @@ def test_stale_compiled_text_is_reported(how, monkeypatch):
     ("jit(join_spec)/join.probe/sort_engine/jit(radix_pass)/gather", "join.probe", True),
     ("jit(sort)/shard_map/sort.perm/sort_engine/jit(radix_pass)/scatter", "sort.perm", True),
     ("jit(join_spec)/join.emit/gather", "join.emit", False),
+    ("jit(join_spec)/join.right_sort/sort_engine/sort", "join.right_sort", True),
     ("jit(f)/sort_engine/sort", "sort_engine", True),
     ("jit(shuffle_pack)/shard_map/shuffle.pack/semi.sketch/gather", "shuffle.pack", False),
     ("jit(join_spec)/concatenate", None, False),

@@ -835,7 +835,8 @@ def radix_round_once(seed) -> bool:
     (narrow/wide ints, bool, dict strings, floats — the digit planner
     must DECLINE float lanes and fall back bitonic), null densities,
     ascending/descending mixes, world sizes and a randomly forced impl
-    tier (auto / radix / radix_pallas); multi-key sort compared in
+    tier (radix / radix_pallas; the default is the oracle's own native
+    sort and would compare a thing with itself); multi-key sort compared in
     emitted order, unique / distributed groupby / join row-checked, all
     against the CYLON_TPU_NO_RADIX=1 bitonic oracle on the same inputs."""
     rng = np.random.default_rng(seed)
@@ -849,7 +850,7 @@ def radix_round_once(seed) -> bool:
         for _ in range(nkeys)
     ]
     asc = [bool(rng.integers(0, 2)) for _ in range(nkeys)]
-    impl = str(rng.choice(["auto", "radix", "radix_pallas"]))
+    impl = str(rng.choice(["radix", "radix_pallas"]))
     params = dict(seed=seed, profile="radix", n=n, world=world,
                   null_p=null_p, specs=specs, asc=asc, impl=impl)
     ctx = ctx_for(world)

@@ -240,6 +240,9 @@ def main():
     ap.add_argument("--gate", type=float, default=0.30,
                     help="minimum fractional sort-pass-byte reduction")
     args = ap.parse_args()
+    # the subject side is the radix engine, by force: the default is the
+    # native sort, which is also the oracle's (rx.disabled() wins over it)
+    os.environ.setdefault("CYLON_TPU_SORT_IMPL", "radix")
     sys.exit(run(args.rows, args.world, args.gate))
 
 
