@@ -524,6 +524,7 @@ EFFECT_SIGNATURES: Dict[str, str] = {
     "LazyFrame.select": "DISPATCH_SAFE",
     "LazyFrame.sort": "DISPATCH_SAFE",
     "LazyFrame.union": "DISPATCH_SAFE",
+    "LazyFrame.with_columns": "DISPATCH_SAFE",
     # the serving layer (ISSUE 9): submit/admission is DISPATCH_SAFE;
     # QueryFuture.result is the single per-query SYNC point; the drain
     # entry points that EXECUTE plans classify like dispatch (SYNC —

@@ -45,6 +45,17 @@ class Column:
         comparisons. NaN / None / NaT become nulls.
         """
         values = np.asarray(values)
+        if values.dtype == np.dtype("U1"):
+            # one-character strings (a flag column) hold no None, NaN or
+            # bool, and a value sorts as its code point: no object pass and
+            # no sort, mark the code points that occur and look every
+            # value's rank up in that table
+            points = values.reshape(-1).view(np.uint32)
+            seen = np.zeros(0x110000, bool)
+            seen[points] = True
+            codes = (np.cumsum(seen, dtype=np.int32) - 1)[points]
+            dictionary = np.flatnonzero(seen).astype(np.uint32).view("U1")
+            return codes, None, DataType(Type.STRING), dictionary
         if values.dtype.kind in ("U", "S", "O"):
             vals = np.asarray(values, dtype=object)
             is_null = np.array([v is None or (isinstance(v, float) and np.isnan(v)) for v in vals])

@@ -42,12 +42,16 @@ SHUFFLE_ALL_TO_ALL = "shuffle.all_to_all"
 SHUFFLE_COMPACT = "shuffle.compact"
 SEMI_SKETCH = "semi.sketch"
 GROUPBY_SEGMENT_SUM = "groupby.segment_sum"
+GROUPBY_KEY_IDS = "groupby.key_ids"
+GROUPBY_DENSE_AGG = "groupby.dense_agg"
+EXPR_EVAL = "expr.eval"
 
 VOCABULARY = (
     JOIN_KEY_IDS, JOIN_RIGHT_SORT, JOIN_PROBE, JOIN_EMIT,
     SORT_KEYS, SORT_PERM, SORT_GATHER, SORT_ENGINE,
     SHUFFLE_COUNT, SHUFFLE_PACK, SHUFFLE_ALL_TO_ALL, SHUFFLE_COMPACT,
-    SEMI_SKETCH, GROUPBY_SEGMENT_SUM,
+    SEMI_SKETCH, GROUPBY_SEGMENT_SUM, GROUPBY_KEY_IDS, GROUPBY_DENSE_AGG,
+    EXPR_EVAL,
 )
 _VOCABULARY = frozenset(VOCABULARY)
 

@@ -17,6 +17,7 @@ two ``searchsorted`` bounds on the host and a code compare on device.
 """
 from __future__ import annotations
 
+import operator
 from typing import FrozenSet, Mapping, Optional, Tuple
 
 import jax
@@ -51,9 +52,24 @@ class Expr:
         """Structural fingerprint (feeds the plan-fingerprint cache)."""
         raise NotImplementedError
 
-    def evaluate(self, cols: Mapping[str, Column]) -> KeyCol:
-        """-> (data, valid|None) arrays over the table's physical rows."""
+    def evaluate(self, cols: Mapping[str, Column], lits=None) -> KeyCol:
+        """-> (data, valid|None) arrays over the table's physical rows.
+        ``lits`` maps ``id(Lit node)`` to what stands in for the literal's
+        value inside a kernel, which takes its literals as arguments so
+        that a sweep of a literal compiles nothing
+        (:func:`numeric_literals`). A python scalar handed to a program is
+        weakly typed there as it is here: either way ``x == 0.1`` compares
+        a float32 column in float32."""
         raise NotImplementedError
+
+    def shape_key(self) -> tuple:
+        """:meth:`key` without the numeric literals' values: the identity
+        of a compiled evaluation kernel."""
+        raise NotImplementedError
+
+    def _walk(self):
+        """Every node of the expression, depth first, left to right."""
+        yield self
 
     # -- operator sugar ----------------------------------------------------
     def _bin(self, op: str, other) -> "BinOp":
@@ -92,6 +108,19 @@ class Expr:
     def __mod__(self, other):
         return self._bin("%", other)
 
+    # a scalar on the left: ``1 - col("d")``
+    def __radd__(self, other):
+        return Lit(other)._bin("+", self)
+
+    def __rsub__(self, other):
+        return Lit(other)._bin("-", self)
+
+    def __rmul__(self, other):
+        return Lit(other)._bin("*", self)
+
+    def __rtruediv__(self, other):
+        return Lit(other)._bin("/", self)
+
     def __and__(self, other):
         return self._bin("&", other)
 
@@ -121,7 +150,9 @@ class Col(Expr):
     def key(self) -> tuple:
         return ("col", self.name)
 
-    def evaluate(self, cols) -> KeyCol:
+    shape_key = key
+
+    def evaluate(self, cols, lits=None) -> KeyCol:
         c = cols[self.name]
         if c.dtype.is_dictionary:
             # codes only compare meaningfully against an encoded literal;
@@ -139,16 +170,24 @@ class Col(Expr):
 class Lit(Expr):
     def __init__(self, value):
         if isinstance(value, Expr) or not isinstance(
-            value, (int, float, bool, str, np.integer, np.floating, np.bool_)
+            value, (int, float, bool, str, np.integer, np.floating, np.bool_,
+                    np.datetime64)
         ):
             # fail at build time with a clear message — an unhashable value
             # would otherwise surface as a bare TypeError from the plan
             # fingerprint inside collect()
             raise TypeError(
-                f"plan literals must be scalars (int/float/bool/str), "
-                f"got {type(value).__name__}"
+                f"plan literals must be scalars (int/float/bool/str/"
+                f"datetime64), got {type(value).__name__}"
             )
         self.value = value
+
+    def physical(self):
+        """The value as the columns hold it: a date or a time as int64
+        nanoseconds (``Column.encode_host``), anything else as it is."""
+        if isinstance(self.value, np.datetime64):
+            return self.value.astype("datetime64[ns]").astype(np.int64)
+        return self.value
 
     def columns(self) -> FrozenSet[str]:
         return frozenset()
@@ -159,8 +198,15 @@ class Lit(Expr):
     def key(self) -> tuple:
         return ("lit", type(self.value).__name__, self.value)
 
-    def evaluate(self, cols) -> KeyCol:
-        return jnp.asarray(self.value), None
+    def shape_key(self) -> tuple:
+        if isinstance(self.value, str):
+            return self.key()
+        return ("lit", type(self.value).__name__)
+
+    def evaluate(self, cols, lits=None) -> KeyCol:
+        if lits is not None and id(self) in lits:
+            return lits[id(self)], None
+        return jnp.asarray(self.physical()), None
 
     def __repr__(self):
         return repr(self.value)
@@ -168,6 +214,16 @@ class Lit(Expr):
 
 _CMP = {"==", "!=", "<", "<=", ">", ">="}
 _BOOL = {"&", "|"}
+
+
+#: ``left op right`` on arrays or scalars, promoting as ``jnp`` does
+_OPS = {
+    "==": operator.eq, "!=": operator.ne, "<": operator.lt,
+    "<=": operator.le, ">": operator.gt, ">=": operator.ge,
+    "+": operator.add, "-": operator.sub, "*": operator.mul,
+    "/": operator.truediv, "%": operator.mod,
+    "&": operator.and_, "|": operator.or_,
+}
 
 
 class BinOp(Expr):
@@ -184,6 +240,14 @@ class BinOp(Expr):
 
     def key(self) -> tuple:
         return ("bin", self.op, self.left.key(), self.right.key())
+
+    def shape_key(self) -> tuple:
+        return ("bin", self.op, self.left.shape_key(), self.right.shape_key())
+
+    def _walk(self):
+        yield self
+        yield from self.left._walk()
+        yield from self.right._walk()
 
     def _dict_literal_cmp(self, c: Column, value, flip: bool) -> KeyCol:
         """Dictionary-encoded column vs string literal: compare codes
@@ -209,7 +273,7 @@ class BinOp(Expr):
             out = code >= lo
         return out, c.valid
 
-    def evaluate(self, cols) -> KeyCol:
+    def evaluate(self, cols, lits=None) -> KeyCol:
         if self.op in _CMP:
             # string-column comparisons route through the dictionary
             l, r = self.left, self.right
@@ -221,39 +285,10 @@ class BinOp(Expr):
                 c = cols[r.name]
                 if c.dtype.is_dictionary:
                     return self._dict_literal_cmp(c, l.value, flip=True)
-        ld, lv = self.left.evaluate(cols)
-        rd, rv = self.right.evaluate(cols)
+        ld, lv = self.left.evaluate(cols, lits)
+        rd, rv = self.right.evaluate(cols, lits)
         valid = _and_valid(lv, rv)
-        op = self.op
-        if op == "==":
-            out = ld == rd
-        elif op == "!=":
-            out = ld != rd
-        elif op == "<":
-            out = ld < rd
-        elif op == "<=":
-            out = ld <= rd
-        elif op == ">":
-            out = ld > rd
-        elif op == ">=":
-            out = ld >= rd
-        elif op == "+":
-            out = ld + rd
-        elif op == "-":
-            out = ld - rd
-        elif op == "*":
-            out = ld * rd
-        elif op == "/":
-            out = ld / rd
-        elif op == "%":
-            out = ld % rd
-        elif op == "&":
-            out = ld & rd
-        elif op == "|":
-            out = ld | rd
-        else:
-            raise ValueError(f"unknown operator {op!r}")
-        return out, valid
+        return _OPS[self.op](ld, rd), valid
 
     def __repr__(self):
         return f"({self.left!r} {self.op} {self.right!r})"
@@ -273,8 +308,15 @@ class UnOp(Expr):
     def key(self) -> tuple:
         return ("un", self.op, self.operand.key())
 
-    def evaluate(self, cols) -> KeyCol:
-        d, v = self.operand.evaluate(cols)
+    def shape_key(self) -> tuple:
+        return ("un", self.op, self.operand.shape_key())
+
+    def _walk(self):
+        yield self
+        yield from self.operand._walk()
+
+    def evaluate(self, cols, lits=None) -> KeyCol:
+        d, v = self.operand.evaluate(cols, lits)
         return (~d if self.op == "~" else -d), v
 
     def __repr__(self):
@@ -291,10 +333,51 @@ def lit(value) -> Lit:
     return Lit(value)
 
 
-def filter_mask(expr: Expr, cols: Mapping[str, Column]) -> jax.Array:
-    """Evaluate a predicate to the boolean KEEP mask ``Table.filter`` takes:
-    null predicate rows (any referenced column null) are dropped."""
-    data, valid = expr.evaluate(cols)
+def numeric_literals(expr: Expr) -> list:
+    """The expression's non-string ``Lit`` nodes in walk order: what an
+    evaluation kernel takes as arguments (a string literal is compared
+    through a column's dictionary on the host and stays in the key)."""
+    return [
+        e for e in expr._walk()
+        if isinstance(e, Lit) and not isinstance(e.value, str)
+    ]
+
+
+def _promotes_as(expr: Expr, dtype_of):
+    """What ``expr`` promotes as: a dtype, or the python type of a
+    python-scalar literal, which promotes weakly (``x + 1.5`` over a
+    float32 column stays float32)."""
+    if isinstance(expr, Col):
+        return np.dtype(dtype_of(expr.name))
+    if isinstance(expr, Lit):
+        v = expr.physical()
+        return type(v) if type(v) in (bool, int, float) else np.asarray(v).dtype
+    if isinstance(expr, UnOp):
+        return _promotes_as(expr.operand, dtype_of)
+    if expr.op in _CMP:
+        return np.dtype(bool)
+    sides = [
+        d() if isinstance(d, type) else jax.ShapeDtypeStruct((), d)
+        for d in (_promotes_as(e, dtype_of) for e in (expr.left, expr.right))
+    ]
+    return np.dtype(jax.eval_shape(_OPS[expr.op], *sides).dtype)
+
+
+def result_dtype(expr: Expr, dtype_of) -> str:
+    """Approximate physical dtype of ``expr`` over columns whose physical
+    dtype ``dtype_of(name)`` gives (plan schema, display and fingerprint
+    only: lowering keeps whatever the evaluation really promotes to)."""
+    return str(np.dtype(jnp.result_type(_promotes_as(expr, dtype_of))))
+
+
+def keep_mask(data: jax.Array, valid: Optional[jax.Array]) -> jax.Array:
+    """An evaluated predicate as the boolean KEEP mask ``Table.filter``
+    takes: a null predicate row (any referenced column null) is dropped."""
     if data.dtype != jnp.bool_:
         raise TypeError(f"filter predicate must be boolean, got {data.dtype}")
     return data if valid is None else data & valid
+
+
+def filter_mask(expr: Expr, cols: Mapping[str, Column]) -> jax.Array:
+    """Evaluate a predicate eagerly to its :func:`keep_mask`."""
+    return keep_mask(*expr.evaluate(cols))

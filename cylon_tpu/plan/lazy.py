@@ -33,6 +33,7 @@ from .nodes import (
     Scan,
     Sort,
     Union,
+    WithColumns,
 )
 
 
@@ -156,6 +157,19 @@ class LazyFrame:
         ) + list(more)
         cols = [c.name if isinstance(c, Col) else c for c in items]
         return self._wrap(Project(self._plan, cols))
+
+    def with_columns(self, exprs: Dict[str, Expr]) -> "LazyFrame":
+        """Add computed columns, one a ``name -> expression`` entry:
+        ``with_columns({"net": col("price") * (1 - col("disc"))})``. A
+        name that exists is replaced in place; an aggregate over an
+        expression is a ``groupby`` over the computed column."""
+        for name, e in exprs.items():
+            if not isinstance(e, Expr):
+                raise TypeError(
+                    f"with_columns[{name!r}] takes a plan expression, "
+                    f"got {type(e).__name__}"
+                )
+        return self._wrap(WithColumns(self._plan, list(exprs.items())))
 
     def join(
         self,

@@ -15,11 +15,16 @@ from __future__ import annotations
 
 from typing import Callable, Dict, List, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
+from ..column import Column
+from ..engine import get_kernel
+from ..obs import stages as _stages
 from ..obs import trace as _obstrace
 from ..utils.tracing import span
-from .expr import filter_mask
+from .expr import keep_mask, numeric_literals
 from .nodes import (
     Filter,
     FusedJoinGroupBySum,
@@ -32,6 +37,7 @@ from .nodes import (
     Shuffle,
     Sort,
     Union,
+    WithColumns,
 )
 
 
@@ -40,18 +46,18 @@ def scan_tables(root: Node) -> list:
     return their bound tables in that order. Called before fingerprinting."""
     tables: list = []
     seen: Dict[int, int] = {}
-
-    def walk(n: Node) -> None:
+    # a loop and not a function that calls itself: this runs once a
+    # query, and a self-naming closure is a cycle for the collector
+    stack = [root]
+    while stack:
+        n = stack.pop()
         if isinstance(n, Scan):
             if id(n) not in seen:
                 seen[id(n)] = len(tables)
                 tables.append(n.table)
             n.ordinal = seen[id(n)]
-            return
-        for c in n.children:
-            walk(c)
-
-    walk(root)
+        else:
+            stack.extend(reversed(n.children))
     return tables
 
 
@@ -163,29 +169,86 @@ def build_executor(root: Node) -> Callable[[List], "object"]:
     order = plan_order(root)
 
     def run(tables: List):
-        memo: Dict[int, object] = {}
-
-        def ex(node: Node):
-            got = memo.get(id(node))
-            if got is not None:
-                return got
-            with span(
-                "plan.node." + type(node).__name__,
-                node_id=order[id(node)],
-            ) as sp:
-                out = _lower_one(node, ex, tables)
-                if _obstrace.analyze_active():
-                    out._materialize()
-                if sp is not None:
-                    rows = out._rows_hint()
-                    if rows is not None:
-                        sp.attrs["rows_out"] = rows
-            memo[id(node)] = out
-            return out
-
-        return ex(root)
+        return _execute(root, order, tables, {})
 
     return run
+
+
+def _execute(node: Node, order: Dict[int, int], tables: List, memo: dict):
+    """One node of a run, its children first; ``memo`` holds the run's
+    results so far (a shared subplan runs once). A module-level function
+    and not a closure of ``run`` that calls itself: a function that names
+    itself is a reference cycle, and a memo hanging from it (a computed
+    projection of a 60M-row table is a gigabyte of HBM) would live until
+    the cycle collector happens by, some queries later."""
+    got = memo.get(id(node))
+    if got is not None:
+        return got
+    with span(
+        "plan.node." + type(node).__name__, node_id=order[id(node)],
+    ) as sp:
+        out = _lower_one(
+            node, lambda child: _execute(child, order, tables, memo), tables
+        )
+        if _obstrace.analyze_active():
+            out._materialize()
+        if sp is not None:
+            rows = out._rows_hint()
+            if rows is not None:
+                sp.attrs["rows_out"] = rows
+    memo[id(node)] = out
+    return out
+
+
+def eval_exprs(t, exprs) -> list:
+    """Plan expressions over table ``t``'s columns, as ``(data, valid |
+    None)`` pairs in the columns' padded layout: one program
+    (``jit_expr_eval``, stage ``expr.eval``) whose numeric literals are
+    arguments, so a sweep of a literal compiles nothing. The literals go
+    in as the scalars they are: a python ``0.1`` is as weakly typed in
+    the program as in an eager evaluation. An expression that reads a
+    dictionary column is compared through the dictionary on the host and
+    evaluates eagerly, as before."""
+    used = sorted(set().union(*(e.columns() for e in exprs)))
+    if not used:
+        raise ValueError("a plan expression must read a column")
+    if any(t._columns[n].dtype.is_dictionary for n in used):
+        env = {n: t._columns[n] for n in used}
+        return [e.evaluate(env) for e in exprs]
+    lits = [l for e in exprs for l in numeric_literals(e)]
+    dtypes = tuple(t._columns[n].dtype for n in used)
+    key = (
+        "expr_eval", tuple(e.shape_key() for e in exprs), tuple(used),
+        tuple(int(d.type) for d in dtypes),
+    )
+
+    def build():
+        def kern(dp, rep):
+            cols = dp
+            cap = cols[0][0].shape[0]
+            env = {
+                n: Column(d, dt, v, None)
+                for n, dt, (d, v) in zip(used, dtypes, cols)
+            }
+            vals = {id(l): x for l, x in zip(lits, rep)}
+            with jax.named_scope(_stages.EXPR_EVAL):
+                out = []
+                for e in exprs:
+                    d, v = e.evaluate(env, vals)
+                    out.append((jnp.broadcast_to(d, (cap,)), v))
+                return out
+
+        return kern
+
+    return get_kernel(t.ctx, key, build)(
+        t._flat_cols(used), tuple(l.physical() for l in lits)
+    )
+
+
+def _expr_mask(t, expr) -> jax.Array:
+    """A plan predicate as the boolean keep mask ``Table.filter`` and
+    ``Table.groupby(_mask=)`` take (a null predicate row is dropped)."""
+    return keep_mask(*eval_exprs(t, [expr])[0])
 
 
 def _lower_one(node: Node, ex, tables):
@@ -195,8 +258,13 @@ def _lower_one(node: Node, ex, tables):
         return ex(node.children[0]).project(list(node.cols))
     if isinstance(node, Filter):
         t = ex(node.children[0])
-        mask = filter_mask(node.expr, {n: t._columns[n] for n in t.column_names})
-        return t.filter(mask)
+        return t.filter(_expr_mask(t, node.expr))
+    if isinstance(node, WithColumns):
+        t = ex(node.children[0])
+        out = eval_exprs(t, [e for _n, e in node.exprs])
+        return t._with_arrays(
+            {n: kc for (n, _e), kc in zip(node.exprs, out)}
+        )
     if isinstance(node, Sort):
         return ex(node.children[0]).sort(list(node.by), list(node.ascending))
     if isinstance(node, Shuffle):
@@ -213,7 +281,10 @@ def _lower_one(node: Node, ex, tables):
         spec: Dict[str, list] = {}
         for c, op in node.aggs:
             spec.setdefault(c, []).append(op)
-        res = t.groupby(list(node.keys), spec)
+        # filter_as_mask rewrite: the table takes the predicate as the
+        # aggregate's row mask (and still picks its own kernel)
+        mask = None if node.mask is None else _expr_mask(t, node.mask)
+        res = t.groupby(list(node.keys), spec, _mask=mask)
         # multiple ops per column group in dict order; restore plan order
         if res.column_names != node.names:
             res = res.project(node.names)

@@ -241,6 +241,68 @@ class Filter(Node):
         return f"Filter {self.expr!r}"
 
 
+class WithColumns(Node):
+    """Computed projection: the child's columns plus one column a
+    ``(name, expression)`` pair, a name that exists replaced in place
+    (lowered by ``plan.lower.eval_exprs``, one program over the rows)."""
+
+    def __init__(self, child: Node, exprs: Sequence[Tuple[str, object]]):
+        from ..dtypes import DataType
+        from .expr import result_dtype
+
+        exprs = tuple((str(n), e) for n, e in exprs)
+        if len({n for n, _e in exprs}) != len(exprs):
+            raise ValueError("with_columns names a column twice")
+        for name, e in exprs:
+            missing = [c for c in sorted(e.columns()) if c not in child.names]
+            if missing:
+                raise KeyError(
+                    f"with_columns {name!r} reads unknown columns: {missing}"
+                )
+        self.children = (child,)
+        self.exprs = exprs
+        phys = {n: p for n, _t, p in child.schema}
+        new = {}
+        for name, e in exprs:
+            p = result_dtype(e, phys.__getitem__)
+            new[name] = (name, int(DataType.from_numpy_dtype(p).type), p)
+        self.schema = tuple(
+            [new.get(e[0], e) for e in child.schema]
+            + [new[n] for n, _e in exprs if n not in phys]
+        )
+
+    @property
+    def computed(self) -> FrozenSet[str]:
+        return frozenset(n for n, _e in self.exprs)
+
+    def with_children(self, kids):
+        return WithColumns(kids[0], self.exprs)
+
+    def partitioning(self) -> Partitioning:
+        return [
+            s for s in self.children[0].partitioning()
+            if not set(s) & self.computed
+        ]
+
+    def ordering(self) -> Optional[Ordering]:
+        kept = [n for n in self.children[0].names if n not in self.computed]
+        return _ord.truncate_to(self.children[0].ordering(), kept)
+
+    def col_stats(self) -> Dict[str, object]:
+        # rows untouched: the bounds of the columns that were not replaced
+        return {
+            n: v for n, v in self.children[0].col_stats().items()
+            if n not in self.computed
+        }
+
+    def _params(self) -> tuple:
+        return tuple((n, e.key()) for n, e in self.exprs)
+
+    def label(self) -> str:
+        spec = ", ".join(f"{n}={e!r}" for n, e in self.exprs)
+        return f"WithColumns [{spec}]"
+
+
 class Join(Node):
     """Equi-join. Output names are fixed at BUILD time from the full child
     schemas (``_suffix_names``, the eager Table.join convention) and kept
@@ -368,10 +430,16 @@ class GroupBy(Node):
         keys: Sequence[str],
         aggs: Sequence[Tuple[str, str]],
         sorted_input: bool = False,
+        mask=None,
     ):
         self.children = (child,)
         self.keys = tuple(keys)
         self.aggs = tuple(aggs)  # [(value column, op name)]
+        # set by the filter_as_mask rewrite: the predicate of a Filter that
+        # stood directly under this node, now the aggregate's row mask
+        # (``Table.groupby(_mask=...)``: the same groups and aggregates as
+        # filter-then-groupby, without compacting the rows first)
+        self.mask = mask
         # annotation set by the order_reuse rewrite: the child provably
         # emits key order, so lowering's eager groupby will run-detect
         # instead of lexsorting (the eager gate re-verifies — the plan
@@ -385,7 +453,9 @@ class GroupBy(Node):
         self.schema = tuple(out)
 
     def with_children(self, kids):
-        return GroupBy(kids[0], self.keys, self.aggs, self.sorted_input)
+        return GroupBy(
+            kids[0], self.keys, self.aggs, self.sorted_input, self.mask
+        )
 
     def partitioning(self) -> Partitioning:
         kept = set(self.keys)
@@ -407,7 +477,10 @@ class GroupBy(Node):
         }
 
     def _params(self) -> tuple:
-        return (self.keys, self.aggs, self.sorted_input)
+        return (
+            self.keys, self.aggs, self.sorted_input,
+            None if self.mask is None else self.mask.key(),
+        )
 
     def label(self) -> str:
         spec = ", ".join(f"{op}({c})" for c, op in self.aggs)
@@ -415,6 +488,8 @@ class GroupBy(Node):
             " [input key-ordered: groupby lexsort elided]"
             if self.sorted_input else ""
         )
+        if self.mask is not None:
+            tail += f" mask {self.mask!r}"
         return f"GroupBy [{', '.join(self.keys)}] agg [{spec}]{tail}"
 
 

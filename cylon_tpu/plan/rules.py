@@ -39,6 +39,10 @@ counted into the tracing registry by ``collect()``):
    part of the plan fingerprint. CYLON_TPU_NO_SEMI_FILTER=1 disables;
 7. ``projection_pushdown`` — prune unused columns down to the scans (and
    below the shuffles, where narrower rows mean fewer exchanged lanes).
+
+Between 4 and 5, ``filter_as_mask`` turns a Filter directly under a
+GroupBy into the aggregate's row mask (``GroupBy.mask``): the table skips
+the rows in its reductions instead of compacting every column first.
 """
 from __future__ import annotations
 
@@ -58,11 +62,13 @@ from .nodes import (
     Shuffle,
     Sort,
     Union,
+    WithColumns,
     _covers,
     _placed_by,
 )
 
 FILTER_PUSHDOWN = "filter_pushdown"
+FILTER_AS_MASK = "filter_as_mask"
 SHUFFLE_ELIM = "shuffle_elimination"
 FUSED_JOIN_GROUPBY = "fused_join_groupby"
 ORDER_REUSE = "order_reuse"
@@ -77,6 +83,7 @@ def optimize(root: Node, world_size: int) -> Tuple[Node, List[str]]:
         root = _physicalize(root)
     root = _eliminate_shuffles(root, fired)
     root = _fuse_join_groupby(root, fired)
+    root = _filter_as_mask(root, fired)
     root = _reuse_order(root, fired)
     if world_size > 1:
         root = _annotate_semi_filter(root, fired)
@@ -100,6 +107,12 @@ def _push_filters(node: Node, fired: List[str]) -> Node:
         # case: this pass runs BEFORE physicalize, so shuffles don't exist
         # yet — filters end up below them because physicalize inserts each
         # shuffle directly under its consumer, above the pushed filter.
+        fired.append(FILTER_PUSHDOWN)
+        inner = _push_filters(Filter(child.children[0], expr), fired)
+        return child.with_children([inner])
+    if isinstance(child, WithColumns) and not cols & child.computed:
+        # the predicate reads none of the computed columns: filter first,
+        # compute on the rows that are left
         fired.append(FILTER_PUSHDOWN)
         inner = _push_filters(Filter(child.children[0], expr), fired)
         return child.with_children([inner])
@@ -256,6 +269,38 @@ def _fuse_join_groupby(node: Node, fired: List[str]) -> Node:
     return FusedJoinGroupBySum(
         join.children[0], join.children[1], join.l_on, join.r_on, val_src,
         node.keys, key_order, f"{val_out}_sum", val_dtype,
+    )
+
+
+# ----------------------------------------------------------------------
+# 4b. a filter directly under an aggregate rides it as a row mask
+# ----------------------------------------------------------------------
+def _filter_as_mask(node: Node, fired: List[str]) -> Node:
+    """``GroupBy(Filter(x, p))`` -> ``GroupBy(x, mask=p)``: the aggregate
+    skips the rows instead of a compaction gathering every column of the
+    rest first. Runs after physicalize and shuffle elimination, so on a
+    mesh a filter that stands under the group-by's Shuffle stays there and
+    shrinks the exchange. ``WithColumns`` between the two is stepped over
+    when the predicate reads none of its columns (filter pushdown put the
+    filter below it; the mask is taken over the computed table, whose rows
+    are the same)."""
+    kids = [_filter_as_mask(c, fired) for c in node.children]
+    node = node.with_children(kids) if node.children else node
+    if not isinstance(node, GroupBy) or node.mask is not None:
+        return node
+    child, above = node.children[0], None
+    if isinstance(child, WithColumns):
+        child, above = child.children[0], child
+    if not isinstance(child, Filter):
+        return node
+    if above is not None and child.expr.columns() & above.computed:
+        return node
+    fired.append(FILTER_AS_MASK)
+    below = child.children[0]
+    if above is not None:
+        below = above.with_children([below])
+    return GroupBy(
+        below, node.keys, node.aggs, node.sorted_input, mask=child.expr
     )
 
 
@@ -417,8 +462,21 @@ def _prune(node: Node, req: Set[str], fired: List[str]) -> Node:
         return node.with_children([child])
     if isinstance(node, GroupBy):
         need = set(node.keys) | {c for c, _ in node.aggs}
+        if node.mask is not None:
+            need |= node.mask.columns()
         child = _prune(node.children[0], need, fired)
         return node.with_children([child])
+    if isinstance(node, WithColumns):
+        # a computed column nobody asks for is not computed
+        kept = [(n, e) for n, e in node.exprs if n in req]
+        below = set(node.children[0].names)
+        need = {n for n in req if n in below}.union(
+            *(e.columns() for _n, e in kept)
+        )
+        child = _prune(node.children[0], need, fired)
+        if len(kept) != len(node.exprs):
+            fired.append(PROJECTION_PUSHDOWN)
+        return WithColumns(child, kept) if kept else child
     if isinstance(node, Join):
         l_req = {s for s, o in node.l_rename.items() if o in req} | set(node.l_on)
         r_req = {s for s, o in node.r_rename.items() if o in req} | set(node.r_on)

@@ -109,7 +109,7 @@ def _rewrite(node: Node, memo: Dict[int, Tuple[Node, str]]) -> Tuple[Node, str]:
         out = (Sort(child, (q,) + node.by, (True,) + node.ascending), q)
     elif isinstance(node, GroupBy):
         child, q = _rewrite(node.children[0], memo)
-        out = (GroupBy(child, (q,) + node.keys, node.aggs), q)
+        out = (GroupBy(child, (q,) + node.keys, node.aggs, mask=node.mask), q)
     elif isinstance(node, Join):
         if node.how == "outer":
             # neither side's qid is non-null on every output row

@@ -1101,6 +1101,21 @@ class Table:
             list(zip(names, self._columns.values())), out, nout, cap_out
         )._attach_ordering(self._ordering)._attach_stats(self._stats)
 
+    def _with_arrays(self, arrays: Dict[str, KeyCol]) -> "Table":
+        """This table with one column a ``name -> (data, valid | None)``
+        entry, arrays in the columns' padded layout (the planner's computed
+        projection), a name that exists replaced in place. Rows untouched:
+        ordering and stats survive on the columns that were not replaced."""
+        cols = OrderedDict(self._columns)
+        for n, (d, v) in arrays.items():
+            cols[n] = Column(d, DataType.from_numpy_dtype(d.dtype), v, None)
+        kept = [n for n in self.column_names if n not in arrays]
+        res = self._replace(columns=cols)
+        res._counts_dev = self.counts_dev  # the rows are the same
+        return res._attach_ordering(
+            _ord.truncate_to(self._ordering, kept)
+        )._attach_stats({n: self._stats.get(n) for n in kept})
+
     def select(self, predicate) -> "Table":
         """Row filter by a vectorized predicate over a dict of column arrays.
         (Reference Select takes a row UDF, table.cpp:504-529; here the
@@ -2445,6 +2460,8 @@ class Table:
         ddof: int = 1,
         quantile: float = 0.5,
         _sorted: bool = False,
+        _mask=None,
+        _dense: bool = True,
     ) -> "Table":
         """Per-shard groupby-aggregate (reference HashGroupBy,
         groupby/hash_groupby.cpp). ``agg`` maps value column -> op(s) from
@@ -2456,9 +2473,42 @@ class Table:
         the rows canonically ordered by the group keys (a prior sort on
         mask-free keys, a key-order join emit, a groupby output...), the
         factorize lexsort is replaced by the run-detect pass automatically
-        — the ``PipelineGroupBy`` fast path without the caller contract."""
+        — the ``PipelineGroupBy`` fast path without the caller contract.
+
+        Dense path: when every key column has a measured range (or is
+        dictionary-coded) and the product of the spans is small
+        (``ops.groupby.DENSE_MAX_SLOTS``), group ids are arithmetic on the
+        rebased keys and every aggregate is a masked reduction a slot: no
+        sort, no scatter, no gather, same output. The input decides.
+
+        ``_mask`` (the planner's ``GroupBy(Filter)`` rewrite): a row mask
+        as :meth:`filter` takes it; rows whose mask is false or null
+        count in no aggregate and found no group, as if filtered first.
+        On the dense path the mask rides the reductions; otherwise the
+        rows are filtered first. ``_dense=False`` (tests and
+        measurements) keeps a call off the dense path."""
         key_names = self._resolve_cols(by)
+        # normalize agg spec -> list of (col, op_id, op_name)
+        specs: List[Tuple[str, int, str]] = []
+        for col, ops in agg.items():
+            ops_list = ops if isinstance(ops, (list, tuple)) else [ops]
+            for o in ops_list:
+                oid = _g.agg_op_id(o)
+                oname = o if isinstance(o, str) else _agg_name(oid)
+                specs.append((col, oid, oname))
         provably_sorted = _ord.covers_prefix(self._ordering, key_names)
+        dense = None
+        if _dense and not (_sorted or provably_sorted):
+            dense = self._dense_groupby_plan(
+                key_names, [oid for _c, oid, _n in specs]
+            )
+        if dense is not None:
+            return self._groupby_dense(key_names, specs, dense, _mask)
+        if _mask is not None:
+            return self.filter(_mask).groupby(
+                by, agg, ddof, quantile, _sorted, _dense=False
+            )
+        bump("groupby.factorize_path")
         if not _sorted and provably_sorted:
             # canonical prefix order: run adjacency AND emitted group order
             # match the factorize path exactly (ops.groupby.sorted_group_ids)
@@ -2487,14 +2537,6 @@ class Table:
             _g.sorted_group_ids if _sorted
             else partial(_g.group_ids, fuse=gb_fuse)
         )
-        # normalize agg spec -> list of (col, op_id, op_name)
-        specs: List[Tuple[str, int, str]] = []
-        for col, ops in agg.items():
-            ops_list = ops if isinstance(ops, (list, tuple)) else [ops]
-            for o in ops_list:
-                oid = _g.agg_op_id(o)
-                oname = o if isinstance(o, str) else _agg_name(oid)
-                specs.append((col, oid, oname))
         all_names = self.column_names
         key_idx = tuple(all_names.index(n) for n in key_names)
         val_idx = tuple(all_names.index(c) for c, _, _ in specs)
@@ -2517,11 +2559,17 @@ class Table:
                 n = counts[0]
                 cap = cols[0][0].shape[0]
                 keys = [cols[i] for i in key_idx]
-                ids, ng = ids_fn(keys, n, cap)
-                rep_rows = _g.group_representatives(ids, co)
-                gmask = jnp.arange(co) < ng
-                rep_idx = jnp.where(gmask, jnp.clip(rep_rows, 0, cap - 1), -1)
-                out = [_j.gather_column(d, v, rep_idx) for d, v in keys]
+                with jax.named_scope(_stages.GROUPBY_KEY_IDS):
+                    ids, ng = ids_fn(keys, n, cap)
+                # each group's keys: a scatter-min of the row index by id
+                # and a gather by it, the stage of the other segment ops
+                with jax.named_scope(_stages.GROUPBY_SEGMENT_SUM):
+                    rep_rows = _g.group_representatives(ids, co)
+                    gmask = jnp.arange(co) < ng
+                    rep_idx = jnp.where(
+                        gmask, jnp.clip(rep_rows, 0, cap - 1), -1
+                    )
+                    out = [_j.gather_column(d, v, rep_idx) for d, v in keys]
                 for (vi, oid) in zip(val_idx, ops_t):
                     d, v = cols[vi]
                     a, av = _g.aggregate_column(
@@ -2537,7 +2585,16 @@ class Table:
                 self.ctx, key + ("emit",), build_emit,
                 **_radix.kernel_kwargs(),
             )((flat, self.counts_dev), ())
-        # build output schema
+        return self._groupby_result(
+            key_names, specs, out, nout, cap_out, out_canonical
+        )
+
+    def _groupby_result(
+        self, key_names, specs, out, nout, cap_out: int, canonical: bool
+    ) -> "Table":
+        """The group-by's output table from a kernel's (key columns,
+        aggregate columns, group count): names, dtypes, dictionaries,
+        stats and ordering descriptor, the same for either path."""
         names_src: List[Tuple[str, Column]] = [
             (n, self._columns[n]) for n in key_names
         ]
@@ -2556,7 +2613,7 @@ class Table:
         res = res._attach_stats(
             {n: self._stats.get(n) for n in key_names}
         )
-        if out_canonical:
+        if canonical:
             res._attach_ordering(Ordering(
                 keys=tuple(key_names),
                 ascending=(True,) * len(key_names),
@@ -2566,6 +2623,88 @@ class Table:
                 ),
             ))
         return res
+
+    def _dense_groupby_plan(self, key_names, op_ids):
+        """``(los, spans, key_meta)`` where the dense path applies, else
+        None: every op dense, every key an integer, bool or dictionary
+        column with a known range, and the slots within
+        ``ops.groupby.DENSE_MAX_SLOTS``.
+        A dictionary column's codes are dense by construction; any other
+        key's range is measured (:meth:`ensure_stats`, cached on the
+        table) and its span rounded up to a power of two, so that a
+        drifting range compiles nothing."""
+        if not all(o in _g.DENSE_OPS for o in op_ids):
+            return None
+        cols = [self._columns[n] for n in key_names]
+        plain = [
+            n for n, c in zip(key_names, cols) if not c.dtype.is_dictionary
+        ]
+        stats = self.ensure_stats(plain) if plain else {}
+        los, spans, meta = [], [], []
+        for n, c in zip(key_names, cols):
+            if c.dtype.is_dictionary:
+                # code 0 in the orderable encoding of an int32; the range
+                # is known without a measurement, and kept like one
+                cls, lo, width = "i32", 1 << 31, max(1, len(c.dictionary))
+                if _st.enabled() and n not in self._stats:
+                    self._stats[n] = _st.ColStat(lo, lo + width - 1, cls)
+            else:
+                stat = stats.get(n)
+                if stat is None or not _st.wire_narrowable(stat.cls):
+                    return None
+                cls, lo = stat.cls, stat.lo
+                width = 1 << max(0, stat.hi - stat.lo).bit_length()
+            los.append((np.uint64 if _st.is64(cls) else np.uint32)(lo))
+            spans.append(width)
+            meta.append((cls, str(c.data.dtype)))
+        slots = _g.dense_slots(spans, [c.valid is not None for c in cols])
+        if slots > _g.DENSE_MAX_SLOTS:
+            return None
+        return tuple(los), tuple(spans), tuple(meta)
+
+    def _groupby_dense(self, key_names, specs, plan, mask) -> "Table":
+        """The dense group-by: one program, ``jit_groupby_dense``."""
+        bump("groupby.dense_path")
+        los, spans, meta = plan
+        all_names = self.column_names
+        key_idx = tuple(all_names.index(n) for n in key_names)
+        val_idx = tuple(all_names.index(c) for c, _, _ in specs)
+        ops_t = tuple(oid for _, oid, _ in specs)
+        flat = self._flat_cols()
+        nullable = tuple(self._columns[n].valid is not None for n in key_names)
+        cap_out = round_cap(_g.dense_slots(spans, nullable))
+        m = None if mask is None else self._as_mask(mask)
+        key = (
+            "groupby_dense", key_idx, val_idx, ops_t, len(flat), spans, meta,
+            nullable, m is not None,
+        )
+
+        def build_emit():
+            def kern(dp, rep):
+                (m, cols, counts) = dp
+                keys = [cols[i] for i in key_idx]
+                lo = list(rep)
+                gid = _g.dense_group_ids(keys, lo, spans, counts[0], m)
+                slots = _g.dense_slots(spans, nullable)
+                rows = _g.dense_rows(gid, slots)
+                aggs = [
+                    _g.dense_aggregate(oid, *cols[vi], gid, slots)
+                    for vi, oid in zip(val_idx, ops_t)
+                ]
+                out, ng = _g.dense_emit(
+                    rows, aggs, meta, lo, spans, nullable, cap_out
+                )
+                return out, _scalar(ng)
+
+            return kern
+
+        with span("groupby.emit", rows=self._rows_hint()):
+            out, nout = get_kernel(self.ctx, key, build_emit)(
+                (m, flat, self.counts_dev), los
+            )
+        return self._groupby_result(
+            key_names, specs, out, nout, cap_out, True
+        )
 
     def distributed_groupby(
         self,

@@ -239,6 +239,55 @@ def test_groupby_segment_sum_compiles_for_tpu(one_chip):
 
 
 # ----------------------------------------------------------------------
+# (f2) group-by: the dense low-cardinality path (TPC-H Q1's shape: two
+# dictionary keys of 3 and 2 codes, float64 values, a row mask)
+# ----------------------------------------------------------------------
+
+def test_dense_groupby_compiles_for_tpu_without_sort_scatter_or_gather(one_chip):
+    from cylon_tpu.obs import stages
+
+    spans, nullable = (3, 2), (False, False)
+    meta = (("i32", "int32"), ("i32", "int32"))
+    ops = [_g.agg_op_id(o) for o in ("sum", "mean", "count", "sum")]
+
+    def dense(flag, status, qty, price, mask, n, lo0, lo1):
+        lo = [lo0, lo1]
+        gid = _g.dense_group_ids(
+            [(flag, None), (status, None)], lo, spans, n, mask
+        )
+        slots = _g.dense_slots(spans, nullable)
+        aggs = [
+            _g.dense_aggregate(o, v, None, gid, slots)
+            for o, v in zip(ops, (qty, qty, qty, price))
+        ]
+        return _g.dense_emit(
+            _g.dense_rows(gid, slots), aggs, meta, lo, spans, nullable, 8
+        )
+
+    compiled = _compile(
+        dense,
+        _spec((ROWS,), jnp.int32, one_chip), _spec((ROWS,), jnp.int32, one_chip),
+        _spec((ROWS,), jnp.float64, one_chip), _spec((ROWS,), jnp.float64, one_chip),
+        _spec((ROWS,), jnp.bool_, one_chip), _spec((), jnp.int32, one_chip),
+        _spec((), jnp.uint32, one_chip), _spec((), jnp.uint32, one_chip),
+    )
+    _module, rows = stages.parse_compiled(compiled.as_text())
+    wide = [(text, op) for text, op in rows if f"[{ROWS}]" in text.split("(")[0]]
+    # no operation that writes a row-sized array sorts, scatters or gathers;
+    # the one that does is the row ids, under its stage name
+    assert wide and all(
+        not any(w in text.split(" = ")[1].split("(")[0]
+                for w in ("sort", "scatter", "gather"))
+        for text, _op in wide
+    )
+    assert any(stages.GROUPBY_KEY_IDS in op.split("/") for _text, op in wide)
+    assert any(stages.GROUPBY_DENSE_AGG in op.split("/") for _text, op in rows)
+    # the row-sized temporaries are the ids and one column's two halves:
+    # the [slots, rows] masks of the reductions are never materialized
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * ROWS
+
+
+# ----------------------------------------------------------------------
 # (h) a forced Pallas kernel that does not lower raises the compiler's
 # error for a TPU device: the call sites pass interpret=False on a TPU
 # mesh (engine.mesh_platform / the context's mesh), so forcing never
