@@ -38,7 +38,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Any, Dict
+from typing import Any, Dict, List, Tuple
 
 import jax
 import numpy as np
@@ -93,6 +93,9 @@ class Report:
     collective_bytes: int = 0
     collective_count: int = 0
     by_prim: Dict[str, float] = field(default_factory=dict)
+    # every sort with the ``jax.named_scope`` path it was traced under and
+    # its pass bytes: which stage of a program a sort belongs to
+    sorts: List[Tuple[str, float]] = field(default_factory=list)
 
     @property
     def total_model_bytes(self) -> float:
@@ -118,11 +121,16 @@ def _merge_scaled(rep: Report, sub: Report, scale: float) -> None:
     rep.collective_count += int(sub.collective_count * scale)
     for k, v in sub.by_prim.items():
         rep.by_prim[k] = rep.by_prim.get(k, 0.0) + v * scale
+    rep.sorts += [(name, b * scale) for name, b in sub.sorts]
 
 
-def _walk(jaxpr, rep: Report) -> None:
+def _walk(jaxpr, rep: Report, scope: str = "") -> None:
     for eqn in jaxpr.eqns:
         prim = eqn.primitive.name
+        # a nested jaxpr's name stacks are relative to its equation's
+        here = "/".join(
+            p for p in (scope, str(eqn.source_info.name_stack)) if p
+        )
         if prim == "scan":
             # a scan body executes `length` times: walk it once and scale
             # (the K-sliced fused join runs its K rounds in ONE scan — an
@@ -133,7 +141,7 @@ def _walk(jaxpr, rep: Report) -> None:
             if inner is not None and hasattr(inner, "eqns"):
                 trips = int(eqn.params.get("length", 1))
                 sub_rep = Report()
-                _walk(inner, sub_rep)
+                _walk(inner, sub_rep, here)
                 _merge_scaled(rep, sub_rep, trips)
             continue
         if prim == "pallas_call":
@@ -193,12 +201,12 @@ def _walk(jaxpr, rep: Report) -> None:
         for v in eqn.params.values():
             sub = _sub(v)
             if sub is not None:
-                _walk(sub, rep)
+                _walk(sub, rep, here)
             elif isinstance(v, (list, tuple)):
                 for vi in v:
                     sub = _sub(vi)
                     if sub is not None:
-                        _walk(sub, rep)
+                        _walk(sub, rep, here)
         if prim in (
             "pjit", "closed_call", "custom_jvp_call", "custom_vjp_call",
             "shard_map", "cond", "scan", "while", "remat", "checkpoint",
@@ -221,6 +229,7 @@ def _walk(jaxpr, rep: Report) -> None:
             rep.sort_pass_bytes += in_bytes * passes
             rep.sort_passes += passes
             rep.by_prim["sort"] = rep.by_prim.get("sort", 0.0) + in_bytes * passes
+            rep.sorts.append((here, in_bytes * passes))
         elif prim in _GATHER_PRIMS:
             w = (in_bytes + out_bytes) * GATHER_PASS_EQ
             rep.gather_bytes += w

@@ -20,40 +20,50 @@ import numpy as np
 from .sort import (
     KeyCol,
     canonical_row_lanes,
+    rows_differ,
     sentinel_compact,
     sorted_runs,
+    sorted_runs_payload,
 )
 
 
-def factorize(
-    key_cols: Sequence[KeyCol], n: jax.Array, cap: int, fuse=None
-) -> Tuple[jax.Array, jax.Array]:
-    """Assign dense ids (in sorted key order) to live rows.
+def factorize_runs(
+    key_cols: Sequence[KeyCol],
+    n: jax.Array,
+    cap: int,
+    payloads: Sequence[jax.Array],
+    fuse=None,
+    presorted: bool = False,
+) -> Tuple[jax.Array, jax.Array, list, Optional[list]]:
+    """Factorization for a consumer that stays in SORTED space (the
+    group-by): the rows are brought into canonical key order with
+    ``payloads`` riding the sort; no ids are made and nothing is sorted
+    back to the original row order.
 
-    Returns (ids [cap] int32 — padding rows get id ``cap``;
-             num_groups scalar int32).
+    Returns (run_start [cap] bool: a live row that opens a run of equal
+    keys; run_end [cap] bool: a row that closes one, the last live row
+    always among them; the payloads in sorted order; the sorted canonical
+    lanes msb first, the ``fuse`` plan's words where one is given, None
+    for ``presorted`` input). Live rows sort first, so the runs of the
+    live rows are the groups, in key order, and a stable sort keeps each
+    run's rows in their original order.
 
-    Scatter-free and gather-free: the canonical lanes ride the chained sort
-    (run boundaries come from the SORTED lanes, no per-column re-gather),
-    and the ids return to original row order through one payload sort keyed
-    by the carried original index (instead of a scatter).
-
-    ``fuse``: stats-driven sort-word fusion plan (ops/sort.FusePlan over
-    the canonical lane stack, pad_bits=1) — fewer chained passes, ids
-    provably identical (canonical_row_lanes docstring).
-    """
+    ``presorted``: the rows already are in that order (the caller's
+    contract, or the table's ordering descriptor): no sort at all, the
+    runs are read off the key columns (reference PipelineGroupBy,
+    groupby/pipeline_groupby.cpp:30-90)."""
     idx = jnp.arange(cap, dtype=jnp.int32)
     live = idx < n
-    lanes = canonical_row_lanes(key_cols, live, fuse=fuse)  # msb first
-    order, diff = sorted_runs(lanes, idx)
-    live_sorted = idx < n  # live rows sort first (class lane)
-    ids_sorted = jnp.cumsum(diff.astype(jnp.int32)) - 1
-    num_groups = jnp.where(n > 0, ids_sorted[jnp.maximum(n - 1, 0)] + 1, 0).astype(
-        jnp.int32
-    )
-    ids_sorted = jnp.where(live_sorted, ids_sorted, cap)
-    (ids,) = sentinel_compact(order, [ids_sorted])  # back to original order
-    return ids, num_groups
+    if presorted:
+        new_run, spays, slanes = rows_differ(key_cols, cap), payloads, None
+    else:
+        lanes = canonical_row_lanes(key_cols, live, fuse=fuse)  # msb first
+        new_run, spays, slanes = sorted_runs_payload(lanes, payloads)
+    # the first padding row opens a run of its own whatever it holds, so
+    # the last live row closes one
+    new_run = new_run | (idx == n)
+    run_end = jnp.concatenate([new_run[1:], jnp.ones((1,), bool)])
+    return new_run & live, run_end, list(spays), slanes
 
 
 def factorize_two(
@@ -96,8 +106,10 @@ def factorize_two(
         cat_cols.append((data, valid))
     # left live rows are [0, nl); right live rows are [cap_l, cap_l+nr):
     # the class lane sorts ALL live rows first, so in sorted order live rows
-    # occupy the [0, nl+nr) prefix. Same scatter/gather-free layout as
-    # :func:`factorize`.
+    # occupy the [0, nl+nr) prefix. Scatter-free and gather-free: the
+    # canonical lanes ride the chained sort (run boundaries come from the
+    # SORTED lanes), and the ids return to original row order through one
+    # payload sort keyed by the carried original index.
     idx = jnp.arange(cap, dtype=jnp.int32)
     live = (idx < nl) | ((idx >= cap_l) & (idx < cap_l + nr))
     lanes = canonical_row_lanes(cat_cols, live, fuse=fuse)  # msb first

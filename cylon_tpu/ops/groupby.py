@@ -6,12 +6,25 @@ kernels run per column (aggregate<op> templates, resolver ~:143-230); the
 aggregate op set {SUM, COUNT, MIN, MAX, MEAN, VAR, STDDEV, NUNIQUE, QUANTILE}
 comes from compute/aggregate_kernels.hpp:40-50.
 
-TPU-native design: group ids come from :func:`factorize` (lexsort +
-run-detect — ids are dense AND in sorted key order, so the output doubles as
-the sorted-key pipeline groupby, groupby/pipeline_groupby.cpp); aggregates are
-XLA ``segment_sum/min/max`` ops, which lower to efficient sorted-segment
-reductions. Single dispatch: num_groups <= live rows bounds the output
-statically, so one kernel + one host sync covers count AND emit.
+TPU-native design, the sort-and-segment path (:func:`groupby_aggregate`):
+one stable sort brings the rows into canonical key order
+(:func:`ops.factorize.factorize_runs`: lexsort + run-detect, so the output
+doubles as the sorted-key pipeline groupby, groupby/pipeline_groupby.cpp)
+with the value columns riding it as payload operands (and any key column
+that cannot be read back out of the fused sort words), and the group-by
+never leaves that order: every aggregate is a segmented scan over
+the runs of equal keys (:func:`ops.sort.run_reduce`; a run adds only its
+own values, so a float sum is as exact as its group is small), and one
+compaction sort moves each run's first row, which then holds the group's
+keys and totals, to the group's slot. There is no per-element scatter and
+no row-sized gather: on a v5e a scatter-add costs 114 ns a row and a sort
+1.3 ns (PERF.md section 6, PR 28). Many columns ride the two sorts in
+batches of eight 32-bit lanes (:func:`ops.sort.ride_sort`), so the program
+holds the same few sorts, and compiles in the same time, at 32 aggregates
+as at 16. Single dispatch: num_groups <= live
+rows bounds the output statically, so one kernel + one host sync covers
+count AND emit. Keys of few distinct values take the dense path below
+instead (no sort at all).
 """
 from __future__ import annotations
 
@@ -22,9 +35,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import stages as _stages
-from .factorize import factorize
+from .factorize import factorize_runs
 from .sort import (
-    KeyCol, lexsort_indices, orderable_key, rows_differ, wide_float, wide_int,
+    KeyCol, flag_compact, fused_decodable, fused_key_decode, lexsort_indices,
+    orderable_key, run_reduce, scan_identity, wide_float, wide_int,
 )
 from .stats import decode_enc
 
@@ -49,165 +63,226 @@ def agg_op_id(name) -> int:
         raise ValueError(f"unknown aggregation {name!r}") from None
 
 
-def group_ids(
-    key_cols: Sequence[KeyCol], n: jax.Array, cap: int, fuse=None
-) -> Tuple[jax.Array, jax.Array]:
-    """(ids [cap] int32 with padding -> cap, num_groups scalar).
-
-    ``fuse``: stats-driven sort-word fusion plan for the factorize lanes
-    (ops/sort.FusePlan; Table.groupby derives it from the key columns'
-    range stats) — identical ids in fewer chained sort passes."""
-    return factorize(key_cols, n, cap, fuse=fuse)
-
-
-def sorted_group_ids(
-    key_cols: Sequence[KeyCol], n: jax.Array, cap: int
-) -> Tuple[jax.Array, jax.Array]:
-    """Group ids for input ALREADY sorted by the key columns: a single
-    run-detection pass, no lexsort (reference PipelineGroupBy,
-    groupby/pipeline_groupby.cpp:30-90 — run detection + per-run aggregates
-    over sorted input). Same contract as :func:`group_ids`, and the ids come
-    out in key order by construction.
-
-    Callers either guarantee sortedness themselves (``pipeline_groupby``,
-    the reference contract) or let ``Table.groupby`` prove it from the
-    table's ordering descriptor (cylon_tpu/ordering.py): input canonically
-    ordered by a key prefix run-detects with null==null adjacency intact,
-    so the ids — and therefore the emitted group order — match the
-    factorize path exactly."""
-    idx = jnp.arange(cap, dtype=jnp.int32)
-    live = idx < n
-    boundary = rows_differ(key_cols, cap) & live
-    ids = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-    ids = jnp.where(live, ids, jnp.int32(cap))
-    return ids.astype(jnp.int32), jnp.sum(boundary).astype(jnp.int32)
-
-
-def group_representatives(ids: jax.Array, cap_out: int) -> jax.Array:
-    """First-occurrence row index of each group id -> [cap_out] int32.
-
-    Entries for ids >= cap_out are dropped; absent groups get cap (clamp on
-    gather + group count masking makes that safe).
-    """
-    cap = ids.shape[0]
-    rows = jnp.arange(cap, dtype=jnp.int32)
-    rep = jnp.full((cap_out,), cap, jnp.int32)
-    # min row index per id == first occurrence
-    return rep.at[ids].min(rows, mode="drop")
-
-
 def _masked(values: jax.Array, valid: Optional[jax.Array], fill) -> jax.Array:
     if valid is None:
         return values
     return jnp.where(valid, values, jnp.asarray(fill, values.dtype))
 
 
-def _seg_sum(vals, ids, cap_out):
-    with jax.named_scope(_stages.GROUPBY_SEGMENT_SUM):
-        return jnp.zeros((cap_out,), vals.dtype).at[ids].add(vals, mode="drop")
+def _flatten(cols: Sequence[KeyCol]) -> list:
+    """The arrays of ``cols``, each column's data then its validity."""
+    return [a for d, v in cols for a in ((d,) if v is None else (d, v))]
 
 
-def _seg_min(vals, ids, cap_out, init):
-    with jax.named_scope(_stages.GROUPBY_SEGMENT_SUM):
-        return jnp.full((cap_out,), init, vals.dtype).at[ids].min(
-            vals, mode="drop"
-        )
+def _unflatten(cols: Sequence[KeyCol], flat: Sequence[jax.Array]) -> list:
+    """:func:`_flatten` undone: ``flat`` in the column structure of ``cols``."""
+    it = iter(flat)
+    return [(next(it), None if v is None else next(it)) for _d, v in cols]
 
 
-def _seg_max(vals, ids, cap_out, init):
-    with jax.named_scope(_stages.GROUPBY_SEGMENT_SUM):
-        return jnp.full((cap_out,), init, vals.dtype).at[ids].max(
-            vals, mode="drop"
-        )
+def _fit(x: jax.Array, cap_out: int) -> jax.Array:
+    """The first ``cap_out`` slots of ``x``, zero-padded when it has fewer."""
+    if x.shape[0] >= cap_out:
+        return x[:cap_out]
+    return jnp.pad(x, (0, cap_out - x.shape[0]))
 
 
-def _type_extrema(dtype):
-    if jnp.issubdtype(dtype, jnp.floating):
-        return jnp.array(jnp.inf, dtype), jnp.array(-jnp.inf, dtype)
-    info = jnp.iinfo(dtype)
-    return jnp.asarray(info.max, dtype), jnp.asarray(info.min, dtype)
-
-
-def aggregate_column(
-    op: int,
-    data: jax.Array,
-    valid: Optional[jax.Array],
-    ids: jax.Array,
-    num_groups: jax.Array,
+def groupby_aggregate(
+    key_cols: Sequence[KeyCol],
+    val_cols: Sequence[KeyCol],
+    ops: Sequence[Tuple[int, int]],
+    n: jax.Array,
     cap_out: int,
+    fuse=None,
+    presorted: bool = False,
     ddof: int = 1,
     quantile: float = 0.5,
-) -> Tuple[jax.Array, Optional[jax.Array]]:
-    """Aggregate one value column over group ids. Null entries are skipped
-    (pandas semantics; count counts non-null). Returns (out [cap_out], valid).
-    """
-    vmask = valid if valid is not None else jnp.ones(data.shape, bool)
-    # padding rows already have ids == cap (dropped by mode="drop" scatters
-    # when cap >= cap_out; make sure by re-masking)
-    live_ids = jnp.where(vmask, ids, jnp.int32(data.shape[0]))
-    cnt = _seg_sum(vmask.astype(wide_int()), live_ids, cap_out)
-    gmask = jnp.arange(cap_out) < num_groups
-    if op == COUNT:
-        return jnp.where(gmask, cnt, 0), None
-    if op == SUM:
-        acc = data.astype(wide_int()) if jnp.issubdtype(data.dtype, jnp.integer) else data
-        s = _seg_sum(_masked(acc, vmask, 0), live_ids, cap_out)
-        return jnp.where(gmask, s, jnp.zeros_like(s)), gmask & (cnt > 0) if valid is not None else None
-    if op in (MIN, MAX):
-        hi, lo = _type_extrema(data.dtype)
-        if op == MIN:
-            out = _seg_min(_masked(data, vmask, hi), live_ids, cap_out, hi)
+):
+    """The sort-and-segment group-by of one shard: ``ops`` are
+    ``(aggregation op, position in val_cols)`` pairs.
+
+    Returns (the groups' key columns, one ``(out, valid)`` an op, all
+    [cap_out] with the groups in slots ``[0, num_groups)`` in canonical
+    key order; num_groups scalar int32). Groups past ``cap_out`` are
+    dropped. Null values are skipped (pandas semantics; count counts
+    non-null).
+
+    Everything happens in the SORTED order of the factorize sort
+    (stage ``groupby.key_ids``): the key and value columns ride that sort
+    as payload operands, each aggregate is a reduction over the runs of
+    equal keys (:func:`ops.sort.run_reduce`), and a run's first row, which
+    then holds the group's keys and totals, moves to the group's slot by
+    one compaction sort (stage ``groupby.segment_sum``). No row-sized
+    scatter or gather; ``presorted`` input runs no sort in front either.
+    A key the ``fuse`` plan's sort words hold bit for bit does not ride:
+    the words ride the compaction and the key is decoded at the slots.
+
+    ``fuse``: stats-driven sort-word fusion plan for the factorize lanes
+    (ops/sort.FusePlan; Table.groupby derives it from the key columns'
+    range stats): the same runs in fewer chained sort passes."""
+    key_cols, val_cols = list(key_cols), list(val_cols)
+    cap = key_cols[0][0].shape[0]
+    # a key the fused sort words hold bit for bit is read back out of them
+    # at the groups' slots; any other rides both sorts beside the values
+    in_words = (
+        fused_decodable(fuse, key_cols)
+        if fuse is not None and not presorted else [False] * len(key_cols)
+    )
+    riders = [c for c, w in zip(key_cols, in_words) if not w]
+    with jax.named_scope(_stages.GROUPBY_KEY_IDS):
+        start, run_end, flat, words = factorize_runs(
+            key_cols, n, cap, _flatten(riders + val_cols),
+            fuse=fuse, presorted=presorted,
+        )
+    words = list(words) if any(in_words) else []
+    cols = _unflatten(riders + val_cols, flat)
+    with jax.named_scope(_stages.GROUPBY_SEGMENT_SUM):
+        carried, aggs, num_groups = _aggregate_runs(
+            start, run_end, n, words + _flatten(cols[: len(riders)]),
+            cols[len(riders):], ops, cap_out, ddof, quantile,
+        )
+        gmask = jnp.arange(cap_out, dtype=jnp.int32) < num_groups
+        decoded = fused_key_decode(
+            fuse, carried[: len(words)], key_cols,
+            jnp.arange(cap, dtype=jnp.int32) < n,
+        ) if words else []
+        rode = iter(_unflatten(riders, carried[len(words):]))
+        keys = [decoded[i] if w else next(rode) for i, w in enumerate(in_words)]
+        keys = [(d, gmask if v is None else gmask & v) for d, v in keys]
+    return keys, aggs, num_groups
+
+
+def _pair_sort(gid, data, valid, cap):
+    """The rows of NUNIQUE / QUANTILE's own order: by group, then nulls
+    last, then value. A group keeps the rows ``[first, first + size)`` it
+    has in the factorize order, so its runs and slots are the same.
+    Returns (sorted group ids, sorted values, sorted validity | None)."""
+    lanes = [data, gid] if valid is None else [data, ~valid, gid]
+    order = lexsort_indices(lanes, cap)
+    return gid[order], data[order], None if valid is None else valid[order]
+
+
+def _aggregate_runs(
+    start, run_end, n, carry, svals, ops, cap_out, ddof, quantile
+):
+    """The aggregates of :func:`groupby_aggregate` over rows in sorted
+    order: ``start`` marks the live rows that open a run, whose ``carry``
+    arrays go to the group's slot with its totals. Returns (carried
+    arrays, ``(out, valid)`` an op, each [cap_out]; num_groups)."""
+    cap = start.shape[0]
+    # the per-row lanes the one scan reduces, by what they hold: ``sum``
+    # and ``mean`` of one column share a lane
+    lanes: dict = {}
+
+    def lane(key, kind, vals):
+        lanes.setdefault(key, (kind, vals))
+        return key
+
+    def fsum(j, tag, fn):
+        data, valid = svals[j]
+        x = _masked(data.astype(wide_float()), valid, 0.0)
+        return lane((j, tag), "sum", fn(x))
+
+    gid = None
+    if any(op in (NUNIQUE, QUANTILE) for op, _j in ops):
+        gid = jnp.where(
+            jnp.arange(cap, dtype=jnp.int32) < n,
+            jnp.cumsum(start.astype(jnp.int32)) - 1, jnp.int32(cap),
+        )
+
+    # what each op reads at a run's first row, then how a slot finishes it
+    plans = []
+    for op, j in ops:
+        data, valid = svals[j]
+        cnt = None
+        if valid is not None:
+            cnt = lane((j, "cnt"), "sum", valid.astype(jnp.int32))
+        if op == COUNT:
+            hs = ()
+        elif op == SUM and data.dtype == wide_float():
+            hs = (fsum(j, "fsum", lambda x: x),)  # the lane of its mean
+        elif op == SUM:
+            acc = (
+                data.astype(wide_int())
+                if jnp.issubdtype(data.dtype, jnp.integer) else data
+            )
+            hs = (lane((j, "sum"), "sum", _masked(acc, valid, 0)),)
+        elif op in (MIN, MAX):
+            kind = "min" if op == MIN else "max"
+            fill = scan_identity(kind, data.dtype)
+            hs = (lane((j, kind), kind, _masked(data, valid, fill)),)
+        elif op == MEAN:
+            hs = (fsum(j, "fsum", lambda x: x),)
+        elif op in (VAR, STDDEV):
+            hs = (fsum(j, "fsum", lambda x: x), fsum(j, "fsumsq", lambda x: x * x))
+        elif op == NUNIQUE:
+            # distinct (group, value) pairs: run-detect in the pair order
+            d = data
+            if jnp.issubdtype(d.dtype, jnp.floating):
+                d = jnp.where(jnp.isnan(d), jnp.zeros_like(d), d)
+            sid, sval, svalid = _pair_sort(gid, d, valid, cap)
+            newpair = (
+                (sid != jnp.roll(sid, 1)) | (sval != jnp.roll(sval, 1))
+            ).at[0].set(True)
+            if svalid is not None:
+                newpair = newpair & svalid
+            hs = (lane((j, "nunique"), "sum", newpair.astype(jnp.int32)),)
+        elif op == QUANTILE:
+            d = _masked(data.astype(wide_float()), valid, jnp.inf)
+            hs = (_pair_sort(gid, d, None, cap)[1],)
         else:
-            out = _seg_max(_masked(data, vmask, lo), live_ids, cap_out, lo)
+            raise ValueError(f"unsupported aggregation op {op}")
+        plans.append((op, valid, cnt, hs))
+
+    reduced = run_reduce(
+        run_end, [v for _k, v in lanes.values()], [k for k, _v in lanes.values()]
+    )
+    first, packed = flag_compact(start, list(carry) + reduced)
+    num_groups = jnp.sum(start, dtype=jnp.int32)
+    # a group's rows lie between its first row and the next group's
+    last = jnp.arange(cap, dtype=jnp.int32) == num_groups - 1
+    size = _fit(jnp.where(last, n, jnp.roll(first, -1)) - first, cap_out)
+    first = _fit(first, cap_out)
+    packed = [_fit(x, cap_out) for x in packed]
+    slot = dict(zip(lanes, packed[len(carry):]))
+    gmask = jnp.arange(cap_out, dtype=jnp.int32) < num_groups
+
+    out = []
+    for op, valid, cnt_h, hs in plans:
+        cnt = jnp.where(gmask, size if cnt_h is None else slot[cnt_h], 0)
         has = gmask & (cnt > 0)
-        return out, (has if valid is not None else None)
-    if op == MEAN:
-        s = _seg_sum(_masked(data.astype(wide_float()), vmask, 0.0), live_ids, cap_out)
-        out = s / jnp.maximum(cnt, 1)
-        return jnp.where(gmask, out, 0.0), gmask & (cnt > 0)
-    if op in (VAR, STDDEV):
-        x = _masked(data.astype(wide_float()), vmask, 0.0)
-        s = _seg_sum(x, live_ids, cap_out)
-        ss = _seg_sum(x * x, live_ids, cap_out)
-        denom = jnp.maximum(cnt - ddof, 1)
-        mean = s / jnp.maximum(cnt, 1)
-        var = (ss - s * mean) / denom
-        var = jnp.maximum(var, 0.0)
-        out = jnp.sqrt(var) if op == STDDEV else var
-        return jnp.where(gmask, out, 0.0), gmask & (cnt > ddof)
-    if op == NUNIQUE:
-        # distinct (id, value) pairs: lexsort by (id, value), run-detect
-        cap = data.shape[0]
-        d = data
-        if jnp.issubdtype(d.dtype, jnp.floating):
-            d = jnp.where(jnp.isnan(d), jnp.zeros_like(d), d)
-        order = lexsort_indices([d, live_ids], cap)
-        sid = live_ids[order]
-        sval = d[order]
-        newpair = (
-            (sid != jnp.roll(sid, 1)) | (sval != jnp.roll(sval, 1))
-        ).at[0].set(True)
-        uniq = _seg_sum(newpair.astype(wide_int()), sid, cap_out)
-        return jnp.where(gmask, uniq, 0), None
-    if op == QUANTILE:
-        cap = data.shape[0]
-        d = _masked(data.astype(wide_float()), vmask, jnp.inf)
-        order = lexsort_indices([d, live_ids], cap)
-        sid = live_ids[order]
-        sval = d[order]
-        # method='sort': the default 'scan' binary search is ~8x slower on TPU
-        starts = jnp.searchsorted(
-            sid, jnp.arange(cap_out), side="left", method="sort"
-        ).astype(jnp.int32)
-        q = quantile
-        pos = starts.astype(wide_float()) + q * jnp.maximum(cnt - 1, 0)
-        lo_i = jnp.clip(jnp.floor(pos).astype(jnp.int32), 0, cap - 1)
-        hi_i = jnp.clip(jnp.ceil(pos).astype(jnp.int32), 0, cap - 1)
-        frac = pos - jnp.floor(pos)
-        out = sval[lo_i] * (1 - frac) + sval[hi_i] * frac
-        has = gmask & (cnt > 0)
-        return jnp.where(has, out, 0.0), has
-    raise ValueError(f"unsupported aggregation op {op}")
+        if op == COUNT:
+            out.append((cnt.astype(wide_int()), None))
+        elif op == SUM:
+            s = slot[hs[0]]
+            out.append((
+                jnp.where(gmask, s, jnp.zeros_like(s)),
+                has if valid is not None else None,
+            ))
+        elif op in (MIN, MAX):
+            out.append((slot[hs[0]], has if valid is not None else None))
+        elif op == MEAN:
+            mean = slot[hs[0]] / jnp.maximum(cnt, 1)
+            out.append((jnp.where(gmask, mean, 0.0), has))
+        elif op in (VAR, STDDEV):
+            s, ss = slot[hs[0]], slot[hs[1]]
+            var = (ss - s * (s / jnp.maximum(cnt, 1))) / jnp.maximum(cnt - ddof, 1)
+            var = jnp.maximum(var, 0.0)
+            res = jnp.sqrt(var) if op == STDDEV else var
+            out.append((jnp.where(gmask, res, 0.0), gmask & (cnt > ddof)))
+        elif op == NUNIQUE:
+            out.append((
+                jnp.where(gmask, slot[hs[0]], 0).astype(wide_int()), None
+            ))
+        else:  # QUANTILE: interpolate between the group's neighbours
+            sval = hs[0]
+            pos = first.astype(wide_float()) + quantile * jnp.maximum(cnt - 1, 0)
+            lo_i = jnp.clip(jnp.floor(pos).astype(jnp.int32), 0, cap - 1)
+            hi_i = jnp.clip(jnp.ceil(pos).astype(jnp.int32), 0, cap - 1)
+            frac = pos - jnp.floor(pos)
+            q = sval[lo_i] * (1 - frac) + sval[hi_i] * frac
+            out.append((jnp.where(has, q, 0.0), has))
+    return packed[: len(carry)], out, num_groups
 
 
 # ops that can be pre-combined locally before the shuffle (reference
@@ -220,20 +295,21 @@ ASSOCIATIVE = frozenset({SUM, MIN, MAX})
 # dense low-cardinality aggregation (no sort, no scatter, no gather)
 # ----------------------------------------------------------------------
 #: ops the dense path computes; var/std/nunique/quantile stay on the
-#: factorize path (they sort or square the values)
+#: sort-and-segment path (they sort or square the values)
 DENSE_OPS = frozenset({SUM, COUNT, MIN, MAX, MEAN})
 
 #: most slots (the product of the key columns' spans, a nullable key
 #: taking one more) for which ``Table.groupby`` takes the dense path by
 #: itself. Each aggregate is one masked reduction a slot, so the work a
-#: row grows with the slots, while the factorize path's (a sort, and a
-#: per-element scatter an aggregate) does not. Measured on a v5e chip at
-#: 16,777,216 rows (PERF.md section 6, PR 27): Q1's eight aggregates take
-#: 16.9 / 39.7 / 129.8 / 497.6 ms at 4 / 64 / 256 / 1,024 slots against
-#: 14,539 ms on the factorize path at 4 and at 1,024 groups; one float64
-#: sum 5.1 / 9.8 / 110.4 ms at 4 / 64 / 1,024 against 2,657 ms. Both are
-#: linear in the slots from 64 on, so the paths would cross near 25,000.
-#: 1,024 is the largest count that was measured: 24 to 29 times ahead.
+#: row grows with the slots, while the sort-and-segment path's (two sorts
+#: and a scan) does not. Measured on a v5e chip at 16,777,216 rows
+#: (PERF.md section 6, PR 27): Q1's eight aggregates take 16.9 / 39.7 /
+#: 129.8 / 497.6 ms at 4 / 64 / 256 / 1,024 slots, one float64 sum 5.1 /
+#: 9.8 / 110.4 ms at 4 / 64 / 1,024, linear in the slots from 64 on. The
+#: constant was set against the other path's scatter form (14.5 s and
+#: 2.7 s there, whatever the groups); that path is some thirty times
+#: faster since PR 28, and PERF.md section 6 (PR 28) has what is known of
+#: where the two cross now.
 DENSE_MAX_SLOTS = 1024
 
 
@@ -252,7 +328,7 @@ def dense_group_ids(
 ) -> jax.Array:
     """Slot of every row, [cap] int32: arithmetic on the rebased keys,
     first key most significant, a null key in its column's last slot (the
-    canonical order of :func:`factorize`). Padding rows and rows whose
+    canonical order of :func:`ops.factorize.factorize_runs`). Padding rows and rows whose
     ``mask`` is false get the slot count, which no reduction matches.
 
     ``los`` are the lower bounds of the keys' orderable encodings (traced
@@ -288,7 +364,7 @@ def dense_aggregate(
 ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """One aggregate of :data:`DENSE_OPS` over the slots: a masked
     reduction a slot, in the dtypes and with the null rules of
-    :func:`aggregate_column` (nulls skipped, count counts non-null, a
+    :func:`groupby_aggregate` (nulls skipped, count counts non-null, a
     float sum stays in the column's dtype, an integer sum widens).
     Returns (out [slots], valid [slots] | None); a slot with no row is
     dropped by the caller."""
@@ -312,10 +388,8 @@ def dense_aggregate(
             )
             return reduce(acc, jnp.zeros((), acc.dtype), jnp.sum), has
         if op in (MIN, MAX):
-            hi, lo = _type_extrema(data.dtype)
-            if op == MIN:
-                return reduce(data, hi, jnp.min), has
-            return reduce(data, lo, jnp.max), has
+            kind, fn = ("min", jnp.min) if op == MIN else ("max", jnp.max)
+            return reduce(data, scan_identity(kind, data.dtype), fn), has
         if op == MEAN:
             x = data.astype(wide_float())
             s = reduce(x, jnp.zeros((), x.dtype), jnp.sum)

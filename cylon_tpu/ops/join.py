@@ -926,7 +926,7 @@ def join_sum_by_key_pushdown(
     VALID-left-value counts (the caller rebuilds the generic SUM's all-null
     -> null validity from them). ``ng`` may exceed ``group_cap`` (the caller
     detects
-    truncation, mirroring the generic group_ids contract); ``n_join``
+    truncation, mirroring the generic group-by's contract); ``n_join``
     saturates to 2^31-1 on int32 wrap (a float32 shadow mirrors the count,
     exactly like join_shard's count_overflow_check policy). Null/padding
     values contribute 0 (SUM skip-null). Intended for floating aggregate
@@ -1024,7 +1024,7 @@ def join_sum_by_key_pushdown(
         jnp.where(grp & is_l_live, lrow, jnp.int32(cap_l)), **kw
     )
     # per-group count of VALID left values, so the caller can mirror the
-    # generic aggregate_column SUM validity (all-null group -> null)
+    # generic groupby_aggregate SUM validity (all-null group -> null)
     vok_s = vok[jnp.clip(lrow, 0, cap_l - 1)] & is_l_live
     vcnt = jnp.zeros((group_cap + 1,), jnp.int32).at[tgt].add(
         (grp & vok_s).astype(jnp.int32), **kw

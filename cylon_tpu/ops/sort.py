@@ -274,6 +274,21 @@ def plan_lane_fusion(
     return FusePlan(tuple(fields), use64, n_words, n_plain)
 
 
+def _field_base(enc: jax.Array, live: jax.Array, asc: bool) -> jax.Array:
+    """What a fused value field is rebased by: the live rows' smallest
+    orderable encoding (their largest for a descending field). Encode
+    (:func:`fused_key_words`) and decode (:func:`fused_key_decode`) both
+    take it from here."""
+    from .stats import mask_of
+
+    wide = enc.dtype == jnp.uint64
+    if asc:
+        enc_max = mask_of(64 if wide else 32, enc.dtype)
+        return jnp.min(jnp.where(live, enc, enc_max))
+    zero = np.uint64(0) if wide else np.uint32(0)
+    return jnp.max(jnp.where(live, enc, zero))
+
+
 def fused_key_words(
     plan: "FusePlan",
     key_cols: Sequence[KeyCol],
@@ -321,14 +336,8 @@ def fused_key_words(
 
                     wide = fdt == jnp.uint64
                     maxf = mask_of(min(bits, 64 if wide else 32), fdt)
-                    enc_max = mask_of(64 if wide else 32, fdt)
-                    if asc:
-                        base = jnp.min(jnp.where(live, enc, enc_max))
-                        v = jnp.minimum(enc - base, maxf)
-                    else:
-                        zero = np.uint64(0) if wide else np.uint32(0)
-                        top = jnp.max(jnp.where(live, enc, zero))
-                        v = jnp.minimum(top - enc, maxf)
+                    base = _field_base(enc, live, asc)
+                    v = jnp.minimum(enc - base if asc else base - enc, maxf)
                 if zero_null_values and valid is not None:
                     v = jnp.where(valid, v, jnp.zeros_like(v))
             fields.append(v)
@@ -336,6 +345,57 @@ def fused_key_words(
         from .stats import assemble_words, layout_words
 
         return assemble_words(fields, layout_words(bits_list, plan.allow64))
+
+
+def fused_decodable(plan: "FusePlan", key_cols: Sequence[KeyCol]) -> list:
+    """Per key column: whether :func:`fused_key_decode` gives its values
+    back from the plan's words bit for bit (an ascending field of an
+    integer, bool or dictionary-code column; a float field canonicalizes
+    -0.0 and NaN and is not)."""
+    from .stats import enc_class, wire_narrowable
+
+    ok = [False] * len(key_cols)
+    for kind, pos, _bits, asc in plan.fields:
+        if kind == "value" and asc:
+            ok[pos] = wire_narrowable(enc_class(key_cols[pos][0].dtype))
+    return ok
+
+
+def fused_key_decode(
+    plan: "FusePlan",
+    words: Sequence[jax.Array],
+    key_cols: Sequence[KeyCol],
+    live: jax.Array,
+) -> list:
+    """The :func:`fused_decodable` key columns of some rows, read back
+    out of those rows' fused ``words`` (:func:`fused_key_words` with
+    ``zero_null_values=True``, msb first; any row subset or order):
+    ``[(data, valid | None) | None]`` by key position, so a consumer that
+    carries the sort words (the group-by) need not carry the keys beside
+    them. ``key_cols`` / ``live`` are the UNSORTED inputs the words were
+    made of: each field's base is their live minimum, as it was there."""
+    from .stats import decode_enc, enc_class, extract_fields, layout_words
+
+    bits_list = [b for _k, _p, b, _a in plan.fields]
+    fields = extract_fields(
+        list(words), layout_words(bits_list, plan.allow64), bits_list
+    )
+    decodable = fused_decodable(plan, key_cols)
+    out: list = [None] * len(key_cols)
+    nulls = {}
+    for (kind, pos, _bits, _asc), field in zip(plan.fields, fields):
+        if kind == "null":
+            nulls[pos] = field == 0
+        elif kind == "value" and decodable[pos]:
+            data, _valid = key_cols[pos]
+            cls = enc_class(data.dtype)
+            enc = orderable_key(data)
+            base = _field_base(enc, live, True)
+            out[pos] = (
+                decode_enc(base + field.astype(enc.dtype), cls, data.dtype),
+                nulls.get(pos),
+            )
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -370,6 +430,88 @@ def run_count_from(new_run: jax.Array, flag: jax.Array) -> jax.Array:
     excl_r = jnp.cumsum(f_r) - f_r
     start_r = jax.lax.cummax(jnp.where(new_run_r, excl_r, 0))
     return jnp.flip(excl_r + f_r - start_r)
+
+
+#: rows a block of :func:`run_reduce`'s scan (one vector register's lanes)
+SCAN_BLOCK = 128
+
+_COMBINE = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
+
+
+def scan_identity(kind: str, dtype):
+    """The value a ``"sum" | "min" | "max"`` reduction starts from."""
+    if kind == "sum":
+        return jnp.zeros((), dtype)
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.array(jnp.inf if kind == "min" else -jnp.inf, dtype)
+    info = jnp.iinfo(dtype)
+    return jnp.asarray(info.max if kind == "min" else info.min, dtype)
+
+
+def _shift_up(x: jax.Array, k: int, fill) -> jax.Array:
+    """``y[..., i] = x[..., i + k]``, ``fill`` past the end."""
+    pad = jnp.full(x.shape[:-1] + (k,), fill, x.dtype)
+    return jnp.concatenate([x[..., k:], pad], axis=-1)
+
+
+def _scan_steps(closed: jax.Array, vals: list, kinds: Sequence[str]):
+    """Shift-and-combine steps along the last axis: afterwards ``vals[i]``
+    reduces its row and the rows after it up to the first ``closed`` one
+    (or the axis' end), and ``closed[i]`` says whether there is one."""
+    k, width = 1, closed.shape[-1]
+    while k < width:
+        vals = [
+            jnp.where(closed, v, _COMBINE[kind](
+                v, _shift_up(v, k, scan_identity(kind, v.dtype))
+            ))
+            for v, kind in zip(vals, kinds)
+        ]
+        closed = closed | _shift_up(closed, k, False)
+        k *= 2
+    return closed, vals
+
+
+def run_reduce(
+    run_end: jax.Array, vals: Sequence[jax.Array], kinds: Sequence[str]
+) -> list:
+    """Reduce every run of rows, for each ``vals[i]`` with its own
+    ``kinds[i]`` of ``"sum" | "min" | "max"``: row i gets the reduction of
+    rows i .. the end of its run, so a run's FIRST row holds the run's
+    total. ``run_end`` [n] bool marks each run's last row.
+
+    A segmented scan: a run adds (or compares) only its own values, so a
+    float sum's error scales with that run's magnitude and not with the
+    prefix before it, as a difference of prefix sums would have it. No
+    scatter, no gather and no sort: blocks of :data:`SCAN_BLOCK`
+    consecutive rows scan themselves by log2(block) shift-and-combine
+    passes, the blocks' heads scan the same way one level up, and one
+    pass hands every open row the total that follows its block."""
+    vals, kinds = list(vals), list(kinds)
+    n = run_end.shape[0]
+    if n <= SCAN_BLOCK:
+        return _scan_steps(run_end, vals, kinds)[1]
+    blocks = -(-n // SCAN_BLOCK)
+    pad = blocks * SCAN_BLOCK - n
+
+    def tile(x, fill):
+        if pad:
+            x = jnp.concatenate([x, jnp.full((pad,), fill, x.dtype)])
+        return x.reshape(blocks, SCAN_BLOCK)
+
+    closed, tiles = _scan_steps(
+        tile(run_end, True),
+        [tile(v, scan_identity(k, v.dtype)) for v, k in zip(vals, kinds)],
+        kinds,
+    )
+    # a block's first row holds the block's reduction up to its first run
+    # end; what follows block b is the scan of the heads from b + 1 on
+    heads = run_reduce(closed[:, 0], [t[:, 0] for t in tiles], kinds)
+    out = []
+    for t, head, kind in zip(tiles, heads, kinds):
+        follows = _shift_up(head, 1, scan_identity(kind, head.dtype))
+        t = jnp.where(closed, t, _COMBINE[kind](t, follows[:, None]))
+        out.append(t.reshape(-1)[:n])
+    return out
 
 
 def canonical_row_lanes(
@@ -414,26 +556,110 @@ def lane_runs_differ(sorted_lanes: Sequence[jax.Array]) -> jax.Array:
     return diff.at[0].set(True)
 
 
+#: 32-bit lanes of payload that one sort carries. The TPU's compiler takes
+#: longer than linearly in a sort's operands (a group-by of 1 / 2 / 4 / 8
+#: float64 sums compiled in 44 / 59 / 142 / 382 s for a described v5e,
+#: PERF.md section 6, PR 28), so more lanes than this ride in batches.
+RIDE_LANES = 8
+
+
+def _carrier(dtype) -> np.dtype:
+    """What a payload of ``dtype`` rides a batched sort as: itself when it
+    is 64 bits wide, else uint32, so that one batch holds one dtype."""
+    dtype = np.dtype(dtype)
+    return dtype if dtype.itemsize == 8 else np.dtype(np.uint32)
+
+
+def _to_carrier(x: jax.Array) -> jax.Array:
+    size = np.dtype(x.dtype).itemsize
+    if size == 8:
+        return x
+    if x.dtype == jnp.bool_:
+        return x.astype(jnp.uint32)
+    bits = jax.lax.bitcast_convert_type(x, np.dtype(f"uint{8 * size}"))
+    return bits.astype(jnp.uint32)
+
+
+def _from_carrier(x: jax.Array, dtype) -> jax.Array:
+    size = np.dtype(dtype).itemsize
+    if size == 8:
+        return x
+    if np.dtype(dtype) == np.bool_:
+        return x != 0
+    bits = x.astype(np.dtype(f"uint{8 * size}"))
+    return jax.lax.bitcast_convert_type(bits, dtype)
+
+
+def ride_sort(sort_fn, payloads: Sequence[jax.Array]):
+    """``sort_fn(payloads) -> (sorted keys, sorted payloads)`` for any
+    number of payloads: up to :data:`RIDE_LANES` lanes ride the one sort,
+    more ride in batches of that many, each batch the same sort of the
+    same keys run again by ``jax.lax.map`` over the stacked lanes (one
+    stack a 64-bit dtype, one for everything narrower, bit for bit as
+    uint32). The program then holds one sort a stack whatever the number
+    of columns, and every batch is permuted alike: the sort is stable, or
+    its keys are distinct where the rows matter."""
+    payloads = list(payloads)
+    lanes = sum(max(1, np.dtype(p.dtype).itemsize // 4) for p in payloads)
+    if lanes <= RIDE_LANES:
+        return sort_fn(payloads)
+    keys_out, _none = sort_fn([])
+    stacks: dict = {}
+    for i, p in enumerate(payloads):
+        stacks.setdefault(_carrier(p.dtype), []).append(i)
+    out: list = [None] * len(payloads)
+    for carrier, at in stacks.items():
+        per = min(RIDE_LANES // (carrier.itemsize // 4), len(at))
+        arrs = [_to_carrier(payloads[i]) for i in at]
+        arrs += [arrs[0]] * (-len(arrs) % per)
+        stacked = jnp.stack(arrs).reshape((-1, per) + arrs[0].shape)
+        rode = jax.lax.map(
+            lambda batch: jnp.stack(
+                sort_fn([batch[j] for j in range(per)])[1]
+            ),
+            stacked,
+        ).reshape((-1,) + arrs[0].shape)
+        for j, i in enumerate(at):
+            out[i] = _from_carrier(rode[j], payloads[i].dtype)
+    return keys_out, out
+
+
 def sorted_runs(
     lanes_msb_first: Sequence[jax.Array], pay: jax.Array
 ) -> Tuple[jax.Array, jax.Array]:
     """Stable row ordering + run boundaries over canonical lanes.
 
     Returns (spay [cap] original indices in sorted order, new_run [cap]).
-    The single implementation of the reversed-lanes chained sort +
-    run-detect idiom shared by factorize and the set algebra.
     """
+    new_run, (spay,), _lanes = sorted_runs_payload(lanes_msb_first, [pay])
+    return spay, new_run
+
+
+def sorted_runs_payload(
+    lanes_msb_first: Sequence[jax.Array], payloads: Sequence[jax.Array]
+) -> Tuple[jax.Array, list, list]:
+    """Stable row ordering + run boundaries over canonical lanes, with
+    ``payloads`` (any dtype; a 64-bit operand rides as its two 32-bit
+    halves, which the TPU's X64 rewriter makes of it) riding the chained
+    sort, in batches when there are many (:func:`ride_sort`). The single
+    implementation of the reversed-lanes chained sort + run-detect idiom
+    shared by factorize and the set algebra.
+
+    Returns (new_run [cap], sorted payloads, sorted lanes msb first)."""
     from . import radix as _radix
 
     lanes = list(lanes_msb_first)
-    perm = _radix.lexsort_perm(list(reversed(lanes)), pay.shape[0])
+    perm = _radix.lexsort_perm(list(reversed(lanes)), lanes[0].shape[0])
     if perm is not None:
         # one gather per lane by the final perm replaces riding every pass
-        return pay[perm], lane_runs_differ([l[perm] for l in lanes])
-    sorted_lanes, pays = lexsort_with_payload(
-        list(reversed(lanes)), [pay]
+        pays = [p[perm] for p in payloads]
+        slanes = [l[perm] for l in lanes]
+        return lane_runs_differ(slanes), pays, slanes
+    slanes, pays = ride_sort(
+        lambda pays: lexsort_with_payload(list(reversed(lanes)), pays), payloads
     )
-    return pays[0], lane_runs_differ(list(reversed(sorted_lanes)))
+    slanes = list(reversed(slanes))
+    return lane_runs_differ(slanes), pays, slanes
 
 
 def split_ride_cols(
@@ -493,6 +719,27 @@ def sentinel_compact(key: jax.Array, payloads: Sequence[jax.Array]) -> list:
     with jax.named_scope(_stages.SORT_ENGINE):
         out = jax.lax.sort(tuple([key] + list(payloads)), num_keys=1, is_stable=True)
         return list(out[1:])
+
+
+def flag_compact(
+    keep: jax.Array, payloads: Sequence[jax.Array]
+) -> Tuple[jax.Array, list]:
+    """Move the rows whose ``keep`` flag is set to the front, in their
+    order: one 1-key sort keyed on the row's own position (the sentinel
+    for a dropped row), so no two kept rows tie and the sort need not be
+    stable. Many payloads ride in batches (:func:`ride_sort`). Returns
+    (positions [cap]: the kept rows' original positions, then the sentinel
+    ``cap``; compacted payloads)."""
+    cap = keep.shape[0]
+    key = jnp.where(keep, jnp.arange(cap, dtype=jnp.int32), jnp.int32(cap))
+    def compact(pays):
+        with jax.named_scope(_stages.SORT_ENGINE):
+            out = jax.lax.sort(
+                tuple([key] + list(pays)), num_keys=1, is_stable=False
+            )
+        return out[0], list(out[1:])
+
+    return ride_sort(compact, payloads)
 
 
 def lexsort_rows(

@@ -484,9 +484,9 @@ def make_join_groupby_step(
             jt, _ = join_shard(lt, rt, l_key_idx, r_key_idx, how, join_cap)
             # group on the (left) join key, sum the aggregate column
             keys = [jt.cols[i] for i in l_key_idx]
-            ids, ng = _g.group_ids(keys, jt.n, join_cap)
-            d, v = jt.cols[agg_col_idx]
-            s, _sv = _g.aggregate_column(_g.SUM, d, v, ids, ng, group_cap)
+            _k, ((s, _sv),), ng = _g.groupby_aggregate(
+                keys, [jt.cols[agg_col_idx]], [(_g.SUM, 0)], jt.n, group_cap
+            )
             n_join = jt.n
         total = s.sum()
         if world > 1:

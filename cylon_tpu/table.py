@@ -28,7 +28,6 @@ from __future__ import annotations
 import numbers
 import threading
 from collections import OrderedDict
-from functools import partial
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
@@ -2138,7 +2137,7 @@ class Table:
                 gmask = jnp.arange(group_cap, dtype=jnp.int32) < ng
                 rep_idx = jnp.where(gmask, reps, -1)
                 out = [_j.gather_column(d, v, rep_idx) for d, v in lk]
-                # mirror aggregate_column's SUM validity: a group whose
+                # mirror groupby_aggregate's SUM validity: a group whose
                 # left values are ALL null sums to null, not 0
                 sum_valid = (
                     None if lcols[val_idx][1] is None
@@ -2511,7 +2510,7 @@ class Table:
         bump("groupby.factorize_path")
         if not _sorted and provably_sorted:
             # canonical prefix order: run adjacency AND emitted group order
-            # match the factorize path exactly (ops.groupby.sorted_group_ids)
+            # match the factorize path exactly (factorize_runs(presorted=True))
             _sorted = True
             bump("ordering.groupby_run_detect")
         # the factorize path emits groups in canonical key order by
@@ -2533,10 +2532,6 @@ class Table:
         if gb_fuse is not None:
             bump("lane_pack.groupby_fused",
                  rows=gb_fuse.n_plain - gb_fuse.n_words)
-        ids_fn = (
-            _g.sorted_group_ids if _sorted
-            else partial(_g.group_ids, fuse=gb_fuse)
-        )
         all_names = self.column_names
         key_idx = tuple(all_names.index(n) for n in key_names)
         val_idx = tuple(all_names.index(c) for c, _, _ in specs)
@@ -2551,32 +2546,21 @@ class Table:
             "groupby", key_idx, val_idx, ops_t, ddof, quantile, len(flat),
             _sorted, cap_out, gb_fuse,
         ) + _radix.impl_tag()
+        # a value column rides the factorize sort once, whatever ops read it
+        ride_idx = tuple(dict.fromkeys(val_idx))
 
         def build_emit():
             def kern(dp, rep):
                 (cols, counts) = dp
-                co = cap_out
-                n = counts[0]
-                cap = cols[0][0].shape[0]
-                keys = [cols[i] for i in key_idx]
-                with jax.named_scope(_stages.GROUPBY_KEY_IDS):
-                    ids, ng = ids_fn(keys, n, cap)
-                # each group's keys: a scatter-min of the row index by id
-                # and a gather by it, the stage of the other segment ops
-                with jax.named_scope(_stages.GROUPBY_SEGMENT_SUM):
-                    rep_rows = _g.group_representatives(ids, co)
-                    gmask = jnp.arange(co) < ng
-                    rep_idx = jnp.where(
-                        gmask, jnp.clip(rep_rows, 0, cap - 1), -1
-                    )
-                    out = [_j.gather_column(d, v, rep_idx) for d, v in keys]
-                for (vi, oid) in zip(val_idx, ops_t):
-                    d, v = cols[vi]
-                    a, av = _g.aggregate_column(
-                        oid, d, v, ids, ng, co, ddof=ddof, quantile=quantile
-                    )
-                    out.append((a, av))
-                return out, _scalar(ng)
+                keys, aggs, ng = _g.groupby_aggregate(
+                    [cols[i] for i in key_idx],
+                    [cols[i] for i in ride_idx],
+                    [(oid, ride_idx.index(vi))
+                     for vi, oid in zip(val_idx, ops_t)],
+                    counts[0], cap_out, fuse=gb_fuse, presorted=_sorted,
+                    ddof=ddof, quantile=quantile,
+                )
+                return keys + aggs, _scalar(ng)
 
             return kern
 

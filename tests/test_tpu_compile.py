@@ -19,6 +19,7 @@ the TPU's library, so the call must not run while any module is imported
 (each pytest worker imports every test file) and the compiles run in this
 process, never in a child.
 """
+import re
 from functools import partial
 
 import numpy as np
@@ -224,9 +225,8 @@ def test_range_partition_compiles_for_four_chips(mesh4):
 
 def test_groupby_segment_sum_compiles_for_tpu(one_chip):
     def groupby_sum(key, val, n):
-        ids, n_groups = _g.group_ids([(key, None)], n, ROWS)
-        out, _valid = _g.aggregate_column(
-            _g.agg_op_id("sum"), val, None, ids, n_groups, ROWS
+        _keys, ((out, _valid),), n_groups = _g.groupby_aggregate(
+            [(key, None)], [(val, None)], [(_g.agg_op_id("sum"), 0)], n, ROWS
         )
         return out, n_groups
 
@@ -236,6 +236,87 @@ def test_groupby_segment_sum_compiles_for_tpu(one_chip):
         _spec((ROWS,), jnp.float32, one_chip),
         _spec((), jnp.int32, one_chip),
     )
+
+
+def test_groupby_cell_shape_compiles_without_scatter(one_chip):
+    """``groupby-w1``'s own shape (int64 key fused into one sort word,
+    float64 value, x64 on): the aggregates ride the factorize sort, so no
+    operation that writes a row-sized array scatters and at most one
+    gathers, and both stages name their operations. The first answer,
+    without a chip, to whether a 64-bit operand rides ``jax.lax.sort`` on
+    a v5e: it compiles, as its two 32-bit halves."""
+    from cylon_tpu.obs import stages
+
+    fuse = _sort.plan_lane_fusion(
+        [("i64", 22, False, True)], pad_bits=1, prefix_bits=0, allow64=True
+    )
+    assert fuse is not None and fuse.n_words == 1
+
+    def groupby_sum(key, val, n):
+        return _g.groupby_aggregate(
+            [(key, None)], [(val, None)], [(_g.agg_op_id("sum"), 0)], n, ROWS,
+            fuse=fuse,
+        )
+
+    compiled = _compile(
+        groupby_sum,
+        _spec((ROWS,), jnp.int64, one_chip),
+        _spec((ROWS,), jnp.float64, one_chip),
+        _spec((), jnp.int32, one_chip),
+    )
+    _module, rows = stages.parse_compiled(compiled.as_text())
+
+    # `%name = shape opcode(operands)`, the shape a tuple for a sort
+    parsed = [
+        re.match(r"%\S+ = (\(.*?\)|\S+) ([\w-]+)\(", text) for text, _op in rows
+    ]
+    wide = [(m[1], m[2]) for m in parsed if m and f"[{ROWS}]" in m[1]]
+    assert wide
+    assert not [shape for shape, opcode in wide if "scatter" in opcode]
+    assert len([shape for shape, opcode in wide if "gather" in opcode]) <= 1
+    # a 64-bit operand rides either sort as two 32-bit operands
+    sorts = [shape for shape, opcode in wide if opcode == "sort"]
+    assert len(sorts) == 2 and all(shape.count("f32[") >= 2 for shape in sorts)
+    for name in (stages.GROUPBY_KEY_IDS, stages.GROUPBY_SEGMENT_SUM):
+        assert any(name in op.split("/") for _text, op in rows), name
+
+
+@pytest.mark.slow  # two to three minutes a case in this sandbox
+@pytest.mark.parametrize("columns", [16, 32])
+def test_groupby_of_many_float64_columns_compiles_in_bounded_time(
+    one_chip, columns
+):
+    """Compile time does not grow with the aggregates: past
+    ``ops.sort.RIDE_LANES`` lanes the payloads ride either sort in batches
+    under one ``jax.lax.map``, so the program holds the same few sorts at
+    16 float64 sums as at 32 (unbatched, 8 sums took 382 s here and the
+    time grew faster than the columns; PERF.md section 6, PR 28)."""
+    import time
+
+    fuse = _sort.plan_lane_fusion(
+        [("i64", 22, False, True)], pad_bits=1, prefix_bits=0, allow64=True
+    )
+
+    def groupby_sums(key, vals, n):
+        return _g.groupby_aggregate(
+            [(key, None)], [(v, None) for v in vals],
+            [(_g.agg_op_id("sum"), j) for j in range(columns)], n, ROWS,
+            fuse=fuse,
+        )
+
+    t0 = time.monotonic()
+    compiled = _compile(
+        groupby_sums,
+        _spec((ROWS,), jnp.int64, one_chip),
+        [_spec((ROWS,), jnp.float64, one_chip)] * columns,
+        _spec((), jnp.int32, one_chip),
+    )
+    seconds = time.monotonic() - t0
+    text = compiled.as_text()
+    # the keys' own sort and one sort a stack of batches, at either site
+    assert len(re.findall(r" sort\(", text)) <= 6
+    assert "scatter(" not in text
+    assert seconds < 900, f"{columns} columns compiled in {seconds:.0f} s"
 
 
 # ----------------------------------------------------------------------
