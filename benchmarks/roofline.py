@@ -6,11 +6,6 @@ Model
 -----
 TPU XLA ``sort`` is a bitonic sorting network: ``P(n) = k*(k+1)/2`` passes
 for ``k = ceil(log2 n)``, each pass streaming every operand lane once.
-The width-adaptive radix engine (ops/radix.py) replaces that with
-``ceil(d/r)`` stable histogram passes for a d-bit key stack; each traced
-``radix_pass`` pjit is priced as one streamed pass of its operands and
-folded into the same ``sort_pass_bytes`` bucket, so the radix/bitonic
-ratio of modeled sort bytes is directly the engine's win.
 Gathers/scatters pay PER ELEMENT (~4-9 ns each on v5e at the narrow row
 widths the packed codec uses — measured round 3 via the join stage
 profile), modeled as ``GATHER_PASS_EQ`` sequential-pass equivalents per
@@ -85,8 +80,6 @@ class Report:
     sort_pass_bytes: float = 0.0  # sum over sorts: operand bytes * passes
     sort_count: int = 0
     sort_passes: float = 0.0  # total modeled passes across all sorts
-    radix_passes: int = 0  # stable histogram passes (ops/radix.py)
-    radix_pass_bytes: float = 0.0  # sum over radix passes: streamed bytes
     gather_bytes: float = 0.0  # pass-equivalent weighted
     scatter_bytes: float = 0.0
     elementwise_bytes: float = 0.0
@@ -112,8 +105,6 @@ def _merge_scaled(rep: Report, sub: Report, scale: float) -> None:
     rep.sort_pass_bytes += sub.sort_pass_bytes * scale
     rep.sort_count += int(sub.sort_count * scale)
     rep.sort_passes += sub.sort_passes * scale
-    rep.radix_passes += int(sub.radix_passes * scale)
-    rep.radix_pass_bytes += sub.radix_pass_bytes * scale
     rep.gather_bytes += sub.gather_bytes * scale
     rep.scatter_bytes += sub.scatter_bytes * scale
     rep.elementwise_bytes += sub.elementwise_bytes * scale
@@ -164,30 +155,6 @@ def _walk(jaxpr, rep: Report, scope: str = "") -> None:
             )
             rep.elementwise_bytes += w
             rep.by_prim[prim] = rep.by_prim.get(prim, 0.0) + w
-            continue
-        if prim == "pjit" and eqn.params.get("name") == "radix_pass":
-            # ONE stable histogram pass of the width-adaptive radix sort
-            # (ops/radix.py): the pass streams its operands (encoded key
-            # lane + permutation) a small constant number of times —
-            # histogram, rank, scatter all fuse over the same n rows. The
-            # R×n one-hot intermediates live in registers/fused loops, so
-            # price streamed in+out bytes and do NOT recurse (recursing
-            # would bill the rank gather at GATHER_PASS_EQ and the
-            # one-hot at R× the lane bytes — the same overstatement the
-            # pallas_call rule avoids). Folding into sort_pass_bytes
-            # keeps total_model_bytes comparable across impls: the
-            # radix/bitonic ratio of sort_pass_bytes IS the modeled win.
-            w = sum(
-                _nbytes(x.aval) for x in eqn.invars if hasattr(x, "aval")
-            ) + sum(
-                _nbytes(x.aval) for x in eqn.outvars if hasattr(x, "aval")
-            )
-            rep.radix_passes += 1
-            rep.radix_pass_bytes += w
-            rep.sort_pass_bytes += w
-            rep.sort_passes += 1
-            rep.sort_count += 1
-            rep.by_prim["radix_pass"] = rep.by_prim.get("radix_pass", 0.0) + w
             continue
         # recurse into nested jaxprs (pjit/closed_call/scan/while/cond/
         # shard_map). A param may hold a raw Jaxpr (has .eqns) or a

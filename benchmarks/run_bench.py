@@ -100,8 +100,6 @@ def _roofline_recorded(extra: dict, hbm: float, measured_s: float, op) -> None:
             total.sort_bytes_per_pass += rep.sort_bytes_per_pass
             total.sort_pass_bytes += rep.sort_pass_bytes
             total.sort_passes += rep.sort_passes
-            total.radix_passes += rep.radix_passes
-            total.radix_pass_bytes += rep.radix_pass_bytes
             total.gather_bytes += rep.gather_bytes
             total.scatter_bytes += rep.scatter_bytes
             total.elementwise_bytes += rep.elementwise_bytes
@@ -120,9 +118,8 @@ def _roofline_recorded(extra: dict, hbm: float, measured_s: float, op) -> None:
         if total.sort_pass_bytes:
             extra["sort_passes_bytes_gb"] = round(total.sort_pass_bytes / 1e9, 2)
         if total.sort_passes:
-            # traced pass census: radix histogram passes count 1 apiece,
-            # bitonic networks k(k+1)/2 — the column the radix engine's
-            # CI gate (tools/sort_smoke.py) reads
+            # traced pass census: a sort of n rows counts k(k+1)/2 passes
+            # at k = ceil(log2 n)
             extra["sort_passes"] = round(total.sort_passes, 1)
     except Exception as e:
         print(f"# roofline(recorded) failed: {e}", file=sys.stderr)
@@ -520,29 +517,18 @@ def run_suite(n_rows: int, reps: int, mesh_devices, scaling: bool):
         out = mt.sort(["a", "b", "c"])
         _sync(out)
 
-    # the packed/nopack pair measures the LANE-FUSION win in isolation, so
-    # both run on the bitonic network (radix kill-switched) — comparable
-    # with every earlier BENCH round; the radix row below is the
-    # width-adaptive engine on the same packed table (sort passes column:
-    # ceil(42/4)=11-ish histogram passes vs L(L+1)/2 bitonic sweeps)
-    from cylon_tpu.ops import radix as _radix_mod
-
-    with _radix_mod.disabled():
-        s, c, laps = _bench(msort, reps)
-        mp_extra = {}
-        _roofline_recorded(mp_extra, hbm, s, msort)
-        record("multikey_sort_packed", s, c, n_rows, world, mp_extra,
-               samples=laps)
-        with _lp_gate.disabled():
-            s, c, laps = _bench(msort, reps)
-            mn_extra = {}
-            _roofline_recorded(mn_extra, hbm, s, msort)
-            record("multikey_sort_nopack", s, c, n_rows, world, mn_extra,
-                   samples=laps)
+    # the packed/nopack pair measures the LANE-FUSION win in isolation
     s, c, laps = _bench(msort, reps)
-    mr_extra = {}
-    _roofline_recorded(mr_extra, hbm, s, msort)
-    record("multikey_sort_radix", s, c, n_rows, world, mr_extra, samples=laps)
+    mp_extra = {}
+    _roofline_recorded(mp_extra, hbm, s, msort)
+    record("multikey_sort_packed", s, c, n_rows, world, mp_extra,
+           samples=laps)
+    with _lp_gate.disabled():
+        s, c, laps = _bench(msort, reps)
+        mn_extra = {}
+        _roofline_recorded(mn_extra, hbm, s, msort)
+        record("multikey_sort_nopack", s, c, n_rows, world, mn_extra,
+               samples=laps)
 
     # config 4: set ops (shuffle on all columns + sorted dedup) — identical
     # schemas required, so pair ``left`` with a second (k, v) table
@@ -659,9 +645,7 @@ def to_markdown(results, header: str) -> str:
             f"| {cmb} | {cbr} "
             # traced sort-pass GB (the TPU wall-time pricing quantity —
             # BENCH.md sliced-join sweep; ordering rows show the elision)
-            # + the traced pass census (radix passes count 1, bitonic
-            # networks L(L+1)/2 — the multikey radix/packed pair reads
-            # the engine's win directly off this column)
+            # + the traced pass census (L(L+1)/2 a sort)
             f"| {r.get('sort_passes_bytes_gb', '')} "
             f"| {r.get('sort_passes', '')} |"
         )

@@ -229,32 +229,6 @@ Q3_DISPATCH_OPS = (
 )
 
 # ---------------------------------------------------------------------------
-# sort-engine pass-count census (radix campaign)
-# ---------------------------------------------------------------------------
-#: radix digit width r: a lane stack carrying d significant bits sorts
-#: in exactly ceil(d/r) stable histogram passes (ops/radix.py pins the
-#: same literal; tools/sort_smoke.py cross-checks the two)
-RADIX_SORT_DIGIT_BITS = 4
-
-#: the Pallas tier trades histogram width for pass count (8-bit digits,
-#: 256-counter VMEM histograms per row tile)
-PALLAS_RADIX_SORT_DIGIT_BITS = 8
-
-
-def radix_sort_passes(total_bits: int, r: int = RADIX_SORT_DIGIT_BITS) -> int:
-    """Contracted pass count for a ``total_bits``-wide key stack."""
-    return -(-int(total_bits) // int(r)) if total_bits > 0 else 0
-
-
-def bitonic_sort_sweeps(cap: int, n_lanes: int = 1) -> int:
-    """Contracted compare-exchange sweep count of the bitonic network the
-    radix engine replaces: ``n_lanes * L(L+1)/2`` at capacity ``2**L``
-    (one full sorting network per key lane in the multi-lane lexsort)."""
-    lg = max(1, (int(cap) - 1).bit_length())
-    return int(n_lanes) * lg * (lg + 1) // 2
-
-
-# ---------------------------------------------------------------------------
 # shuffle-codec row-pass census (pallas codec campaign, ISSUE 20)
 # ---------------------------------------------------------------------------
 #: row passes one send-side pack costs per scanned row, per impl: the
@@ -370,15 +344,6 @@ SYNC_SITE_BUDGETS: Dict[str, SyncBudget] = {
     "obs.prof.record_fused": SyncBudget(
         0, note="dispatch-time shape-derived work units; the window "
         "resolves later at the existing deferred count fetch",
-    ),
-    # the sort engine (radix campaign): the pass-count evidence that
-    # drives autopilot sort_impl decisions is computed entirely from
-    # trace-time statics (lane widths, capacity, hint spans) — a
-    # radix-sorted dispatch keeps the exact same sync census as the
-    # bitonic one it replaces
-    "obs.prof.record_sort": SyncBudget(
-        0, note="impl tag + host-side pass census + perf_counter window; "
-        "the deferred count fetch resolves the window later",
     ),
     # the shuffle codec engine (pallas codec campaign): the per-round
     # impl evidence is dispatch-wall stamps + the static row-pass census

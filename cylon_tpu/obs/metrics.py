@@ -343,11 +343,6 @@ STABLE_METRICS: Dict[str, Tuple[str, str]] = {
     "lane_pack.": (
         "mixed", "bit-width packing: stats_kernel/sort_fused/join_fused/"
         "groupby_fused counters, wire.* gate counters + ratio gauge"),
-    "radix.": (
-        "counter", "width-adaptive sort engine: trace_passes (rows = "
-        "histogram passes traced per compile, the pass census beside "
-        "the bitonic sweep model) + declined (digit planner fell back "
-        "to bitonic: float key lane or no width evidence)"),
     "ordering.": (
         "counter", "order-property consumers: sort_elided/dist_sort_elided/"
         "sort_suffix/join_presorted_probe/join_key_order_emit/"

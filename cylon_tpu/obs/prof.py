@@ -425,38 +425,6 @@ def record_fused(units: Dict[str, np.ndarray], world: int, t0: float) -> None:
         _degrade(e)
 
 
-def record_sort(
-    impl: str, passes: int, rows: int, world: int, t0: float
-) -> None:
-    """Per-pass stage clocks for one sort-family dispatch under the
-    RESOLVED sort impl (ops/radix.py): work units are ``passes x rows``
-    — the pass count is the whole point of the radix engine, so the
-    ledger tracks it per impl (stage key ``sort.<impl>`` ->
-    ``prof.stage_ms.sort.radix`` etc., beside the shuffle tracks the
-    PR 15 critical path names). Same pending-window ride-along as
-    :func:`record_fused`: the sort program is still in flight here, the
-    query's device-resolved end stamps the window (0 sync sites).
-    Per-shard attribution is uniform (shape-derived, honest)."""
-    if not profiling_active():
-        return
-    try:
-        from .. import fault as _fault
-        from . import trace as _trace
-
-        _fault.inject.check("obs.prof")
-        if _trace.current() is None:
-            return
-        if passes <= 0 or rows <= 0:
-            return
-        units = {
-            f"sort.{impl}": float(passes) * float(rows)
-            * np.ones(max(world, 1), np.float64)
-        }
-        _attach(StageProfile("sort", world, t0, None, units))
-    except Exception as e:
-        _degrade(e)
-
-
 def finalize(q) -> None:
     """Resolve any window-pending profiles on a finishing query trace
     (called from ``obs.trace._maybe_finish`` before the trace is

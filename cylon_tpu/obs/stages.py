@@ -3,7 +3,7 @@ instruction of every dispatched program to its stage.
 
 The hot-path kernels wrap their work in ``with jax.named_scope(NAME):``
 with the names below, so a compiled program's instructions carry
-``op_name="jit(join_spec)/join.probe/sort_engine/jit(radix_pass)/gather"``.
+``op_name="jit(join_probe)/join.right_sort/sort_engine/jit(argsort)/sort"``.
 A profiler trace read through ``jax.profiler.ProfileData`` names a device
 operation by its HLO instruction text WITHOUT that metadata, so the stage
 of a trace event is a join of the event's instruction name with the

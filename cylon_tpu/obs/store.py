@@ -350,19 +350,8 @@ def _absorb_record(profiles: Dict, hists: Dict, rec: Dict, seq: int) -> int:
             agg[0] += 1
             agg[1] = round(agg[1] + float(ms), 3)
             agg[2] = max(agg[2], float(ratio))
-        # sort-impl evidence (note_sort): per-impl [n, ms_sum,
-        # passes_sum, alt_passes_sum] dispatch clocks the sort_impl
-        # re-coster judges radix-vs-bitonic on
-        for impl, (n_s, ms, passes, alt) in (rec.get("sort") or {}).items():
-            ev = p.setdefault("sort_ev", {}).setdefault(
-                impl, [0, 0.0, 0, 0]
-            )
-            ev[0] += int(n_s)
-            ev[1] = round(ev[1] + float(ms), 3)
-            ev[2] += int(passes)
-            ev[3] += int(alt)
-        # shuffle-codec evidence (note_codec): same shape as sort_ev —
-        # per-impl [n, ms_sum, row_passes_sum, alt_row_passes_sum]
+        # shuffle-codec evidence (note_codec): per-impl
+        # [n, ms_sum, row_passes_sum, alt_row_passes_sum]
         # pack+compact dispatch clocks the codec_impl re-coster judges
         # xla-vs-pallas on
         for impl, (n_c, ms, passes, alt) in (rec.get("codec") or {}).items():
@@ -920,26 +909,6 @@ def note_stages(stages: Dict[str, tuple]) -> None:
         e[1] = max(e[1], round(float(ratio), 3))
         worst = max(worst, float(ratio))
     rec["strag"] = round(worst, 3)
-
-
-def note_sort(
-    impl: str, sec: float, passes: int, alt_passes: int
-) -> None:
-    """Fold one sort dispatch's impl evidence into the active exec
-    record: dispatch-wall seconds under the RESOLVED impl plus the pass
-    counts of both impls for this shape (host-side estimators,
-    ops/radix.py — ``alt_passes`` is what the OTHER impl would have
-    paid, so one-sided profiles can still walk back through the cost
-    model). The ``sort_impl`` re-coster reads the per-impl aggregate
-    (plan/feedback._sort_impl_proposal). Contextvar + dict math only."""
-    rec = _EXEC.get()
-    if rec is None:
-        return
-    ev = rec.setdefault("sort", {}).setdefault(impl, [0, 0.0, 0, 0])
-    ev[0] += 1
-    ev[1] = round(ev[1] + float(sec) * 1e3, 3)
-    ev[2] += int(passes)
-    ev[3] += int(alt_passes)
 
 
 def note_codec(

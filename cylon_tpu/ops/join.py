@@ -28,20 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import stages as _stages
-from . import radix as _radix
 from .factorize import factorize_two
 from .sort import KeyCol
-
-
-def _ids_hint(ids: jax.Array, cap_cat: int):
-    """Radix digit-span hint for a canonical join-id lane
-    (:func:`_canonical_ids` output): the uint32 fast path carries raw
-    orderable keys (MAXU padding — full 32-bit span, no hint), the
-    factorize path dense int32 ids bounded by ``cap_cat`` (its padding
-    sentinel), so only ``bit_length(cap_cat)`` digit bits ever vary."""
-    if ids.dtype == jnp.uint32:
-        return None
-    return _radix.bound_hint(cap_cat)
 
 
 def _inv_perm(p: jax.Array) -> jax.Array:
@@ -88,6 +76,7 @@ def _merged_counts(
     padding rows report cnt == 0 / r_cnt == 0.
     """
     from .sort import (
+        kv_sort,
         run_count_from,
         run_count_upto,
         run_start_broadcast,
@@ -97,7 +86,7 @@ def _merged_counts(
     with jax.named_scope(_stages.JOIN_PROBE):
         keys = jnp.concatenate([r_ids, l_ids])  # rights FIRST (tie order matters)
         pay = jnp.arange(cap_r + cap_l, dtype=jnp.int32)
-        skey, spay = _radix.kv_sort(keys, pay, _ids_hint(keys, cap_r + cap_l))
+        skey, spay = kv_sort(keys, pay)
         is_r_live = spay < nr
         is_l = spay >= cap_r
         rl = is_r_live.astype(jnp.int32)
@@ -159,13 +148,13 @@ def _key_order_emit(
     shadow). INNER/LEFT only — the unmatched-right append of RIGHT/FULL
     has no key-ordered formulation here."""
     from .gather import pack_gather
-    from .sort import run_count_upto, run_start_broadcast
+    from .sort import kv_sort, run_count_upto, run_start_broadcast
 
     with jax.named_scope(_stages.JOIN_PROBE):
         cap_cat = cap_r + cap_l
         keys = jnp.concatenate([r_ids, l_ids])  # rights FIRST (tie order matters)
         pay = jnp.arange(cap_cat, dtype=jnp.int32)
-        skey, spay = _radix.kv_sort(keys, pay, _ids_hint(keys, cap_cat))
+        skey, spay = kv_sort(keys, pay)
         is_l = spay >= cap_r
         is_l_live = is_l & (spay < cap_r + nl)
         is_r_live = (~is_l) & (spay < nr)
@@ -214,13 +203,7 @@ def impl_tag() -> tuple:
     with after a mid-process env flip. Join-family cache keys append this
     tag so an A/B flip recompiles instead of reusing the stale program.
     The analyzer (cylon_tpu/analysis) treats a call to this function inside
-    a key expression as the keyed carrier of all four knobs.
-
-    The sort-engine component rides along (ops/radix.impl_tag): the
-    probe/emit kv-sorts and the right ride sort lower through ops/radix,
-    so the resolved sort impl (CYLON_TPU_SORT_IMPL / CYLON_TPU_NO_RADIX /
-    the tuned per-shape decision) is part of every join-family program's
-    identity too."""
+    a key expression as the keyed carrier of all four knobs."""
     from ..utils import envgate as _eg
 
     return (
@@ -228,7 +211,7 @@ def impl_tag() -> tuple:
         _eg.SEGSUM_IMPL.get(),
         _eg.EMIT_IMPL.get(),
         _eg.EXPAND_GATHER.get(),
-    ) + _radix.impl_tag()
+    )
 
 
 def _repeat_ss(ends: jax.Array, cap_out: int) -> jax.Array:
@@ -350,15 +333,11 @@ def _canonical_ids(
         return l_ids, r_ids
 
 
-def _right_order(r_ids: jax.Array, cap_cat: int) -> jax.Array:
-    """Stable argsort of the canonical right ids: the native sort, or
-    radix passes where a radix tier is selected (ops/radix.py)."""
-    with jax.named_scope(_stages.JOIN_RIGHT_SORT):
-        r_order = _radix.argsort_perm(r_ids, _ids_hint(r_ids, cap_cat))
-        if r_order is None:
-            with jax.named_scope(_stages.SORT_ENGINE):
-                r_order = jnp.argsort(r_ids, stable=True).astype(jnp.int32)
-        return r_order
+def _right_order(r_ids: jax.Array) -> jax.Array:
+    """Stable argsort of the canonical right ids."""
+    with jax.named_scope(_stages.JOIN_RIGHT_SORT), \
+            jax.named_scope(_stages.SORT_ENGINE):
+        return jnp.argsort(r_ids, stable=True).astype(jnp.int32)
 
 
 def _probe(
@@ -374,7 +353,7 @@ def _probe(
     l_ids, r_ids = _canonical_ids(
         l_key_cols, r_key_cols, nl, nr, cap_l, cap_r, fuse=fuse
     )
-    r_order = _right_order(r_ids, cap_l + cap_r)
+    r_order = _right_order(r_ids)
     lo, cnt, r_cnt = _merged_counts(
         l_ids, r_ids, nl, nr, cap_l, cap_r, need_rcnt
     )
@@ -823,13 +802,7 @@ def spec_join(
                 r_sorted = list(r_cols)
             else:
                 ride, payloads, heavy = split_ride_cols(r_cols)
-                perm = _radix.argsort_perm(r_ids, _ids_hint(r_ids, cap_l + cap_r))
-                if perm is not None:
-                    # radix: one gather per column by the final perm replaces
-                    # riding every bitonic pass
-                    spays = [p[perm] for p in payloads]
-                    heavy_sorted = pack_gather(heavy, perm)[0] if heavy else []
-                elif heavy:
+                if heavy:
                     # carry the order only when something needs gathering by it
                     iota = jnp.arange(cap_r, dtype=jnp.int32)
                     with jax.named_scope(_stages.SORT_ENGINE):
@@ -872,7 +845,7 @@ def spec_join(
         if r_presorted:
             r_order = jnp.arange(cap_r, dtype=jnp.int32)
         else:
-            r_order = _right_order(r_ids, cap_l + cap_r)
+            r_order = _right_order(r_ids)
         out_cols, n_out = emit_gather(
             lo, cnt, r_order, r_cnt, l_cols, r_cols, nl, nr, how, cap_out,
             emit_impl,

@@ -7,7 +7,7 @@ stage, priced 3.0 row passes by the profiler's calibration table;
 one XLA pid pass plus one kernel pass):
 murmur hash over the key columns, a scatter-add histogram
 (``shuffle.bucket_counts``), a stable grouping sort
-(``shuffle.shuffle_gather_order`` — radix/bitonic passes), a ``pid``
+(``shuffle.shuffle_gather_order``), a ``pid``
 gather through that order and a scatter back to row order just to learn
 each row's destination slot. Receive side (the COMPACT stage): a
 liveness mask, a stable argsort by it, and a 400x-priced gather of the
@@ -36,8 +36,7 @@ codec argument; the redistribution-fusion payoff model of arxiv
       gather are gone; the emitted buffer is the XLA path's gather
       result bit-for-bit, dead rows included.
 
-Implementation selection mirrors the sort engine's lattice
-(ops/radix.py, PR 19); every resolver step is shape-static:
+Implementation selection; every resolver step is shape-static:
 
 1. ``CYLON_TPU_NO_PALLAS_CODEC=1`` — kill switch, XLA codec
    everywhere. Its ``disabled()`` context manager IS the differential
@@ -75,8 +74,7 @@ no nested jit (see ops/pallas_gather.py tail note).
 
 Deviation from the plan of record, stated plainly: the pack kernel
 emits ``dest`` + histogram and the ONE collision-free lane-buffer
-scatter stays in XLA (``shuffle.pack_lane_buffer``) — the same
-discipline as ops/pallas_radix.py's carried-perm scatter, because
+scatter stays in XLA (``shuffle.pack_lane_buffer``), because
 Mosaic cannot vector-scatter VMEM and the scatter is the one
 intermediate-free op in the chain. Likewise the compact kernel moves
 rows and the elementwise wire/quant decode (``gather.wire_unpack_cols``)
@@ -119,7 +117,7 @@ except Exception:  # pragma: no cover
     pltpu = None
 
 #: rows per pack-kernel grid tile: the [TILE, P] one-hot stays under
-#: 512 KB VMEM at P <= 256 — the same sizing rule as pallas_radix
+#: 512 KB VMEM at P <= 256
 TILE = 512
 
 #: compact-kernel VMEM budget for the resident move matrix (the whole
@@ -187,8 +185,8 @@ def impl_tag() -> tuple:
 def kernel_kwargs() -> dict:
     """Extra engine.get_kernel kwargs for shuffle-family kernels: a
     pallas codec embeds pallas_calls, which have no shard_map
-    replication rule — same check_vma=False discipline as the sort
-    engine (ops/radix.kernel_kwargs). get_kernel keys include the
+    replication rule — same check_vma=False discipline as the windowed
+    emit (ops/join.emit_impl_kwargs). get_kernel keys include the
     wrapping flags, so this cannot alias the checked program."""
     if resolved_impl() == "pallas":
         return {"check_vma": False}

@@ -5,6 +5,10 @@ lexicographic sort, cpp/src/cylon/arrow/arrow_kernels.hpp:95-143, introsort in
 util/sort.hpp:127-144). On TPU the native primitive is ``jax.lax.sort`` /
 ``jnp.lexsort`` — a bitonic/stable sort that XLA lowers to the hardware — so
 every ordering here is expressed as one lexsort over normalized key lanes.
+This is the only sort engine: a width-adaptive LSD radix engine lost to the
+native sort on a v5e in every stage that sorts (42-166 times slower, 9 times
+at 2-3 bit lanes: its passes are per-element gathers and scatters at 17 ns a
+row) and was deleted (PERF.md section 6, PRs 26 and 29).
 
 Padding discipline: all kernels receive fixed-capacity arrays where only rows
 ``[0, n)`` are live. A most-significant "row class" lane forces
@@ -135,9 +139,9 @@ def lexsort_with_payload(
     TPU rationale: XLA's multi-key sort comparator blows up compile time
     super-linearly in the key count (measured on v5e at 4M rows: 1 key 13 s,
     3 keys 148 s) while warm time is no better than k chained 1-key passes
-    (80 ms vs 76 ms). LSD radix order — sort by the least significant lane
-    first — plus per-pass stability reproduces the multi-key order exactly
-    (verified element-identical).
+    (80 ms vs 76 ms). Sorting by the least significant lane first, with
+    every pass stable, reproduces the multi-key order exactly (verified
+    element-identical).
 
     ``keep_lanes=False`` drops each lane after the pass it keys (a consumed
     lane is never read again), saving ~k/2 lanes of memory-bandwidth-bound
@@ -172,22 +176,10 @@ def lexsort_with_payload(
         return ops[:k], ops[k:]
 
 
-def lexsort_indices(
-    lanes: Sequence[jax.Array], cap: int, hints=None
-) -> jax.Array:
+def lexsort_indices(lanes: Sequence[jax.Array], cap: int) -> jax.Array:
     """Permutation that stably lexsorts ``lanes`` (least-significant first):
-    the chained-pass replacement for ``jnp.lexsort``.
-
-    Impl-selected (ops/radix.py): when the resolved sort impl is a radix
-    tier and every lane has an integer digit plan, the permutation comes
-    from LSD histogram passes — the stable lexsort permutation is unique,
-    so the result is bit-identical to the chained bitonic path."""
-    from . import radix as _radix
-
+    the chained-pass replacement for ``jnp.lexsort``."""
     with jax.named_scope(_stages.SORT_PERM):
-        perm = _radix.lexsort_perm(lanes, cap, hints)
-        if perm is not None:
-            return perm
         iota = jnp.arange(cap, dtype=jnp.int32)
         _, pays = lexsort_with_payload(lanes, [iota], keep_lanes=False)
         return pays[0]
@@ -646,15 +638,7 @@ def sorted_runs_payload(
     shared by factorize and the set algebra.
 
     Returns (new_run [cap], sorted payloads, sorted lanes msb first)."""
-    from . import radix as _radix
-
     lanes = list(lanes_msb_first)
-    perm = _radix.lexsort_perm(list(reversed(lanes)), lanes[0].shape[0])
-    if perm is not None:
-        # one gather per lane by the final perm replaces riding every pass
-        pays = [p[perm] for p in payloads]
-        slanes = [l[perm] for l in lanes]
-        return lane_runs_differ(slanes), pays, slanes
     slanes, pays = ride_sort(
         lambda pays: lexsort_with_payload(list(reversed(lanes)), pays), payloads
     )
@@ -709,6 +693,13 @@ def merge_ride_cols(
             hi += 1
             out.append((gd, None if v is None else gv))
     return out
+
+
+def kv_sort(keys: jax.Array, pay: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Stable 1-key sort of ``pay`` by ``keys`` (the join probe's merged
+    sort). Returns (skey, spay)."""
+    with jax.named_scope(_stages.SORT_ENGINE):
+        return jax.lax.sort((keys, pay), num_keys=1, is_stable=True)
 
 
 def sentinel_compact(key: jax.Array, payloads: Sequence[jax.Array]) -> list:
@@ -788,8 +779,6 @@ def lexsort_rows_payload(
     — the stats measured those values too); only the don't-care padding
     permutation may differ.
     """
-    from . import radix as _radix
-
     if ascending is None:
         ascending = [True] * len(key_cols)
     if fuse is not None:
@@ -799,36 +788,22 @@ def lexsort_rows_payload(
             nulls_last=nulls_last, prefix_lane=prefix_lane,
         )
         lanes = list(reversed(words))  # least-significant first
-        # radix over fused words: the layout's live widths bound the digit
-        # spans (the least-significant word additionally skips its
-        # constant-zero bottom tie padding)
-        hints = _radix.fuse_word_hints(fuse)
     else:
         lanes = []  # least-significant first (lexsort convention)
-        hints = []  # per-lane radix digit spans, same order
         with jax.named_scope(_stages.SORT_KEYS):
             pad = row_class(n, cap, None)
             for (data, valid), asc in zip(
                 reversed(list(key_cols)), list(reversed(list(ascending)))
             ):
                 lanes.append(_norm_key(data, asc))
-                hints.append(None)  # dtype-default span (floats decline)
                 if valid is not None:
                     null_lane = (~valid).astype(jnp.int8)
                     if not nulls_last:
                         null_lane = -null_lane
                     lanes.append(null_lane)
-                    hints.append(_radix.bias_hint(1, 2))  # {-1,0,1} nulls
         if prefix_lane is not None:
             lanes.append(prefix_lane)
-            hints.append(_radix.bound_hint(cap + 1))  # run ids + padding id
         lanes.append(pad)  # most significant: padding always last
-        hints.append(_radix.bias_hint(1, 2))  # {-1,0,1,2} row classes
-    with jax.named_scope(_stages.SORT_PERM):
-        perm = _radix.lexsort_perm(lanes, cap, hints)
-    if perm is not None:
-        with jax.named_scope(_stages.SORT_GATHER):
-            return perm, [p[perm] for p in payloads]
     iota = jnp.arange(cap, dtype=jnp.int32)
     with jax.named_scope(_stages.SORT_PERM):
         _, pays = lexsort_with_payload(

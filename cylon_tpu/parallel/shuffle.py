@@ -91,21 +91,10 @@ def exchange_counts(counts: jax.Array, axis_name: str) -> jax.Array:
     ).reshape(-1)
 
 
-def shuffle_gather_order(pid: jax.Array, num_partitions: int) -> jax.Array:
-    """Stable order grouping rows by target partition (padding last).
-
-    By default the native stable argsort of the id lane. pid is bounded
-    by ``num_partitions`` (the padding/dropped sentinel), so a selected
-    radix tier (ops/radix.py) groups in ``ceil(log2(P+1)/r)`` histogram
-    passes, 1-2 at any real world size. That is a count of passes, not
-    a time: on a v5e the two passes over 1,048,576 ids cost 27 ms a
-    table and the native argsort of the lane at most 3 (PERF.md
-    section 5, PR 26)."""
-    from ..ops import radix as _radix
-
-    order = _radix.argsort_perm(pid, _radix.bound_hint(num_partitions))
-    if order is not None:
-        return order
+def shuffle_gather_order(pid: jax.Array) -> jax.Array:
+    """Stable order grouping rows by target partition: the native stable
+    argsort of the id lane (the padding/dropped sentinel, the number of
+    partitions, is the largest id, so it groups last)."""
     with jax.named_scope(_stages.SORT_ENGINE):
         return jnp.argsort(pid, stable=True).astype(jnp.int32)
 
@@ -137,7 +126,7 @@ def build_send_slots_round(
     """
     with jax.named_scope(_stages.SHUFFLE_PACK):
         cap = pid.shape[0]
-        order = shuffle_gather_order(pid, num_partitions)
+        order = shuffle_gather_order(pid)
         spid = pid[order]
         starts = jnp.cumsum(counts) - counts  # exclusive prefix per partition
         safe_pid = jnp.clip(spid, 0, num_partitions - 1)
@@ -266,7 +255,7 @@ def relay_send_slots(
     still works against the selector-masked relay count matrix.
     """
     cap = pid.shape[0]
-    order = shuffle_gather_order(pid, num_partitions)
+    order = shuffle_gather_order(pid)
     spid = pid[order]
     starts = jnp.cumsum(counts) - counts
     safe_pid = jnp.clip(spid, 0, num_partitions - 1)

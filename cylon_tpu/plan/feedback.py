@@ -125,20 +125,10 @@ class Decisions(NamedTuple):
     #: whenever a topology is declared). Policy only: both modes are
     #: row-exact, the CYLON_TPU_NO_TOPO oracle pins it.
     hop_mode: Optional[str] = None
-    #: sort engine impl (ops/radix.py): ``"bitonic"`` walks a shape back
-    #: to the chained compare sort when its journaled sort-stage clocks
-    #: show radix not beating the bitonic lowering (the ROADMAP's "a
-    #: kernel must beat its XLA lowering to merge" rule, enforced at
-    #: runtime per fingerprint); ``"radix"``/``"radix_pallas"`` pin a
-    #: tier. None = the static default (the native sort, which is what
-    #: ``"bitonic"`` names; no proposal ever pins a radix tier). Policy
-    #: only: the stable lexsort permutation is unique, so every impl is
-    #: bit-exact — only milliseconds move.
-    sort_impl: Optional[str] = None
     #: shuffle codec impl (ops/pallas_codec.py): ``"xla"`` walks a shape
     #: back to the XLA pack/compact lowerings when its journaled codec
     #: dispatch clocks show the fused Pallas kernels not beating them
-    #: (the same beat-your-lowering rule as sort_impl); ``"pallas"``
+    #: (a kernel must beat its XLA lowering to stay); ``"pallas"``
     #: pins the fused tier. None = the static default (XLA; the
     #: proposer below only ever walks back to it, so the autopilot never
     #: selects a kernel the chip's compiler refuses). Policy only: the
@@ -289,11 +279,6 @@ def tuned_hop_mode() -> Optional[str]:
     return d.hop_mode if d is not None else None
 
 
-def tuned_sort_impl() -> Optional[str]:
-    d = _APPLIED.get()
-    return d.sort_impl if d is not None else None
-
-
 def tuned_codec_impl() -> Optional[str]:
     d = _APPLIED.get()
     return d.codec_impl if d is not None else None
@@ -316,10 +301,6 @@ def effective_decisions(p: Dict[str, Any]) -> tuple:
         sm = "explore"
     elif sm == STATIC:
         sm = None
-    si = dec.get("sort_impl")
-    if si == STATIC:
-        # decided: nothing to walk back, keep the static default
-        si = None
     ci = dec.get("codec_impl")
     if ci == STATIC:
         # decided: nothing to walk back, keep the static default
@@ -332,7 +313,6 @@ def effective_decisions(p: Dict[str, Any]) -> tuple:
         dec.get("footprint"),
         dec.get("skew_trigger"),
         dec.get("hop_mode"),
-        si,
         ci,
     )
 
@@ -367,7 +347,7 @@ def update_profile_decisions(p: Dict[str, Any], kind: str = "exec") -> None:
             # hysteresis streaks mature on the same record — the
             # runner-up keeps its matured streak and flips on the next
             # gate-relevant observation. A decision that leaves the
-            # EFFECTIVE tuple unchanged (the impl fields settling an
+            # EFFECTIVE tuple unchanged (codec_impl settling an
             # unset incumbent to STATIC — both carry None in the
             # fingerprint by design, the no-exploratory-recompile
             # principle) is recorded in ``dec`` so re-judging stops, but
@@ -444,17 +424,6 @@ def _proposals(
         if p.get("hop_n", 0) >= m and p.get("topo"):
             cand, ok = _hop_mode_proposal(p, mg)
             out["hop_mode"] = (cand, ok)
-
-        # -- sort impl: radix must beat its bitonic lowering, judged on
-        # the journaled sort-stage dispatch clocks (obs/prof record_sort
-        # -> store.note_sort). Every observation also carries the pass
-        # counts of BOTH impls (host-side estimators, ops/radix.py), so
-        # a one-sided profile walks back through the per-pass cost model
-        # without an exploratory recompile ------------------------------
-        if p.get("sort_ev"):
-            cand, ok = _sort_impl_proposal(p, mg, m)
-            if ok is not None:
-                out["sort_impl"] = (cand, ok)
 
         # -- shuffle codec impl: the fused pallas pack/compact must beat
         # their XLA lowerings, judged on the journaled per-stage codec
@@ -617,64 +586,12 @@ def _hop_mode_proposal(p: Dict[str, Any], mg: float) -> Tuple[Any, bool]:
     return (None, True)
 
 
-def _sort_impl_proposal(
-    p: Dict[str, Any], mg: float, m: int
-) -> Tuple[Any, Optional[bool]]:
-    """Candidate sort impl from the per-impl dispatch-clock evidence
-    ``p["sort_ev"] = {impl: [n, ms_sum, passes_sum, alt_passes_sum]}``.
-
-    Both impls measured: propose the faster by the margin — "bitonic"
-    when the compare sort wins (the auto-default walk-back), STATIC when
-    radix holds (decision MADE: keep the default, stop re-judging).
-    One impl measured: model the other through the pass-count ratio the
-    observation carried (a radix run knows the bitonic sweep count its
-    shape would have paid, and vice versa) — the same
-    no-exploratory-flip principle as the hop-mode proposal. Returns
-    ``(None, None)`` when the evidence floor is not met."""
-
-    def _ev(impl):
-        ev = (p.get("sort_ev") or {}).get(impl)
-        if not ev or ev[0] < m:
-            return None
-        n, ms, passes, alt = ev
-        return ms / n, passes / max(n, 1), alt / max(n, 1)
-
-    bit = _ev("bitonic")
-    rad = _ev("radix") or _ev("radix_pallas")
-    if bit is not None and rad is not None:
-        if bit[0] <= rad[0] * (1.0 - mg):
-            return ("bitonic", True)
-        if rad[0] <= bit[0] * (1.0 - mg):
-            return (STATIC, True)
-        return (None, True)  # within the margin: keep the static default
-    if rad is not None:
-        ms, passes, alt = rad
-        if passes <= 0 or alt <= 0:
-            return (None, True)
-        modeled_bitonic = ms / passes * alt
-        if ms > modeled_bitonic * (1.0 + mg):
-            return ("bitonic", True)
-        return (STATIC, True)
-    if bit is not None:
-        ms, passes, alt = bit
-        if passes <= 0 or alt <= 0:
-            # alt == 0: the shape's lanes are radix-ineligible — nothing
-            # to decide
-            return (None, True)
-        modeled_radix = ms / passes * alt
-        if modeled_radix > ms * (1.0 + mg):
-            return ("bitonic", True)
-        return (STATIC, True)
-    return (None, None)
-
-
 def _codec_impl_proposal(
     p: Dict[str, Any], mg: float, m: int
 ) -> Tuple[Any, Optional[bool]]:
     """Candidate shuffle codec impl from the per-impl dispatch-clock
     evidence ``p["codec_ev"] = {impl: [n, ms_sum, row_passes_sum,
-    alt_row_passes_sum]}`` — the sort_impl proposal's shape, two-way
-    xla|pallas.
+    alt_row_passes_sum]}``, two-way xla|pallas.
 
     Both impls measured: "xla" when the XLA lowerings win by the
     margin, STATIC when the fused kernels hold (decision MADE: keep the
@@ -802,14 +719,6 @@ def describe(base: tuple) -> list:
         lines.append(
             f"hop_mode tuned: {d.hop_mode} "
             f"(was 2hop-on-topology, n={p.get('hop_n', 0)})"
-        )
-    if d.sort_impl is not None:
-        n_sort = sum(
-            ev[0] for ev in (p.get("sort_ev") or {}).values()
-        )
-        lines.append(
-            f"sort_impl tuned: {d.sort_impl} "
-            f"(was the static default, n={n_sort})"
         )
     if d.codec_impl is not None:
         n_codec = sum(

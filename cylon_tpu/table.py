@@ -49,7 +49,6 @@ from .ops import gather as _g_pack
 from .ops import quant as _quant
 from .ops import sketch as _sketch
 from .ops import pallas_codec as _codec
-from .ops import radix as _radix
 from .ops import sort as _sort_mod
 from .ops import stats as _st
 from .fault import errors as _fault_errors
@@ -1244,10 +1243,7 @@ class Table:
                     ),
                     allow64=bool(jax.config.jax_enable_x64),
                 )
-        # the radix tag keys the resolved sort impl (+ kill switch +
-        # tuned decision) into the program identity — an impl flip
-        # recompiles exactly once, never aliases (ops/radix.impl_tag)
-        key = ("sort", key_idx, asc, len(flat), m, fuse) + _radix.impl_tag()
+        key = ("sort", key_idx, asc, len(flat), m, fuse)
 
         def build():
             def kern(dp, rep):
@@ -1287,32 +1283,10 @@ class Table:
         if fuse is not None:
             bump("lane_pack.sort_fused",
                  rows=fuse.n_plain - fuse.n_words)
-        t0_prof = _time.perf_counter()
         with span("sort", rows=self._rows_hint()):
-            out = get_kernel(self.ctx, key, build, **_radix.kernel_kwargs())(
+            out = get_kernel(self.ctx, key, build)(
                 (flat, self.counts_dev), ()
             )
-        t1_prof = _time.perf_counter()
-        # sort-impl evidence for the autopilot: the resolved impl's
-        # dispatch wall (exact cost on CPU; dispatch-wall proxy on TPU's
-        # async runtime) + both impls' host-estimated pass counts for
-        # this shape, so a one-sided profile can still walk back through
-        # the per-pass cost model (plan/feedback._sort_impl_proposal).
-        # Pure host arithmetic + contextvars — 0 sync sites; note_sort
-        # no-ops outside plan executions (no active exec record).
-        impl = _radix.resolved_impl()
-        rp, bp = _radix.sort_pass_census(
-            [flat[i] for i in key_idx[m:]], self._shard_cap, bool(m),
-            fuse, impl=impl if impl != "bitonic" else "radix",
-        )
-        if impl != "bitonic" and rp <= 0:
-            impl = "bitonic"  # lane stack declined radix at trace time
-        passes, alt = (rp, bp) if impl != "bitonic" else (bp, rp)
-        _prof.record_sort(
-            impl, passes, self._rows_hint() or self._shard_cap,
-            self.ctx.world_size, t0_prof,
-        )
-        _obsstore.note_sort(impl, t1_prof - t0_prof, passes, alt)
         # a sort permutes rows within each shard: counts are unchanged, so
         # a deferred count lane passes straight through (no forced sync)
         res = self._rebuild_cols(
@@ -2270,7 +2244,7 @@ class Table:
 
         cap_out = a.shard_cap + b.shard_cap if is_union else a.shard_cap
         key = ("setop_union" if is_union else "setop2", nc, cap_out,
-               sorted_fast) + _radix.impl_tag()
+               sorted_fast)
         if sorted_fast:
             bump("ordering.setop_sorted_probe")
 
@@ -2299,10 +2273,7 @@ class Table:
 
         rep = () if is_union else (jnp.asarray(op == "intersect"),)
         with span(f"setop.{op}", rows=self._rows_hint()):
-            out, nout = get_kernel(
-                self.ctx, key + ("emit",), build_emit,
-                **_radix.kernel_kwargs(),
-            )(
+            out, nout = get_kernel(self.ctx, key + ("emit",), build_emit)(
                 (lflat, rflat, a.counts_dev, b.counts_dev), rep
             )
         # deferred counts: fetch + overshoot compaction happen at result
@@ -2389,7 +2360,7 @@ class Table:
         if sorted_fast:
             bump("ordering.unique_run_detect")
         key = ("unique", key_idx, keep, len(flat), cap_out, order_idx,
-               sorted_fast) + _radix.impl_tag()
+               sorted_fast)
 
         def build_emit():
             def kern(dp, rep):
@@ -2416,10 +2387,7 @@ class Table:
             return kern
 
         with span("unique", rows=self._rows_hint()):
-            out, nout = get_kernel(
-                self.ctx, key + ("emit",), build_emit,
-                **_radix.kernel_kwargs(),
-            )(
+            out, nout = get_kernel(self.ctx, key + ("emit",), build_emit)(
                 (flat, self.counts_dev), ()
             )
         # deferred counts: fetch + overshoot compaction at materialization
@@ -2545,7 +2513,7 @@ class Table:
         key = (
             "groupby", key_idx, val_idx, ops_t, ddof, quantile, len(flat),
             _sorted, cap_out, gb_fuse,
-        ) + _radix.impl_tag()
+        )
         # a value column rides the factorize sort once, whatever ops read it
         ride_idx = tuple(dict.fromkeys(val_idx))
 
@@ -2565,10 +2533,9 @@ class Table:
             return kern
 
         with span("groupby.emit", rows=self._rows_hint()):
-            out, nout = get_kernel(
-                self.ctx, key + ("emit",), build_emit,
-                **_radix.kernel_kwargs(),
-            )((flat, self.counts_dev), ())
+            out, nout = get_kernel(self.ctx, key + ("emit",), build_emit)(
+                (flat, self.counts_dev), ()
+            )
         return self._groupby_result(
             key_names, specs, out, nout, cap_out, out_canonical
         )
@@ -3525,7 +3492,7 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
         quant_sig, ("topo", tuple(topo_cfg) if topo_cfg else None),
     ) + (
         ("semi", spec.probe_row, spec.use_range) if semi else ()
-    ) + _radix.impl_tag() + _codec.impl_tag()
+    ) + _codec.impl_tag()
     has_lanes = any(
         tag is not None or has_valid for tag, _nl, has_valid in plan_sig
     )

@@ -173,13 +173,6 @@ EXPAND_GATHER = EnvKnob(
     keyed_via="ops.join.impl_tag appended to every join-family cache key",
     note="in-kernel gather flavor of the Pallas windowed expand",
 )
-SORT_IMPL = EnvKnob(
-    "CYLON_TPU_SORT_IMPL", "auto", kind="impl",
-    keyed_via="ops.radix.impl_tag appended to every sort-family cache "
-    "key; plan fingerprints carry ops.radix.gate_state",
-    note="sort engine: 'auto' (= 'bitonic', the chip's native sort), "
-    "'bitonic', 'radix', 'radix_pallas'",
-)
 CODEC_IMPL = EnvKnob(
     "CYLON_TPU_CODEC_IMPL", "auto", kind="impl",
     keyed_via="ops.pallas_codec.impl_tag appended to every shuffle-family "

@@ -1,7 +1,6 @@
 """Fused Pallas shuffle codec tests (ISSUE 20, ops/pallas_codec.py).
 
-Four layers, mirroring the sort engine's test discipline
-(test_radix_sort.py) and the quant tier's differential layout
+Four layers, mirroring the quant tier's differential layout
 (test_quant_wire.py):
 
   1. kernel unit differentials — fused_pack_dest (hash mode AND

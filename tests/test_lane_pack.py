@@ -438,16 +438,9 @@ def test_q3_sort_gb_reduction(world, devices):
 
         return run
 
-    # pin the BITONIC engine for both runs: packing's sort-byte gain is
-    # a sweep-count claim (fewer sort words -> fewer L(L+1)/2 networks),
-    # which only the comparison sort exhibits — the radix engine prices
-    # passes by total significant bits, which packing leaves unchanged
-    # (its gate lives in tools/sort_smoke.py instead)
-    from cylon_tpu.ops import radix as rx
-    with rx.disabled():
-        tp = _sort_totals(q3("packed"))
-        with stmod.disabled():
-            tu = _sort_totals(q3("oracle"))
+    tp = _sort_totals(q3("packed"))
+    with stmod.disabled():
+        tu = _sort_totals(q3("oracle"))
     assert tp.sort_count < tu.sort_count
     assert tp.collective_bytes <= tu.collective_bytes
     reduction = 1.0 - tp.sort_pass_bytes / tu.sort_pass_bytes

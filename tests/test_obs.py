@@ -570,8 +570,7 @@ def test_traceview_critical_report(ctx8, rng, profiled, tmp_path, capsys):
     The uniform leg runs under the codec kill switch: "local stages
     dominate a uniform shuffle" is an XLA-codec stage-algebra claim
     (3-pass pack), and the fused pallas codec exists precisely to shrink
-    those stages below the collective — same pin discipline as
-    test_lane_pack's bitonic-era gate under CYLON_TPU_NO_RADIX."""
+    those stages below the collective."""
     import tools.traceview as tv
     from cylon_tpu.ops import pallas_codec as _pc
 

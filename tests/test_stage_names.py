@@ -203,8 +203,8 @@ def test_stale_compiled_text_is_reported(how, monkeypatch):
 
 
 @pytest.mark.parametrize("path,stage,engine_in", [
-    ("jit(join_spec)/join.probe/sort_engine/jit(radix_pass)/gather", "join.probe", True),
-    ("jit(sort)/shard_map/sort.perm/sort_engine/jit(radix_pass)/scatter", "sort.perm", True),
+    ("jit(join_probe)/join.right_sort/sort_engine/jit(argsort)/iota", "join.right_sort", True),
+    ("jit(shuffle_pack)/shard_map/shuffle.pack/sort_engine/jit(argsort)/sort", "shuffle.pack", True),
     ("jit(join_spec)/join.emit/gather", "join.emit", False),
     ("jit(join_spec)/join.right_sort/sort_engine/sort", "join.right_sort", True),
     ("jit(f)/sort_engine/sort", "sort_engine", True),

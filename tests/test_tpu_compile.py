@@ -9,9 +9,9 @@ the bring-up established:
 - every program the default path is made of lowers for v5e, on one chip and
   on the 2x2 mesh;
 - the fused compact kernel lowers as a Mosaic kernel; the fused pack kernel
-  and the Pallas radix pass are refused, with the compiler's own message —
-  which is why ``auto`` resolves both codec stages to XLA
-  (ops/pallas_codec.py) and why a forced kernel raises on a TPU mesh.
+  is refused, with the compiler's own message — which is why ``auto``
+  resolves both codec stages to XLA (ops/pallas_codec.py) and why a forced
+  kernel raises on a TPU mesh.
 
 This is the only file that describes a TPU topology, and it does so only
 inside the module-scoped ``topo`` fixture: one process at a time may load
@@ -32,9 +32,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceShardin
 from cylon_tpu.ops import groupby as _g
 from cylon_tpu.ops import join as _j
 from cylon_tpu.ops import pallas_codec as _codec
-from cylon_tpu.ops import pallas_radix as _pr
 from cylon_tpu.ops import partition as _p
-from cylon_tpu.ops import radix as _radix
 from cylon_tpu.ops import sort as _sort
 from cylon_tpu.parallel import shuffle as _sh
 from cylon_tpu.parallel.pipeline import make_distributed_join_step
@@ -133,22 +131,12 @@ def test_xla_pack_chain_compiles_for_tpu(one_chip):
 
 
 # ----------------------------------------------------------------------
-# (c) the two sort engines
+# (c) the sort engine
 # ----------------------------------------------------------------------
-
-def test_radix_pass_compiles_for_tpu(one_chip):
-    fn = partial(_radix.radix_pass, shift=0, bits=_radix.RADIX_BITS)
-    _compile(
-        fn,
-        _spec((ROWS,), jnp.uint32, one_chip),
-        _spec((ROWS,), jnp.int32, one_chip),
-    )
-
 
 def test_bitonic_lexsort_compiles_for_tpu(one_chip):
     def lexsort(key):
-        with _radix.disabled():  # the chained lax.sort path
-            return _sort.lexsort_indices([key], ROWS)
+        return _sort.lexsort_indices([key], ROWS)
 
     _compile(lexsort, _spec((ROWS,), jnp.int32, one_chip))
 
@@ -386,17 +374,4 @@ def test_forced_pallas_pack_raises_for_tpu(one_chip):
             pack,
             _spec((ROWS,), jnp.int32, one_chip),
             _spec((), jnp.int32, one_chip),
-        )
-
-
-def test_forced_pallas_radix_pass_raises_for_tpu(one_chip):
-    fn = partial(
-        _pr.radix_pass_pallas, shift=0, bits=_radix.PALLAS_RADIX_BITS,
-        interpret=False,
-    )
-    with pytest.raises(Exception, match="block shape"):
-        _compile(
-            fn,
-            _spec((ROWS,), jnp.uint32, one_chip),
-            _spec((ROWS,), jnp.int32, one_chip),
         )

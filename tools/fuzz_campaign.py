@@ -819,99 +819,6 @@ def packing_round_once(seed) -> bool:
     return ok
 
 
-def _radix_off(fn):
-    """Run ``fn`` on the bitonic network (width-adaptive radix engine
-    kill-switched) — the CYLON_TPU_NO_RADIX=1 differential oracle. The
-    stable lexsort permutation is unique, so every radix-sorted op must
-    match this oracle in EMITTED order, bit for bit."""
-    from cylon_tpu.ops.radix import disabled
-
-    with disabled():
-        return fn()
-
-
-def radix_round_once(seed) -> bool:
-    """Radix sort-engine oracle round: random key bit-widths, dtype mixes
-    (narrow/wide ints, bool, dict strings, floats — the digit planner
-    must DECLINE float lanes and fall back bitonic), null densities,
-    ascending/descending mixes, world sizes and a randomly forced impl
-    tier (radix / radix_pallas; the default is the oracle's own native
-    sort and would compare a thing with itself); multi-key sort compared in
-    emitted order, unique / distributed groupby / join row-checked, all
-    against the CYLON_TPU_NO_RADIX=1 bitonic oracle on the same inputs."""
-    rng = np.random.default_rng(seed)
-    n = int(rng.integers(4, MAX_N))
-    world = int(rng.choice([1, 2, 4, 8]))
-    null_p = float(rng.choice([0.0, 0.1, 0.3]))
-    nkeys = int(rng.integers(1, 4))
-    kinds = ["i8", "i16", "i32", "i64", "bool", "str", "f32", "f64"]
-    specs = [
-        (str(rng.choice(kinds)), int(rng.integers(1, 21)))
-        for _ in range(nkeys)
-    ]
-    asc = [bool(rng.integers(0, 2)) for _ in range(nkeys)]
-    impl = str(rng.choice(["radix", "radix_pallas"]))
-    params = dict(seed=seed, profile="radix", n=n, world=world,
-                  null_p=null_p, specs=specs, asc=asc, impl=impl)
-    ctx = ctx_for(world)
-    knames = [f"k{i}" for i in range(nkeys)]
-    data = {kn: _rand_key_col(rng, n, sp, null_p)
-            for kn, sp in zip(knames, specs)}
-    data["v"] = rng.normal(size=n).astype(np.float32)
-    df = pd.DataFrame(data)
-    rdf = pd.DataFrame({
-        **{kn: _rand_key_col(rng, max(n // 2, 1), sp, null_p)
-           for kn, sp in zip(knames, specs)},
-        "w": rng.normal(size=max(n // 2, 1)).astype(np.float32),
-    })
-    ok = True
-    prev = os.environ.get("CYLON_TPU_SORT_IMPL")
-    os.environ["CYLON_TPU_SORT_IMPL"] = impl
-    try:
-        t = ct.Table.from_pandas(ctx, df)
-        got = t.sort(knames, ascending=asc).to_pandas()
-        want = _radix_off(
-            lambda: ct.Table.from_pandas(ctx, df)
-            .sort(knames, ascending=asc).to_pandas()
-        )
-        # exact emitted-order comparison: the stable radix permutation
-        # must equal the bitonic one row-for-row (check() re-sorts and
-        # would mask a stability bug)
-        g = got.astype(str).reset_index(drop=True)
-        w = want.astype(str).reset_index(drop=True)
-        if len(g) != len(w) or not g.equals(w):
-            print(f"MISMATCH radix/sort_order params={params}", flush=True)
-            ok = False
-
-        got = t.unique(knames).to_pandas()
-        want = _radix_off(
-            lambda: ct.Table.from_pandas(ctx, df).unique(knames).to_pandas()
-        )
-        ok &= check(got, want, "radix/unique", params)
-
-        got = t.distributed_groupby(knames, {"v": "sum"}).to_pandas()
-        want = _radix_off(
-            lambda: ct.Table.from_pandas(ctx, df)
-            .distributed_groupby(knames, {"v": "sum"}).to_pandas()
-        )
-        ok &= check(got, want, "radix/groupby", params)
-
-        rt = ct.Table.from_pandas(ctx, rdf)
-        got = t.distributed_join(rt, on=knames, how="inner").to_pandas()
-        want = _radix_off(
-            lambda: ct.Table.from_pandas(ctx, df).distributed_join(
-                ct.Table.from_pandas(ctx, rdf), on=knames, how="inner"
-            ).to_pandas()
-        )
-        ok &= check(got, want, "radix/join", params)
-    finally:
-        if prev is None:
-            os.environ.pop("CYLON_TPU_SORT_IMPL", None)
-        else:
-            os.environ["CYLON_TPU_SORT_IMPL"] = prev
-    return ok
-
-
 def _codec_off(fn):
     """Run ``fn`` with the fused Pallas shuffle codec kill-switched
     (CYLON_TPU_NO_PALLAS_CODEC=1) — the bit-exact differential oracle:
@@ -1761,7 +1668,7 @@ def main():
                     choices=["default", "skew", "plan", "shuffle",
                              "ordering", "semi", "packing", "serve",
                              "spill", "autotune", "quant", "chaos",
-                             "stream", "topo", "radix", "codec"],
+                             "stream", "topo", "codec"],
                     default="default",
                     help="'skew': adversarial hot-key rounds (one key ~50%% "
                          "of rows, world {4,8}, undersized fused capacities); "
@@ -1803,12 +1710,7 @@ def main():
                          "mix, nulls, skew, K, ISSUE 17) — shuffle + "
                          "distributed join vs the CYLON_TPU_NO_TOPO "
                          "flat-exchange oracle, exact row equality; "
-                         "'radix': width-adaptive radix sort-engine "
-                         "rounds (random key widths/dtypes/nulls/"
-                         "asc mix/world + forced impl tier) — sort in "
-                         "exact emitted order, unique/groupby/join by "
-                         "rows, vs the CYLON_TPU_NO_RADIX=1 bitonic "
-                         "oracle; 'codec': fused Pallas shuffle-codec "
+                         "'codec': fused Pallas shuffle-codec "
                          "rounds (random dtype/width/null mixes, pow2 "
                          "worlds, quant tolerance, optional 2-D topo "
                          "mesh, forced impl) — join/groupby by rows, "
@@ -1829,7 +1731,6 @@ def main():
           "chaos": chaos_round_once,
           "stream": stream_round_once,
           "topo": topo_round_once,
-          "radix": radix_round_once,
           "codec": codec_round_once}.get(args.profile, round_once)
     t_end = time.time() + args.minutes * 60
     seed = args.seed0

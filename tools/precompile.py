@@ -55,13 +55,6 @@ def main():
     ap.add_argument("--topo", type=str, default="",
                     help="OxI 2-D mesh (e.g. 4x2): warm the two-hop "
                          "shuffle kernels on a world of O*I devices")
-    ap.add_argument("--sort-impl", type=str, default="",
-                    help="comma list from {bitonic,radix,radix_pallas} or "
-                         "'all': warm the requested ops once per sort "
-                         "engine impl (the impl rides every sort-family "
-                         "cache key, so each impl is a distinct program; "
-                         "an image baked with all three makes a runtime "
-                         "CYLON_TPU_SORT_IMPL flip compile-free)")
     ap.add_argument("--codec-impl", type=str, default="",
                     help="comma list from {xla,pallas} or 'all': warm the "
                          "requested ops once per shuffle-codec impl (the "
@@ -70,29 +63,21 @@ def main():
                          "pre-baked image is compile-free)")
     args = ap.parse_args()
 
-    # literals (not imported from ops.radix / ops.pallas_codec):
-    # cylon_tpu must not import before _force_cpu_mesh has declared the
-    # virtual mesh
-    _SORT_IMPLS = ("bitonic", "radix", "radix_pallas")
+    # a literal (not imported from ops.pallas_codec): cylon_tpu must not
+    # import before _force_cpu_mesh has declared the virtual mesh
     _CODEC_IMPLS = ("xla", "pallas")
-
-    def _impl_list(arg, universe, flag):
-        if not arg:
-            return [None]
-        req = (
-            list(universe) if arg.strip() == "all"
-            else [x.strip() for x in arg.split(",") if x.strip()]
+    codec_impls = [None]
+    if args.codec_impl:
+        codec_impls = (
+            list(_CODEC_IMPLS) if args.codec_impl.strip() == "all"
+            else [x.strip() for x in args.codec_impl.split(",") if x.strip()]
         )
-        bad = [x for x in req if x not in universe]
+        bad = [x for x in codec_impls if x not in _CODEC_IMPLS]
         if bad:
             raise SystemExit(
-                f"{flag}: unknown impl(s) {bad}; choose from "
-                f"{sorted(universe)} or 'all'"
+                f"--codec-impl: unknown impl(s) {bad}; choose from "
+                f"{sorted(_CODEC_IMPLS)} or 'all'"
             )
-        return req
-
-    sort_impls = _impl_list(args.sort_impl, _SORT_IMPLS, "--sort-impl")
-    codec_impls = _impl_list(args.codec_impl, _CODEC_IMPLS, "--codec-impl")
 
     world = 1
     if args.topo:
@@ -143,7 +128,7 @@ def main():
         left = make(n, "v")
         right = make(n, "w")
 
-        def timed(name, fn, impl=None):
+        def t(name, fn):
             t0 = time.perf_counter()
             try:
                 fn()
@@ -153,24 +138,15 @@ def main():
             wall = time.perf_counter() - t0
             line = {"op": name, "cap": cap, "platform": platform,
                     "wall_s": round(wall, 2)}
-            if impl:
-                line["sort_impl"] = impl
             if cimpl:
                 line["codec_impl"] = cimpl
             if err:
                 line["error"] = err
             print(json.dumps(line), flush=True)
 
-        for impl, cimpl in (
-            (s, c) for s in sort_impls for c in codec_impls
-        ):
-            if impl is not None:
-                os.environ["CYLON_TPU_SORT_IMPL"] = impl
+        for cimpl in codec_impls:
             if cimpl is not None:
                 os.environ["CYLON_TPU_CODEC_IMPL"] = cimpl
-
-            def t(name, fn):
-                timed(name, fn, impl)
 
             if "join" in ops:
                 t("join_inner", lambda: left.join(right, on="k"))
@@ -196,8 +172,6 @@ def main():
                     "groupby_sum",
                     lambda: left.distributed_groupby("k", {"v": "sum"}),
                 )
-        if args.sort_impl:
-            os.environ.pop("CYLON_TPU_SORT_IMPL", None)
         if args.codec_impl:
             os.environ.pop("CYLON_TPU_CODEC_IMPL", None)
         # drop per-bucket jit caches so memory stays bounded across buckets
