@@ -294,6 +294,12 @@ def reset_latency() -> None:
 STABLE_METRICS: Dict[str, Tuple[str, str]] = {
     "host_sync": ("counter", "device->host count fetches (the sync census)"),
     "sort": ("span", "local sort dispatch"),
+    "sort.ride_lanes": (
+        "counter", "payload rides of Table.sort and the speculative join's "
+        "right sort, counted at dispatch (rows= 32-bit lanes that rode)"),
+    "sort.ride_batches": (
+        "counter", "the same rides (rows= sorts that carried the lanes: 1 "
+        "when they fit one, more past ops/sort.RIDE_LANES)"),
     "unique": ("span", "local unique dispatch"),
     "stats.measure": ("span", "on-demand column range-stats kernel"),
     "join.": ("span", "join phases: speculative/fused/pallas_pk/sum_pushdown"),

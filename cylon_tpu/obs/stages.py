@@ -34,7 +34,6 @@ JOIN_PROBE = "join.probe"
 JOIN_EMIT = "join.emit"
 SORT_KEYS = "sort.keys"
 SORT_PERM = "sort.perm"
-SORT_GATHER = "sort.gather"
 SORT_ENGINE = "sort_engine"
 SHUFFLE_COUNT = "shuffle.count"
 SHUFFLE_PACK = "shuffle.pack"
@@ -48,7 +47,7 @@ EXPR_EVAL = "expr.eval"
 
 VOCABULARY = (
     JOIN_KEY_IDS, JOIN_RIGHT_SORT, JOIN_PROBE, JOIN_EMIT,
-    SORT_KEYS, SORT_PERM, SORT_GATHER, SORT_ENGINE,
+    SORT_KEYS, SORT_PERM, SORT_ENGINE,
     SHUFFLE_COUNT, SHUFFLE_PACK, SHUFFLE_ALL_TO_ALL, SHUFFLE_COMPACT,
     SEMI_SKETCH, GROUPBY_SEGMENT_SUM, GROUPBY_KEY_IDS, GROUPBY_DENSE_AGG,
     EXPR_EVAL,

@@ -37,8 +37,9 @@ import numpy as np
 from ..obs import stages as _stages
 from .factorize import factorize_runs
 from .sort import (
-    KeyCol, flag_compact, fused_decodable, fused_key_decode, lexsort_indices,
-    orderable_key, run_reduce, scan_identity, wide_float, wide_int,
+    KeyCol, flag_compact, flatten_cols, fused_decodable, fused_key_decode,
+    lexsort_indices, orderable_key, run_reduce, scan_identity, unflatten_cols,
+    wide_float, wide_int,
 )
 from .stats import decode_enc
 
@@ -67,17 +68,6 @@ def _masked(values: jax.Array, valid: Optional[jax.Array], fill) -> jax.Array:
     if valid is None:
         return values
     return jnp.where(valid, values, jnp.asarray(fill, values.dtype))
-
-
-def _flatten(cols: Sequence[KeyCol]) -> list:
-    """The arrays of ``cols``, each column's data then its validity."""
-    return [a for d, v in cols for a in ((d,) if v is None else (d, v))]
-
-
-def _unflatten(cols: Sequence[KeyCol], flat: Sequence[jax.Array]) -> list:
-    """:func:`_flatten` undone: ``flat`` in the column structure of ``cols``."""
-    it = iter(flat)
-    return [(next(it), None if v is None else next(it)) for _d, v in cols]
 
 
 def _fit(x: jax.Array, cap_out: int) -> jax.Array:
@@ -131,14 +121,14 @@ def groupby_aggregate(
     riders = [c for c, w in zip(key_cols, in_words) if not w]
     with jax.named_scope(_stages.GROUPBY_KEY_IDS):
         start, run_end, flat, words = factorize_runs(
-            key_cols, n, cap, _flatten(riders + val_cols),
+            key_cols, n, cap, flatten_cols(riders + val_cols),
             fuse=fuse, presorted=presorted,
         )
     words = list(words) if any(in_words) else []
-    cols = _unflatten(riders + val_cols, flat)
+    cols = unflatten_cols(riders + val_cols, flat)
     with jax.named_scope(_stages.GROUPBY_SEGMENT_SUM):
         carried, aggs, num_groups = _aggregate_runs(
-            start, run_end, n, words + _flatten(cols[: len(riders)]),
+            start, run_end, n, words + flatten_cols(cols[: len(riders)]),
             cols[len(riders):], ops, cap_out, ddof, quantile,
         )
         gmask = jnp.arange(cap_out, dtype=jnp.int32) < num_groups
@@ -146,7 +136,7 @@ def groupby_aggregate(
             fuse, carried[: len(words)], key_cols,
             jnp.arange(cap, dtype=jnp.int32) < n,
         ) if words else []
-        rode = iter(_unflatten(riders, carried[len(words):]))
+        rode = iter(unflatten_cols(riders, carried[len(words):]))
         keys = [decoded[i] if w else next(rode) for i, w in enumerate(in_words)]
         keys = [(d, gmask if v is None else gmask & v) for d, v in keys]
     return keys, aggs, num_groups

@@ -742,6 +742,20 @@ def _emit_inner_left_windowed(
         return list(out_l) + list(out_r), total
 
 
+def ride_right(r_ids: jax.Array, r_cols: Sequence[KeyCol]) -> list:
+    """The right table in the order of its canonical key ids: one stable
+    sort keyed by ``r_ids`` with every column (data and validity lanes, a
+    64-bit one as its two 32-bit halves) riding it as a payload, in
+    batches past ``ops.sort.RIDE_LANES`` lanes (:func:`ops.sort.ride_sort`).
+    No order is carried and nothing is gathered by one."""
+    from .sort import flatten_cols, ride_sort, sentinel_compact, unflatten_cols
+
+    _ids, rode = ride_sort(
+        lambda pays: (None, sentinel_compact(r_ids, pays)), flatten_cols(r_cols)
+    )
+    return unflatten_cols(r_cols, rode)
+
+
 def spec_join(
     l_key_cols: Sequence[KeyCol],
     r_key_cols: Sequence[KeyCol],
@@ -766,7 +780,12 @@ def spec_join(
     multi-operand ``lax.sort`` keyed by the canonical right ids yields the
     key-sorted right table directly, replacing the separate
     ``argsort(r_ids)`` + packed permute gather of :func:`emit_gather` (and
-    mask-free columns stay mask-free with no lane codec at all).
+    mask-free columns stay mask-free with no lane codec at all). Every
+    right column rides, a 64-bit one as its two 32-bit halves, and no order
+    is carried: at 4,194,304 rows the sort of the ids with an int64 and a
+    float64 riding takes 15.6 ms on a v5e, where the argsort and their
+    gathers by its order took 83.7 (PERF.md section 6, PR 30); past ``ops.sort.RIDE_LANES`` lanes the columns ride in
+    batches of the same stable sort (:func:`ops.sort.ride_sort`).
     RIGHT/FULL_OUTER composes the probe + emit pieces unchanged.
 
     ``r_presorted=True`` (right rows provably key-ordered already — ordering
@@ -789,38 +808,9 @@ def spec_join(
         l_key_cols, r_key_cols, nl, nr, cap_l, cap_r, fuse=key_fuse
     )
     if how in (INNER, LEFT):
-        # <=32-bit right columns ride the key sort as payload operands; any
-        # 64-bit columns are gathered by the carried order through the int32
-        # lane codec (ops/sort split/merge_ride_cols — the TPU X64 rewriter
-        # has no audited lowering for 64-bit variadic-sort operands)
-        from .gather import pack_gather
-        from .sort import merge_ride_cols, split_ride_cols
-
         with jax.named_scope(_stages.JOIN_RIGHT_SORT):
-            if r_presorted:
-                # sorted-run reuse: the rows ARE the key-sorted payload
-                r_sorted = list(r_cols)
-            else:
-                ride, payloads, heavy = split_ride_cols(r_cols)
-                if heavy:
-                    # carry the order only when something needs gathering by it
-                    iota = jnp.arange(cap_r, dtype=jnp.int32)
-                    with jax.named_scope(_stages.SORT_ENGINE):
-                        sorted_ops = jax.lax.sort(
-                            tuple([r_ids] + payloads + [iota]),
-                            num_keys=1, is_stable=True,
-                        )
-                    spays = list(sorted_ops[1:-1])
-                    heavy_sorted = pack_gather(heavy, sorted_ops[-1])[0]
-                else:
-                    with jax.named_scope(_stages.SORT_ENGINE):
-                        sorted_ops = jax.lax.sort(
-                            tuple([r_ids] + payloads),
-                            num_keys=1, is_stable=True,
-                        )
-                    spays = list(sorted_ops[1:])
-                    heavy_sorted = []
-                r_sorted = merge_ride_cols(r_cols, ride, spays, heavy_sorted)
+            # sorted-run reuse: presorted rows ARE the key-sorted payload
+            r_sorted = list(r_cols) if r_presorted else ride_right(r_ids, r_cols)
         if emit_key_order:
             # probe + emit in one sorted-space pass, no compaction sort
             out_cols, total, shadow = _key_order_emit(

@@ -149,6 +149,8 @@ def lexsort_with_payload(
 
     Returns (sorted_lanes | None, sorted_payloads).
     """
+    if not keep_lanes and not payloads:
+        return None, []  # nothing is wanted back: no pass
     with jax.named_scope(_stages.SORT_ENGINE):
         k = len(lanes)
         if not keep_lanes:
@@ -582,6 +584,33 @@ def _from_carrier(x: jax.Array, dtype) -> jax.Array:
     return jax.lax.bitcast_convert_type(bits, dtype)
 
 
+def _ride_stacks(dtypes: Sequence) -> dict:
+    """How payloads of ``dtypes`` ride in batches: ``{carrier dtype:
+    (their positions, payloads a batch)}``, a batch :data:`RIDE_LANES`
+    lanes wide at most."""
+    stacks: dict = {}
+    for i, dtype in enumerate(dtypes):
+        stacks.setdefault(_carrier(dtype), []).append(i)
+    return {
+        carrier: (at, min(RIDE_LANES // (carrier.itemsize // 4), len(at)))
+        for carrier, at in stacks.items()
+    }
+
+
+def ride_census(dtypes: Sequence) -> Tuple[int, int]:
+    """``(lanes, batches)`` of a :func:`ride_sort` over payloads of
+    ``dtypes``: the 32-bit lanes that ride, and the sorts that carry them
+    (1 when they fit one sort; the sort of the keys alone is not one).
+    What the host counts at dispatch (``sort.ride_lanes``,
+    ``sort.ride_batches``), from the rule the ride itself follows."""
+    lanes = sum(max(1, np.dtype(d).itemsize // 4) for d in dtypes)
+    if lanes <= RIDE_LANES:
+        return lanes, 1
+    return lanes, sum(
+        -(-len(at) // per) for at, per in _ride_stacks(dtypes).values()
+    )
+
+
 def ride_sort(sort_fn, payloads: Sequence[jax.Array]):
     """``sort_fn(payloads) -> (sorted keys, sorted payloads)`` for any
     number of payloads: up to :data:`RIDE_LANES` lanes ride the one sort,
@@ -592,16 +621,12 @@ def ride_sort(sort_fn, payloads: Sequence[jax.Array]):
     of columns, and every batch is permuted alike: the sort is stable, or
     its keys are distinct where the rows matter."""
     payloads = list(payloads)
-    lanes = sum(max(1, np.dtype(p.dtype).itemsize // 4) for p in payloads)
-    if lanes <= RIDE_LANES:
+    dtypes = [p.dtype for p in payloads]
+    if ride_census(dtypes)[0] <= RIDE_LANES:
         return sort_fn(payloads)
     keys_out, _none = sort_fn([])
-    stacks: dict = {}
-    for i, p in enumerate(payloads):
-        stacks.setdefault(_carrier(p.dtype), []).append(i)
     out: list = [None] * len(payloads)
-    for carrier, at in stacks.items():
-        per = min(RIDE_LANES // (carrier.itemsize // 4), len(at))
+    for at, per in _ride_stacks(dtypes).values():
         arrs = [_to_carrier(payloads[i]) for i in at]
         arrs += [arrs[0]] * (-len(arrs) % per)
         stacked = jnp.stack(arrs).reshape((-1, per) + arrs[0].shape)
@@ -646,53 +671,17 @@ def sorted_runs_payload(
     return lane_runs_differ(slanes), pays, slanes
 
 
-def split_ride_cols(
-    cols: Sequence[KeyCol],
-) -> Tuple[list, list, list]:
-    """Partition columns for the payload-riding sort pattern.
-
-    <=32-bit columns (data + validity lanes) RIDE a variadic sort as payload
-    operands; 64-bit columns can't (the TPU X64 rewriter has no audited
-    lowering for 64-bit variadic-sort operands) and are gathered by the
-    order instead. Returns (ride mask, flattened payloads, heavy columns).
-    """
-    ride = [np.dtype(d.dtype).itemsize <= 4 for d, _ in cols]
-    payloads: list = []
-    for (d, v), r in zip(cols, ride):
-        if r:
-            payloads.append(d)
-            if v is not None:
-                payloads.append(v)
-    heavy = [c for c, r in zip(cols, ride) if not r]
-    return ride, payloads, heavy
+def flatten_cols(cols: Sequence[KeyCol]) -> list:
+    """The arrays of ``cols``, each column's data then its validity: what
+    rides a sort as its payloads."""
+    return [a for d, v in cols for a in ((d,) if v is None else (d, v))]
 
 
-def merge_ride_cols(
-    cols: Sequence[KeyCol],
-    ride: Sequence[bool],
-    spays: Sequence[jax.Array],
-    heavy_sorted: Sequence[KeyCol],
-) -> list:
-    """Reassemble :func:`split_ride_cols` output after the sort: ridden
-    columns from the sorted payloads (walked in flattening order), heavy
-    columns from their gathered counterparts. Orders are permutations here,
-    so mask-free columns stay mask-free."""
-    out: list = []
-    pi = hi = 0
-    for (d, v), r in zip(cols, ride):
-        if r:
-            sd = spays[pi]
-            pi += 1
-            sv = None
-            if v is not None:
-                sv = spays[pi]
-                pi += 1
-            out.append((sd, sv))
-        else:
-            gd, gv = heavy_sorted[hi]
-            hi += 1
-            out.append((gd, None if v is None else gv))
-    return out
+def unflatten_cols(cols: Sequence[KeyCol], flat: Sequence[jax.Array]) -> list:
+    """:func:`flatten_cols` undone: ``flat`` in the column structure of
+    ``cols`` (a sort permutes rows, so mask-free columns stay mask-free)."""
+    it = iter(flat)
+    return [(next(it), None if v is None else next(it)) for _d, v in cols]
 
 
 def kv_sort(keys: jax.Array, pay: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -743,9 +732,13 @@ def lexsort_rows(
     """Stable argsort of rows by multiple key columns.
 
     Returns a permutation [cap] with live rows ordered first, then null-key
-    rows (per-column null ordering), then padding.
+    rows (per-column null ordering), then padding: the row positions, as
+    the one payload of :func:`lexsort_rows_payload`.
     """
-    return lexsort_rows_payload(key_cols, n, cap, [], ascending, nulls_last)[0]
+    iota = jnp.arange(cap, dtype=jnp.int32)
+    return lexsort_rows_payload(
+        key_cols, n, cap, [iota], ascending, nulls_last
+    )[0]
 
 
 def lexsort_rows_payload(
@@ -757,13 +750,23 @@ def lexsort_rows_payload(
     nulls_last: bool = True,
     prefix_lane: Optional[jax.Array] = None,
     fuse: Optional["FusePlan"] = None,
-) -> Tuple[jax.Array, list]:
-    """:func:`lexsort_rows` with ``payloads`` riding the sort passes.
+) -> list:
+    """``payloads`` in the stable order of the rows by multiple key columns
+    (live rows first, then null-key rows, then padding): they ride the sort
+    passes, and no permutation is carried beside them.
 
-    Returns (order [cap] permutation, sorted_payloads). Carrying a column as
-    a payload operand costs ~one lane of memory traffic per pass; a separate
-    row gather by ``order`` costs a full random gather — on TPU the payload
-    route wins whenever the column fits a sort operand (<= 32-bit).
+    Every fixed-width payload rides, a 64-bit one as its two 32-bit halves
+    (what the TPU's X64 rewriter makes of a sort operand), bit for bit. On
+    a v5e at 4,194,304 rows a 64-bit column adds 5-6 ms to a pass (the
+    fused word with an int64 and a float64 sorts in 17.0 ms, with an iota
+    alone in 5.8), where its gather by a carried order cost 66 ms (a
+    float64) or 18 (an int64, packed), and the order a lane of its own
+    (PERF.md section 6, PR 28 and PR 30): riding wins up to about five
+    unfused passes for an int64 and twelve for a float64, which takes four
+    float64 sort keys and more; one rule, no dispatch. Past
+    :data:`RIDE_LANES` lanes the payloads ride in batches
+    (:func:`ride_sort`) of the whole chained sort, as in
+    :func:`sorted_runs_payload`.
 
     ``prefix_lane``: optional lane sorted just below the padding class (more
     significant than every key) — the sorted-run-reuse hook: a caller whose
@@ -774,10 +777,10 @@ def lexsort_rows_payload(
     ``fuse``: a stats-driven :class:`FusePlan` over exactly
     (pad_bits=2, prefix, key_cols in order) — the whole lane stack
     bit-packs into ``fuse.n_words`` physical sort words, so an N-lane
-    chained lexsort runs as n_words passes. The resulting permutation is
+    chained lexsort runs as n_words passes. The resulting order is
     identical on live rows (null rows still order by their masked payload
     — the stats measured those values too); only the don't-care padding
-    permutation may differ.
+    order may differ.
     """
     if ascending is None:
         ascending = [True] * len(key_cols)
@@ -804,12 +807,11 @@ def lexsort_rows_payload(
         if prefix_lane is not None:
             lanes.append(prefix_lane)
         lanes.append(pad)  # most significant: padding always last
-    iota = jnp.arange(cap, dtype=jnp.int32)
     with jax.named_scope(_stages.SORT_PERM):
-        _, pays = lexsort_with_payload(
-            lanes, list(payloads) + [iota], keep_lanes=False
-        )
-    return pays[-1], pays[:-1]
+        return ride_sort(
+            lambda pays: lexsort_with_payload(lanes, pays, keep_lanes=False),
+            payloads,
+        )[1]
 
 
 def prefix_run_lane(

@@ -471,9 +471,9 @@ def make_join_groupby_step(
             and agg_is_left
             and jnp.issubdtype(agg_dtype, jnp.floating)
             and np.dtype(agg_dtype).itemsize <= 4
-            # 64-bit ride lanes have no audited TPU variadic-sort lowering
-            # (ops/sort.split_ride_cols rationale) — f64 takes the generic
-            # path
+            # f64 takes the generic path: a gate on a rule PR 30 retired
+            # (a 64-bit lane does ride a TPU sort), kept with the
+            # planner's twin of it until ROADMAP D15 lifts both
         ):
             lk = [lt.cols[i] for i in l_key_idx]
             rk = [rt.cols[i] for i in r_key_idx]

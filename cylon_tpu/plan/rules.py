@@ -247,8 +247,9 @@ def _fuse_join_groupby(node: Node, fired: List[str]) -> Node:
     dt = np.dtype(join.children[0].dtype_of(val_src)[1])
     if dt.kind != "f" or dt.itemsize > 4:
         # the pushdown accumulates in the value dtype; ints need the wide
-        # accumulator of the generic groupby, and 64-bit ride lanes have no
-        # audited TPU variadic-sort lowering (ops/sort.split_ride_cols)
+        # accumulator of the generic groupby. The 32-bit gate rests on a
+        # rule PR 30 retired (a 64-bit lane does ride a TPU sort, as its
+        # two halves): lifting it changes plans, ROADMAP D15
         return node
     # group keys must be exactly the join keys, each pair once (either
     # side's name: inner-join key values agree rowwise)

@@ -82,6 +82,18 @@ def _speculative_join() -> bool:
     return _eg.EXACT_JOIN.get() != "1"
 
 
+def _bump_ride(cols: Sequence[KeyCol]) -> None:
+    """Count, at dispatch, what rides the sort that orders ``cols``
+    (:func:`ops.sort.ride_census`): ``sort.ride_lanes`` (rows= the 32-bit
+    lanes) and ``sort.ride_batches`` (rows= the sorts that carry them; 1
+    when they fit one)."""
+    lanes, batches = _sort_mod.ride_census(
+        [a.dtype for a in _sort_mod.flatten_cols(cols)]
+    )
+    bump("sort.ride_lanes", rows=lanes)
+    bump("sort.ride_batches", rows=batches)
+
+
 def _scalar(x) -> jax.Array:
     """Per-shard [1] arrays carry scalars through shard_map."""
     return x.reshape(1) if hasattr(x, "reshape") else jnp.asarray([x])
@@ -1259,22 +1271,18 @@ class Table:
                         if m
                         else None
                     )
-                # <=32-bit columns RIDE the sort as payload operands (a lane
-                # per pass instead of a random row gather); 64-bit columns
-                # fall back to one packed gather by the order (the int32
-                # lane codec path) — ops/sort split/merge_ride_cols
-                ride, payloads, heavy = _sort_mod.split_ride_cols(cols)
-                order, spays = _sort_mod.lexsort_rows_payload(
-                    keys, n, cap, payloads, ascending=list(asc[m:]),
-                    prefix_lane=prefix_lane, fuse=fuse,
+                # every column (data and validity lanes, 64-bit ones as
+                # their two 32-bit halves) RIDES the sort as a payload, and
+                # no order is carried to gather by: 17 ms where sort and
+                # gathers took 90 (sort-w1; PERF.md section 6, PR 30)
+                return _sort_mod.unflatten_cols(
+                    cols,
+                    _sort_mod.lexsort_rows_payload(
+                        keys, n, cap, _sort_mod.flatten_cols(cols),
+                        ascending=list(asc[m:]), prefix_lane=prefix_lane,
+                        fuse=fuse,
+                    ),
                 )
-                with jax.named_scope(_stages.SORT_GATHER):
-                    heavy_out = (
-                        _g_pack.pack_gather(heavy, order)[0] if heavy else []
-                    )
-                    return _sort_mod.merge_ride_cols(
-                        cols, ride, spays, heavy_out
-                    )
 
             return kern
 
@@ -1283,6 +1291,7 @@ class Table:
         if fuse is not None:
             bump("lane_pack.sort_fused",
                  rows=fuse.n_plain - fuse.n_words)
+        _bump_ride(flat)
         with span("sort", rows=self._rows_hint()):
             out = get_kernel(self.ctx, key, build)(
                 (flat, self.counts_dev), ()
@@ -1558,6 +1567,8 @@ class Table:
         if r_presorted:
             bump("ordering.join_presorted_probe")
         if _speculative_join():
+            if howi in (_j.INNER, _j.LEFT) and not r_presorted:
+                _bump_ride(rflat)  # spec_join's right sort
             # INNER/LEFT/RIGHT: max(cap_l, cap_r) covers every <=1-match-per-
             # key workload at HALF the emit/gather width of cap_l + cap_r;
             # overflow falls back to the exact two-phase path below AND

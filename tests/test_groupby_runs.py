@@ -355,6 +355,36 @@ def test_ride_sort_batches_only_past_its_lanes(rng):
     assert sorts(4 * _sort.RIDE_LANES) == (2, 1)
 
 
+@pytest.mark.parametrize("dtypes,want", [
+    ([np.int64, np.float64], (4, 1)),  # the suite's table fits one sort
+    ([np.float64] * 4, (8, 1)),
+    ([np.float64] * 5, (10, 2)),
+    ([np.int64] + [np.float64] * 5, (12, 3)),
+    ([np.int32] * 8 + [np.bool_], (9, 2)),
+    ([np.float64, np.int64, np.float32, np.int8, np.bool_] * 4, (28, 4)),
+], ids=lambda v: str(len(v)) if isinstance(v, list) else None)
+def test_ride_census_counts_the_sorts_ride_sort_runs(dtypes, want):
+    """``ride_census`` (what the host counts at dispatch) says how many
+    lanes ride and how many sorts carry them: the program ``ride_sort``
+    traces holds that many, the loops' trips summed."""
+    import re
+
+    assert _sort.ride_census(dtypes) == want
+    key = jax.numpy.arange(64, dtype=np.int32)
+
+    def one_sort(ps):
+        out = jax.lax.sort(tuple([key] + list(ps)), num_keys=1)
+        return out[0], list(out[1:])
+
+    pays = [jax.numpy.zeros(64, d) for d in dtypes]
+    text = str(jax.make_jaxpr(lambda ps: _sort.ride_sort(one_sort, ps))(pays))
+    trips = sum(int(n) for n in re.findall(r"length=(\d+)", text))
+    if want[0] <= _sort.RIDE_LANES:
+        assert trips == 0 and text.count(" sort[") == 1
+    else:
+        assert trips == want[1]
+
+
 @pytest.mark.parametrize("nullable", [False, True])
 @pytest.mark.parametrize("presorted", [False, True])
 def test_groupby_of_many_columns_matches_pandas(

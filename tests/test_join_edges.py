@@ -7,6 +7,8 @@ import pandas as pd
 import pytest
 
 import cylon_tpu as ct
+import ride_cases
+from cylon_tpu.utils import tracing
 
 
 def test_int32_max_keys(ctx8):
@@ -264,3 +266,64 @@ def test_join_count_int32_wrap_raises(local_ctx):
     rt = ct.Table.from_pydict(local_ctx, {"k": k})
     with pytest.raises(ValueError, match="2\\^31"):
         lt.join(rt, on="k", how="inner")
+
+
+# ----------------------------------------------------------------------
+# the right side's columns ride the key sort (PR 30): 64-bit ones as their
+# two halves, bit for bit; more than eight lanes in batches
+# ----------------------------------------------------------------------
+
+def _reference_join(lcols, rcols, how):
+    """INNER / LEFT join on ``k`` in plain numpy: ``[(data, valid)]`` of the
+    left columns then the right ones, a right column null where a LEFT
+    join's row found no match."""
+    lk, rk = lcols["k"][0], rcols["k"][0]
+    r_order = np.argsort(rk, kind="stable")
+    lo = np.searchsorted(rk[r_order], lk, "left")
+    cnt = np.searchsorted(rk[r_order], lk, "right") - lo
+    rows = np.maximum(cnt, 1) if how == "left" else cnt
+    li = np.repeat(np.arange(len(lk)), rows)
+    within = np.arange(rows.sum()) - np.repeat(np.cumsum(rows) - rows, rows)
+    matched = np.repeat(cnt, rows) > 0
+    ri = r_order[np.where(matched, np.repeat(lo, rows) + within, 0)]
+    out = [(d[li], None if v is None else v[li]) for d, v in lcols.values()]
+    for d, v in rcols.values():
+        out.append((d[ri], matched if v is None else matched & v[ri]))
+    return out
+
+
+def _row_set(cols):
+    """Rows as bits (zero under a null) with their validity, in one
+    canonical order: what two joins of the same rows agree on."""
+    lanes = []
+    for data, valid in cols:
+        valid = np.ones(len(data), bool) if valid is None else valid
+        lanes += [ride_cases.bits(data, valid).astype(np.uint64), valid.astype(np.uint64)]
+    mat = np.stack(lanes, axis=1)
+    return mat[np.lexsort(mat.T[::-1])]
+
+
+@pytest.mark.parametrize("world", [1, 4])
+@pytest.mark.parametrize("how", ["inner", "left"])
+@pytest.mark.parametrize("schema", ride_cases.SCHEMAS + ("wide5",))
+def test_joined_columns_come_out_bit_for_bit(devices, rng, schema, how, world):
+    """An INNER and a LEFT join whose right side holds int64, float64,
+    nullable float64, mixed 32/64-bit and five float64 columns: every
+    emitted row equals the numpy join's as bits (NaN payloads, -0.0,
+    infinities, a subnormal, int64's extremes), and the right side rode
+    one sort, or batches of it past eight lanes."""
+    ctx = ct.CylonContext.init_distributed(ct.TPUConfig(devices=devices[:world]))
+    lcols = ride_cases.columns(rng, 1200, schema, "l")
+    rcols = ride_cases.columns(rng, 900, schema, "r")
+    lt, rt = ride_cases.table(ctx, lcols), ride_cases.table(ctx, rcols)
+    before = ride_cases.ride_counts(tracing)
+    out = lt.distributed_join(rt, on="k", how=how)
+    lanes, batches = np.subtract(ride_cases.ride_counts(tracing), before)
+    got = list(ride_cases.physical(out).values())
+    want = _reference_join(lcols, rcols, how)
+    assert len(got) == len(want) and out.row_count == len(want[0][0])
+    assert (_row_set(got) == _row_set(want)).all()
+    assert (lanes, batches) == {
+        "int64": (4, 1), "float64": (4, 1), "nullable-float64": (5, 1),
+        "mixed": (7, 1), "wide5": (12, 3),
+    }[schema]

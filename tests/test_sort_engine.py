@@ -32,6 +32,7 @@ import pytest
 import jax.numpy as jnp
 
 import cylon_tpu as ct
+import ride_cases
 from cylon_tpu.obs import stages
 from cylon_tpu.obs import store as obs_store
 from cylon_tpu.ops import join as join_ops
@@ -560,3 +561,112 @@ def test_sort_dispatch_does_no_census(devices, tmp_path, monkeypatch):
         assert "sort_ev" not in obs_store.store().profiles["bbbb"]
     finally:
         obs_store.reset_stores()
+
+
+# ---------------------------------------------------------------------------
+# 5. every column rides the sort that orders it (PR 30): 64-bit ones as
+#    their two halves, bit for bit; many in batches; nothing is gathered
+# ---------------------------------------------------------------------------
+
+def _stable_reference(cols, counts, keys):
+    """Each shard's rows in numpy's stable order of ``keys``."""
+    ends = np.cumsum(np.asarray(counts, np.int64))
+    orders = [
+        (e - c) + np.lexsort([cols[k][0][e - c:e] for k in reversed(keys)])
+        for c, e in zip(counts, ends)
+    ]
+    order = np.concatenate(orders)
+    return {
+        name: (data[order], None if valid is None else valid[order])
+        for name, (data, valid) in cols.items()
+    }
+
+
+def _ride_delta(fn):
+    """``fn()``'s result and the (lanes, batches) its rides counted."""
+    before = ride_cases.ride_counts(tracing)
+    out = fn()
+    after = ride_cases.ride_counts(tracing)
+    return out, (after[0] - before[0], after[1] - before[1])
+
+
+@pytest.mark.parametrize("world", [1, 4])
+@pytest.mark.parametrize("schema", ride_cases.SCHEMAS)
+def test_sorted_columns_come_out_bit_for_bit(devices, rng, schema, world):
+    """``Table.sort`` of int64, float64, nullable float64 and mixed 32/64-bit
+    columns: every column equals numpy's stable order of the shard's rows as
+    bits (NaN payloads, -0.0, infinities, a subnormal, int64's extremes),
+    and the whole table rode one sort."""
+    cols = ride_cases.columns(rng, 1500, schema, "")
+    t = ride_cases.table(_ctx(devices, world), cols)
+    got, (lanes, batches) = _ride_delta(lambda: t.sort("k"))
+    ride_cases.assert_same_bits(
+        ride_cases.physical(got), _stable_reference(cols, t.row_counts, ["k"])
+    )
+    assert batches == 1
+    assert lanes == sum(
+        max(1, a.dtype.itemsize // 4)
+        for d, v in cols.values() for a in ((d,) if v is None else (d, v))
+    )
+
+
+@pytest.mark.parametrize("world", [1, 4])
+@pytest.mark.parametrize("keys", [["k"], ["k", "f0"]], ids=["fused", "two-pass"])
+def test_wide_table_rides_the_sort_in_batches(devices, rng, keys, world):
+    """Twelve lanes (an int64 key and five float64 columns) are more than
+    one sort carries: they ride in batches, of the one fused pass or of the
+    whole chained sort of an unfused key pair, and equal the reference."""
+    cols = ride_cases.columns(rng, 1500, "wide5", "")
+    cols["f0"] = (rng.normal(size=1500), None)  # a key: plain values
+    t = ride_cases.table(_ctx(devices, world), cols)
+    got, (lanes, batches) = _ride_delta(lambda: t.sort(keys))
+    ride_cases.assert_same_bits(
+        ride_cases.physical(got), _stable_reference(cols, t.row_counts, keys)
+    )
+    assert (lanes, batches) == (12, 3)  # the int64 stack, two of float64
+
+
+_OPCODE = re.compile(r" = (?:\([^=]*?\)|\S+) ([a-z][\w\-]*)\(")
+
+
+def _opcodes_under(text, scope):
+    """Opcodes of the instructions (fused ones too) of an HLO text whose
+    ``op_name`` path holds ``scope``."""
+    out = []
+    for line in text.splitlines():
+        op_name = stages._OP_NAME.search(line)
+        found = _OPCODE.search(stages._METADATA.sub("", line))
+        if found and op_name and scope in op_name[1].split("/"):
+            out.append(found[1])
+    return out
+
+
+@pytest.mark.parametrize("op,program,scope", [
+    ("sort", "sort", stages.SORT_PERM),
+    ("join", "join_spec", stages.JOIN_RIGHT_SORT),
+])
+def test_suite_schema_gathers_nothing_by_an_order(devices, op, program, scope):
+    """The benchmark's widths (int64 key, float64 value): the program that
+    orders them holds a sort under ``scope`` and no gather there; in
+    ``jit_sort`` no gather at all (``sort.gather`` named the last one)."""
+    ctx = _ctx(devices, 1)
+    rng = np.random.default_rng(5)
+    ta, tb = (
+        ct.Table.from_numpy(ctx, ["k", name], [
+            rng.integers(0, 2048, 2048).astype(np.int64), rng.random(2048),
+        ])
+        for name in ("v", "w")
+    )
+    assert _DISPATCH[op](ta, tb).row_count > 0
+    texts = [
+        fn.lower(*spec).compile().as_text()
+        for _key, fn, spec in stages.dispatched_programs(ctx)
+        if fn.__name__ == program
+    ]
+    assert texts
+    for text in texts:
+        under = _opcodes_under(text, scope)
+        assert "sort" in under and "gather" not in under, under
+        if program == "sort":
+            assert not re.search(r"\sgather\(", text)
+    assert "sort.gather" not in stages.VOCABULARY

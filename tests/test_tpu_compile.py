@@ -141,6 +141,63 @@ def test_bitonic_lexsort_compiles_for_tpu(one_chip):
     _compile(lexsort, _spec((ROWS,), jnp.int32, one_chip))
 
 
+def _wide_ops(compiled):
+    """``(shape, opcode)`` of the compiled program's operations that write
+    a ``[ROWS]`` array (a tuple shape for a sort), and its stage rows."""
+    from cylon_tpu.obs import stages
+
+    _module, rows = stages.parse_compiled(compiled.as_text())
+    parsed = [
+        re.match(r"%\S+ = (\(.*?\)|\S+) ([\w-]+)\(", text) for text, _op in rows
+    ]
+    return [(m[1], m[2]) for m in parsed if m and f"[{ROWS}" in m[1]], rows
+
+
+def test_suite_sort_compiles_with_its_64bit_columns_riding(one_chip):
+    """``sort-w1``'s own program shape (the int64 key fused with the
+    padding class into one sort word, the int64 key and the float64 value
+    as payloads, x64 on): one sort whose operands are the word and the two
+    columns' four 32-bit halves, and nothing gathers or scatters a row."""
+    fuse = _sort.plan_lane_fusion(
+        [("i64", 22, False, True)], pad_bits=2, prefix_bits=0, allow64=True
+    )
+    assert fuse is not None and fuse.n_words == 1
+
+    def sort_rows(key, val, n):
+        return _sort.lexsort_rows_payload(
+            [(key, None)], n, ROWS, [key, val], fuse=fuse
+        )
+
+    compiled = _compile(
+        sort_rows,
+        _spec((ROWS,), jnp.int64, one_chip),
+        _spec((ROWS,), jnp.float64, one_chip),
+        _spec((), jnp.int32, one_chip),
+    )
+    # fused or not, no instruction of the program gathers or scatters
+    assert not re.search(r"\s(gather|scatter)\(", compiled.as_text())
+    wide, _rows = _wide_ops(compiled)
+    sorts = [shape for shape, opcode in wide if opcode == "sort"]
+    assert len(sorts) == 1 and sorts[0].count("f32[") == 2, sorts
+    assert sorts[0].count("32[") >= 5, sorts  # the word and four halves
+
+
+def test_join_right_sort_compiles_with_its_64bit_columns_riding(one_chip):
+    """The speculative join's right side at the suite's widths (int64 key,
+    float64 value): one stable sort keyed by the ids carries both columns,
+    and no gather by an order is left."""
+    compiled = _compile(
+        lambda ids, key, val: _j.ride_right(ids, [(key, None), (val, None)]),
+        _spec((ROWS,), jnp.int32, one_chip),
+        _spec((ROWS,), jnp.int64, one_chip),
+        _spec((ROWS,), jnp.float64, one_chip),
+    )
+    assert not re.search(r"\s(gather|scatter)\(", compiled.as_text())
+    wide, _rows = _wide_ops(compiled)
+    sorts = [shape for shape, opcode in wide if opcode == "sort"]
+    assert len(sorts) == 1 and sorts[0].count("f32[") == 2, sorts
+
+
 # ----------------------------------------------------------------------
 # (d), (e) the local sort join at both dtype widths; (g) the distributed
 # join step on the four-chip mesh
@@ -252,13 +309,7 @@ def test_groupby_cell_shape_compiles_without_scatter(one_chip):
         _spec((ROWS,), jnp.float64, one_chip),
         _spec((), jnp.int32, one_chip),
     )
-    _module, rows = stages.parse_compiled(compiled.as_text())
-
-    # `%name = shape opcode(operands)`, the shape a tuple for a sort
-    parsed = [
-        re.match(r"%\S+ = (\(.*?\)|\S+) ([\w-]+)\(", text) for text, _op in rows
-    ]
-    wide = [(m[1], m[2]) for m in parsed if m and f"[{ROWS}]" in m[1]]
+    wide, rows = _wide_ops(compiled)
     assert wide
     assert not [shape for shape, opcode in wide if "scatter" in opcode]
     assert len([shape for shape, opcode in wide if "gather" in opcode]) <= 1
