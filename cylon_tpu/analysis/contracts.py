@@ -282,10 +282,12 @@ SYNC_SITE_BUDGETS: Dict[str, SyncBudget] = {
     ),
     # ops that own genuine host decisions
     "Table.join": SyncBudget(
-        3,
+        4,
         note="speculative stats fetch (overflow check) + exact-path probe "
-        "stats fetch + the pallas_pk stats fetch — each a packed single "
-        "fetch; the emit phases reuse the probe counts",
+        "stats fetch + the pallas_pk stats fetch + the semi-reduction's "
+        "counts fetch (a selective join then skips the speculative one: "
+        "its row count is known) — each a packed single fetch; the emit "
+        "phases reuse the probe counts",
     ),
     "Table._fused_join": SyncBudget(1, note="fused-step stats fetch"),
     "table._shuffle_many": SyncBudget(
@@ -600,6 +602,7 @@ EFFECT_SIGNATURES: Dict[str, str] = {
     "Table.to_pandas": "SYNC",
     "Table.to_pydict": "SYNC",
     "Table.to_string": "SYNC",
+    "Table.topk": "SYNC",
     "Table.union": "DISPATCH_SAFE",
     "Table.unique": "DISPATCH_SAFE",
     "Table.where": "MATERIALIZE",

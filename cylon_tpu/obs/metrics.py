@@ -300,15 +300,26 @@ STABLE_METRICS: Dict[str, Tuple[str, str]] = {
     "sort.ride_batches": (
         "counter", "the same rides (rows= sorts that carried the lanes: 1 "
         "when they fit one, more past ops/sort.RIDE_LANES)"),
+    "sort.topk": ("span", "Table.topk's one program, at dispatch"),
+    "plan.topk": ("counter", "TopK plan nodes lowered onto Table.topk"),
     "unique": ("span", "local unique dispatch"),
     "stats.measure": ("span", "on-demand column range-stats kernel"),
-    "join.": ("span", "join phases: speculative/fused/pallas_pk/sum_pushdown"),
+    "join.": (
+        "mixed", "join phases as spans (speculative/fused/pallas_pk/"
+        "sum_pushdown/semi: the semi-reduction's dispatch and counts "
+        "fetch) + the emit census as counters, from numbers the host "
+        "holds: emit_slots (rows= slots an emit wrote) and emit_rows "
+        "(rows= rows live in them)"),
     "setop.": ("span", "union/subtract/intersect dispatch"),
     "groupby.": ("span", "groupby phases (emit)"),
     "shuffle.count": ("span", "shuffle count-phase kernel + fetch"),
     "shuffle.exchange": ("span", "whole K-round exchange wall"),
     "shuffle.round.": ("span", "per-round pack/collective/compact dispatch"),
     "shuffle.rounds": ("counter", "round count K per shuffle (rows=K)"),
+    "shuffle.range.": (
+        "counter", "how evenly a range shuffle's sampled splitters cut the "
+        "rows, from the counts it fetches anyway: shard_rows_max (rows= "
+        "the fullest shard's) and shard_rows_mean (rows= the mean)"),
     "shuffle.overlap_efficiency": (
         "gauge", "fraction of the measured exchange device window "
         "(dispatch-open to the deferred round-count fetch return) spent "

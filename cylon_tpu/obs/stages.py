@@ -32,8 +32,10 @@ JOIN_KEY_IDS = "join.key_ids"
 JOIN_RIGHT_SORT = "join.right_sort"
 JOIN_PROBE = "join.probe"
 JOIN_EMIT = "join.emit"
+JOIN_SEMI = "join.semi"
 SORT_KEYS = "sort.keys"
 SORT_PERM = "sort.perm"
+SORT_TOPK = "sort.topk"
 SORT_ENGINE = "sort_engine"
 SHUFFLE_COUNT = "shuffle.count"
 SHUFFLE_PACK = "shuffle.pack"
@@ -46,8 +48,8 @@ GROUPBY_DENSE_AGG = "groupby.dense_agg"
 EXPR_EVAL = "expr.eval"
 
 VOCABULARY = (
-    JOIN_KEY_IDS, JOIN_RIGHT_SORT, JOIN_PROBE, JOIN_EMIT,
-    SORT_KEYS, SORT_PERM, SORT_ENGINE,
+    JOIN_KEY_IDS, JOIN_RIGHT_SORT, JOIN_PROBE, JOIN_EMIT, JOIN_SEMI,
+    SORT_KEYS, SORT_PERM, SORT_TOPK, SORT_ENGINE,
     SHUFFLE_COUNT, SHUFFLE_PACK, SHUFFLE_ALL_TO_ALL, SHUFFLE_COMPACT,
     SEMI_SKETCH, GROUPBY_SEGMENT_SUM, GROUPBY_KEY_IDS, GROUPBY_DENSE_AGG,
     EXPR_EVAL,

@@ -605,6 +605,7 @@ def _emit_inner_left(
     r_sorted_cols: Sequence[KeyCol],
     nl, how: int, cap_out: int, cap_r: int,
     emit_impl: str = "gather",
+    mask_free: bool = False,
 ) -> Tuple[list, jax.Array]:
     """INNER/LEFT emit against an ALREADY key-sorted right payload: the
     ``jnp.repeat`` for li, one packed left-row gather (payload + base/cnt
@@ -614,7 +615,13 @@ def _emit_inner_left(
     :func:`emit_impl_for`) swaps the left gather for the Pallas streamed
     expand (ops/pallas_gather), unless the table is wide enough that the
     expand's VMEM footprint (~L * 3 windows * 4 B at T=4096) would
-    overflow — wide tables keep the XLA gather."""
+    overflow — wide tables keep the XLA gather.
+
+    ``mask_free`` (INNER only; the join of semi-reduced sides asks for it):
+    every output row has a row on both sides, so a column that had no
+    validity lane gets none (``pack_gather(all_valid=True)``) and a
+    following join on it keeps the single-lane key path."""
+    mask_free = mask_free and how == INNER
     if emit_impl.startswith("windowed"):
         # VMEM gate: lanes = data lanes (2 for 64-bit) + validity lanes +
         # 5 bookkeeping; scratch+out ≈ lanes * (2*4224 + 4096) * 4 B.
@@ -647,11 +654,13 @@ def _emit_inner_left(
         li = _repeat_ss(ends, cap_out)
         out_pos = jnp.arange(cap_out, dtype=jnp.int32)
         li = jnp.where(out_pos < total_l, li, -1)
-        out_l, (base_g, cnt_g) = pack_gather(l_cols, li, extra_lanes=[base, cnt])
+        out_l, (base_g, cnt_g) = pack_gather(
+            l_cols, li, extra_lanes=[base, cnt], all_valid=mask_free
+        )
 
         has_match = (li >= 0) & (cnt_g > 0)
         rpos = jnp.where(has_match, jnp.clip(base_g + out_pos, 0, cap_r - 1), -1)
-        out_r, _ = pack_gather(r_sorted_cols, rpos)
+        out_r, _ = pack_gather(r_sorted_cols, rpos, all_valid=mask_free)
         return list(out_l) + list(out_r), total_l
 
 
@@ -769,6 +778,7 @@ def spec_join(
     r_presorted: bool = False,
     emit_key_order: bool = False,
     key_fuse=None,
+    mask_free: bool = False,
 ) -> Tuple[list, jax.Array, jax.Array]:
     """Single-dispatch speculative join: probe + count + emit + gather in one
     program with the minimal pass count.
@@ -824,7 +834,8 @@ def spec_join(
         total = count_from_probe(cnt, r_cnt, nl, nr, how)
         shadow = count_overflow_check(cnt, r_cnt)
         out_cols, n_out = _emit_inner_left(
-            lo, cnt, l_cols, r_sorted, nl, how, cap_out, cap_r, emit_impl
+            lo, cnt, l_cols, r_sorted, nl, how, cap_out, cap_r, emit_impl,
+            mask_free=mask_free,
         )
     else:
         lo, cnt, r_cnt = _merged_counts(
@@ -841,6 +852,127 @@ def spec_join(
             emit_impl,
         )
     return out_cols, total, shadow
+
+
+#: bit of the merged sort's payload that marks a row that is not live (a
+#: padding slot or a row its table's mask dropped); the position is below
+_SEMI_DEAD = 1 << 30
+
+
+def semi_capable(cap_l: int, cap_r: int) -> bool:
+    """Whether both sides' positions fit under the dead bit."""
+    return cap_l + cap_r < _SEMI_DEAD
+
+
+def semi_hits(
+    l_ids: jax.Array, r_ids: jax.Array, l_live: jax.Array, r_live: jax.Array
+) -> Tuple[jax.Array, jax.Array]:
+    """The rows of an INNER join that have a partner, found from the key
+    ids alone, before any payload moves: the semi-reduction in front of a
+    selective join (stage ``join.semi``).
+
+    One sort of [right ids ++ left ids] with the row's position as the
+    second key (the order of :func:`_merged_counts`' stable kv-sort; a row
+    that is not live, a padding slot or one its mask dropped, carries the
+    dead bit and counts for nothing), two blocked run scans
+    (:func:`ops.sort.run_reduce`) for the live partners of each row, and
+    ONE single-operand sort that brings the positions of the
+    rows with a partner to the front: the rights' ascending, then the lefts' (a left's
+    position is ``cap_r`` plus its row, so it sorts after every right).
+
+    Returns (``hits`` [cap_l + cap_r] int32, ``stats`` [4] int32: rights
+    with a partner, lefts with a partner, the join's exact row count, and
+    the float32 shadow of that count's bits, see
+    :func:`count_overflow_check`)."""
+    from .sort import run_reduce
+
+    cap_l, cap_r = l_ids.shape[0], r_ids.shape[0]
+    with jax.named_scope(_stages.JOIN_SEMI):
+        dead = jnp.int32(_SEMI_DEAD)
+        keys = jnp.concatenate([r_ids, l_ids])  # rights FIRST, as the probe's
+        live = jnp.concatenate([r_live, l_live])
+        pay = jnp.arange(cap_r + cap_l, dtype=jnp.int32) | jnp.where(
+            live, jnp.int32(0), dead
+        )
+        # both operands are keys and the sort is not stable: a row's
+        # position is its own, so no two rows tie, the order inside a run
+        # is the stable one (rows that are not live after the live ones,
+        # which counts for nothing), and the stable sort's own iota, a
+        # third operand, is not carried
+        with jax.named_scope(_stages.SORT_ENGINE):
+            skey, spay = jax.lax.sort(
+                (keys, pay), num_keys=2, is_stable=False
+            )
+        s_live = spay < dead
+        pos = spay & (dead - 1)
+        is_l = pos >= cap_r
+        l_liv, r_liv = s_live & is_l, s_live & ~is_l
+        new_run = jnp.concatenate(
+            [jnp.ones((1,), bool), skey[1:] != skey[:-1]]
+        )
+        run_end = jnp.concatenate([new_run[1:], jnp.ones((1,), bool)])
+        # rights precede lefts inside a run: a right sees every live left
+        # of its run at or after it, and a left every live right at or
+        # before it, which is the same scan over the flipped order (a
+        # run's start is its end there). The blocked scan and no
+        # ``jnp.cumsum``: its passes carry this scope's name into the
+        # trace, a prefix sum's ``reduce-window`` pieces carry none
+        (l_after,) = run_reduce(run_end, [l_liv.astype(jnp.int32)], ["sum"])
+        (r_upto,) = run_reduce(
+            jnp.flip(new_run), [jnp.flip(r_liv.astype(jnp.int32))], ["sum"]
+        )
+        cnt = jnp.where(l_liv, jnp.flip(r_upto), 0)
+        r_hit = r_liv & (l_after > 0)
+        l_hit = cnt > 0
+        big = jnp.int32(2**31 - 1)
+        hits = _sort_one(jnp.where(l_hit | r_hit, pos, big))
+        stats = jnp.stack([
+            jnp.sum(r_hit).astype(jnp.int32),
+            jnp.sum(l_hit).astype(jnp.int32),
+            jnp.sum(cnt).astype(jnp.int32),
+            jax.lax.bitcast_convert_type(
+                jnp.sum(cnt.astype(jnp.float32)), jnp.int32
+            ),
+        ])
+        return hits, stats
+
+
+def _sort_one(key: jax.Array) -> jax.Array:
+    """One operand, sorted: the cheapest sort the chip has (not stable:
+    equal values are interchangeable, and a stable sort carries an iota)."""
+    with jax.named_scope(_stages.SORT_ENGINE):
+        return jax.lax.sort(key, is_stable=False)
+
+
+def reduce_by_hits(
+    hits: jax.Array,
+    stats: jax.Array,
+    l_cols: Sequence[KeyCol],
+    r_cols: Sequence[KeyCol],
+    cap_lo: int,
+    cap_ro: int,
+) -> Tuple[list, list]:
+    """Both sides cut to their rows with a partner, in row order, at the
+    capacities the host chose from :func:`semi_hits`' counts: two gathers
+    over ``cap_lo`` and ``cap_ro`` slots, not over the inputs'."""
+    from .gather import pack_gather
+
+    cap_r = r_cols[0][0].shape[0]
+    with jax.named_scope(_stages.JOIN_SEMI):
+        n_r, n_l = stats[0], stats[1]
+        r_idx = jnp.where(
+            jnp.arange(cap_ro, dtype=jnp.int32) < n_r, hits[:cap_ro], -1
+        )
+        # the lefts follow the rights: a slice that starts where they end,
+        # over a tail long enough that the start is never clamped
+        tail = jnp.concatenate([hits, jnp.zeros((cap_lo,), jnp.int32)])
+        l_pos = jax.lax.dynamic_slice(tail, (n_r,), (cap_lo,))
+        l_idx = jnp.where(
+            jnp.arange(cap_lo, dtype=jnp.int32) < n_l, l_pos - cap_r, -1
+        )
+        out_l, _ = pack_gather(list(l_cols), l_idx, all_valid=True)
+        out_r, _ = pack_gather(list(r_cols), r_idx, all_valid=True)
+        return list(out_l), list(out_r)
 
 
 def gather_column(

@@ -105,11 +105,18 @@ def range_partition_ids(
         hist = jax.lax.psum(hist, axis_name)  # reference MPI_Allreduce :410
     total = jnp.sum(hist)
     # bin -> partition: equal cumulative weight split (reference
-    # build_bin_to_partition :418-440)
+    # build_bin_to_partition :418-440), by the rows in front of the bin's
+    # MIDDLE, so a bin goes where most of it lies. By the rows in front of
+    # the bin (the reference's rule) evenly filled bins put the bin at a
+    # boundary a few rows short of it or past it by chance, and a whole bin
+    # (1/16 of a partition) goes to one side: enough to double the
+    # power-of-two capacities downstream (PERF.md section 6, PR 31). Still
+    # monotone in the bin, so partitions stay ordered.
     cum = jnp.cumsum(hist) - hist  # exclusive
+    mid = cum.astype(wide_float()) + hist.astype(wide_float()) / 2
     per_part = jnp.maximum(total.astype(wide_float()) / num_partitions, 1.0)
     bin_to_part = jnp.clip(
-        (cum.astype(wide_float()) / per_part).astype(jnp.int32), 0, num_partitions - 1
+        (mid / per_part).astype(jnp.int32), 0, num_partitions - 1
     )
     pid = bin_to_part[jnp.clip(b, 0, num_bins - 1)]
     if not ascending:

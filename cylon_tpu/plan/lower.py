@@ -36,6 +36,7 @@ from .nodes import (
     Scan,
     Shuffle,
     Sort,
+    TopK,
     Union,
     WithColumns,
 )
@@ -293,6 +294,15 @@ def _lower_one(node: Node, ex, tables):
         lchild, l_shuf = _peel_shuffle(node.children[0], node.l_on)
         rchild, r_shuf = _peel_shuffle(node.children[1], node.r_on)
         lt, rt = ex(lchild), ex(rchild)
+        # join_mask rewrite: each side's predicate as its row mask, read
+        # before the columns only the mask needs are left behind
+        sides, masks = [], []
+        for t, mask, keep in zip((lt, rt), node.masks, node.keep):
+            masks.append(None if mask is None else _expr_mask(t, mask))
+            if keep is not None:
+                t = t.project([n for n in t.column_names if n in keep])
+            sides.append(t)
+        (lt, rt), (l_mask, r_mask) = sides, masks
         # pre-rename both sides to the build-time output names so pruning
         # can never change the suffixing (nodes.Join docstring)
         lt = lt.rename({n: node.l_rename[n] for n in lt.column_names})
@@ -308,6 +318,7 @@ def _lower_one(node: Node, ex, tables):
             # lexsort elides (the eager join stamps the ordering descriptor
             # and e.g. Table.groupby auto-run-detects off it)
             emit_order="key" if node.emit_key_order else "left",
+            _left_mask=l_mask, _right_mask=r_mask,
         )
     if isinstance(node, FusedJoinGroupBySum):
         lchild, l_shuf = _peel_shuffle(node.children[0], node.l_on)
@@ -330,6 +341,11 @@ def _lower_one(node: Node, ex, tables):
         return res
     if isinstance(node, Union):
         return ex(node.children[0]).union(ex(node.children[1]))
+    if isinstance(node, TopK):
+        _obstrace.bump("plan.topk")
+        return ex(node.children[0]).topk(
+            list(node.by), node.n, list(node.ascending)
+        )
     if isinstance(node, Limit):
         t = ex(node.children[0])
         return t.take(np.arange(min(node.n, t.row_count), dtype=np.int64))

@@ -320,12 +320,26 @@ class Join(Node):
         _renames: Optional[Tuple[Dict[str, str], Dict[str, str]]] = None,
         emit_key_order: bool = False,
         semi_filter: Optional[str] = None,
+        masks: Tuple[object, object] = (None, None),
+        keep: Tuple[Optional[Tuple[str, ...]], Optional[Tuple[str, ...]]] = (
+            None, None,
+        ),
     ):
         self.children = (left, right)
         self.l_on = tuple(l_on)
         self.r_on = tuple(r_on)
         self.how = how
         self.suffixes = tuple(suffixes)
+        # set by the join_mask rewrite: the predicate of a Filter that stood
+        # directly under a side of this INNER join, over that side's column
+        # names, now the side's row mask (``Table.join(_left_mask=...)``:
+        # the same rows as filter-then-join, but nothing is compacted
+        # before the join's keys-only semi-reduction has said which rows
+        # have a partner). ``keep`` (set by projection pushdown) names the
+        # columns of a side that go into the join, where its mask reads
+        # columns nothing above the join does
+        self.masks = tuple(masks)
+        self.keep = tuple(None if k is None else tuple(k) for k in keep)
         # set by the order_reuse rewrite: lower with emit_order='key' so the
         # join's probe kv-sort doubles as the downstream op's key sort
         self.emit_key_order = bool(emit_key_order)
@@ -346,11 +360,22 @@ class Join(Node):
         )
 
     def with_children(self, kids):
+        return self.replaced(kids)
+
+    def replaced(self, kids, **changes) -> "Join":
+        """A copy over ``kids`` that keeps what the rewrites have written
+        on this node (renames, emit order, semi filter, masks, kept
+        columns), ``changes`` overriding."""
+        notes = {
+            "_renames": (self.l_rename, self.r_rename),
+            "emit_key_order": self.emit_key_order,
+            "semi_filter": self.semi_filter,
+            "masks": self.masks, "keep": self.keep,
+        }
+        notes.update(changes)
         return Join(
             kids[0], kids[1], self.l_on, self.r_on, self.how, self.suffixes,
-            _renames=(self.l_rename, self.r_rename),
-            emit_key_order=self.emit_key_order,
-            semi_filter=self.semi_filter,
+            **notes,
         )
 
     @property
@@ -413,6 +438,8 @@ class Join(Node):
             tuple(sorted(self.l_rename.items())),
             tuple(sorted(self.r_rename.items())),
             self.emit_key_order, self.semi_filter,
+            tuple(None if m is None else m.key() for m in self.masks),
+            self.keep,
         )
 
     def label(self) -> str:
@@ -420,6 +447,13 @@ class Join(Node):
         tail = " emit=key-order" if self.emit_key_order else ""
         if self.semi_filter:
             tail += f" semi-filter={self.semi_filter}"
+        for side, m in zip(("left", "right"), self.masks):
+            if m is not None:
+                tail += f" {side}-mask {m!r}"
+        if any(m is not None for m in self.masks):
+            tail += (
+                " [capacity: semi-reduce, then round_cap of the counted rows]"
+            )
         return f"Join how={self.how} on [{keys}]{tail}"
 
 
@@ -607,6 +641,34 @@ class Limit(Node):
 
     def label(self) -> str:
         return f"Limit {self.n}"
+
+
+class TopK(Node):
+    """The first ``n`` rows of the global order by ``by``: what
+    ``Limit(Sort(x))`` means, made by the ``topk`` rewrite and lowered to
+    ``Table.topk``, which orders the key lanes alone, gathers ``n`` rows
+    and fetches no row count. Rows re-split like Limit's, so partitioning
+    is lost; the rows come out in the requested order."""
+
+    def __init__(self, child: Node, by: Sequence[str],
+                 ascending: Sequence[bool], n: int):
+        self.children = (child,)
+        self.by = tuple(by)
+        self.ascending = tuple(bool(a) for a in ascending)
+        self.n = int(n)
+        self.schema = child.schema
+
+    def with_children(self, kids):
+        return TopK(kids[0], self.by, self.ascending, self.n)
+
+    def _params(self) -> tuple:
+        return (self.by, self.ascending, self.n)
+
+    def label(self) -> str:
+        return (
+            f"TopK {self.n} by [{', '.join(self.by)}] "
+            f"asc={list(self.ascending)}"
+        )
 
 
 class FusedJoinGroupBySum(Node):

@@ -1,9 +1,13 @@
 """The rule-based plan rewriter.
 
-``optimize(root, world_size)`` runs five passes and returns the rewritten
-plan plus the ordered list of rule firings (surfaced by ``.explain()`` and
-counted into the tracing registry by ``collect()``):
+``optimize(root, world_size)`` runs its passes in this order and returns
+the rewritten plan plus the ordered list of rule firings (surfaced by
+``.explain()`` and counted into the tracing registry by ``collect()``):
 
+0. ``topk`` — ``Limit(Sort(x))`` becomes ``TopK`` (lowered to
+   ``Table.topk``: the key lanes sorted, ``n`` rows gathered, no count
+   fetched), before the physicalizer can put a range shuffle under the
+   sort;
 1. ``filter_pushdown`` — move Filters below Projects/Sorts/Unions, below
    the covering side of a Join, and below a GroupBy when the predicate
    only reads group keys (the later physicalize pass then inserts shuffles
@@ -42,7 +46,10 @@ counted into the tracing registry by ``collect()``):
 
 Between 4 and 5, ``filter_as_mask`` turns a Filter directly under a
 GroupBy into the aggregate's row mask (``GroupBy.mask``): the table skips
-the rows in its reductions instead of compacting every column first.
+the rows in its reductions instead of compacting every column first; and
+``join_mask`` does the same for a Filter directly under a side of an INNER
+Join (``Join.masks``): the join's keys-only semi-reduction treats a masked
+row as dead, and nothing is gathered at the table's capacity.
 """
 from __future__ import annotations
 
@@ -61,6 +68,7 @@ from .nodes import (
     Scan,
     Shuffle,
     Sort,
+    TopK,
     Union,
     WithColumns,
     _covers,
@@ -74,21 +82,42 @@ FUSED_JOIN_GROUPBY = "fused_join_groupby"
 ORDER_REUSE = "order_reuse"
 SEMI_FILTER = "semi_filter"
 PROJECTION_PUSHDOWN = "projection_pushdown"
+TOPK = "topk"
+JOIN_MASK = "join_mask"
 
 
 def optimize(root: Node, world_size: int) -> Tuple[Node, List[str]]:
     fired: List[str] = []
+    root = _make_topk(root, fired)
     root = _push_filters(root, fired)
     if world_size > 1:
         root = _physicalize(root)
     root = _eliminate_shuffles(root, fired)
     root = _fuse_join_groupby(root, fired)
     root = _filter_as_mask(root, fired)
+    root = _filter_as_join_mask(root, fired)
     root = _reuse_order(root, fired)
     if world_size > 1:
         root = _annotate_semi_filter(root, fired)
     root = _prune_columns(root, fired)
     return root, fired
+
+
+# ----------------------------------------------------------------------
+# 0. a limit over a sort is a top-k
+# ----------------------------------------------------------------------
+def _make_topk(node: Node, fired: List[str]) -> Node:
+    """``Limit(Sort(x, by), n)`` -> ``TopK(x, by, n)``. Runs first: the
+    physicalizer then puts no range shuffle under a sort whose output is
+    cut to ``n`` rows at once (``Table.topk`` takes each shard's first
+    ``n`` and orders only those across the mesh)."""
+    kids = [_make_topk(c, fired) for c in node.children]
+    node = node.with_children(kids) if node.children else node
+    if isinstance(node, Limit) and isinstance(node.children[0], Sort):
+        sort = node.children[0]
+        fired.append(TOPK)
+        return TopK(sort.children[0], sort.by, sort.ascending, node.n)
+    return node
 
 
 # ----------------------------------------------------------------------
@@ -306,6 +335,38 @@ def _filter_as_mask(node: Node, fired: List[str]) -> Node:
 
 
 # ----------------------------------------------------------------------
+# 4c. a filter directly under an inner join rides it as a row mask
+# ----------------------------------------------------------------------
+def _filter_as_join_mask(node: Node, fired: List[str]) -> Node:
+    """``Join(Filter(x, p), y)`` -> ``Join(x, y, masks=(p, None))``, either
+    side, INNER joins only (an outer join would bring a dropped row back
+    as an unmatched one). The filter no longer compacts every column of
+    its table at the table's capacity: the join's keys-only semi-reduction
+    treats a masked row as dead, and only the rows that have a partner are
+    ever gathered (``Table.join``'s docstring has the capacity rule). Runs
+    after physicalize and shuffle elimination, so on a mesh a filter that
+    stands under the join's Shuffle stays there and shrinks the exchange;
+    a side is masked only where its rows do not move."""
+    kids = [_filter_as_join_mask(c, fired) for c in node.children]
+    node = node.with_children(kids) if node.children else node
+    if not isinstance(node, Join) or node.how != "inner":
+        return node
+    kids, masks = list(node.children), list(node.masks)
+    before = len(fired)
+    for side in (0, 1):
+        while isinstance(kids[side], Filter):
+            f = kids[side]
+            masks[side] = (
+                f.expr if masks[side] is None else masks[side] & f.expr
+            )
+            kids[side] = f.children[0]
+            fired.append(JOIN_MASK)
+    if len(fired) == before:
+        return node
+    return node.replaced(kids, masks=tuple(masks))
+
+
+# ----------------------------------------------------------------------
 # 5. order-property propagation / reuse
 # ----------------------------------------------------------------------
 def _reuse_order(node: Node, fired: List[str]) -> Node:
@@ -359,12 +420,7 @@ def _reuse_order(node: Node, fired: List[str]) -> Node:
             # the probe's compaction key changes) and annotate the groupby;
             # the eager gate run-detects off the emitted descriptor
             fired.append(ORDER_REUSE)
-            j2 = Join(
-                join.children[0], join.children[1], join.l_on, join.r_on,
-                join.how, join.suffixes,
-                _renames=(join.l_rename, join.r_rename),
-                emit_key_order=True,
-            )
+            j2 = join.replaced(join.children, emit_key_order=True)
             return GroupBy(j2, node.keys, node.aggs, sorted_input=True)
     return node
 
@@ -461,6 +517,9 @@ def _prune(node: Node, req: Set[str], fired: List[str]) -> Node:
     if isinstance(node, Limit):
         child = _prune(node.children[0], req, fired)
         return node.with_children([child])
+    if isinstance(node, TopK):
+        child = _prune(node.children[0], req | set(node.by), fired)
+        return node.with_children([child])
     if isinstance(node, GroupBy):
         need = set(node.keys) | {c for c, _ in node.aggs}
         if node.mask is not None:
@@ -481,9 +540,17 @@ def _prune(node: Node, req: Set[str], fired: List[str]) -> Node:
     if isinstance(node, Join):
         l_req = {s for s, o in node.l_rename.items() if o in req} | set(node.l_on)
         r_req = {s for s, o in node.r_rename.items() if o in req} | set(node.r_on)
-        left = _prune(node.children[0], l_req, fired)
-        right = _prune(node.children[1], r_req, fired)
-        return node.with_children([left, right])
+        kids, keep = [], list(node.keep)
+        for side, need in enumerate((l_req, r_req)):
+            mask = node.masks[side]
+            reads = set() if mask is None else mask.columns()
+            child = _prune(node.children[side], need | reads, fired)
+            if reads - need:
+                # what only the mask reads goes no further than the mask
+                keep[side] = tuple(n for n in child.names if n in need)
+                fired.append(PROJECTION_PUSHDOWN)
+            kids.append(child)
+        return node.replaced(kids, keep=tuple(keep))
     if isinstance(node, FusedJoinGroupBySum):
         left = _prune(node.children[0], set(node.l_on) | {node.val_col}, fired)
         right = _prune(node.children[1], set(node.r_on), fired)

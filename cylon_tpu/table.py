@@ -1078,7 +1078,21 @@ class Table:
     def filter(self, mask: Union["Table", Column, jax.Array]) -> "Table":
         """Keep rows where mask is True. The vectorized analog of the
         reference's UDF Select (table.cpp:504-529) and of pycylon's boolean
-        __getitem__ (data/table.pyx:1066-1223)."""
+        __getitem__ (data/table.pyx:1066-1223).
+
+        **Capacity of the result.** The table's own: the rows kept are a
+        subset, so ``shard_cap`` is an exact upper bound known without a
+        count, and the filter is one dispatch with no fetch (the sync
+        budget of a filter is 0). The count rides the result on the device;
+        whoever first asks for it pays the fetch, and the result is then
+        sliced down where the capacity overshot the rows four times or
+        more (``_materialize_counts``). So a filter that keeps little still
+        compacts every column over the whole capacity, and what follows
+        runs at that capacity until a count is fetched. The planner avoids
+        both where it can: a filter under an aggregate rides it as a row
+        mask (``filter_as_mask``), one under an inner join rides the join
+        (``join_mask``; ``Table.join`` has the rule for what follows), and
+        in neither case does this method run."""
         m = self._as_mask(mask)
         names = self.column_names
         flat = self._flat_cols()
@@ -1346,6 +1360,81 @@ class Table:
             res._ordering = res._ordering._replace(scope="global")
         return res
 
+    def topk(
+        self,
+        order_by: Union[str, int, Sequence[Union[str, int]]],
+        n: int,
+        ascending: Union[bool, Sequence[bool]] = True,
+    ) -> "Table":
+        """The first ``n`` rows of ``distributed_sort(order_by, ascending)``
+        (all of them where the table has fewer), in that order: what
+        ``sort`` then ``head(n)`` gives, nulls last and ties in row order,
+        without sorting the table. Only the key lanes and a row position
+        are ordered (stage ``sort.topk``); no other column rides the sort
+        and ``n`` rows are gathered by the first ``n`` positions. The
+        result's capacity is ``round_cap(n)`` and its count stays on the
+        device: nothing is fetched (on a mesh each shard keeps its own
+        first ``n``, and those at most ``world * n`` rows take the sort
+        and limit that were there, which fetch their counts)."""
+        names = self._resolve_cols(order_by)
+        asc = self._resolve_asc(ascending, len(names))
+        n = int(n)
+        if n < 0:
+            raise ValueError("topk needs n >= 0")
+        res = self._topk_local(names, asc, n)
+        if self.world_size == 1:
+            return res
+        res = res.distributed_sort(order_by, ascending)
+        return res.take(
+            np.arange(min(n, res.row_count), dtype=np.int64)
+        )
+
+    def _topk_local(self, names, asc, n: int) -> "Table":
+        """Each shard's own first ``n`` rows by ``names`` (one program, no
+        fetch): the whole of :meth:`topk` on one device."""
+        all_names = self.column_names
+        key_idx = tuple(all_names.index(c) for c in names)
+        flat = self._flat_cols()
+        cap_out = round_cap(n)
+        key = ("topk", key_idx, asc, len(flat))
+
+        def build():
+            def kern(dp, rep):
+                (cols, counts) = dp
+                (dummy, limit) = rep
+                k = dummy.shape[0]
+                live = counts[0]
+                cap = cols[0][0].shape[0]
+                with jax.named_scope(_stages.SORT_TOPK):
+                    order = _sort_mod.lexsort_rows(
+                        [cols[i] for i in key_idx], live, cap, list(asc)
+                    )
+                    if k <= cap:
+                        order = order[:k]
+                    else:
+                        order = jnp.concatenate(
+                            [order, jnp.full((k - cap,), -1, jnp.int32)]
+                        )
+                    kept = jnp.minimum(live, limit).astype(jnp.int32)
+                    idx = jnp.where(
+                        jnp.arange(k, dtype=jnp.int32) < kept, order, -1
+                    )
+                    out, _ = _g_pack.pack_gather(
+                        list(cols), idx, all_valid=True
+                    )
+                    return out, _scalar(kept)
+
+            return kern
+
+        with span("sort.topk", rows=self._rows_hint()):
+            out, kept = get_kernel(self.ctx, key, build)(
+                (flat, self.counts_dev),
+                (jnp.zeros((cap_out,), jnp.int8), np.int32(n)),
+            )
+        return self._rebuild_cols(
+            list(zip(all_names, self._columns.values())), out, kept, cap_out
+        )
+
     # ------------------------------------------------------------------
     # shuffle (the distributed backbone)
     # ------------------------------------------------------------------
@@ -1452,9 +1541,35 @@ class Table:
         algorithm: str = "sort",
         config: Optional["object"] = None,
         emit_order: str = "left",
+        _left_mask: Optional[jax.Array] = None,
+        _right_mask: Optional[jax.Array] = None,
+        _totals: Optional[np.ndarray] = None,
     ) -> "Table":
         """Per-shard (local) equi-join — all 4 types (reference Join,
         table.cpp:428-480; join/hash_join.cpp + sort_join.cpp).
+
+        **Capacity of the result.** A join emits into a capacity it must
+        choose before it knows its row count. By default it speculates:
+        ``round_cap(max(cap_l, cap_r))`` slots a shard (the sum for a full
+        outer join), which holds every join of about one match a key, and
+        the count it fetches afterwards slices the result down where it
+        overshot four times or more (``_maybe_compact``). That is right
+        when most rows find a partner and wasteful when few do: the sorts
+        and the emit's gathers then run over the padded inputs for rows
+        that die. So an INNER join that a filter rides as a row mask
+        (``_left_mask`` / ``_right_mask``, the planner's ``join_mask``
+        rewrite: the one sign of selectivity the join is given) reduces
+        first (:meth:`_semi_reduced`): one keys-only program finds the
+        rows of either side that have a live partner and the exact output
+        count, the host fetches the three counts, each side is cut to
+        ``round_cap`` of its rows with a partner, and the join proper
+        runs on those at ``round_cap`` of the exact count. Every capacity
+        after the keys-only program is then under twice the rows that live
+        in it. A join without a mask never reduces, whatever an earlier
+        join of its signature counted. Where the two sides' positions do
+        not fit the keys-only program's word (``ops.join.semi_capable``)
+        the masks are applied as filters first (``Table.filter``'s
+        capacity) and the join speculates as any other.
 
         ``algorithm``: 'sort' and 'hash' both execute the sort/searchsorted
         join (SURVEY.md §7: argsort is native, hash multimaps are not —
@@ -1531,6 +1646,27 @@ class Table:
             "join", howi, lk_idx, rk_idx, len(lflat), len(rflat),
             r_presorted, emit_key, join_fuse,
         ) + _j.impl_tag()
+        masked = _left_mask is not None or _right_mask is not None
+        if masked and howi != _j.INNER:
+            raise ValueError("a row mask rides an inner join only")
+        if masked:
+            if not _j.semi_capable(left.shard_cap, right.shard_cap):
+                # a mask never falls through unread: filter, then join
+                if _left_mask is not None:
+                    left = left.filter(_left_mask)
+                if _right_mask is not None:
+                    right = right.filter(_right_mask)
+                return left.join(
+                    right, left_on=l_names, right_on=r_names, how=how,
+                    suffixes=suffixes, emit_order=emit_order,
+                )
+            left, right, totals = left._semi_reduced(
+                right, l_names, r_names, _left_mask, _right_mask, join_fuse
+            )
+            return left.join(
+                right, left_on=l_names, right_on=r_names, how=how,
+                suffixes=suffixes, emit_order=emit_order, _totals=totals,
+            )
 
         # Speculative single-dispatch path: fuse probe+count+emit into ONE
         # program with a capacity-factor output (cap_l+cap_r covers every
@@ -1577,7 +1713,10 @@ class Table:
             # dispatch only once per join signature. FULL_OUTER's zero-match
             # minimum is nl + nr, so it always keeps the sum.
             hints = self.ctx.__dict__.setdefault("_spec_cap_hints", {})
-            if howi == _j.FULL_OUTER:
+            if _totals is not None:
+                # the semi-reduction counted the rows: no speculation
+                spec_cap = round_cap(int(_totals.max()))
+            elif howi == _j.FULL_OUTER:
                 spec_cap = round_cap(cap_l + cap_r)
             else:
                 spec_cap = max(
@@ -1585,6 +1724,8 @@ class Table:
                 )
 
             emit_impl, emit_kw = _j.emit_impl_kwargs(self.ctx)
+            # the join of semi-reduced sides: every row has its partner
+            counted = _totals is not None
 
             def build_spec():
                 def kern(dp, rep):
@@ -1595,6 +1736,7 @@ class Table:
                         lk, rk, lcols, rcols, nl[0], nr[0], howi, co,
                         emit_impl, r_presorted=r_presorted,
                         emit_key_order=emit_key, key_fuse=join_fuse,
+                        mask_free=counted,
                     )
                     # pack count + f32 overflow shadow into one [2] i32 lane
                     # so the host needs a single fetch
@@ -1607,18 +1749,26 @@ class Table:
 
             with span("join.speculative", rows=self._rows_hint()):
                 out, stats = get_kernel(
-                    self.ctx, key + ("spec",), build_spec, name="join_spec",
-                    **emit_kw,
+                    self.ctx,
+                    key + (("spec", "counted") if counted else ("spec",)),
+                    build_spec, name="join_spec", **emit_kw,
                 )(
                     (lflat_k, rflat_k, lflat, rflat, left.counts_dev, right.counts_dev),
                     (jnp.zeros((spec_cap,), jnp.int8),),
                 )
-                bump("host_sync")
-                stats = _fetch(stats).reshape(-1, 2)
-                totals = stats[:, 0].astype(np.int64)
-                shadows = stats[:, 1].copy().view(np.float32)
-            _check_join_count(totals, shadows)
-            if totals.max() <= spec_cap:
+                if _totals is None:
+                    bump("host_sync")
+                    stats = _fetch(stats).reshape(-1, 2)
+                    totals = stats[:, 0].astype(np.int64)
+                    _check_join_count(
+                        totals, stats[:, 1].copy().view(np.float32)
+                    )
+                else:
+                    totals = _totals
+            fits = totals.max() <= spec_cap
+            bump("join.emit_slots", rows=spec_cap * len(totals))
+            bump("join.emit_rows", rows=int(totals.sum()) if fits else 0)
+            if fits:
                 res = self._rebuild_cols(
                     list(zip(out_names, src_cols)), out, totals, spec_cap
                 )
@@ -1671,6 +1821,8 @@ class Table:
         cnts = pstats[:, 0].astype(np.int64)
         _check_join_count(cnts, pstats[:, 1].copy().view(np.float32))
         cap_out = round_cap(int(cnts.max()))
+        bump("join.emit_slots", rows=cap_out * len(cnts))
+        bump("join.emit_rows", rows=int(cnts.sum()))
 
         # phase 2: emit + gather, reusing the probe state (no re-sort)
         emit_impl, emit_kw = _j.emit_impl_kwargs(self.ctx)
@@ -1704,6 +1856,95 @@ class Table:
         return self._rebuild_cols(
             list(zip(out_names, src_cols)), out, cnts, cap_out
         )._attach_ordering(carry_ordering)
+
+    def _semi_reduced(
+        self, other: "Table", l_names, r_names, l_mask, r_mask, join_fuse
+    ) -> Tuple["Table", "Table", np.ndarray]:
+        """Both sides of an INNER join cut to the rows that have a live
+        partner on the other side, in row order, and the join's exact row
+        count a shard: the semi-reduction in front of a selective join
+        (``Table.join``'s docstring has the rule). ``l_mask`` / ``r_mask``
+        (bool, the padded layout; None for every live row) are the filters
+        that ride the join: a row they drop is as dead as a padding slot.
+
+        Two programs and one fetch. ``join_semi`` reads the key columns
+        and the masks alone (``ops.join.semi_hits``: one kv-sort of the
+        merged key ids, two blocked run scans, one single-operand sort); the host
+        fetches rows-with-a-partner of either side and the total;
+        ``join_reduce`` gathers each side's kept rows at ``round_cap`` of
+        its count. No payload column is read before its rows are known."""
+        l_on, r_on = bool(l_mask is not None), bool(r_mask is not None)
+        lflat_k, rflat_k = self._flat_cols(l_names), other._flat_cols(r_names)
+        sig = (
+            tuple(self.column_names.index(n) for n in l_names),
+            tuple(other.column_names.index(n) for n in r_names),
+        )
+
+        def build_semi():
+            def kern(dp, rep):
+                (lk, rk, nl, nr, masks) = dp
+                cap_l, cap_r = lk[0][0].shape[0], rk[0][0].shape[0]
+                l_ids, r_ids = _j._canonical_ids(
+                    lk, rk, nl[0], nr[0], cap_l, cap_r, fuse=join_fuse
+                )
+                masks = list(masks)
+                l_live = jnp.arange(cap_l, dtype=jnp.int32) < nl[0]
+                r_live = jnp.arange(cap_r, dtype=jnp.int32) < nr[0]
+                if l_on:
+                    l_live = l_live & masks.pop(0)
+                if r_on:
+                    r_live = r_live & masks.pop(0)
+                hits, stats = _j.semi_hits(l_ids, r_ids, l_live, r_live)
+                return hits, stats
+
+            return kern
+
+        with span("join.semi", rows=self._rows_hint()):
+            hits, stats = get_kernel(
+                self.ctx, ("join_semi", sig, l_on, r_on, join_fuse),
+                build_semi,
+            )(
+                (
+                    lflat_k, rflat_k, self.counts_dev, other.counts_dev,
+                    [m for m in (l_mask, r_mask) if m is not None],
+                ),
+                (),
+            )
+            bump("host_sync")
+            got = _fetch(stats).reshape(-1, 4)
+        n_r, n_l = got[:, 0].astype(np.int64), got[:, 1].astype(np.int64)
+        totals = got[:, 2].astype(np.int64)
+        _check_join_count(totals, got[:, 3].copy().view(np.float32))
+        cap_lo, cap_ro = round_cap(int(n_l.max())), round_cap(int(n_r.max()))
+        lflat, rflat = self._flat_cols(), other._flat_cols()
+
+        def build_reduce():
+            def kern(dp, rep):
+                (hits, stats, lcols, rcols) = dp
+                (dl, dr) = rep
+                return _j.reduce_by_hits(
+                    hits, stats, lcols, rcols, dl.shape[0], dr.shape[0]
+                )
+
+            return kern
+
+        out_l, out_r = get_kernel(
+            self.ctx, ("join_reduce", len(lflat), len(rflat)), build_reduce
+        )(
+            (hits, stats, lflat, rflat),
+            (jnp.zeros((cap_lo,), jnp.int8), jnp.zeros((cap_ro,), jnp.int8)),
+        )
+        # row subsets in row order: ordering and stats survive, as a
+        # filter's do
+        left = self._rebuild_cols(
+            list(zip(self.column_names, self._columns.values())),
+            out_l, n_l, cap_lo,
+        )._attach_ordering(self._ordering)._attach_stats(self._stats)
+        right = other._rebuild_cols(
+            list(zip(other.column_names, other._columns.values())),
+            out_r, n_r, cap_ro,
+        )._attach_ordering(other._ordering)._attach_stats(other._stats)
+        return left, right, totals
 
     def _pallas_pk_join(
         self,
@@ -4022,6 +4263,15 @@ def _shuffle_many(specs: Sequence["_ShuffleSpec"]) -> List["Table"]:
             st["use_filter"] = False
             st["send_counts"] = got[:, :w]  # [src, dst]
             base = w
+            if spec.kind == "range":
+                # how evenly the sampled splitters cut the rows: the
+                # fullest shard and the mean, from the counts just fetched
+                recv = st["send_counts"].sum(axis=0)
+                bump("shuffle.range.shard_rows_max", rows=int(recv.max()))
+                bump(
+                    "shuffle.range.shard_rows_mean",
+                    rows=int(round(float(recv.mean()))),
+                )
         # global column range stats measured by the count pass: fold the
         # per-shard words, cache on the INPUT table (later local ops on it
         # skip the stats kernel) and remember them for the wire plan and

@@ -670,3 +670,34 @@ def test_suite_schema_gathers_nothing_by_an_order(devices, op, program, scope):
         if program == "sort":
             assert not re.search(r"\sgather\(", text)
     assert "sort.gather" not in stages.VOCABULARY
+
+
+@pytest.mark.parametrize("seed", [1, 7, 2147530001, 987654321])
+def test_sample_sort_cuts_evenly_filled_bins_at_their_middle(devices, seed):
+    """Uniform keys fill the 64 bins of the sample sort evenly: the bin at
+    a boundary goes where its middle lies, so whatever the seed no shard
+    holds half a bin (1/32 of a shard) more than its share. By the rows in
+    front of the bin, as the reference cuts, most seeds gave one shard a
+    whole bin more (1/16) and with it twice the capacities downstream."""
+    ctx = _ctx(devices, 4)
+    rows = 1 << 16
+    rng = np.random.default_rng(seed)
+    t = ct.Table.from_numpy(ctx, ["k", "v"], [
+        rng.integers(0, rows, rows).astype(np.int64), rng.random(rows),
+    ])
+    before = {
+        name: tracing.snapshot().get(name, {}).get("rows", 0)
+        for name in (
+            "shuffle.range.shard_rows_max", "shuffle.range.shard_rows_mean",
+        )
+    }
+    got = t.distributed_sort("k")
+    after = tracing.snapshot()
+    fullest, mean = (
+        after[name]["rows"] - before[name]
+        for name in before
+    )
+    assert mean == rows // 4
+    assert fullest / mean - 1 < 1 / 32, (fullest, mean)
+    keys = got.to_pandas()["k"].to_numpy()
+    assert (np.diff(keys) >= 0).all() and len(keys) == rows

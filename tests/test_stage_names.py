@@ -217,7 +217,7 @@ def test_outermost_name_is_the_stage(path, stage, engine_in):
 
 
 def test_vocabulary_is_defined_once():
-    assert len(set(stages.VOCABULARY)) == len(stages.VOCABULARY) == 16
+    assert len(set(stages.VOCABULARY)) == len(stages.VOCABULARY) == 18
     constants = {
         v for k, v in vars(stages).items() if k.isupper() and isinstance(v, str)
     }

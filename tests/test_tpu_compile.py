@@ -213,6 +213,65 @@ def _join_step_specs(mesh, key_dtype, val_dtype):
     counts = _spec((world,), jnp.int32, rows)
     return (cols, counts, cols, counts), ()
 
+def test_semi_reduction_compiles_with_two_sorts_and_no_payload(one_chip):
+    """The keys-only program in front of a selective join at TPC-H's key
+    width (int32 keys, a mask on the probe side): two sorts, one of the
+    merged ids with the row's position as its second key and one of a
+    single operand, neither stable (a stable sort would carry an iota),
+    and nothing gathers or scatters a row."""
+
+    def semi(lk, rk, nl, nr, r_mask):
+        l_ids, r_ids = _j._canonical_ids(
+            [(lk, None)], [(rk, None)], nl, nr, ROWS // 8, ROWS
+        )
+        l_live = jnp.arange(ROWS // 8, dtype=jnp.int32) < nl
+        r_live = (jnp.arange(ROWS, dtype=jnp.int32) < nr) & r_mask
+        return _j.semi_hits(l_ids, r_ids, l_live, r_live)
+
+    compiled = _compile(
+        semi,
+        _spec((ROWS // 8,), jnp.int32, one_chip),
+        _spec((ROWS,), jnp.int32, one_chip),
+        _spec((), jnp.int32, one_chip), _spec((), jnp.int32, one_chip),
+        _spec((ROWS,), jnp.bool_, one_chip),
+    )
+    text = compiled.as_text()
+    assert not re.search(r"\s(gather|scatter)\(", text)
+    sorts = re.findall(r"= (\(.*?\)|\S+) sort\(", text)
+    assert len(sorts) == 2, sorts
+    merged = ROWS + ROWS // 8
+    assert sorted(s.count(f"[{merged}]") for s in sorts) == [1, 2], sorts
+    assert "is_stable=true" not in text
+
+
+def test_semi_reduced_sides_gather_at_their_own_capacity(one_chip):
+    """``jit_join_reduce``: the rows with a partner are gathered at the
+    capacities the host chose from their counts (here an eighth and a
+    sixty-fourth of the probe side's), not at the inputs'."""
+    cap_lo, cap_ro = ROWS // 64, ROWS // 8
+
+    def reduce(hits, stats, lkey, rkey, rval):
+        return _j.reduce_by_hits(
+            hits, stats, [(lkey, None)], [(rkey, None), (rval, None)],
+            cap_lo, cap_ro,
+        )
+
+    compiled = _compile(
+        reduce,
+        _spec((ROWS + ROWS // 8,), jnp.int32, one_chip),
+        _spec((4,), jnp.int32, one_chip),
+        _spec((ROWS // 8,), jnp.int32, one_chip),
+        _spec((ROWS,), jnp.int32, one_chip),
+        _spec((ROWS,), jnp.float64, one_chip),
+    )
+    text = compiled.as_text()
+    assert not re.search(r"\ssort\(", text)
+    gathers = re.findall(r"= (\S+) gather\(", text)
+    assert gathers and all(
+        f"[{cap_lo}" in g or f"[{cap_ro}" in g for g in gathers
+    ), gathers
+
+
 
 @pytest.mark.parametrize(
     "key_dtype,val_dtype",
