@@ -316,6 +316,12 @@ STABLE_METRICS: Dict[str, Tuple[str, str]] = {
     "shuffle.exchange": ("span", "whole K-round exchange wall"),
     "shuffle.round.": ("span", "per-round pack/collective/compact dispatch"),
     "shuffle.rounds": ("counter", "round count K per shuffle (rows=K)"),
+    "shuffle.pack.ride_lanes": (
+        "counter", "payload rides of the pack's sort by destination, "
+        "counted at each pack dispatch (rows= 32-bit lanes that rode)"),
+    "shuffle.pack.ride_batches": (
+        "counter", "the same rides (rows= sorts that carried the lanes: 1 "
+        "when they fit one, more past ops/sort.RIDE_LANES)"),
     "shuffle.range.": (
         "counter", "how evenly a range shuffle's sampled splitters cut the "
         "rows, from the counts it fetches anyway: shard_rows_max (rows= "

@@ -11,9 +11,12 @@ composable-rounds thesis, PAPERS.md): the host sizes ``bucket_cap`` from a
 per-round BYTE BUDGET (:func:`plan_rounds`; config.py) so peak exchange
 memory is O(budget) instead of O(max-shard padding), hot buckets drain over
 ``ceil(count/cap)`` rounds, and each round's per-destination send counts
-ride HEADER ROWS of the packed lane buffer (:func:`pack_lane_buffer` /
+ride HEADER ROWS of the packed lane buffer (:func:`pack_by_sort` /
 :func:`split_header`) — one collective per round moves the payload AND the
-counts, so a distributed join issues 2 collectives, not 4. "Reassembly" is
+counts, so a distributed join issues 2 collectives, not 4. The pack moves
+rows by a sort keyed by destination, never by a row-sized scatter
+(:func:`pack_lane_buffer` and :func:`scatter_send` serve the in-program
+pipeline and the forced Pallas codec). "Reassembly" is
 a lane-level compaction argsort (:func:`compact_received_lanes`). The
 round scheduler and double-buffered dispatch live in
 ``table.py _shuffle_many``; the fused pipeline composes the same
@@ -30,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import stages as _stages
+from ..ops import sort as _sort
 from ..ops.gather import (
     lane_plan,
     pack_cols,
@@ -403,6 +407,19 @@ def header_slots(
     ).astype(jnp.int32)
 
 
+def _header_values(
+    counts_round: jax.Array, header_extra: Optional[jax.Array], width: int
+) -> jax.Array:
+    """[P, width] int32: what a chunk's header rows hold, flattened: the
+    round send count in lane 0, then ``header_extra``, zeros after."""
+    hv = jnp.zeros((counts_round.shape[0], width), jnp.int32)
+    hv = hv.at[:, 0].set(counts_round.astype(jnp.int32))
+    if header_extra is not None:
+        E = header_extra.shape[1]
+        hv = hv.at[:, 1 : 1 + E].set(header_extra.astype(jnp.int32))
+    return hv
+
+
 def pack_lane_buffer(
     lanes: List[jax.Array],
     dest: jax.Array,
@@ -428,11 +445,7 @@ def pack_lane_buffer(
                 jnp.arange(num_partitions, dtype=jnp.int32) * rows, 0
             ].set(counts_round.astype(jnp.int32))
         else:
-            hv = jnp.zeros((num_partitions, n_header * L), jnp.int32)
-            hv = hv.at[:, 0].set(counts_round.astype(jnp.int32))
-            if header_extra is not None:
-                E = header_extra.shape[1]
-                hv = hv.at[:, 1 : 1 + E].set(header_extra.astype(jnp.int32))
+            hv = _header_values(counts_round, header_extra, n_header * L)
             hidx = (
                 jnp.arange(num_partitions, dtype=jnp.int32)[:, None] * rows
                 + jnp.arange(n_header, dtype=jnp.int32)[None, :]
@@ -441,6 +454,87 @@ def pack_lane_buffer(
         return buf.at[
             header_slots(dest, num_partitions, bucket_cap, n_header)
     ].set(packed, mode="drop")
+
+
+def _round_windows(
+    counts: jax.Array, num_partitions: int, bucket_cap: int, round_idx
+):
+    """``windows(x [cap]) -> [P, bucket_cap]``: of rows sorted by
+    destination (the stable order of :func:`shuffle_gather_order`), the
+    rows that round ``round_idx`` sends to each destination, zero past
+    that destination's :func:`round_counts`. Destination ``p``'s rows are
+    the contiguous ``[starts[p] + r*cap, ... + rc[p])`` of the sorted
+    array, so each window is one ``dynamic_slice``; ``x`` is padded by a
+    window's length, so that a slice near the end is never shifted back
+    (one past the end reads the padding and is masked anyway)."""
+    starts = jnp.cumsum(counts) - counts
+    offs = starts + jnp.asarray(round_idx, jnp.int32) * bucket_cap
+    rc = round_counts(counts, bucket_cap, round_idx)
+    live = jnp.arange(bucket_cap, dtype=jnp.int32)[None, :] < rc[:, None]
+
+    def windows(x: jax.Array) -> jax.Array:
+        padded = jnp.concatenate([x, jnp.zeros((bucket_cap,), x.dtype)])
+        w = jnp.stack([
+            jax.lax.dynamic_slice(padded, (offs[p],), (bucket_cap,))
+            for p in range(num_partitions)
+        ])
+        return jnp.where(live, w, jnp.zeros((), x.dtype))
+
+    return windows, rc
+
+
+def _ride_by_pid(pid: jax.Array, payloads: Sequence[jax.Array]) -> list:
+    """``payloads`` in the stable order of their rows' destination (the
+    dropped sentinel last): they ride one sort keyed by ``pid``, in
+    batches when there are many (:func:`~cylon_tpu.ops.sort.ride_sort`)."""
+    def by_pid(pays):
+        with jax.named_scope(_stages.SORT_ENGINE):
+            out = jax.lax.sort((pid, *pays), num_keys=1, is_stable=True)
+        return out[0], list(out[1:])
+
+    return _sort.ride_sort(by_pid, payloads)[1]
+
+
+def pack_by_sort(
+    lanes: List[jax.Array],
+    passthrough: Sequence[jax.Array],
+    pid: jax.Array,
+    counts: jax.Array,
+    num_partitions: int,
+    bucket_cap: int,
+    round_idx,
+    header_extra: Optional[jax.Array] = None,
+    n_header: int = HEADER_ROWS,
+) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
+    """The pack phase of a chunked round without a row-sized scatter or
+    gather: every int32 lane and every passthrough column rides ONE stable
+    sort keyed by the row's destination ``pid`` (a sort moves a row in
+    2-4 ns on a v5e where a scatter moves it in 80), and each
+    destination's chunk of round ``round_idx`` (a traced scalar: one
+    program serves every round) is a contiguous window of the sorted rows
+    (:func:`_round_windows`).
+
+    Returns ``(head, pts)`` bit for bit as :func:`build_send_slots_round`
+    + :func:`pack_lane_buffer` + :func:`scatter_send` lay them: ``head``
+    the header-augmented lane buffer ``[P * (bucket_cap + n_header), L]``
+    (``round_counts`` itself when there is no lane), ``pts`` one
+    ``[P * bucket_cap]`` send buffer a passthrough column. The stable sort
+    keeps the arrival order inside a bucket that the slots gave."""
+    with jax.named_scope(_stages.SHUFFLE_PACK):
+        windows, rc = _round_windows(
+            counts, num_partitions, bucket_cap, round_idx
+        )
+        rode = _ride_by_pid(pid, list(lanes) + list(passthrough))
+        pts = tuple(windows(x).reshape(-1) for x in rode[len(lanes):])
+        if not lanes:
+            return rc, pts
+        L = len(lanes)
+        hv = _header_values(rc, header_extra, n_header * L)
+        data = jnp.stack([windows(x) for x in rode[:L]], axis=2)
+        head = jnp.concatenate(
+            [hv.reshape(num_partitions, n_header, L), data], axis=1
+        )
+        return head.reshape(-1, L), pts
 
 
 def exchange_buffer(buf: jax.Array, num_partitions: int, axis_name: str) -> jax.Array:
@@ -494,6 +588,16 @@ def split_header_scales(
 # rows, broadcast back per received row at compact
 # ----------------------------------------------------------------------
 
+def _q8_magnitudes(cols: Cols, wplan) -> List[jax.Array]:
+    """The finite magnitude (f32, 0 for NaN and infinities) of every q8
+    column in field order: what a block scale is the max of."""
+    mags = []
+    for ci, _dt in wire_q8_cols(wplan):
+        x = cols[ci][0].astype(jnp.float32)
+        mags.append(jnp.where(jnp.isfinite(x), jnp.abs(x), jnp.float32(0.0)))
+    return mags
+
+
 def quant_chunk_scales(
     cols: Cols, wplan, dest: jax.Array, num_partitions: int,
     bucket_cap: int,
@@ -507,14 +611,32 @@ def quant_chunk_scales(
 
     chunk = dest // bucket_cap  # sentinel rows -> num_partitions (dropped)
     scales = []
-    for ci, _dt in wire_q8_cols(wplan):
-        x = cols[ci][0].astype(jnp.float32)
-        mag = jnp.where(jnp.isfinite(x), jnp.abs(x), jnp.float32(0.0))
+    for mag in _q8_magnitudes(cols, wplan):
         bm = jnp.zeros((num_partitions,), jnp.float32).at[chunk].max(
             mag, mode="drop"
         )
         scales.append(_q.safe_scale(bm))
     return jnp.stack(scales, axis=1)
+
+
+def quant_chunk_scales_sorted(
+    cols: Cols, wplan, pid: jax.Array, counts: jax.Array,
+    num_partitions: int, bucket_cap: int, round_idx,
+) -> jax.Array:
+    """:func:`quant_chunk_scales` for :func:`pack_by_sort`, which has no
+    row-space ``dest``: the q8 columns' magnitudes ride the same stable
+    sort by destination and each chunk's scale is the max over its round
+    window (the same rows, so the same scale)."""
+    from ..ops import quant as _q
+
+    windows, _rc = _round_windows(counts, num_partitions, bucket_cap, round_idx)
+    return jnp.stack(
+        [
+            _q.safe_scale(jnp.max(windows(mag), axis=1))
+            for mag in _ride_by_pid(pid, _q8_magnitudes(cols, wplan))
+        ],
+        axis=1,
+    )
 
 
 def send_row_scales(

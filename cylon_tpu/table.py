@@ -94,6 +94,23 @@ def _bump_ride(cols: Sequence[KeyCol]) -> None:
     bump("sort.ride_batches", rows=batches)
 
 
+def _bump_pack_ride(plan_sig, wire, n_pt: int) -> None:
+    """The same count at a pack dispatch, over the lane plan: the int32
+    lanes (the wire plan's words when there is one) and the float64
+    passthrough columns that ride the pack's sort by destination
+    (:func:`parallel.shuffle.pack_by_sort`): ``shuffle.pack.ride_lanes``
+    and ``shuffle.pack.ride_batches``."""
+    n_lanes = (
+        wire.n_words if wire is not None
+        else sum(nl + bool(hv) for _tag, nl, hv in plan_sig)
+    )
+    lanes, batches = _sort_mod.ride_census(
+        [np.int32] * n_lanes + [np.float64] * n_pt
+    )
+    bump("shuffle.pack.ride_lanes", rows=lanes)
+    bump("shuffle.pack.ride_batches", rows=batches)
+
+
 def _scalar(x) -> jax.Array:
     """Per-shard [1] arrays carry scalars through shard_map."""
     return x.reshape(1) if hasattr(x, "reshape") else jnp.asarray([x])
@@ -3812,16 +3829,20 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
                     _sh.wire_header_rows(wire) if wire is not None
                     else _sh.HEADER_ROWS
                 )
+                # rows reach the send buffer by a sort keyed by destination
+                # that every lane rides, each destination's chunk a window
+                # of the sorted rows (parallel/shuffle.pack_by_sort); only
+                # a forced Pallas codec yields row-space slots and scatters
+                dest = None
                 if _codec.pack_engaged(kind, semi, has_lanes, n_header, world):
                     # fused hash→partition→slot kernel (ops/pallas_codec):
                     # dest/cnt come out of ONE VMEM pass over the key words;
-                    # the collision-free lane-buffer scatter below is shared
-                    # with the XLA path, so `head` is bit-identical by
-                    # construction. Range/task/semi packs can't replay the
-                    # pid in Mosaic — the XLA pid lane (incl. the semi probe
-                    # rewrite above) feeds the same kernel and histogram +
-                    # rank + slot still fuse; in hash mode `pid` above is
-                    # dead and DCE'd.
+                    # the lane-buffer scatter below is the XLA chain's, so
+                    # `head` is bit-identical by construction. Range/task/
+                    # semi packs can't replay the pid in Mosaic — the XLA
+                    # pid lane (incl. the semi probe rewrite above) feeds
+                    # the same kernel and histogram + rank + slot still
+                    # fuse; in hash mode `pid` above is dead and DCE'd.
                     if _codec.pack_fuses_hash(kind, semi):
                         words, valids, hv = _codec.hash_operands(list(kcols))
                         dest, cnt = _codec.fused_pack_dest(
@@ -3835,10 +3856,6 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
                         )
                 else:
                     cnt = _sh.bucket_counts(pid, world)
-                    dest, _leftover = _sh.build_send_slots_round(
-                        pid, cnt, world, bc, rnd
-                    )
-                rc = _sh.round_counts(cnt, bc, rnd)
                 hx = None
                 if wire is not None:
                     # bit-width-adaptive wire narrowing: lanes are the packed
@@ -3850,10 +3867,17 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
                     # (widened) header rows beside the counts (n_header above).
                     qrows = None
                     if _g_pack.wire_q8_cols(wire):
-                        scales = _sh.quant_chunk_scales(
-                            cols, wire, dest, world, bc
-                        )
-                        qrows = _sh.send_row_scales(scales, dest, bc)
+                        if dest is None:
+                            # a row's chunk is its destination
+                            scales = _sh.quant_chunk_scales_sorted(
+                                cols, wire, pid, cnt, world, bc, rnd
+                            )
+                            qrows = _sh.send_row_scales(scales, pid, 1)
+                        else:
+                            scales = _sh.quant_chunk_scales(
+                                cols, wire, dest, world, bc
+                            )
+                            qrows = _sh.send_row_scales(scales, dest, bc)
                         hx = jax.lax.bitcast_convert_type(scales, jnp.int32)
                     lanes, passthrough = _g_pack.wire_pack_cols(
                         list(cols), wire, bases, qscales=qrows
@@ -3862,15 +3886,18 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
                 else:
                     _plan, lanes, passthrough = _g_pack.pack_cols(list(cols))
                     pt_eff = pt_order
-                if lanes:
+                if dest is None:
                     # the fused count/payload exchange: this round's per-
-                    # destination send counts ride the lane buffer's header row
-                    head = _sh.pack_lane_buffer(
-                        lanes, dest, rc, world, bc,
-                        header_extra=hx, n_header=n_header,
+                    # destination send counts ride the lane buffer's header
+                    # row (a pure-f64 table has none: `head` is the counts)
+                    return _sh.pack_by_sort(
+                        lanes, [passthrough[ci] for ci in pt_eff], pid, cnt,
+                        world, bc, rnd, header_extra=hx, n_header=n_header,
                     )
-                else:
-                    head = rc  # pure-f64 table: dedicated count lane
+                head = _sh.pack_lane_buffer(
+                    lanes, dest, _sh.round_counts(cnt, bc, rnd), world, bc,
+                    header_extra=hx, n_header=n_header,
+                )
                 pts = tuple(
                     _sh.scatter_send(passthrough[ci], dest, world, bc)
                     for ci in pt_eff
@@ -4844,6 +4871,11 @@ def _shuffle_many_rounds(states, rows_total) -> List["Table"]:
                     )
 
                 cimpl = _codec.resolved_impl()
+                if not (cimpl == "pallas" and pk_sup):
+                    # the pack just dispatched was the sort-and-slice one
+                    _bump_pack_ride(
+                        st["plan_sig"], st["wire"], len(st["pt_eff"])
+                    )
                 st["codec_impls"] = (
                     ("pallas" if fuse_hash else "pallas_pid")
                     if cimpl == "pallas" and pk_sup else "xla",

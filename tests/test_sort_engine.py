@@ -672,6 +672,82 @@ def test_suite_schema_gathers_nothing_by_an_order(devices, op, program, scope):
     assert "sort.gather" not in stages.VOCABULARY
 
 
+@pytest.mark.parametrize("op", ["join", "sort", "shuffle"])
+def test_pack_rides_one_sort_under_the_engine(devices, op):
+    """Every kind of shuffle (hash with the semi filter, range, plain
+    hash) packs by the ride sort: ``jit_shuffle_pack`` holds one sort,
+    under ``sort_engine`` within ``shuffle.pack``, that carries more than
+    the partition ids and an order, and the scatters left are histograms
+    (the bucket counts into ``[world]``, the range partition's 64 bins)."""
+    packs = [
+        fn.lower(*spec).compile().as_text()
+        for name, fn, spec in _dispatched(devices, op, 4)
+        if name == "shuffle_pack"
+    ]
+    assert packs
+    for text in packs:
+        _module, rows = stages.parse_compiled(text)
+        sorts = [(t, o) for t, o in rows if _SORT.search(t)]
+        assert len(sorts) == 1, sorts
+        sort_text, op_name = sorts[0]
+        assert stages.in_sort_engine(op_name), op_name
+        assert stages.stage_of(op_name) == stages.SHUFFLE_PACK, op_name
+        operands = re.findall(r"\w+\[\d+\]", sort_text.split(" sort(")[0])
+        assert len(operands) > 2, operands
+        scattered = re.findall(r"= \w+\[([\d,]*)\]\S* scatter\(", text)
+        assert scattered and all(
+            n.isdigit() and int(n) <= 64 for n in scattered
+        ), scattered
+
+
+def test_pack_dispatch_counts_what_rides(devices, rng, monkeypatch):
+    """A four-shard ``distributed_join`` bumps ``shuffle.pack.ride_lanes``
+    and ``shuffle.pack.ride_batches`` once a pack dispatch, by
+    ``ride_census`` of what that table's pack really carried (seen where
+    the program is traced: its int32 lanes and float64 passthroughs)."""
+    traced = []
+    real = shuffle_ops.pack_by_sort
+
+    def spy(lanes, passthrough, *args, **kwargs):
+        traced.append(sort_ops.ride_census(
+            [a.dtype for a in list(lanes) + list(passthrough)]
+        ))
+        return real(lanes, passthrough, *args, **kwargs)
+
+    monkeypatch.setattr(shuffle_ops, "pack_by_sort", spy)
+    ctx = _ctx(devices, 4)
+    # keys over all 64 bits, so that no wire plan narrows them: the lane
+    # plan alone says what rides (two key lanes, the value's two halves)
+    pool = rng.integers(-2**62, 2**62, 300)
+    ta, tb = (
+        ct.Table.from_numpy(
+            ctx, ["k", name], [rng.choice(pool, 1500), rng.random(1500)]
+        )
+        for name in ("v", "w")
+    )
+
+    def counted():
+        got = tracing.report("shuffle.")
+        return tuple(
+            int(got[name][field]) if name in got else 0
+            for name, field in (
+                ("shuffle.pack.ride_lanes", "rows"),
+                ("shuffle.pack.ride_batches", "rows"),
+                ("shuffle.pack.ride_lanes", "count"),
+                ("shuffle.round.pack", "count"),
+            )
+        )
+
+    before = counted()
+    assert ta.distributed_join(tb, on="k", how="inner").row_count > 0
+    lanes, batches, bumps, dispatches = (
+        a - b for a, b in zip(counted(), before)
+    )
+    assert traced == [(4, 1), (4, 1)]  # one program a table
+    assert bumps == dispatches == 2  # one round a table
+    assert (lanes, batches) == tuple(map(sum, zip(*traced)))
+
+
 @pytest.mark.parametrize("seed", [1, 7, 2147530001, 987654321])
 def test_sample_sort_cuts_evenly_filled_bins_at_their_middle(devices, seed):
     """Uniform keys fill the 64 bins of the sample sort evenly: the bin at

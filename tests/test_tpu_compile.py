@@ -130,6 +130,73 @@ def test_xla_pack_chain_compiles_for_tpu(one_chip):
     assert "tpu_custom_call" not in compiled.as_text()
 
 
+def _suite_pack(key, val, n, rnd):
+    """One shard's pack at the suite's widths (int64 key, float64 value,
+    x64 on) as ``build_pack`` makes it on the default path."""
+    from cylon_tpu.ops import gather as _gather
+
+    pid = _p.hash_partition_ids([(key, None)], n, WORLD)
+    cnt = _sh.bucket_counts(pid, WORLD)
+    _plan, lanes, passthrough = _gather.pack_cols([(key, None), (val, None)])
+    return _sh.pack_by_sort(
+        lanes, [passthrough[1]], pid, cnt, WORLD, ROWS // WORLD, rnd
+    )
+
+
+def _assert_pack_rides_a_sort(text, rows):
+    """The compiled pack of ``rows`` rows a shard: nothing scatters or
+    gathers an array of the rows' or the send buffers' size (the bucket
+    counts scatter into ``[WORLD]``), and one stable sort under
+    ``sort_engine`` within ``shuffle.pack`` carries the key's two lanes
+    and the value's two halves behind the partition ids."""
+    from cylon_tpu.obs import stages
+
+    moved = re.findall(r"= (\S+) (?:scatter|gather)\(", text)
+    assert all(re.match(r"\w+\[\d{1,3}\]", shape) for shape in moved), moved
+    _module, parsed = stages.parse_compiled(text)
+    sorts = [(t, op) for t, op in parsed if re.search(r"\ssort\(", t)]
+    assert len(sorts) == 1, sorts
+    sort_text, op_name = sorts[0]
+    assert stages.in_sort_engine(op_name), op_name
+    assert stages.stage_of(op_name) == stages.SHUFFLE_PACK, op_name
+    shape = sort_text.split(" sort(")[0]
+    assert shape.count(f"[{rows}]") >= 5 and shape.count(f"f32[{rows}]") == 2, shape
+
+
+def test_sorted_pack_compiles_for_tpu_without_a_row_sized_scatter(one_chip):
+    compiled = _compile(
+        _suite_pack,
+        _spec((ROWS,), jnp.int64, one_chip),
+        _spec((ROWS,), jnp.float64, one_chip),
+        _spec((), jnp.int32, one_chip),
+        _spec((), jnp.int32, one_chip),
+    )
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    _assert_pack_rides_a_sort(text, ROWS)
+    # the scatter chain held the slots, the order and both buffers' updates
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * ROWS
+
+
+def test_sorted_pack_compiles_inside_the_four_chip_shard_map(mesh4):
+    rows = NamedSharding(mesh4, PartitionSpec("dp"))
+
+    def kern(key, val, counts, rnd):
+        return _suite_pack(key, val, counts[0], rnd[0])
+
+    step = jax.jit(jax.shard_map(
+        kern, mesh=mesh4, in_specs=PartitionSpec("dp"),
+        out_specs=PartitionSpec("dp"),
+    ))
+    compiled = step.lower(
+        _spec((WORLD * STEP_ROWS,), jnp.int64, rows),
+        _spec((WORLD * STEP_ROWS,), jnp.float64, rows),
+        _spec((WORLD,), jnp.int32, rows),
+        _spec((WORLD,), jnp.int32, rows),
+    ).compile()
+    _assert_pack_rides_a_sort(compiled.as_text(), STEP_ROWS)
+
+
 # ----------------------------------------------------------------------
 # (c) the sort engine
 # ----------------------------------------------------------------------
