@@ -326,6 +326,18 @@ STABLE_METRICS: Dict[str, Tuple[str, str]] = {
         "counter", "how evenly a range shuffle's sampled splitters cut the "
         "rows, from the counts it fetches anyway: shard_rows_max (rows= "
         "the fullest shard's) and shard_rows_mean (rows= the mean)"),
+    "shuffle.hash.": (
+        "counter", "how unevenly a hash shuffle spread the rows, from the "
+        "count matrix chosen after the semi filter's decision: "
+        "shard_rows_max (rows= the fullest shard's) and shard_rows_mean "
+        "(rows= the mean)"),
+    "shuffle.coll_slots": (
+        "counter", "row slots a shuffle's collective rounds ship (rows= "
+        "K x world^2 x cap), beside shuffle.exchanged_bytes"),
+    "shuffle.coll_rows": (
+        "counter", "rows those rounds carry (rows= the chosen count "
+        "matrix's sum less a skew-split schedule's relayed tail): over "
+        "shuffle.coll_slots it is how full the exchange's buffers are"),
     "shuffle.overlap_efficiency": (
         "gauge", "fraction of the measured exchange device window "
         "(dispatch-open to the deferred round-count fetch return) spent "

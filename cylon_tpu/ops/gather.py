@@ -614,6 +614,34 @@ def host_unpack_cols(plan, lane_cols, handle_passthrough):
     return out
 
 
+#: index rows one packed gather emits. Out of a source much shorter than
+#: the index vector the TPU lays the gathered ``[rows, L]`` matrix out with
+#: its L lanes padded to a tile's 128: 512 bytes a row whatever L is, and as
+#: much again for each lane's ``[rows, 1]`` slice. That is 4 GiB a buffer at
+#: this many rows and 8 GiB at the 2^24 slots that the fullest shard of a
+#: skewed join of 32,000,000 rows gives every chip, where the compiler
+#: refuses the program (16.13 GB of a v5e's 15.75). A longer index vector is
+#: gathered block by block; up to this length the program is the one gather
+#: it always was.
+PACK_GATHER_BLOCK = 1 << 23
+
+
+def _gather_packed(packed: jax.Array, safe: jax.Array) -> List[jax.Array]:
+    """The lanes of ``packed[safe]``, each ``[len(safe)]``: ONE gather, or
+    one a block of ``PACK_GATHER_BLOCK`` index rows where there are more."""
+    n, n_lanes = safe.shape[0], packed.shape[1]
+
+    def rows(idx):
+        g = packed[idx]
+        return [g[:, j] for j in range(n_lanes)]
+
+    if n <= PACK_GATHER_BLOCK:
+        return rows(safe)
+    pad = -n % PACK_GATHER_BLOCK
+    blocks = jnp.pad(safe, (0, pad)).reshape(-1, PACK_GATHER_BLOCK)
+    return [g.reshape(-1)[:n] for g in jax.lax.map(rows, blocks)]
+
+
 def pack_gather(
     cols: Sequence[KeyCol],
     idx: jax.Array,
@@ -642,9 +670,7 @@ def pack_gather(
     if len(lanes) == 1:
         g_cols = [lanes[0][safe]]
     elif lanes:
-        packed = jnp.stack(lanes, axis=1)  # [cap, L]
-        g = packed[safe]  # ONE gather
-        g_cols = [g[:, j] for j in range(len(lanes))]
+        g_cols = _gather_packed(jnp.stack(lanes, axis=1), safe)  # [cap, L]
     else:
         g_cols = []
 

@@ -42,6 +42,18 @@ RAM, and land directly on their owner shard. A one-hot distribution then
 ships O(rows) bytes instead of O(world x max-bucket) — ``_shuffle_many``
 emits the traced ``shuffle.skew_split`` counter and non-skewed plans stay
 byte-identical to :func:`~cylon_tpu.parallel.shuffle.plan_rounds`.
+
+From which world size on the split can engage: a destination is heavy
+when one of its buckets is OVER ``SKEW_MIN_RATIO`` = 4 times the mean
+bucket. Sources that hold equal rows R (every table loaded by an even row
+split) give a mean bucket of ``R / world``, and no bucket holds more than
+R, so on up to four shards nothing is ever heavy: an eager shuffle there
+pays for skew with rounds and padded slots alone (``shuffle.coll_rows``
+over ``shuffle.coll_slots`` says how many), and the split and the host
+relay start at five shards, in practice eight. Only unevenly loaded
+sources or a tuned trigger under 4 (``plan/feedback.tuned_skew_trigger``,
+inside a lowered plan) engage it on four.
+``tests/test_fkjoin_skew.py`` holds both halves of that.
 """
 from __future__ import annotations
 
@@ -363,6 +375,11 @@ def plan_schedule(
     the same destinations, results are bit-identical either way — and
     the tuned value rides the plan fingerprint (the Decisions component)
     so a flip recompiles, never aliases.
+
+    With the static trigger the split engages from five shards on: over
+    ``world`` sources of equal rows R the mean bucket is ``R / world``
+    and a bucket is at most R, which is never over 4 times the mean while
+    ``world <= 4`` (the module docstring).
     """
     cap0, k0 = _sh.plan_rounds(
         send_counts, row_bytes, world, byte_budget, max_rounds

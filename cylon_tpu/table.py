@@ -2079,7 +2079,18 @@ class Table:
         the per-shard join; fused mode rejects a non-default ``algorithm``
         (its join is baked into the fused program).
         Undersized capacities are detected via the overflow flag and retried
-        with doubled capacities (no wrong answers, just a recompile)."""
+        with doubled capacities (no wrong answers, just a recompile).
+
+        Capacity under skew: one program runs on every shard (SPMD), so
+        every shard of a shuffled side has the slots of the FULLEST one,
+        ``round_cap`` (the next power of two) of its received rows, and the
+        result's shards have ``round_cap(max(cap_l, cap_r))`` slots, the
+        speculative capacity that covers any join of at most one match a
+        key. A foreign key that sends half the rows to one of four shards
+        therefore gives all four the slots of that half, about half of
+        them empty (``join.emit_rows`` over ``join.emit_slots``), and every
+        sort and gather of the local join runs at that width on every
+        chip."""
         if on is not None:
             kwargs["on"] = on
         kwargs.setdefault("how", how)
@@ -4290,15 +4301,6 @@ def _shuffle_many(specs: Sequence["_ShuffleSpec"]) -> List["Table"]:
             st["use_filter"] = False
             st["send_counts"] = got[:, :w]  # [src, dst]
             base = w
-            if spec.kind == "range":
-                # how evenly the sampled splitters cut the rows: the
-                # fullest shard and the mean, from the counts just fetched
-                recv = st["send_counts"].sum(axis=0)
-                bump("shuffle.range.shard_rows_max", rows=int(recv.max()))
-                bump(
-                    "shuffle.range.shard_rows_mean",
-                    rows=int(round(float(recv.mean()))),
-                )
         # global column range stats measured by the count pass: fold the
         # per-shard words, cache on the INPUT table (later local ops on it
         # skip the stats kernel) and remember them for the wire plan and
@@ -4568,6 +4570,27 @@ def _shuffle_many(specs: Sequence["_ShuffleSpec"]) -> List["Table"]:
             annotate_add(relay_bytes=relay_bytes)
         st["new_counts"] = st["send_counts"].sum(axis=0).astype(np.int64)
         bump("shuffle.rounds", rows=st["n_rounds"])
+        # how full the rounds' buffers are: the slots the collective rounds
+        # ship (K x world^2 x cap, however empty a cold bucket is) and the
+        # rows they carry (a skew-split schedule's relay tail rides none)
+        bump("shuffle.coll_slots", rows=sched.coll_row_slots(w))
+        bump(
+            "shuffle.coll_rows",
+            rows=int(st["new_counts"].sum()) - sched.relay_rows(),
+        )
+        kind = st["spec"].kind
+        if kind in ("hash", "range"):
+            # how unevenly the rows came out (a hash's skew after the semi
+            # filter's decision, the sampled splitters' cut): the fullest
+            # shard and the mean, from the counts the host fetched anyway
+            bump(
+                f"shuffle.{kind}.shard_rows_max",
+                rows=int(st["new_counts"].max()),
+            )
+            bump(
+                f"shuffle.{kind}.shard_rows_mean",
+                rows=int(round(float(st["new_counts"].mean()))),
+            )
         st["rounds_out"] = []
         # spill-tier decision from the same measured counts: per-shard
         # staged-output bytes vs the device spill budget (the forced knob

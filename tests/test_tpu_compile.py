@@ -265,6 +265,39 @@ def test_join_right_sort_compiles_with_its_64bit_columns_riding(one_chip):
     assert len(sorts) == 1 and sorts[0].count("f32[") == 2, sorts
 
 
+def test_packed_gather_of_a_skewed_joins_slots_fits_the_chip(
+    one_chip, monkeypatch
+):
+    """The emit's packed gathers at the 2^24 output slots that the fullest
+    shard of a skewed join of 32,000,000 rows (twice ``join-skew-w4``'s)
+    gives every chip, out of the 2^18-slot build side. A gathered [rows, L]
+    matrix is laid out with L padded to 128 lanes, 8 GiB here, and the whole
+    join program was refused (16.13 GB of 15.75); in blocks of
+    ``PACK_GATHER_BLOCK`` rows it keeps one block's."""
+    from cylon_tpu.ops import gather as _gather
+
+    def temp_gib():
+        def emit(key, val, base, cnt, idx):
+            return _gather.pack_gather(
+                [(key, None), (val, None)], idx, extra_lanes=[base, cnt]
+            )
+
+        side = 1 << 18
+        compiled = _compile(
+            emit,
+            _spec((side,), jnp.int64, one_chip),
+            _spec((side,), jnp.float64, one_chip),
+            _spec((side,), jnp.int32, one_chip),
+            _spec((side,), jnp.int32, one_chip),
+            _spec((1 << 24,), jnp.int32, one_chip),
+        )
+        return compiled.memory_analysis().temp_size_in_bytes / 2**30
+
+    assert temp_gib() < 5.0
+    monkeypatch.setattr(_gather, "PACK_GATHER_BLOCK", 1 << 25)
+    assert temp_gib() > 7.5  # what one gather of all the rows would hold
+
+
 # ----------------------------------------------------------------------
 # (d), (e) the local sort join at both dtype widths; (g) the distributed
 # join step on the four-chip mesh
