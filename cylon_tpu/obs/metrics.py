@@ -338,6 +338,11 @@ STABLE_METRICS: Dict[str, Tuple[str, str]] = {
         "counter", "rows those rounds carry (rows= the chosen count "
         "matrix's sum less a skew-split schedule's relayed tail): over "
         "shuffle.coll_slots it is how full the exchange's buffers are"),
+    "shuffle.reassemble.": (
+        "counter", "a shuffle of more than one round, or with a relay or "
+        "ring tail, reassembling its parts in one program (.parts rows= "
+        "the blocks written, .rows rows= the live rows placed); a "
+        "one-round shuffle bumps neither"),
     "shuffle.overlap_efficiency": (
         "gauge", "fraction of the measured exchange device window "
         "(dispatch-open to the deferred round-count fetch return) spent "

@@ -41,6 +41,7 @@ SHUFFLE_COUNT = "shuffle.count"
 SHUFFLE_PACK = "shuffle.pack"
 SHUFFLE_ALL_TO_ALL = "shuffle.all_to_all"
 SHUFFLE_COMPACT = "shuffle.compact"
+SHUFFLE_REASSEMBLE = "shuffle.reassemble"
 SEMI_SKETCH = "semi.sketch"
 GROUPBY_SEGMENT_SUM = "groupby.segment_sum"
 GROUPBY_KEY_IDS = "groupby.key_ids"
@@ -51,6 +52,7 @@ VOCABULARY = (
     JOIN_KEY_IDS, JOIN_RIGHT_SORT, JOIN_PROBE, JOIN_EMIT, JOIN_SEMI,
     SORT_KEYS, SORT_PERM, SORT_TOPK, SORT_ENGINE,
     SHUFFLE_COUNT, SHUFFLE_PACK, SHUFFLE_ALL_TO_ALL, SHUFFLE_COMPACT,
+    SHUFFLE_REASSEMBLE,
     SEMI_SKETCH, GROUPBY_SEGMENT_SUM, GROUPBY_KEY_IDS, GROUPBY_DENSE_AGG,
     EXPR_EVAL,
 )

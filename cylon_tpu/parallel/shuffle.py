@@ -884,3 +884,54 @@ def compact_received(
             (d, None if ov is None else v)
             for (d, v), (_, ov) in zip(gathered, cols)
         ]
+
+
+def reassemble_blocks(
+    parts: Sequence[Cols], counts: Sequence[jax.Array], cap_out: int
+) -> List[Tuple[jax.Array, Optional[jax.Array]]]:
+    """Row-wise concat of K same-schema parts, one shard's view: part k's
+    whole buffer is written as ONE block at the running offset of the
+    ``counts`` (the parts' live rows) before it, in order. A part's live
+    rows are a contiguous prefix of its buffer (the compact kernel leaves
+    them so), so its dead tail lands where the next part's block
+    overwrites it, or past the total; no row is addressed on its own.
+
+    A column's dtype is the promotion of the parts'; it has a validity
+    lane where any part's has (blocks of ones for the parts without).
+    Slots past the total are zero, and invalid."""
+    with jax.named_scope(_stages.SHUFFLE_REASSEMBLE):
+        offs, total = [], jnp.int32(0)
+        for n in counts:
+            offs.append(total)
+            total = total + n
+        live = jnp.arange(cap_out, dtype=jnp.int32) < total
+
+        def place(blocks, dtype):
+            # dynamic_update_slice CLAMPS a start so that the update
+            # fits: the scratch overhangs the output by the largest part,
+            # so no start (at most the total, itself at most ``cap_out``)
+            # is ever moved. The mask zeroes what the last block's dead
+            # tail left past the total.
+            over = max(b.shape[0] for b in blocks)
+            buf = jnp.zeros((cap_out + over,), dtype)
+            for b, off in zip(blocks, offs):
+                buf = jax.lax.dynamic_update_slice(
+                    buf, b.astype(dtype), (off,)
+                )
+            return jnp.where(live, buf[:cap_out], jnp.zeros((), dtype))
+
+        out = []
+        for cols in zip(*parts):
+            common = jnp.result_type(*[d.dtype for d, _v in cols])
+            data = place([d for d, _v in cols], common)
+            valid = None
+            if any(v is not None for _d, v in cols):
+                valid = place(
+                    [
+                        jnp.ones(d.shape, jnp.bool_) if v is None else v
+                        for d, v in cols
+                    ],
+                    jnp.bool_,
+                )
+            out.append((data, valid))
+        return out

@@ -2515,7 +2515,7 @@ class Table:
             ca.dtype != cb.dtype
             for ca, cb in zip(a._columns.values(), b._columns.values())
         ):
-            # mixed-dtype schemas need _concat2's per-column promotion of
+            # mixed-dtype schemas need the concat's per-column promotion of
             # the RESULT dtype; keep the concat+unique path for that edge
             return _concat_tables([a, b]).unique()
         lflat = a._flat_cols()
@@ -5045,7 +5045,15 @@ def _shuffle_many_rounds(states, rows_total) -> List["Table"]:
                 parts = round_tables + (
                     [relay_tbl] if relay_tbl is not None else []
                 ) + ([ring_tbl] if ring_tbl is not None else [])
-                res = parts[0] if len(parts) == 1 else _concat_tables(parts)
+                if len(parts) > 1:
+                    # that the reassembly ran, and over what: the blocks
+                    # one program wrote and the live rows they placed
+                    bump("shuffle.reassemble.parts", rows=len(parts))
+                    bump(
+                        "shuffle.reassemble.rows",
+                        rows=sum(int(p.row_counts.sum()) for p in parts),
+                    )
+                res = _concat_tables(parts)
                 # compact when the uniform bucket sizing overshot; any
                 # input sortedness is gone — rows arrive source-major per
                 # round and K-round chunks interleave
@@ -5321,6 +5329,20 @@ def promote_encoded_shards(shards: List["OrderedDict[str, Tuple]"]) -> None:
                 s[name] = (data.astype(np.float64), valid, DataType(Type.DOUBLE), None)
 
 
+def _dict_union(a: np.ndarray, b: np.ndarray) -> np.ndarray:
+    """Union of two dictionaries. Both are sorted and unique (the Column
+    invariant): the native merge is O(sum) where union1d re-sorts the
+    concat every fold."""
+    from . import native as _native
+
+    got = _native.dict_union(np.asarray(a), np.asarray(b))
+    return got[0] if got is not None else np.union1d(a, b)
+
+
+def _same_dict(a: np.ndarray, b: np.ndarray) -> bool:
+    return a is b or (len(a) == len(b) and bool((a == b).all()))
+
+
 def unify_encoded_shards(shards: List["OrderedDict[str, Tuple]"]) -> None:
     """Promote disagreeing types, then remap per-shard dictionary codes onto
     the union dictionary in place, so string columns from different shards
@@ -5329,18 +5351,13 @@ def unify_encoded_shards(shards: List["OrderedDict[str, Tuple]"]) -> None:
     live = [s for s in shards if s is not None]
     if not live:
         return
-    from . import native as _native
-
     for name in list(live[0].keys()):
         if not live[0][name][2].is_dictionary:
             continue
         dicts = [s[name][3] for s in live]
         union = dicts[0]
         for d in dicts[1:]:
-            # per-shard dictionaries are sorted+unique: the native merge is
-            # O(sum) where union1d re-sorts the concat every fold
-            got = _native.dict_union(np.asarray(union), np.asarray(d))
-            union = got[0] if got is not None else np.union1d(union, d)
+            union = _dict_union(union, d)
         for s in live:
             data, valid, dtype, d = s[name]
             remap = np.searchsorted(union, d).astype(np.int32)
@@ -5419,6 +5436,9 @@ def _agg_name(oid: int) -> str:
 
 
 def _remap_codes(col: Column, mapping: np.ndarray, dictionary: np.ndarray) -> Column:
+    if not len(mapping):
+        # an empty dictionary codes no live row: nothing to look up
+        return Column(col.data, col.dtype, col.valid, dictionary)
     m = jnp.asarray(mapping)
     data = m[jnp.clip(col.data, 0, len(mapping) - 1)]
     return Column(data, col.dtype, col.valid, dictionary)
@@ -5440,10 +5460,7 @@ def _unify_dict_pair(
             raise ValueError(f"cannot join string key {an!r} with numeric key {bn!r}")
         if not (ca.dtype.is_dictionary and cb.dtype.is_dictionary):
             continue
-        if ca.dictionary is cb.dictionary or (
-            len(ca.dictionary) == len(cb.dictionary)
-            and (ca.dictionary == cb.dictionary).all()
-        ):
+        if _same_dict(ca.dictionary, cb.dictionary):
             continue
         union, map_a, map_b = unify_dictionaries(ca, cb)
         new_a[an] = _remap_codes(ca, map_a, union)
@@ -5507,73 +5524,95 @@ def _promote_key_pair(
     )
 
 
+#: tables one reassembly program takes. A longer list (``parallel/dag``'s
+#: chunk outputs, a public ``concat`` of many tables) is folded in groups of
+#: this many, so the programs a context compiles differ in at most this
+#: many part counts a schema. Not a knob.
+CONCAT_FAN_IN = 16
+
+
 def _concat_tables(tables: Sequence["Table"]) -> "Table":
     """Row-wise concat of same-schema tables, per shard (reference Merge,
-    table.cpp:267-289). Balanced binary-tree fold: O(k log k) copy volume
-    over k chunks instead of the O(k^2) of a linear accumulator fold."""
+    table.cpp:267-289): every shard's output is its rows of the first
+    table, then of the second, and so on. A shuffle of more than one round
+    reassembles its rounds' outputs here (round-major, then the relay and
+    the ring table), and every public concat lands here too."""
+    tables = list(tables)
     assert len(tables) >= 1
+    while len(tables) > 1:
+        tables = [
+            _concat_rows(tables[i:i + CONCAT_FAN_IN])
+            for i in range(0, len(tables), CONCAT_FAN_IN)
+        ]
+    return tables[0]
+
+
+def _unify_dicts(tables: List["Table"]) -> List["Table"]:
+    """Remap every dictionary column of ``tables`` onto the union of the
+    tables' dictionaries for it. Tables that already hold one dictionary (a
+    shuffle's rounds hold the same object) come back as they are."""
+    remapped = [{} for _ in tables]  # a table: name -> its remapped column
+    for name in tables[0].column_names:
+        parts = [t._columns[name] for t in tables]
+        is_dict = parts[0].dtype.is_dictionary
+        if any(c.dtype.is_dictionary != is_dict for c in parts):
+            raise ValueError(
+                f"cannot concat string column {name!r} with a numeric one"
+            )
+        if not is_dict:
+            continue
+        union = parts[0].dictionary
+        for c in parts[1:]:
+            if not _same_dict(c.dictionary, union):
+                union = _dict_union(union, c.dictionary)
+        for new, c in zip(remapped, parts):
+            if not _same_dict(c.dictionary, union):
+                remap = np.searchsorted(union, c.dictionary).astype(np.int32)
+                new[name] = _remap_codes(c, remap, union)
+    # as in _unify_dict_pair: the remap keeps code order, so a sortedness
+    # descriptor survives; range stats only where the codes were not rewritten
+    return [
+        t if not new else t._replace(
+            columns=OrderedDict(t._columns, **new)
+        )._attach_ordering(t._ordering)._attach_stats(
+            {n: v for n, v in t._stats.items() if n not in new}
+        )
+        for t, new in zip(tables, remapped)
+    ]
+
+
+def _concat_rows(tables: List["Table"]) -> "Table":
+    """One program for all K tables: each one's buffers go as blocks at its
+    running row offset (:func:`parallel.shuffle.reassemble_blocks`)."""
     if len(tables) == 1:
         return tables[0]
-    mid = len(tables) // 2
-    a = _concat_tables(tables[:mid])
-    b = _concat_tables(tables[mid:])
-    a2, b2 = _unify_dict_pair(a, b, a.column_names, b.column_names)
-    return _concat2(a2, b2)
-
-
-def _concat2(a: "Table", b: "Table") -> "Table":
-    ctx = a.ctx
-    names = a.column_names
-    if names != b.column_names:
+    names = tables[0].column_names
+    if any(t.column_names != names for t in tables[1:]):
         raise ValueError("concat requires identical schemas")
-    new_counts = a.row_counts + b.row_counts
+    tables = _unify_dicts(tables)
+    first = tables[0]
+    new_counts = sum(t.row_counts for t in tables)
     cap_out = round_cap(int(new_counts.max()))
-    aflat = a._flat_cols()
-    bflat = b._flat_cols()
-    key = ("concat2", len(aflat))
+    flats = [t._flat_cols() for t in tables]
+    key = ("shuffle_reassemble", len(tables), len(names))
 
     def build():
         def kern(dp, rep):
-            (ac, bc, na, nb) = dp
+            (parts, counts) = dp
             (dummy,) = rep
-            co = dummy.shape[0]
-            cap_a = ac[0][0].shape[0]
-            cap_b = bc[0][0].shape[0]
-            na0, nb0 = na[0], nb[0]
-            ia = jnp.arange(cap_a, dtype=jnp.int32)
-            ib = jnp.arange(cap_b, dtype=jnp.int32)
-            dest_a = jnp.where(ia < na0, ia, co)
-            dest_b = jnp.where(ib < nb0, na0 + ib, co)
-            out = []
-            for (da, va), (db, vb) in zip(ac, bc):
-                common = jnp.promote_types(da.dtype, db.dtype)
-                buf = jnp.zeros((co,), common)
-                buf = buf.at[dest_a].set(da.astype(common), mode="drop")
-                buf = buf.at[dest_b].set(db.astype(common), mode="drop")
-                if va is None and vb is None:
-                    vout = None
-                else:
-                    vam = jnp.ones((cap_a,), bool) if va is None else va
-                    vbm = jnp.ones((cap_b,), bool) if vb is None else vb
-                    vbuf = jnp.zeros((co,), bool)
-                    vbuf = vbuf.at[dest_a].set(vam, mode="drop")
-                    vbuf = vbuf.at[dest_b].set(vbm, mode="drop")
-                    vout = vbuf
-                out.append((buf, vout))
-            return out, _scalar(na0 + nb0)
+            return _sh.reassemble_blocks(
+                parts, [n[0] for n in counts], dummy.shape[0]
+            )
 
         return kern
 
-    out, _nout = get_kernel(ctx, key, build)(
-        (aflat, bflat, a.counts_dev, b.counts_dev),
+    out = get_kernel(first.ctx, key, build)(
+        (flats, [t.counts_dev for t in tables]),
         (jnp.zeros((cap_out,), jnp.int8),),
     )
-    # new_counts is already known on the host (sum of the inputs' counts):
-    # fetching the kernel's count lane here was a redundant device->host
-    # sync on every multi-round shuffle's reassembly — flagged by the
-    # graft-lint host-sync pass (analysis/hostsync.py) and removed
-    return a._rebuild_cols(
-        list(zip(names, a._columns.values())), out, new_counts, cap_out
+    # the counts are the sum of the inputs', which the host holds: no fetch
+    return first._rebuild_cols(
+        list(zip(names, first._columns.values())), out, new_counts, cap_out
     )
 
 

@@ -208,6 +208,7 @@ def test_stale_compiled_text_is_reported(how, monkeypatch):
     ("jit(join_spec)/join.right_sort/sort_engine/sort", "join.right_sort", True),
     ("jit(f)/sort_engine/sort", "sort_engine", True),
     ("jit(shuffle_pack)/shard_map/shuffle.pack/semi.sketch/gather", "shuffle.pack", False),
+    ("jit(shuffle_reassemble)/shard_map/shuffle.reassemble/dynamic_update_slice", "shuffle.reassemble", False),
     ("jit(join_spec)/concatenate", None, False),
     ("", None, False),
 ])
@@ -217,7 +218,7 @@ def test_outermost_name_is_the_stage(path, stage, engine_in):
 
 
 def test_vocabulary_is_defined_once():
-    assert len(set(stages.VOCABULARY)) == len(stages.VOCABULARY) == 18
+    assert len(set(stages.VOCABULARY)) == len(stages.VOCABULARY) == 19
     constants = {
         v for k, v in vars(stages).items() if k.isupper() and isinstance(v, str)
     }

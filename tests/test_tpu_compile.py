@@ -198,6 +198,41 @@ def test_sorted_pack_compiles_inside_the_four_chip_shard_map(mesh4):
 
 
 # ----------------------------------------------------------------------
+# (b2) the reassembly of a shuffle's rounds: four parts of 2^21 slots of
+# the suite's two 64-bit columns into 2^23 (``join-skew-w4``'s shapes)
+# ----------------------------------------------------------------------
+
+def test_reassembly_compiles_for_tpu_as_block_writes(one_chip):
+    from cylon_tpu.obs import stages
+
+    cap, parts, out_cap = 1 << 21, 4, 1 << 23
+
+    def reassemble(blocks, counts):
+        return _sh.reassemble_blocks(blocks, counts, out_cap)
+
+    compiled = _compile(
+        reassemble,
+        [
+            [(_spec((cap,), jnp.int64, one_chip), None),
+             (_spec((cap,), jnp.float64, one_chip), None)]
+        ] * parts,
+        [_spec((), jnp.int32, one_chip)] * parts,
+    )
+    text = compiled.as_text()
+    # nothing is addressed by the row: no scatter and no gather of any size
+    assert not re.search(r"\s(scatter|gather)\(", text)
+    _module, rows = stages.parse_compiled(text)
+    writes = [op for t, op in rows if " dynamic-update-slice(" in t]
+    # a 64-bit column is two 32-bit lanes; the first part's offset is 0
+    assert len(writes) == 2 * 2 * (parts - 1), len(writes)
+    assert all(
+        stages.stage_of(op) == stages.SHUFFLE_REASSEMBLE for op in writes
+    )
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * mem.output_size_in_bytes
+
+
+# ----------------------------------------------------------------------
 # (c) the sort engine
 # ----------------------------------------------------------------------
 
