@@ -53,9 +53,9 @@ def sync_monitor() -> Iterator[List[SyncEvent]]:
     events: List[SyncEvent] = []
     real = _table._fetch
 
-    def spy(arr):
+    def spy(arr, site):
         events.append(_attribute())
-        return real(arr)
+        return real(arr, site)
 
     _table._fetch = spy
     try:

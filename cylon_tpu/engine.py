@@ -22,6 +22,7 @@ import contextvars
 import functools
 import re
 import threading
+from time import perf_counter_ns
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -31,6 +32,7 @@ from jax.sharding import PartitionSpec
 from .compat import shard_map
 from .context import CylonContext
 from .obs import stages as _stages
+from .obs.trace import note_dispatch
 from .utils.tracing import bump
 
 # kernel-invocation recording for roofline analysis (benchmarks/roofline.py):
@@ -148,6 +150,32 @@ def on_mesh(mesh, kernel: Callable, name: Optional[str] = None) -> Callable:
     return traced
 
 
+class TimedProgram:
+    """A jitted program as the caches hold it: the call reads the clock
+    before and after and hands the difference to ``obs.trace``
+    (:func:`~cylon_tpu.obs.trace.note_dispatch`: the rollup span
+    ``dispatch.<name>`` and the thread's open record). Built once, where
+    the program is; a call allocates nothing. Everything else asked of it
+    (``lower``, ``_cache_size``, ``__name__``) is the jitted function's."""
+
+    __slots__ = ("fn", "name", "span")
+
+    def __init__(self, fn: Callable, name: str):
+        self.fn = fn
+        self.name = name
+        self.span = "dispatch." + name
+
+    def __call__(self, *args):
+        t0 = perf_counter_ns()
+        try:
+            return self.fn(*args)
+        finally:
+            note_dispatch(self, t0, perf_counter_ns())
+
+    def __getattr__(self, attr):
+        return getattr(self.fn, attr)
+
+
 def round_cap(n: int, minimum: int = 8) -> int:
     """Round a capacity up to a power of two (>= minimum)."""
     n = max(int(n), minimum)
@@ -187,10 +215,12 @@ def get_kernel(
     Pallas tier) can pick interpret mode from the MESH, not from the
     process's default backend.
 
+    What the cache holds is a :class:`TimedProgram`: every call is timed
+    (``dispatch.<program>`` in the rollup, and the public call's record).
     On a cache MISS the callable handed back is a one-shot wrapper that
     registers the first call's argument spec for the device stage table
-    (``obs.stages.register_dispatch``); every later call gets the bare
-    jitted function, so a warm dispatch pays nothing."""
+    (``obs.stages.register_dispatch``); every later call gets the cached
+    object itself, so a warm dispatch pays two clock reads and no more."""
     cache = ctx.__dict__.get("_jit_cache")
     if cache is None:
         with cache_lock(ctx):
@@ -206,9 +236,8 @@ def get_kernel(
         with cache_lock(ctx):
             fn = cache.get(key)  # double-check: lost the build race
             if fn is None:
-                kernel = on_mesh(
-                    ctx.mesh, builder(), program_name(key, name)
-                )
+                pname = program_name(key, name)
+                kernel = on_mesh(ctx.mesh, builder(), pname)
                 if use_shard_map:
                     fn = jax.jit(
                         shard_map(
@@ -224,7 +253,7 @@ def get_kernel(
                     )
                 else:
                     fn = jax.jit(kernel)
-                cache[key] = fn
+                fn = cache[key] = TimedProgram(fn, pname)
                 missed = True
     if _KERNEL_RECORD is None and not missed:
         return fn

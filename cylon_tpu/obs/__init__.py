@@ -15,7 +15,11 @@ Four modules, layered bottom-up:
   L3 budgets pin that mechanically).
 - :mod:`.export` — the bounded flight-recorder ring of the last N query
   traces and the Chrome trace-event (Perfetto-loadable) exporter, one
-  track per query (plus per-shard stage tracks for profiled queries).
+  track per query (plus per-shard stage tracks for profiled queries);
+  and, always on, the last 256 public calls' records of where the host's
+  time went (:class:`.trace.OpRecord`: every dispatch and every fetch
+  timed, the fetches by site) with the eight slowest the process has
+  seen: :func:`last_ops`, :func:`slowest_ops`.
 - :mod:`.prof` — the critical-path profiler (ISSUE 15,
   ``CYLON_TPU_PROF``): per-stage per-shard device stage clocks derived
   sync-free from already-fetched counts + the deferred-fetch window,
@@ -61,7 +65,9 @@ from .trace import (  # noqa: F401
 from .export import (  # noqa: F401
     OpsServer,
     ensure_ops_server,
+    last_ops,
     prometheus_text,
+    slowest_ops,
     traces,
     validate_prometheus,
     write_chrome,
@@ -80,6 +86,7 @@ __all__ = [
     "export",
     "fingerprint_key",
     "latency_quantiles",
+    "last_ops",
     "latency_report",
     "ledger",
     "metrics",
@@ -90,6 +97,7 @@ __all__ = [
     "query_trace",
     "resource",
     "slo",
+    "slowest_ops",
     "store",
     "trace",
     "traces",

@@ -35,7 +35,11 @@ OPS ENDPOINT
       (the shed-storm rule flips it under overload; recovery is the
       breach aging out of the rolling window after drain).
     - ``/queries``: the flight-recorder ring as JSON — the "what just
-      happened" dump, scrapeable mid-incident.
+      happened" dump, scrapeable mid-incident. It holds, tracing on or
+      off, the last ``OPS_KEPT`` public calls' records of where the host's
+      time went (``obs.trace.OpRecord``: dispatches, waits by fetch site,
+      exposed and plain host time); ``/queries/slowest`` the
+      ``SLOWEST_KEPT`` slowest the process has seen.
 
     Every evaluation the endpoint triggers is host dict math; scraping
     can never sync the device. ``python -m tools.traceview --live
@@ -91,6 +95,69 @@ def traces() -> List:
 def reset_ring() -> None:
     with _ring_lock:
         _RING.clear()
+
+
+# ----------------------------------------------------------------------
+# the always-on records of the public calls (obs.trace.OpRecord)
+# ----------------------------------------------------------------------
+#: the last public calls' records, whether or not tracing is on
+OPS_KEPT = 256
+#: and the slowest the process has seen, so that a stall survives a
+#: window of thousands of queries
+SLOWEST_KEPT = 8
+_OPS: "deque" = deque(maxlen=OPS_KEPT)
+_SLOWEST: List = []
+_SLOW_FLOOR = [0]  # wall_ns a record must pass to enter a full _SLOWEST
+
+
+def record_op(rec) -> None:
+    """A public call returned: keep its record (the object: its tail, a
+    deferred count fetch, is still to come)."""
+    with _ring_lock:
+        _OPS.append(rec)
+    consider_slow(rec)
+
+
+def consider_slow(rec) -> None:
+    """Keep ``rec`` among the slowest if its call and tail now outlast the
+    fastest of them (called when the call returns and after each tail
+    event; one comparison where it does not)."""
+    wall = rec.wall_ns()
+    if wall <= _SLOW_FLOOR[0]:
+        return
+    with _ring_lock:
+        if rec not in _SLOWEST:
+            _SLOWEST.append(rec)
+        _SLOWEST.sort(key=lambda r: -r.wall_ns())
+        del _SLOWEST[SLOWEST_KEPT:]
+        if len(_SLOWEST) == SLOWEST_KEPT:
+            _SLOW_FLOOR[0] = _SLOWEST[-1].wall_ns()
+
+
+def last_ops(n: Optional[int] = None) -> List[Dict]:
+    """The last ``n`` public calls' records (all that are kept, at most
+    ``OPS_KEPT``, where ``n`` is None), oldest first, as
+    ``OpRecord.as_dict()`` gives them."""
+    with _ring_lock:
+        recs = list(_OPS)
+    if n is not None:
+        recs = recs[-n:] if n > 0 else []
+    return [r.as_dict() for r in recs]
+
+
+def slowest_ops() -> List[Dict]:
+    """The ``SLOWEST_KEPT`` records with the longest call and tail that the
+    process has seen, slowest first."""
+    with _ring_lock:
+        recs = list(_SLOWEST)
+    return [r.as_dict() for r in recs]
+
+
+def reset_ops() -> None:
+    with _ring_lock:
+        _OPS.clear()
+        del _SLOWEST[:]
+        _SLOW_FLOOR[0] = 0
 
 
 def _export_at_exit() -> None:  # pragma: no cover - exit hook
@@ -357,17 +424,14 @@ def prometheus_text() -> str:
                 fam(base + "_rows_total", "counter", f"rows of {raw}")
                 lines.append(f"{base}_rows_total {_fmt_val(s['rows'])}")
 
-    # ---- per-fingerprint latency quantiles (summary form) ------------
-    rep = _metrics.latency_report()
-    if rep:
-        name = "cylon_tpu_query_latency_seconds"
-        fam(name, "summary",
-            "per-plan-fingerprint query latency (dispatch to deferred "
-            "count-fetch return)")
-        for key, q in sorted(rep.items()):
-            lbl = f'fingerprint="{_prom_escape(key)}"'
-            for quant, field in (("0.5", "p50_s"), ("0.95", "p95_s"),
-                                 ("0.99", "p99_s")):
+    def summaries(name, help_text, label, report, quantiles):
+        """One summary family: ``report`` is {label value: quantile dict}."""
+        if not report:
+            return
+        fam(name, "summary", help_text)
+        for key, q in sorted(report.items()):
+            lbl = f'{label}="{_prom_escape(key)}"'
+            for quant, field in quantiles:
                 lines.append(
                     f'{name}{{{lbl},quantile="{quant}"}} '
                     f"{_fmt_val(q[field])}"
@@ -377,6 +441,22 @@ def prometheus_text() -> str:
                 f"{name}_sum{{{lbl}}} "
                 f"{_fmt_val(q['mean_s'] * q['count'])}"
             )
+
+    quantiles = (("0.5", "p50_s"), ("0.95", "p95_s"), ("0.99", "p99_s"))
+    # ---- per-fingerprint latency quantiles (summary form) ------------
+    summaries(
+        "cylon_tpu_query_latency_seconds",
+        "per-plan-fingerprint query latency (dispatch to deferred "
+        "count-fetch return)",
+        "fingerprint", _metrics.latency_report(), quantiles,
+    )
+    # ---- the host's wait in each fetch, by site -----------------------
+    summaries(
+        "cylon_tpu_host_sync_wait_seconds",
+        "host wait in a device-to-host fetch, by site "
+        "(obs.stages.FETCH_SITES)",
+        "site", _metrics.wait_report(), quantiles + (("1", "max_s"),),
+    )
 
     # ---- resource-ledger watermarks ----------------------------------
     leds = _resource.ledgers()
@@ -460,12 +540,36 @@ def validate_prometheus(text: str) -> List[str]:
 # ----------------------------------------------------------------------
 # the flight ring as JSON (the /queries substrate)
 # ----------------------------------------------------------------------
+def _op_json(rec: Dict) -> Dict:
+    """A public call's record in the shape of a ring entry."""
+    return {
+        "qid": rec["rid"], "kind": "op", "name": rec["name"],
+        "label": rec["name"], "fingerprint": None,
+        "wall_ms": round((rec["end_ns"] - rec["start_ns"]) * 1e-6, 3),
+        "device_resolved_ms": None, "thread": rec["thread"],
+        "attrs": {}, "counters": {}, "host": rec,
+    }
+
+
+def slowest_json() -> List[Dict]:
+    """``/queries/slowest``: the slowest public calls' records."""
+    return [_op_json(r) for r in slowest_ops()]
+
+
 def queries_json(trace_list: Optional[List] = None) -> List[Dict]:
     """The ring, oldest first, as JSON-safe dicts: qid/kind/name/
-    fingerprint/wall + device-resolved ms, attrs and counters."""
+    fingerprint/wall + device-resolved ms, attrs and counters, and under
+    ``host`` the always-on record of the public call the trace ran in.
+    Without ``trace_list`` (the ``/queries`` document) the public calls'
+    records that no listed trace carries come first, as entries of kind
+    ``op``: with tracing off they are the whole document."""
+    out: List[Dict] = []
     if trace_list is None:
         trace_list = traces()
-    out: List[Dict] = []
+        carried = {q.op.rid for q in trace_list if q.op is not None}
+        out.extend(
+            _op_json(r) for r in last_ops() if r["rid"] not in carried
+        )
     for q in trace_list:
         dev = q.device_resolved_s()
         out.append({
@@ -487,6 +591,7 @@ def queries_json(trace_list: Optional[List] = None) -> List[Dict]:
                 k: (c if not r else [c, r])
                 for k, (c, r) in q.counters.items()
             },
+            "host": None if q.op is None else q.op.as_dict(),
         })
     return out
 
@@ -495,7 +600,8 @@ def queries_json(trace_list: Optional[List] = None) -> List[Dict]:
 # the stdlib HTTP ops server
 # ----------------------------------------------------------------------
 class OpsServer:
-    """``/metrics`` + ``/healthz`` + ``/queries`` on a daemon thread.
+    """``/metrics`` + ``/healthz`` + ``/queries`` (+ ``/queries/slowest``)
+    on a daemon thread.
     Stdlib-only (http.server); start() returns the bound port (pass 0
     for an ephemeral one — tests and the opsd smoke use that). Binds
     LOOPBACK by default: the endpoint is unauthenticated and ``/queries``
@@ -545,6 +651,11 @@ class OpsServer:
                     elif path == "/queries":
                         self._reply(
                             200, json.dumps(queries_json()),
+                            "application/json",
+                        )
+                    elif path == "/queries/slowest":
+                        self._reply(
+                            200, json.dumps(slowest_json()),
                             "application/json",
                         )
                     else:

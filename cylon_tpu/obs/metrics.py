@@ -52,12 +52,17 @@ _ROLLUP: Dict[str, Dict[str, float]] = defaultdict(
 # ----------------------------------------------------------------------
 # the process-global rollup (compat surface of utils/tracing.py)
 # ----------------------------------------------------------------------
+def _span_locked(name: str, dt: float) -> Dict[str, float]:
+    s = _ROLLUP[name]
+    s["count"] += 1
+    s["total_s"] += dt
+    s["max_s"] = max(s["max_s"], dt)
+    return s
+
+
 def rollup_span(name: str, dt: float, rows: Optional[int] = None) -> None:
     with _lock:
-        s = _ROLLUP[name]
-        s["count"] += 1
-        s["total_s"] += dt
-        s["max_s"] = max(s["max_s"], dt)
+        s = _span_locked(name, dt)
         if rows is not None:
             s["rows"] += int(rows)
 
@@ -114,6 +119,14 @@ def reset_rollup() -> None:
 BUCKETS_PER_DECADE = 24
 
 
+def bucket_of(seconds: float) -> int:
+    """The geometric bucket a duration falls in (the one copy of the
+    rule: :meth:`Histogram.record` and the tests that look a stall up)."""
+    return int(math.floor(
+        math.log10(max(float(seconds), 1e-9)) * BUCKETS_PER_DECADE
+    ))
+
+
 class Histogram:
     """Geometric-bucket latency histogram (seconds). NOT thread-safe on
     its own — every registry access serializes under the module lock."""
@@ -129,7 +142,7 @@ class Histogram:
 
     def record(self, seconds: float) -> None:
         s = max(float(seconds), 1e-9)
-        b = int(math.floor(math.log10(s) * BUCKETS_PER_DECADE))
+        b = bucket_of(s)
         self.buckets[b] = self.buckets.get(b, 0) + 1
         self.n += 1
         self.total_s += s
@@ -218,14 +231,18 @@ def latency_quantiles(key: str) -> Optional[Dict[str, float]]:
         h = _HISTS.get(key)
         if h is None or not h.n:
             return None
-        return {
-            "count": h.n,
-            "mean_s": h.total_s / h.n,
-            "p50_s": h.quantile(0.50),
-            "p95_s": h.quantile(0.95),
-            "p99_s": h.quantile(0.99),
-            "max_s": h.max_s,
-        }
+        return _summary(h)
+
+
+def _summary(h: Histogram) -> Dict[str, float]:
+    return {
+        "count": h.n,
+        "mean_s": h.total_s / h.n,
+        "p50_s": h.quantile(0.50),
+        "p95_s": h.quantile(0.95),
+        "p99_s": h.quantile(0.99),
+        "max_s": h.max_s,
+    }
 
 
 def latency_report() -> Dict[str, Dict[str, float]]:
@@ -284,6 +301,42 @@ def reset_latency() -> None:
 
 
 # ----------------------------------------------------------------------
+# the host's waits and program calls (obs.trace notes them, always on)
+# ----------------------------------------------------------------------
+#: one wait histogram a fetch site (``obs.stages.FETCH_SITES``, so the
+#: registry is bounded by the vocabulary): a stalled fetch is a bucket with
+#: a count, where the rollup's ``max_s`` keeps only the worst
+_WAITS: Dict[str, Histogram] = {}
+
+
+def rollup_wait(site: str, dt: float) -> None:
+    """One device-to-host fetch at ``site`` that the host waited ``dt``
+    seconds in: the rollup span ``host_sync.<site>`` and the site's
+    histogram, under one lock."""
+    with _lock:
+        _span_locked("host_sync." + site, dt)
+        h = _WAITS.get(site)
+        if h is None:
+            h = _WAITS[site] = Histogram()
+        h.record(dt)
+
+
+def wait_report() -> Dict[str, Dict]:
+    """``{site: {count, mean_s, p50_s, p95_s, p99_s, max_s, buckets}}`` of
+    the waits since the process started (or :func:`reset_waits`)."""
+    with _lock:
+        return {
+            site: {**_summary(h), "buckets": dict(h.buckets)}
+            for site, h in _WAITS.items() if h.n
+        }
+
+
+def reset_waits() -> None:
+    with _lock:
+        _WAITS.clear()
+
+
+# ----------------------------------------------------------------------
 # the documented stable names (docs/ARCHITECTURE.md "Observability")
 # ----------------------------------------------------------------------
 #: name-or-prefix -> (kind, meaning). Prefixes end with "."; a metric is
@@ -293,6 +346,15 @@ def reset_latency() -> None:
 #: changes made only with their consumers.
 STABLE_METRICS: Dict[str, Tuple[str, str]] = {
     "host_sync": ("counter", "device->host count fetches (the sync census)"),
+    "host_sync.": (
+        "span", "the host's wait in each device->host fetch, by site "
+        "(obs.stages.FETCH_SITES; table._fetch is the one place that "
+        "counts, times and names a fetch): count, total_s, max_s, and a "
+        "wait histogram a site (/metrics: quantiles)"),
+    "dispatch.": (
+        "span", "host time inside each program call, by program name "
+        "(engine.get_kernel's cached callable reads the clock around the "
+        "jitted call; a compile shows here as the first call's seconds)"),
     "sort": ("span", "local sort dispatch"),
     "sort.ride_lanes": (
         "counter", "payload rides of Table.sort and the speculative join's "

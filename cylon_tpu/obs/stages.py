@@ -72,6 +72,37 @@ def in_sort_engine(op_name: str) -> bool:
 
 
 # ----------------------------------------------------------------------
+# fetch sites: where the host waits for the device
+# ----------------------------------------------------------------------
+#: every ``table._fetch(arr, site)`` call site, by name, with whether the
+#: fetch is a COUNT SYNC (it bumps the rollup counter ``host_sync``, the
+#: census that ``analysis/contracts.py`` pins and ``host_syncs`` reads) or
+#: a transfer of rows that rides a sync already counted. ``obs.trace``
+#: times each site as the rollup span ``host_sync.<site>`` and names the
+#: device's idle time after it ``host.gap.<site>``; a site outside this
+#: table is a ``KeyError`` at the fetch (``tests/test_host_timeline.py``
+#: scans the package for literals).
+FETCH_SITES = {
+    "table.counts": True,         # Table._materialize_counts, the deferred one
+    "stats.measure": True,        # Table.ensure_stats
+    "to_numpy": False,            # Table._host_physical, a column's data
+    "to_numpy.valid": False,      # ... and its validity lane
+    "join.speculative": True,     # Table.join, the speculative program's totals
+    "join.exact_counts": True,    # ... the exact path's probe counts
+    "join.semi": True,            # Table._semi_reduced, the three counts
+    "join.pallas_pk": True,       # Table._pallas_pk_join
+    "join.fused": True,           # distributed_join(mode="fused")
+    "shuffle.counts": True,       # _shuffle_many, the count kernel's matrix
+    "shuffle.round_counts": True,  # _shuffle_many_rounds, every round's counts
+    "spill.stage_lanes": True,    # parallel/spill.stage_table
+    "spill.stage_passthrough": False,
+    "spill.relay_lanes": True,    # parallel/spill.fetch_relay
+    "spill.relay_passthrough": False,
+    "task.counts": True,          # parallel/task.task_partition
+}
+
+
+# ----------------------------------------------------------------------
 # which programs ran, with which shapes and shardings
 # ----------------------------------------------------------------------
 #: programs remembered per context (a context's ``_jit_cache`` is not

@@ -32,6 +32,12 @@ never fetches: ``analysis/contracts.py`` pins 0 sync sites on this
 module's hot entry points, and the runtime census under an enabled
 tracer is asserted by ``tools/trace_smoke.py`` in CI.
 
+Always on, beside all of that: every public call leaves one
+:class:`OpRecord` of where the HOST's time went (every program call and
+every fetch timed, the fetches named by site), kept in the flight ring
+whether tracing is enabled or not — :func:`op`, :func:`note_dispatch`,
+:class:`fetch_wait`; ``obs.last_ops`` / ``obs.slowest_ops`` read them.
+
 Disabled cost: with tracing off and no active trace, ``span()`` takes
 the legacy fast path — one contextvar read, one perf_counter pair, one
 locked rollup update; NO Span/QueryTrace allocation
@@ -41,6 +47,7 @@ per-query overhead under 2%).
 from __future__ import annotations
 
 import contextlib
+import functools
 import itertools
 import sys
 import threading
@@ -54,6 +61,7 @@ from ..utils import envgate as _eg
 from . import export as _export
 from . import metrics as _metrics
 from . import store as _obsstore
+from .stages import FETCH_SITES
 
 _ACTIVE: "ContextVar[Optional[QueryTrace]]" = ContextVar(
     "cylon_tpu_query_trace", default=None
@@ -74,6 +82,239 @@ def tracing_active() -> bool:
     value) builds span trees + the flight ring without the stderr
     firehose."""
     return _eg.TRACE.truthy()
+
+
+# ----------------------------------------------------------------------
+# the host's part of a public call: one always-on record
+# ----------------------------------------------------------------------
+_ns = time.perf_counter_ns
+#: the exposed time before a public call's first dispatch has no fetch in
+#: front of it; a record files it under this name, beside the fetch sites
+OP_START = "op.start"
+_FETCH_EVENT = {site: "host_sync." + site for site in FETCH_SITES}
+_GAP_EVENT = {
+    site: "host.gap." + site for site in (*FETCH_SITES, OP_START)
+}
+
+
+class OpRecord:
+    """Where the host's time went in one public call (``Table.join``,
+    ``distributed_sort``, ``LazyFrame.collect``, ...: :func:`op`), built
+    from the two places where the host meets the device: every program call
+    (``engine.get_kernel``'s timed callable, :func:`note_dispatch`) and
+    every fetch (``table._fetch``, :class:`fetch_wait`). Times are
+    ``perf_counter_ns``. Always on; written by the thread that opened it
+    alone, read by anyone through :meth:`as_dict`.
+
+    ``exposed_ns`` estimates the time the call left the device with nothing
+    to do: from the record's start to its first dispatch's return, and from
+    every fetch's return (the device has drained, or is about to) to the
+    next dispatch's return, the host's wait in a further fetch left out
+    (the device is busy with what that fetch waits for). It is an upper
+    estimate: after the first of two fetches in a row the device may still
+    hold the second's program. A dispatch or fetch between this call's
+    return and the thread's next public call (the deferred count fetch of
+    ``Table.row_count``) is this record's TAIL: it is counted here, its
+    duration added to ``tail_ns``, and an interval still open is cut where
+    the next record opens, so no nanosecond is counted twice. What is left
+    of the call is plain host time: :meth:`plain_ns`."""
+
+    __slots__ = (
+        "rid", "name", "thread", "t0", "t1", "tail_ns",
+        "n_dispatch", "dispatch_ns", "n_fetch", "wait_ns", "exposed_ns",
+        "sites", "max_wait_ns", "max_wait_site", "max_wait_after",
+        "_gap_t0", "_gap_site", "_gap_ann",
+    )
+
+    def __init__(self, name: str, t0: int):
+        self.rid = next(_QIDS)
+        self.name = name
+        self.thread = threading.get_ident()
+        self.t0 = t0
+        self.t1 = 0
+        self.tail_ns = 0
+        self.n_dispatch = self.dispatch_ns = 0
+        self.n_fetch = self.wait_ns = self.exposed_ns = 0
+        #: site -> [fetches, wait_ns, exposed_ns]
+        self.sites: Dict[str, List[int]] = {OP_START: [0, 0, 0]}
+        self.max_wait_ns = 0
+        self.max_wait_site = self.max_wait_after = ""
+        self._gap_ann = None
+        self._open_gap(OP_START, t0)
+
+    # -- the exposed interval: open at a fetch's return, closed at the
+    # next dispatch's return; also a host event on the profiler's clock
+    def _open_gap(self, site: str, now: int) -> None:
+        self._gap_t0, self._gap_site = now, site
+        self._gap_ann = TraceAnnotation(_GAP_EVENT[site])
+        self._gap_ann.__enter__()
+
+    def _mark_gap(self, now: int) -> None:
+        """Count the open interval up to ``now``; it stays open."""
+        d = now - self._gap_t0
+        self.exposed_ns += d
+        self.sites[self._gap_site][2] += d
+        self._gap_t0 = now
+
+    def _close_gap(self, now: int) -> None:
+        self._mark_gap(now)
+        self._gap_t0 = 0
+        self._gap_ann.__exit__(None, None, None)
+        self._gap_ann = None
+
+    def wall_ns(self) -> int:
+        """The call and its tail."""
+        return max(self.t1 - self.t0, 0) + self.tail_ns
+
+    def plain_ns(self) -> int:
+        """The Python between the programs: the call and its tail less
+        the waits and the program calls."""
+        return self.wall_ns() - self.wait_ns - self.dispatch_ns
+
+    def as_dict(self) -> Dict[str, Any]:
+        return {
+            "rid": self.rid, "name": self.name, "thread": self.thread,
+            "start_ns": self.t0, "end_ns": self.t1, "tail_ns": self.tail_ns,
+            "n_dispatch": self.n_dispatch, "dispatch_ns": self.dispatch_ns,
+            "n_fetch": self.n_fetch, "wait_ns": self.wait_ns,
+            "exposed_ns": self.exposed_ns, "plain_ns": self.plain_ns(),
+            "sites": {
+                site: {"n_fetch": n, "wait_ns": w, "exposed_ns": e}
+                for site, (n, w, e) in self.sites.items()
+            },
+            "max_wait": {
+                "ns": self.max_wait_ns, "site": self.max_wait_site,
+                "after": self.max_wait_after,
+            },
+        }
+
+
+class _Host(threading.local):
+    """A thread's clock readings at the device's edge."""
+
+    rec: Optional[OpRecord] = None  # the open record, else the last one
+    open = False                    # a public call is running
+    program = ""                    # the program called last
+    dispatch_ns = 0                 # how long that call took
+    t_dispatch = 0                  # when it returned
+    t_fetch = 0                     # when the last fetch returned
+
+
+_HOST = _Host()
+
+
+def op(name: str):
+    """Decorator of a public call: the outermost one on a thread opens an
+    :class:`OpRecord` (and, inside a profiler session, the host event
+    ``op.<name>``); inner ones fold into it."""
+    event = "op." + name
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            h = _HOST
+            if h.open:
+                return fn(*args, **kwargs)
+            now = _ns()
+            prev = h.rec
+            if prev is not None and prev._gap_t0:
+                prev._close_gap(now)  # the tail ends where the next opens
+            rec = h.rec = OpRecord(name, now)
+            h.open = True
+            try:
+                with TraceAnnotation(event):
+                    return fn(*args, **kwargs)
+            finally:
+                now = rec.t1 = _ns()
+                if rec._gap_t0:
+                    rec._mark_gap(now)
+                h.open = False
+                _export.record_op(rec)
+
+        return call
+
+    return deco
+
+
+def note_dispatch(program, t0: int, t1: int) -> None:
+    """One call of ``program`` (an ``engine.TimedProgram``: its ``name``,
+    and ``span`` = ``dispatch.<name>``) took the host from ``t0`` to
+    ``t1``: the rollup span and the thread's record."""
+    dt = t1 - t0
+    _metrics.rollup_span(program.span, dt * 1e-9)
+    h = _HOST
+    h.program, h.dispatch_ns, h.t_dispatch = program.name, dt, t1
+    rec = h.rec
+    if rec is not None:
+        rec.n_dispatch += 1
+        rec.dispatch_ns += dt
+        if rec._gap_t0:
+            rec._close_gap(t1)  # the device has work again
+        if not h.open:
+            rec.tail_ns += dt
+            _export.consider_slow(rec)
+
+
+class fetch_wait:
+    """The one place a device-to-host fetch is timed and named (entered by
+    ``table._fetch`` alone): the rollup span ``host_sync.<site>`` and the
+    site's wait histogram, the thread's record, and inside a profiler
+    session the host event ``host_sync.<site>``."""
+
+    __slots__ = ("site", "t0", "ann")
+
+    def __init__(self, site: str):
+        self.site = site
+
+    def __enter__(self):
+        self.ann = TraceAnnotation(_FETCH_EVENT[self.site])
+        rec = _HOST.rec
+        now = self.t0 = _ns()
+        if rec is not None and rec._gap_t0:
+            rec._close_gap(now)  # a wait is no exposed time
+        self.ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self.ann.__exit__(*exc)
+        site = self.site
+        h = _HOST
+        t1 = h.t_fetch = _ns()
+        dt = t1 - self.t0
+        _metrics.rollup_wait(site, dt * 1e-9)
+        rec = h.rec
+        if rec is None:
+            return
+        rec.n_fetch += 1
+        rec.wait_ns += dt
+        at = rec.sites.get(site)
+        if at is None:
+            at = rec.sites[site] = [0, 0, 0]
+        at[0] += 1
+        at[1] += dt
+        if dt > rec.max_wait_ns:
+            rec.max_wait_ns, rec.max_wait_site = dt, site
+            rec.max_wait_after = h.program
+        rec._open_gap(site, t1)
+        if not h.open:
+            rec.tail_ns += dt
+            _export.consider_slow(rec)
+
+
+def last_dispatch_s() -> float:
+    """Seconds the host spent in this thread's last program call."""
+    return _HOST.dispatch_ns * 1e-9
+
+
+def last_dispatch_return_s() -> float:
+    """When this thread's last program call returned (``perf_counter``)."""
+    return _HOST.t_dispatch * 1e-9
+
+
+def last_fetch_return_s() -> float:
+    """When this thread's last fetch returned (``perf_counter``): the
+    device-resolved end of whatever the fetch waited for."""
+    return _HOST.t_fetch * 1e-9
 
 
 class Span:
@@ -119,7 +360,7 @@ class QueryTrace:
     __slots__ = (
         "qid", "name", "kind", "hist_key", "obs_key", "label", "thread",
         "t0", "t1", "resolved", "closed", "finished", "pending",
-        "spans", "_stack", "counters", "values", "attrs",
+        "spans", "_stack", "counters", "values", "attrs", "op",
     )
 
     def __init__(self, name: str, kind: str = "query"):
@@ -141,6 +382,9 @@ class QueryTrace:
         self.counters: Dict[str, List[int]] = {}
         self.values: Dict[str, float] = {}
         self.attrs: Dict[str, Any] = {}
+        #: the public call's always-on record (OpRecord) this trace was
+        #: opened inside, so that trace and record are one set of numbers
+        self.op: Optional["OpRecord"] = _HOST.rec if _HOST.open else None
 
     # -- span plumbing (called only from this thread's span()) ---------
     def _open(self, name, rows, attrs) -> Span:

@@ -926,9 +926,10 @@ def stage_table(sink, table, counts: np.ndarray, qspec=None) -> None:
     with span("shuffle.spill.stage", rows=int(np.sum(counts))):
         dp = (flat, table.counts_dev) if q_cols else (flat,)
         mat, pts = get_kernel(ctx, key, build)(dp, ())
-        bump("host_sync")
-        mat_np = np.asarray(_fetch(mat))
-        pts_np = [np.asarray(_fetch(p)) for p in pts]
+        mat_np = np.asarray(_fetch(mat, "spill.stage_lanes"))
+        pts_np = [
+            np.asarray(_fetch(p, "spill.stage_passthrough")) for p in pts
+        ]
     cap = mat_np.shape[0] // world
     mat_np = mat_np.reshape(world, cap, mat_np.shape[1])
     qmat_np = qsc_np = None
@@ -1021,9 +1022,8 @@ def fetch_relay(
     pt_eff = tuple(
         ci for ci in pt_order if qspec is None or qspec[ci] != "q8"
     )
-    bump("host_sync")
-    mat_np = np.asarray(_fetch(mat))
-    pts_np = [np.asarray(_fetch(p)) for p in pts]
+    mat_np = np.asarray(_fetch(mat, "spill.relay_lanes"))
+    pts_np = [np.asarray(_fetch(p, "spill.relay_passthrough")) for p in pts]
     cap = mat_np.shape[0] // world
     mat_np = mat_np.reshape(world, cap, mat_np.shape[1])
     qmat_np = qsc_np = None

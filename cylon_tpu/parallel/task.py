@@ -81,7 +81,6 @@ def task_partition(
     from ..dtypes import DataType, Type
     from ..engine import get_kernel, round_cap
     from ..ops import partition as _p
-    from ..utils.tracing import bump
 
     if plan.world != table.world_size:
         raise ValueError(
@@ -136,10 +135,9 @@ def task_partition(
     sorted_cols, cnts = get_kernel(table.ctx, key2, build_sort)(
         (flat, shuffled.counts_dev), ()
     )
-    bump("host_sync")
     from ..table import _fetch
 
-    cnts = _fetch(cnts).reshape(table.world_size, T)  # [P, T]
+    cnts = _fetch(cnts, "task.counts").reshape(table.world_size, T)  # [P, T]
     offs = np.concatenate(
         [np.zeros((table.world_size, 1), np.int64), np.cumsum(cnts, axis=1)],
         axis=1,

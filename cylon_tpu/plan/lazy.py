@@ -254,6 +254,7 @@ class LazyFrame:
         lines.append(_fired_line(fired))
         return "\n".join(lines)
 
+    @_obstrace.op("collect")
     def collect(self):
         """Optimize, lower and execute the plan; returns an eager Table
         with host-known row counts (the result's deferred count lane is
@@ -313,6 +314,7 @@ class LazyFrame:
         entry, hit = plan_executable(ctx, fingerprint, compile_plan)
         return tables, fingerprint, entry, hit
 
+    @_obstrace.op("dispatch")
     def dispatch(self):
         """Execute the plan WITHOUT the result-count host sync — the
         ``collect_async`` precursor for concurrent query serving.

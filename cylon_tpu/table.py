@@ -122,14 +122,23 @@ def _as_i32(x):
     return x.astype(jnp.int32)
 
 
-def _fetch(arr) -> np.ndarray:
+def _fetch(arr, site: str) -> np.ndarray:
     """Device->host fetch that works under multi-process ``jax.distributed``:
     a global array's remote shards are not addressable from this host, so
     ``np.asarray`` alone would raise — allgather across processes first
     (the reference's equivalent host boundary is each rank owning only its
-    partition, table.cpp:791-829). Inside a ``jax.profiler`` session the
-    wait is a host event named ``host_sync`` on the device trace's clock."""
-    with jax.profiler.TraceAnnotation("host_sync"):
+    partition, table.cpp:791-829).
+
+    The ONE place a fetch is counted, timed and named. ``site`` is a
+    literal of ``obs.stages.FETCH_SITES``: a count sync bumps the census
+    counter ``host_sync``; every fetch is the rollup span
+    ``host_sync.<site>`` (the host's wait, with a histogram a site), a
+    line of the public call's record, and inside a ``jax.profiler``
+    session a host event of that name on the device trace's clock
+    (``obs.trace.fetch_wait``)."""
+    if _stages.FETCH_SITES[site]:
+        bump("host_sync")
+    with _obstrace.fetch_wait(site):
         if jax.process_count() > 1 and hasattr(arr, "is_fully_addressable"):
             if not arr.is_fully_addressable:
                 from jax.experimental import multihost_utils
@@ -340,8 +349,9 @@ class Table:
         with self._mat_lock:
             if self._counts_host is not None:
                 return  # lost the race: the other thread materialized
-            bump("host_sync")
-            got = _fetch(self._counts_fut).reshape(-1).astype(np.int64)
+            got = _fetch(
+                self._counts_fut, "table.counts"
+            ).reshape(-1).astype(np.int64)
             tight = round_cap(int(got.max()) if got.size else 0)
             if tight * 4 <= self._shard_cap:
                 compacted = self._compact(tight)
@@ -504,9 +514,10 @@ class Table:
                 got = get_kernel(self.ctx, key, build)(
                     (flat, self.counts_dev), ()
                 )
-                bump("host_sync")
                 bump("lane_pack.stats_kernel")
-                w = _fetch(got).reshape(self.world_size, len(missing), 4)
+                w = _fetch(got, "stats.measure").reshape(
+                    self.world_size, len(missing), 4
+                )
             for i, (n, cls) in enumerate(missing):
                 stat = _st.fold_stat_words(w[:, i, :], cls)
                 self._stats[n] = stat
@@ -752,8 +763,11 @@ class Table:
         (data ndarray, valid ndarray | None)."""
         col = self._columns[name]
         world, cap = self.ctx.world_size, self._shard_cap
-        data = _fetch(col.data).reshape(world, cap)
-        valid = None if col.valid is None else _fetch(col.valid).reshape(world, cap)
+        data = _fetch(col.data, "to_numpy").reshape(world, cap)
+        valid = (
+            None if col.valid is None
+            else _fetch(col.valid, "to_numpy.valid").reshape(world, cap)
+        )
         parts, vparts = [], []
         for i in range(world):
             c = int(self._row_counts[i])
@@ -1092,6 +1106,7 @@ class Table:
             return jax.device_put(full.reshape(-1), self.ctx.sharding)
         return mask
 
+    @_obstrace.op("filter")
     def filter(self, mask: Union["Table", Column, jax.Array]) -> "Table":
         """Keep rows where mask is True. The vectorized analog of the
         reference's UDF Select (table.cpp:504-529) and of pycylon's boolean
@@ -1221,8 +1236,12 @@ class Table:
             with _engine.cache_lock(self.ctx):
                 gather = cache.get(("take_gather",))
                 if gather is None:
-                    gather = jax.jit(
-                        lambda d, i: d[i], out_shardings=self.ctx.sharding
+                    gather = _engine.TimedProgram(
+                        jax.jit(
+                            lambda d, i: d[i],
+                            out_shardings=self.ctx.sharding,
+                        ),
+                        "take_gather",
                     )
                     cache[("take_gather",)] = gather
         cols: "OrderedDict[str, Column]" = OrderedDict()
@@ -1235,6 +1254,7 @@ class Table:
     # ------------------------------------------------------------------
     # sort
     # ------------------------------------------------------------------
+    @_obstrace.op("sort")
     def sort(
         self,
         order_by: Union[str, int, Sequence[Union[str, int]]],
@@ -1339,6 +1359,7 @@ class Table:
             canonical=mask_free and all(asc), lexsort_exact=True,
         ))
 
+    @_obstrace.op("distributed_sort")
     def distributed_sort(
         self,
         order_by: Union[str, int, Sequence[Union[str, int]]],
@@ -1377,6 +1398,7 @@ class Table:
             res._ordering = res._ordering._replace(scope="global")
         return res
 
+    @_obstrace.op("topk")
     def topk(
         self,
         order_by: Union[str, int, Sequence[Union[str, int]]],
@@ -1547,6 +1569,7 @@ class Table:
     # ------------------------------------------------------------------
     # join
     # ------------------------------------------------------------------
+    @_obstrace.op("join")
     def join(
         self,
         other: "Table",
@@ -1774,8 +1797,7 @@ class Table:
                     (jnp.zeros((spec_cap,), jnp.int8),),
                 )
                 if _totals is None:
-                    bump("host_sync")
-                    stats = _fetch(stats).reshape(-1, 2)
+                    stats = _fetch(stats, "join.speculative").reshape(-1, 2)
                     totals = stats[:, 0].astype(np.int64)
                     _check_join_count(
                         totals, stats[:, 1].copy().view(np.float32)
@@ -1833,8 +1855,7 @@ class Table:
         lo, cnt, r_order, r_cnt, pstats = get_kernel(
             self.ctx, key + ("probe",), build_probe, name="join_probe"
         )((lflat_k, rflat_k, left.counts_dev, right.counts_dev), ())
-        bump("host_sync")
-        pstats = _fetch(pstats).reshape(-1, 2)
+        pstats = _fetch(pstats, "join.exact_counts").reshape(-1, 2)
         cnts = pstats[:, 0].astype(np.int64)
         _check_join_count(cnts, pstats[:, 1].copy().view(np.float32))
         cap_out = round_cap(int(cnts.max()))
@@ -1927,8 +1948,7 @@ class Table:
                 ),
                 (),
             )
-            bump("host_sync")
-            got = _fetch(stats).reshape(-1, 4)
+            got = _fetch(stats, "join.semi").reshape(-1, 4)
         n_r, n_l = got[:, 0].astype(np.int64), got[:, 1].astype(np.int64)
         totals = got[:, 2].astype(np.int64)
         _check_join_count(totals, got[:, 3].copy().view(np.float32))
@@ -2035,8 +2055,8 @@ class Table:
                 self.ctx, key, build, check_vma=False,
                 use_shard_map=self.ctx.world_size > 1,
             )(args, ())
-            bump("host_sync")
-            stats = _fetch(stats).reshape(-1, 2)  # the ONE host sync
+            # the ONE host sync
+            stats = _fetch(stats, "join.pallas_pk").reshape(-1, 2)
         if int(stats[:, 1].sum()) != 0:
             # speculation miss (duplicate right keys / bucket overflow):
             # exact sort-based join, correctness never depends on the hint
@@ -2056,6 +2076,7 @@ class Table:
         )
         return res._maybe_compact(res._row_counts)
 
+    @_obstrace.op("distributed_join")
     def distributed_join(
         self,
         other: "Table",
@@ -2262,10 +2283,14 @@ class Table:
             cache = ctx.__dict__.setdefault("_jit_cache", {})
             step = cache.get(key)
             if step is None:
-                step = make_distributed_join_step(
-                    ctx.mesh, ctx.axis_name, lk_idx, rk_idx, howi,
-                    bucket_cap, join_cap, respill, num_slices,
-                    quant_l=quant_l, quant_r=quant_r, topo=topo_cfg,
+                # cached outside get_kernel, timed like its programs
+                step = _engine.TimedProgram(
+                    make_distributed_join_step(
+                        ctx.mesh, ctx.axis_name, lk_idx, rk_idx, howi,
+                        bucket_cap, join_cap, respill, num_slices,
+                        quant_l=quant_l, quant_r=quant_r, topo=topo_cfg,
+                    ),
+                    "join_fused",
                 )
                 cache[key] = step
             t0_prof = _time.perf_counter()
@@ -2283,8 +2308,7 @@ class Table:
                 stats = jnp.concatenate(
                     [nout.astype(jnp.int32), overflow.astype(jnp.int32)]
                 )
-                bump("host_sync")
-                stats = _fetch(stats)  # THE host sync
+                stats = _fetch(stats, "join.fused")  # THE host sync
                 # fused-pipeline stage clocks (obs/prof.py): the stats
                 # fetch above IS this attempt's device-resolved end, and
                 # every work unit is shape-derived — host math only
@@ -2296,7 +2320,7 @@ class Table:
                         other._rows_hint() or cap_r * world,
                         join_cap,
                     ),
-                    world, t0_prof, _time.perf_counter(),
+                    world, t0_prof, _obstrace.last_fetch_return_s(),
                 )
             P = world
             nout_h = stats[:P].astype(np.int64)
@@ -2700,6 +2724,7 @@ class Table:
     # ------------------------------------------------------------------
     # groupby
     # ------------------------------------------------------------------
+    @_obstrace.op("groupby")
     def groupby(
         self,
         by: Union[str, int, Sequence[Union[str, int]]],
@@ -2937,6 +2962,7 @@ class Table:
             key_names, specs, out, nout, cap_out, True
         )
 
+    @_obstrace.op("distributed_groupby")
     def distributed_groupby(
         self,
         by: Union[str, int, Sequence[Union[str, int]]],
@@ -4287,12 +4313,11 @@ def _shuffle_many(specs: Sequence["_ShuffleSpec"]) -> List["Table"]:
                 name="shuffle_count",
             )(dp, ())
     for st in states:
-        bump("host_sync")
         spec = st["spec"]
         w = st["world"]
         S = len(st["stat_cols"])
         per = (2 * w if spec.sketch is not None else w) + 4 * S
-        got = _fetch(st["counts_fut"]).reshape(w, per)
+        got = _fetch(st["counts_fut"], "shuffle.counts").reshape(w, per)
         if spec.sketch is not None:
             st["counts_pair"] = (got[:, :w], got[:, w : 2 * w])
             st["send_counts"] = got[:, :w]  # provisional; gated below
@@ -4822,14 +4847,13 @@ def _shuffle_many_rounds(states, rows_total) -> List["Table"]:
                     )
                 if st["wire"] is not None:
                     rep = rep + (st["bases"],)
-                t_pk0 = _time.perf_counter()
                 with span("shuffle.round.pack"):
                     head, pts = get_kernel(
                         ctx, st["key"] + ("pack", st["wire"]),
                         st["build_pack"], name="shuffle_pack",
                         **_codec.kernel_kwargs(),
                     )(dp, rep)
-                t_pk1 = _time.perf_counter()
+                codec_s = _obstrace.last_dispatch_s()
                 # the two-hop plan joins both dispatch keys: its cap_o /
                 # header statics are baked into the kernel bodies, so a
                 # plan (or kill-switch) flip compiles its own program
@@ -4845,7 +4869,6 @@ def _shuffle_many_rounds(states, rows_total) -> List["Table"]:
                          len(st["pt_eff"]), tp_key),
                         st["build_coll"],
                     )((head, pts), ())
-                t_cp0 = _time.perf_counter()
                 with span("shuffle.round.compact"):
                     out, nout = get_kernel(
                         ctx,
@@ -4857,12 +4880,12 @@ def _shuffle_many_rounds(states, rows_total) -> List["Table"]:
                         coll_out,
                         (st["bases"],) if st["wire"] is not None else (),
                     )
-                t_cp1 = _time.perf_counter()
-                # codec-impl evidence for the autopilot (the sort engine's
-                # clock discipline, table.py sort above): the resolved
-                # impl's pack+compact dispatch walls + BOTH impls' modeled
-                # row-pass counts for this shape, so a one-sided profile
-                # can walk back through the per-pass cost model
+                codec_s += _obstrace.last_dispatch_s()
+                # codec-impl evidence for the autopilot: the resolved
+                # impl's pack+compact dispatch walls (the clock readings
+                # every program call leaves with obs.trace) + BOTH impls'
+                # modeled row-pass counts for this shape, so a one-sided
+                # profile can walk back through the per-pass cost model
                 # (plan/feedback._codec_impl_proposal). Pure host
                 # arithmetic + contextvars — 0 sync sites; note_codec
                 # no-ops outside plan executions.
@@ -4907,7 +4930,7 @@ def _shuffle_many_rounds(states, rows_total) -> List["Table"]:
                 if pk_sup or cp_sup:
                     _obsstore.note_codec(
                         cimpl,
-                        (t_pk1 - t_pk0) + (t_cp1 - t_cp0),
+                        codec_s,
                         _codec_units(cimpl),
                         _codec_units(
                             "xla" if cimpl == "pallas" else "pallas"
@@ -4947,7 +4970,9 @@ def _shuffle_many_rounds(states, rows_total) -> List["Table"]:
                     _spill.stage_table(
                         st["sink_obj"], *prev, qspec=st["spill_qsig"]
                     )
-        t_disp = _time.perf_counter()
+        # when the last program of the loop was enqueued (a staging fetch
+        # of a spilled tier, which blocks, is no issuing)
+        t_disp = _obstrace.last_dispatch_return_s()
 
         # the ONE deferred sync per table: every round's received counts
         # come back in a single stacked fetch (fetching per round made the
@@ -4955,7 +4980,6 @@ def _shuffle_many_rounds(states, rows_total) -> List["Table"]:
         # host-sync pass, which pins host_syncs as K-independent), then
         # validate against the count-phase expectation and assemble tables
         for st in states:
-            bump("host_sync")
             t = st["t"]
             src_pairs = st["src_pairs"]
             bc = st["bucket_cap"]
@@ -4967,11 +4991,12 @@ def _shuffle_many_rounds(states, rows_total) -> List["Table"]:
                 # fetch as the round counts — the ring adds no host sync
                 nouts.append(ring_out[1])
             got_all = _fetch(
-                nouts[0] if len(nouts) == 1 else jnp.stack(nouts)
+                nouts[0] if len(nouts) == 1 else jnp.stack(nouts),
+                "shuffle.round_counts",
             ).reshape(len(nouts), -1).astype(np.int64)
             # stage-clock stamp: this fetch's return IS the device-
             # resolved end of this table's exchange (all rounds complete)
-            st["t_dev"] = _time.perf_counter()
+            st["t_dev"] = _obstrace.last_fetch_return_s()
             round_tables: List["Table"] = []
             for r, (out, _nout) in enumerate(st["rounds_out"]):
                 got = got_all[r]

@@ -245,7 +245,7 @@ def test_midloop_sync_fixture_flagged(devices):
     def bad_round_loop(bufs):
         out = []
         for b in bufs:  # one host sync per round — the anti-pattern
-            out.append(_t._fetch(b))
+            out.append(_t._fetch(b, "shuffle.round_counts"))
         return out
 
     with sync_monitor() as events:

@@ -225,8 +225,8 @@ def test_vocabulary_is_defined_once():
     assert constants == set(stages.VOCABULARY)
 
 
-# -- (d) a warm dispatch pays nothing -----------------------------------
-def test_warm_get_kernel_returns_the_bare_jitted_function():
+# -- (d) a warm dispatch pays two clock reads ---------------------------
+def test_warm_get_kernel_returns_the_cached_timed_program():
     ctx = _ctx(2)
     key = ("unit_stage_names", 1)
 
@@ -249,6 +249,7 @@ def test_warm_get_kernel_returns_the_bare_jitted_function():
     assert fn is cached and spec[0].sharding == x.sharding
     assert engine.get_kernel(ctx, key, build) is cached
     assert engine.get_kernel(ctx, key, build) is cached
+    assert isinstance(cached, engine.TimedProgram)
     assert cached.__name__ == "unit_stage_names"
 
 
@@ -273,7 +274,7 @@ def test_span_and_fetch_are_host_events_in_a_profiler_session(tmp_path, local_ct
     jax.profiler.start_trace(str(tmp_path))
     try:
         with tracing.span("unit.stage_span", rows=3):
-            got = _table._fetch(jnp.arange(4))
+            got = _table._fetch(jnp.arange(4), "to_numpy")
     finally:
         jax.profiler.stop_trace()
     assert got.tolist() == [0, 1, 2, 3]
@@ -284,4 +285,4 @@ def test_span_and_fetch_are_host_events_in_a_profiler_session(tmp_path, local_ct
         if plane.name.startswith("/host:")
         for line in plane.lines for e in line.events
     }
-    assert {"unit.stage_span", "host_sync"} <= names
+    assert {"unit.stage_span", "host_sync.to_numpy"} <= names
