@@ -405,6 +405,12 @@ STABLE_METRICS: Dict[str, Tuple[str, str]] = {
         "ring tail, reassembling its parts in one program (.parts rows= "
         "the blocks written, .rows rows= the live rows placed); a "
         "one-round shuffle bumps neither"),
+    "shuffle.bincount.": (
+        "counter", "which form a count of rows into bins took "
+        "(ops.partition.bin_counts: the shuffle's bucket counts, the range "
+        "partitioner's histogram), bumped where a kernel is TRACED, not "
+        "where it runs: .dense a compare and a sum (rows= the bins, up to "
+        "DENSE_BINS_MAX), .scatter an int32 scatter-add past it"),
     "shuffle.overlap_efficiency": (
         "gauge", "fraction of the measured exchange device window "
         "(dispatch-open to the deferred round-count fetch return) spent "

@@ -5,8 +5,9 @@ each round-tripping its intermediate through HBM. Send side (the PACK
 stage, priced 3.0 row passes by the profiler's calibration table;
 ``pallas_pid`` in the pass tables below is the pid-input pack mode —
 one XLA pid pass plus one kernel pass):
-murmur hash over the key columns, a scatter-add histogram
-(``shuffle.bucket_counts``), a stable grouping sort
+murmur hash over the key columns, a bucket histogram
+(``shuffle.bucket_counts``: a compare against the partition ids and a sum
+over the rows, ``ops.partition.bin_counts``), a stable grouping sort
 (``shuffle.shuffle_gather_order``), a ``pid``
 gather through that order and a scatter back to row order just to learn
 each row's destination slot. Receive side (the COMPACT stage): a
@@ -24,7 +25,7 @@ codec argument; the redistribution-fusion payoff model of arxiv
       [1, P] running histogram (the sequential grid's carry) turns them
       into exact global bucket positions — emitting the per-row send
       slot ``dest`` and the full bucket histogram in a single pass.
-      The hash pass, the scatter-add, the grouping sort, and both
+      The hash pass, the histogram pass, the grouping sort, and both
       permutation round-trips are gone.
   kernel 2 (**fused compact**, one ``pallas_call`` over the P source
       chunks): the received chunk counts/starts ride scalar prefetch;

@@ -43,6 +43,7 @@ from ..ops.gather import (
     wire_q8_cols,
     wire_unpack_cols,
 )
+from ..ops.partition import bin_counts
 
 Cols = Sequence[Tuple[jax.Array, Optional[jax.Array]]]
 
@@ -80,10 +81,10 @@ def ordering_after_shuffle(kind: str):
 
 def bucket_counts(pid: jax.Array, num_partitions: int) -> jax.Array:
     """Rows per target partition on this shard -> [P] int32 (padding pid==P
-    is dropped)."""
-    return (
-        jnp.zeros((num_partitions,), jnp.int32).at[pid].add(1, mode="drop")
-    )
+    is dropped): :func:`ops.partition.bin_counts`, which for the handful of
+    partitions a mesh has is a compare against the partition ids and a sum
+    over the rows, not a scatter-add."""
+    return bin_counts(pid, num_partitions)
 
 
 def exchange_counts(counts: jax.Array, axis_name: str) -> jax.Array:

@@ -125,9 +125,7 @@ def task_partition(
             out = [
                 (d[order], None if v is None else v[order]) for d, v in cols
             ]
-            cnt = jnp.zeros((T,), jnp.int32).at[jnp.clip(lane, 0, T)].add(
-                1, mode="drop"
-            )
+            cnt = _p.bin_counts(jnp.clip(lane, 0, T), T)
             return out, cnt
 
         return kern

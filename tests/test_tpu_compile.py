@@ -458,6 +458,84 @@ def test_range_partition_compiles_for_four_chips(mesh4):
     ).compile()
 
 
+def _custom_fusions(text):
+    """``(shape, op_name)`` of the compiled program's ``kCustom`` fusions:
+    what a scatter or a gather becomes on a TPU."""
+    return [
+        (m[1], m[2])
+        for m in re.finditer(
+            r"= (\(.*?\)|\S+) fusion\([^\n]*kind=kCustom[^\n]*?"
+            r'op_name="([^"]*)"',
+            text,
+        )
+    ]
+
+
+def _range_count_text(mesh4, num_bins):
+    """``sort-w4``'s count: the range partition ids of an int64 key (x64
+    on), 2^20 rows a shard, then the bucket counts."""
+    rows = NamedSharding(mesh4, PartitionSpec("dp"))
+
+    def kern(key, counts):
+        pid = _p.range_partition_ids(
+            (key, None), counts[0], WORLD, num_bins=num_bins, axis_name="dp"
+        )
+        return pid, _sh.bucket_counts(pid, WORLD)
+
+    step = jax.jit(jax.shard_map(
+        kern, mesh=mesh4, in_specs=PartitionSpec("dp"),
+        out_specs=PartitionSpec("dp"),
+    ))
+    return step.lower(
+        _spec((WORLD * ROWS,), jnp.int64, rows),
+        _spec((WORLD,), jnp.int32, rows),
+    ).compile().as_text()
+
+
+def test_range_count_compiles_for_four_chips_without_a_scatter(mesh4):
+    """The histogram of 64 bins and the count into four partitions are a
+    compare and a sum: no scatter-add of every row (the int64 histogram was
+    a ``(u32[64], u32[64])`` variadic one, 62.5 ms a call on a v5e; the
+    bucket counts an ``s32[4]`` one, 9.15 ms), and the bin-to-partition
+    lookup of 64 entries is no gather either."""
+    text = _range_count_text(mesh4, None)
+    assert not re.search(r"\s(scatter|gather)\(", text)
+    assert _custom_fusions(text) == []
+
+
+def test_hash_count_compiles_for_tpu_without_a_scatter(one_chip):
+    """``join-w4``'s count: the id hash of an int64 key, then the bucket
+    counts; under the semi filter the filtered ids are counted too."""
+    def count(key, keep, n):
+        pid = _p.hash_partition_ids([(key, None)], n, WORLD)
+        pid_f = jnp.where(keep, pid, WORLD)
+        return jnp.concatenate(
+            [_sh.bucket_counts(pid, WORLD), _sh.bucket_counts(pid_f, WORLD)]
+        )
+
+    text = _compile(
+        count,
+        _spec((ROWS,), jnp.int64, one_chip),
+        _spec((ROWS,), jnp.bool_, one_chip),
+        _spec((), jnp.int32, one_chip),
+    ).as_text()
+    assert not re.search(r"\s(scatter|gather)\(", text)
+    assert _custom_fusions(text) == []
+
+
+def test_a_histogram_past_the_dense_bound_scatters_in_int32(mesh4):
+    """Past ``DENSE_BINS_MAX`` bins the rows are scatter-added, into int32
+    and never into the two halves of an int64."""
+    text = _range_count_text(mesh4, _p.DENSE_BINS_MAX + 1)
+    adds = [
+        shape for shape, op in _custom_fusions(text)
+        if op.endswith("scatter-add")
+    ]
+    assert adds and all(
+        re.fullmatch(rf"s32\[{_p.DENSE_BINS_MAX + 1}\]\S*", a) for a in adds
+    ), adds
+
+
 # ----------------------------------------------------------------------
 # (f) group-by: factorize + segment-sum
 # ----------------------------------------------------------------------
