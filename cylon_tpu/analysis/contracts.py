@@ -471,6 +471,7 @@ EFFECT_SIGNATURES: Dict[str, str] = {
     "DataFrame.to_pandas": "SYNC",
     "DataFrame.to_table": "DISPATCH_SAFE",
     "DataFrame.where": "MATERIALIZE",
+    "LazyFrame.agg": "DISPATCH_SAFE",
     "LazyFrame.collect": "SYNC",
     # the serving submit path (ISSUE 9): enqueue-only — zero host syncs
     "LazyFrame.collect_async": "DISPATCH_SAFE",

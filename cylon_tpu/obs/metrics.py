@@ -373,7 +373,21 @@ STABLE_METRICS: Dict[str, Tuple[str, str]] = {
         "holds: emit_slots (rows= slots an emit wrote) and emit_rows "
         "(rows= rows live in them)"),
     "setop.": ("span", "union/subtract/intersect dispatch"),
-    "groupby.": ("span", "groupby phases (emit)"),
+    "groupby.": (
+        "mixed", "groupby phases as spans (emit) + the path a call took as "
+        "counters: dense_path, factorize_path, partial_path"),
+    "groupby.partial_path": (
+        "counter", "group-bys on a mesh whose shards' partial states were "
+        "combined in place (Table.distributed_groupby on the dense plan: "
+        "no exchange of rows); bumped beside groupby.dense_path"),
+    "groupby.partial.rows": (
+        "counter", "input rows those calls aggregated without an exchange "
+        "(rows= the table's host-known row count, 0 while it is deferred); "
+        "beside shuffle.coll_rows it is the share of rows that never "
+        "crossed the mesh"),
+    "groupby.combine.slots": (
+        "counter", "slots of the partial table a combine moved (rows= the "
+        "dense id space: the product of the key spans, 1 without keys)"),
     "shuffle.count": ("span", "shuffle count-phase kernel + fetch"),
     "shuffle.exchange": ("span", "whole K-round exchange wall"),
     "shuffle.round.": ("span", "per-round pack/collective/compact dispatch"),

@@ -46,6 +46,7 @@ SEMI_SKETCH = "semi.sketch"
 GROUPBY_SEGMENT_SUM = "groupby.segment_sum"
 GROUPBY_KEY_IDS = "groupby.key_ids"
 GROUPBY_DENSE_AGG = "groupby.dense_agg"
+GROUPBY_COMBINE = "groupby.combine"
 EXPR_EVAL = "expr.eval"
 
 VOCABULARY = (
@@ -54,7 +55,7 @@ VOCABULARY = (
     SHUFFLE_COUNT, SHUFFLE_PACK, SHUFFLE_ALL_TO_ALL, SHUFFLE_COMPACT,
     SHUFFLE_REASSEMBLE,
     SEMI_SKETCH, GROUPBY_SEGMENT_SUM, GROUPBY_KEY_IDS, GROUPBY_DENSE_AGG,
-    EXPR_EVAL,
+    GROUPBY_COMBINE, EXPR_EVAL,
 )
 _VOCABULARY = frozenset(VOCABULARY)
 

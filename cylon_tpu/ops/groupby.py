@@ -41,7 +41,7 @@ from .sort import (
     lexsort_indices, orderable_key, run_reduce, scan_identity, unflatten_cols,
     wide_float, wide_int,
 )
-from .stats import decode_enc
+from .stats import decode_enc, wire_narrowable
 
 # aggregation op ids, mirroring reference AggregationOpId
 # (compute/aggregate_kernels.hpp:40-50)
@@ -303,6 +303,16 @@ DENSE_OPS = frozenset({SUM, COUNT, MIN, MAX, MEAN})
 DENSE_MAX_SLOTS = 1024
 
 
+def dense_span(stat) -> Optional[int]:
+    """Slots a key column with range ``stat`` (``ops.stats.ColStat``)
+    takes on the dense path: its span rounded up to a power of two, so
+    that a drifting range compiles nothing; None where there is no stat
+    or the key's encoding is not an integer's (a float key)."""
+    if stat is None or not wire_narrowable(stat.cls):
+        return None
+    return 1 << max(0, stat.hi - stat.lo).bit_length()
+
+
 def dense_slots(spans: Sequence[int], nullable: Sequence[bool]) -> int:
     """Slots of the dense id space: the product of the spans, a nullable
     key taking one more slot (its null, last)."""
@@ -314,7 +324,7 @@ def dense_slots(spans: Sequence[int], nullable: Sequence[bool]) -> int:
 
 def dense_group_ids(
     key_cols: Sequence[KeyCol], los, spans: Sequence[int], n: jax.Array,
-    mask: Optional[jax.Array],
+    mask: Optional[jax.Array], cap: Optional[int] = None,
 ) -> jax.Array:
     """Slot of every row, [cap] int32: arithmetic on the rebased keys,
     first key most significant, a null key in its column's last slot (the
@@ -323,9 +333,12 @@ def dense_group_ids(
 
     ``los`` are the lower bounds of the keys' orderable encodings (traced
     scalars: a drifting range compiles nothing), ``spans`` the static
-    widths that hold every live value."""
+    widths that hold every live value. With no key at all every kept row
+    has slot 0 of one (an aggregate over the whole table), and ``cap``
+    says how many rows a shard holds."""
     with jax.named_scope(_stages.GROUPBY_KEY_IDS):
-        cap = key_cols[0][0].shape[0]
+        if key_cols:
+            cap = key_cols[0][0].shape[0]
         keep = jnp.arange(cap, dtype=jnp.int32) < n
         if mask is not None:
             keep = keep & mask
@@ -348,25 +361,25 @@ def dense_rows(gid: jax.Array, slots: int) -> jax.Array:
         return jnp.sum(onehot, axis=1, dtype=jnp.int32)
 
 
-def dense_aggregate(
+def dense_partial(
     op: int, data: jax.Array, valid: Optional[jax.Array], gid: jax.Array,
     slots: int,
 ) -> Tuple[jax.Array, Optional[jax.Array]]:
-    """One aggregate of :data:`DENSE_OPS` over the slots: a masked
-    reduction a slot, in the dtypes and with the null rules of
-    :func:`groupby_aggregate` (nulls skipped, count counts non-null, a
-    float sum stays in the column's dtype, an integer sum widens).
-    Returns (out [slots], valid [slots] | None); a slot with no row is
-    dropped by the caller."""
+    """One shard's partial state of an aggregate of :data:`DENSE_OPS`:
+    ``(cnt, acc)``, the values that count a slot ([slots] int32; nulls are
+    skipped) and their masked sum, minimum or maximum a slot (None for
+    COUNT; MEAN keeps its sum and divides in :func:`dense_finalize`), in
+    the dtypes of :func:`groupby_aggregate` (a float sum stays in the
+    column's dtype, an integer sum widens). The partials of disjoint sets
+    of rows combine slot by slot (:func:`dense_combine`)."""
     with jax.named_scope(_stages.GROUPBY_DENSE_AGG):
         onehot = gid[None, :] == jnp.arange(slots, dtype=jnp.int32)[:, None]
         if valid is not None:
             onehot = onehot & valid[None, :]
         # at most cap < 2**31 rows a shard: an int32 count is exact
         cnt = jnp.sum(onehot, axis=1, dtype=jnp.int32)
-        has = (cnt > 0) if valid is not None else None
         if op == COUNT:
-            return cnt.astype(wide_int()), None
+            return cnt, None
 
         def reduce(x, fill, fn):
             return fn(jnp.where(onehot, x[None, :], fill), axis=1)
@@ -376,15 +389,105 @@ def dense_aggregate(
                 data.astype(wide_int())
                 if jnp.issubdtype(data.dtype, jnp.integer) else data
             )
-            return reduce(acc, jnp.zeros((), acc.dtype), jnp.sum), has
+            return cnt, reduce(acc, jnp.zeros((), acc.dtype), jnp.sum)
         if op in (MIN, MAX):
             kind, fn = ("min", jnp.min) if op == MIN else ("max", jnp.max)
-            return reduce(data, scan_identity(kind, data.dtype), fn), has
+            return cnt, reduce(data, scan_identity(kind, data.dtype), fn)
         if op == MEAN:
             x = data.astype(wide_float())
-            s = reduce(x, jnp.zeros((), x.dtype), jnp.sum)
-            return s / jnp.maximum(cnt, 1), cnt > 0
+            return cnt, reduce(x, jnp.zeros((), x.dtype), jnp.sum)
     raise ValueError(f"aggregation op {op} has no dense form")
+
+
+def dense_finalize(
+    op: int, cnt: jax.Array, acc: Optional[jax.Array], may_be_empty: bool,
+) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """An aggregate's ``(out [slots], valid [slots] | None)`` from its
+    (combined) partial state. ``may_be_empty``: a slot that has rows may
+    still hold no value (the column is nullable, or there are no keys and
+    so the one slot is emitted whatever it holds); the result is then null
+    there, as SQL has it, and a count 0. A slot with no row at all is
+    dropped by the caller."""
+    with jax.named_scope(_stages.GROUPBY_DENSE_AGG):
+        if op == COUNT:
+            return cnt.astype(wide_int()), None
+        if op == MEAN:
+            return acc / jnp.maximum(cnt, 1), cnt > 0
+        return acc, (cnt > 0) if may_be_empty else None
+
+
+def dense_aggregate(
+    op: int, data: jax.Array, valid: Optional[jax.Array], gid: jax.Array,
+    slots: int, may_be_empty: Optional[bool] = None,
+) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """One aggregate of :data:`DENSE_OPS` over the slots of one shard that
+    holds every row: a masked reduction a slot (:func:`dense_partial`)
+    and its closing arithmetic (:func:`dense_finalize`), with the null
+    rules of :func:`groupby_aggregate` (nulls skipped, count counts
+    non-null). Returns (out [slots], valid [slots] | None)."""
+    if may_be_empty is None:
+        may_be_empty = valid is not None
+    return dense_finalize(
+        op, *dense_partial(op, data, valid, gid, slots), may_be_empty
+    )
+
+
+#: how an op's accumulator combines (every other: a sum)
+_COMBINE = {MIN: jnp.minimum, MAX: jnp.maximum}
+
+
+def _fold(parts: jax.Array, fn) -> jax.Array:
+    """``parts`` [world, ...] folded along the first axis in shard order:
+    ((p0 . p1) . p2) ..., the same on every shard and in every run."""
+    acc = parts[0]
+    for p in range(1, parts.shape[0]):
+        acc = fn(acc, parts[p])
+    return acc
+
+
+def dense_combine(rows: jax.Array, parts, axis_name: str):
+    """The shards' partial slot tables combined over the mesh axis: ``rows``
+    ([slots] int32) and ``parts`` (``(op, cnt, acc)`` an aggregate, as
+    :func:`dense_partial` gives them) in, the same structure out, every
+    shard holding the whole table's. Stage ``groupby.combine``.
+
+    Counts and integer sums are exact in any order and take one ``psum``
+    each dtype. A float sum is NOT: the shards' partials are gathered
+    (``all_gather`` moves a float64 as the 32-bit halves the chip holds it
+    in, and adds nothing) and added in shard order on every shard, in the
+    arithmetic the reductions themselves use, so a query gives the same
+    bits run to run and the mesh's reduction order is nobody's choice.
+    Minima and maxima ride the same gather: the TPU compiler lowers a
+    64-bit all-reduce for sums only (``ops/partition.py``). The lanes of
+    one dtype share a collective: Q1's nine lanes of six slots are one
+    psum and one all_gather."""
+    with jax.named_scope(_stages.GROUPBY_COMBINE):
+        lanes = [(rows, jnp.add)]
+        for op, cnt, acc in parts:
+            lanes.append((cnt, jnp.add))
+            if acc is not None:
+                lanes.append((acc, _COMBINE.get(op, jnp.add)))
+        groups = {}  # (dtype, summed exactly) -> the lanes' positions
+        for i, (x, fn) in enumerate(lanes):
+            exact = fn is jnp.add and jnp.issubdtype(x.dtype, jnp.integer)
+            groups.setdefault((x.dtype, exact), []).append(i)
+        out = [None] * len(lanes)
+        for (_dtype, exact), idx in groups.items():
+            stacked = jnp.stack([lanes[i][0] for i in idx])
+            if exact:
+                total = jax.lax.psum(stacked, axis_name)
+                for k, i in enumerate(idx):
+                    out[i] = total[k]
+            else:
+                every = jax.lax.all_gather(stacked, axis_name)
+                for k, i in enumerate(idx):  # [world, lanes, slots]
+                    out[i] = _fold(every[:, k], lanes[i][1])
+        it = iter(out)
+        rows = next(it)
+        return rows, [
+            (op, next(it), None if acc is None else next(it))
+            for op, _cnt, acc in parts
+        ]
 
 
 def dense_emit(
@@ -395,10 +498,11 @@ def dense_emit(
     their slot: ``(key columns + aggregate columns, each [cap_out], number
     of groups)``, groups in canonical key order. ``key_meta`` holds each
     key's ``(enc class, dtype)``; the handful of slots is reordered by a
-    stable argsort of their emptiness."""
+    stable argsort of their emptiness. With no key the one slot is a row
+    whatever it holds: an aggregate over no rows is still one row."""
     with jax.named_scope(_stages.GROUPBY_DENSE_AGG):
         slots = rows.shape[0]
-        present = rows > 0
+        present = (rows > 0) if key_meta else jnp.ones_like(rows, jnp.bool_)
         ng = jnp.sum(present, dtype=jnp.int32)
         order = jnp.argsort(~present, stable=True).astype(jnp.int32)
         order = jnp.pad(order, (0, cap_out - slots))

@@ -154,6 +154,13 @@ class ColStat(NamedTuple):
         return ColStat(min(self.lo, other.lo), max(self.hi, other.hi), self.cls)
 
 
+def dictionary_stat(size: int) -> ColStat:
+    """The range of a dictionary column's int32 codes, known without a
+    measurement: code 0 in the orderable encoding, ``size`` codes."""
+    lo = 1 << 31
+    return ColStat(lo, lo + max(1, int(size)) - 1, "i32")
+
+
 def field_bits(stat: ColStat) -> int:
     """QUANTIZED field width of a stat's span: exact for 0-2 bits, else
     rounded up to a multiple of 4 (cap 64). Quantization is what keeps the

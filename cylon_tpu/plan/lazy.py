@@ -203,6 +203,29 @@ class LazyFrame:
             return LazyGroupBy(self, keys)
         return self._wrap(GroupBy(self._plan, keys, _normalize_aggs(agg)))
 
+    def agg(
+        self, spec: Dict[str, TUnion[str, Sequence[str]]]
+    ) -> "LazyFrame":
+        """Aggregates over the whole frame, no keys: ``filter(p).agg({"x":
+        "sum"})`` is ``select sum(x) where p``. Ops: sum, count, min, max,
+        mean; columns are named ``col_op`` as in :meth:`groupby`. Exactly
+        one row comes back, also where no row passes: ``count`` 0 and
+        every other aggregate null, as SQL has it. A filter below rides
+        the reductions as their row mask, and on a mesh each shard's
+        partial state is combined in place (``partial_aggregate``)."""
+        from ..ops import groupby as _g
+
+        aggs = _normalize_aggs(spec)
+        bad = sorted({
+            op for _c, op in aggs if _g.agg_op_id(op) not in _g.DENSE_OPS
+        })
+        if bad:
+            raise ValueError(
+                "LazyFrame.agg takes sum, count, min, max and mean; group "
+                f"by a key for {bad}"
+            )
+        return self._wrap(GroupBy(self._plan, (), aggs))
+
     def sort(
         self,
         by: TUnion[str, Sequence[str]],

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import ordering as _ord
 from ..column import Column
 from ..engine import get_kernel
 from ..obs import stages as _stages
@@ -252,6 +253,15 @@ def _expr_mask(t, expr) -> jax.Array:
     return keep_mask(*eval_exprs(t, [expr])[0])
 
 
+def _globally_ordered(t, keys) -> bool:
+    """Does ``t``'s descriptor prove its rows in ``keys`` order over the
+    whole mesh (one shard is the whole mesh)?"""
+    o = t._ordering
+    return _ord.covers_prefix(o, keys) and (
+        t.world_size == 1 or o.scope == "global"
+    )
+
+
 def _lower_one(node: Node, ex, tables):
     if isinstance(node, Scan):
         return tables[node.ordinal]
@@ -285,7 +295,18 @@ def _lower_one(node: Node, ex, tables):
         # filter_as_mask rewrite: the table takes the predicate as the
         # aggregate's row mask (and still picks its own kernel)
         mask = None if node.mask is None else _expr_mask(t, node.mask)
-        res = t.groupby(list(node.keys), spec, _mask=mask)
+        keys = list(node.keys)
+        if node.partial or not keys:
+            # partial_aggregate rewrite: no Shuffle stands under the node;
+            # the table combines the shards' partial states in place where
+            # its dense plan applies and shuffles where it does not
+            res = t.distributed_groupby(keys, spec, _mask=mask)
+            if keys and not _globally_ordered(res, keys):
+                # the node claimed global key order (a Sort above it may be
+                # gone): hold the table to it if it took the other path
+                res = res.distributed_sort(keys)
+        else:
+            res = t.groupby(keys, spec, _mask=mask)
         # multiple ops per column group in dict order; restore plan order
         if res.column_names != node.names:
             res = res.project(node.names)

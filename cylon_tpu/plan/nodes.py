@@ -148,7 +148,16 @@ class Scan(Node):
     def col_stats(self) -> Dict[str, object]:
         if self.table is None:  # detached stub
             return dict(self.table_stats)
-        return dict(self.table._stats)
+        stats = dict(self.table._stats)
+        # a dictionary column's codes have a range without a measurement
+        # (what Table._dense_groupby_plan reads): the partial_aggregate
+        # rule must see it on a table no group-by has touched yet
+        from ..ops.stats import dictionary_stat
+
+        for n, c in self.table._columns.items():
+            if c.dtype.is_dictionary and n not in stats:
+                stats[n] = dictionary_stat(len(c.dictionary))
+        return stats
 
     def stream_gen(self):
         """The bound table's streaming identity ``(source_token,
@@ -458,6 +467,9 @@ class Join(Node):
 
 
 class GroupBy(Node):
+    """Aggregates a group of equal ``keys``; with no key (``LazyFrame.agg``)
+    one row over the whole table, also where no row passes."""
+
     def __init__(
         self,
         child: Node,
@@ -465,6 +477,7 @@ class GroupBy(Node):
         aggs: Sequence[Tuple[str, str]],
         sorted_input: bool = False,
         mask=None,
+        partial: bool = False,
     ):
         self.children = (child,)
         self.keys = tuple(keys)
@@ -479,6 +492,13 @@ class GroupBy(Node):
         # instead of lexsorting (the eager gate re-verifies — the plan
         # claim is advisory, the kernel choice is the table's)
         self.sorted_input = bool(sorted_input)
+        # set by the partial_aggregate rewrite on a mesh: the dense plan
+        # takes this aggregate, so no Shuffle stands under it; each shard
+        # reduces its own rows and the partial states are combined in
+        # place (``Table.distributed_groupby``), the groups emitted once,
+        # on the first shard, in key order. Lowering holds the result to
+        # that, whatever path the table then takes
+        self.partial = bool(partial)
         by_name = {e[0]: e for e in child.schema}
         out = [by_name[k] for k in keys]
         for c, op in self.aggs:
@@ -486,21 +506,36 @@ class GroupBy(Node):
             out.append((f"{c}_{op}",) + _agg_out_dtype(op, t, p))
         self.schema = tuple(out)
 
-    def with_children(self, kids):
-        return GroupBy(
-            kids[0], self.keys, self.aggs, self.sorted_input, self.mask
+    def replaced(self, child: Node, **changes) -> "GroupBy":
+        """A copy over ``child`` with ``changes`` to the annotations."""
+        kw = dict(
+            sorted_input=self.sorted_input, mask=self.mask,
+            partial=self.partial,
         )
+        kw.update(changes)
+        return GroupBy(child, self.keys, self.aggs, **kw)
+
+    def with_children(self, kids):
+        return self.replaced(kids[0])
 
     def partitioning(self) -> Partitioning:
+        if self.partial:
+            return []  # every group on the first shard: no claim needed
         kept = set(self.keys)
         return [s for s in self.children[0].partitioning() if set(s) <= kept]
 
     def ordering(self) -> Optional[Ordering]:
-        # groups emit in canonical key order (factorize id order)
+        if not self.keys:
+            return None  # one row: nothing to order by
+        # groups emit in canonical key order (factorize id order). A
+        # partial aggregate's lie on ONE shard, so the order is global,
+        # and a slot table's null keys all carry one payload (the slot
+        # past the span), so a lexsort of it is the identity: the claim
+        # that lets order_reuse drop a Sort and its range shuffle
         return Ordering(
             keys=self.keys, ascending=(True,) * len(self.keys),
-            nulls_last=True, scope="shard", canonical=True,
-            lexsort_exact=False,
+            nulls_last=True, scope="global" if self.partial else "shard",
+            canonical=True, lexsort_exact=self.partial,
         )
 
     def col_stats(self) -> Dict[str, object]:
@@ -513,7 +548,7 @@ class GroupBy(Node):
     def _params(self) -> tuple:
         return (
             self.keys, self.aggs, self.sorted_input,
-            None if self.mask is None else self.mask.key(),
+            None if self.mask is None else self.mask.key(), self.partial,
         )
 
     def label(self) -> str:
@@ -522,9 +557,15 @@ class GroupBy(Node):
             " [input key-ordered: groupby lexsort elided]"
             if self.sorted_input else ""
         )
+        if self.partial:
+            tail += (
+                " [partial aggregate: combined in place, no shuffle,"
+                " groups on the first shard]"
+            )
         if self.mask is not None:
             tail += f" mask {self.mask!r}"
-        return f"GroupBy [{', '.join(self.keys)}] agg [{spec}]{tail}"
+        head = f"GroupBy [{', '.join(self.keys)}]" if self.keys else "Aggregate"
+        return f"{head} agg [{spec}]{tail}"
 
 
 class Sort(Node):

@@ -21,6 +21,16 @@ the rewritten plan plus the ordered list of rule firings (surfaced by
    ordered key tuple the other side will hash (plus dtype-identical key
    pairs) — a subset placement co-locates rows but routes them to
    different shards than the fresh hash of the full tuple;
+3b. ``partial_aggregate`` — on a mesh, a GroupBy whose Shuffle still
+   stands and which the dense plan takes (every op one of sum, count,
+   min, max, mean; every key with a known integer range, from
+   ``Node.col_stats`` and the dictionary sizes a Scan reports; few enough
+   slots) loses the Shuffle and is marked ``partial``: every shard
+   reduces its own rows, the partial states are combined in place
+   (``Table.distributed_groupby``) and the groups lie on the first shard
+   in key order, so the node's order is global and ``order_reuse`` drops
+   a following Sort with its range shuffle. An aggregate without keys
+   (``LazyFrame.agg``) is always one. Any other GroupBy keeps its plan;
 4. ``fused_join_groupby`` — collapse GroupBy(sum)-over-inner-Join on the
    join key into :class:`~cylon_tpu.plan.nodes.FusedJoinGroupBySum`
    (lowers to ``ops.join.join_sum_by_key_pushdown``);
@@ -84,6 +94,7 @@ SEMI_FILTER = "semi_filter"
 PROJECTION_PUSHDOWN = "projection_pushdown"
 TOPK = "topk"
 JOIN_MASK = "join_mask"
+PARTIAL_AGGREGATE = "partial_aggregate"
 
 
 def optimize(root: Node, world_size: int) -> Tuple[Node, List[str]]:
@@ -93,6 +104,8 @@ def optimize(root: Node, world_size: int) -> Tuple[Node, List[str]]:
     if world_size > 1:
         root = _physicalize(root)
     root = _eliminate_shuffles(root, fired)
+    if world_size > 1:
+        root = _partial_aggregates(root, fired)
     root = _fuse_join_groupby(root, fired)
     root = _filter_as_mask(root, fired)
     root = _filter_as_join_mask(root, fired)
@@ -187,7 +200,7 @@ def _physicalize(node: Node) -> Node:
             Shuffle(kids[0], node.l_on, "hash"),
             Shuffle(kids[1], node.r_on, "hash"),
         ]
-    elif isinstance(node, GroupBy):
+    elif isinstance(node, GroupBy) and node.keys:
         kids = [Shuffle(kids[0], node.keys, "hash")]
     elif isinstance(node, Union):
         kids = [Shuffle(k, k.names, "hash") for k in kids]
@@ -254,12 +267,56 @@ def _eliminate_shuffles(node: Node, fired: List[str]) -> Node:
 
 
 # ----------------------------------------------------------------------
+# 3b. an aggregate the dense plan takes is combined where its rows lie
+# ----------------------------------------------------------------------
+def _dense_plan_takes(node: GroupBy, child: Node) -> bool:
+    """Will ``Table._dense_groupby_plan`` take this aggregate over
+    ``child``'s rows? Decided from what the plan carries: the ops, and
+    the keys' ranges in ``child.col_stats()`` (a dictionary column's from
+    its size). Every key is counted as nullable (the plan holds no
+    validity), so a yes here is a yes there; a no keeps the Shuffle, under
+    which the table still picks its kernel a shard."""
+    from ..ops import groupby as _g
+
+    if not all(_g.agg_op_id(op) in _g.DENSE_OPS for _c, op in node.aggs):
+        return False
+    stats = child.col_stats()
+    spans = [_g.dense_span(stats.get(k)) for k in node.keys]
+    if None in spans:
+        return False
+    return _g.dense_slots(spans, [True] * len(spans)) <= _g.DENSE_MAX_SLOTS
+
+
+def _partial_aggregates(node: Node, fired: List[str]) -> Node:
+    """``GroupBy(Shuffle hash(x))`` -> ``GroupBy(x, partial=True)`` where
+    the dense plan takes it; a GroupBy without keys (never given a
+    Shuffle) likewise. Runs after shuffle elimination: a GroupBy whose
+    input is already co-located has no Shuffle left and stays local."""
+    kids = [_partial_aggregates(c, fired) for c in node.children]
+    node = node.with_children(kids) if node.children else node
+    if not isinstance(node, GroupBy) or node.partial:
+        return node
+    child = node.children[0]
+    if node.keys:
+        if not (
+            isinstance(child, Shuffle) and child.kind == "hash"
+            and child.keys == node.keys
+        ):
+            return node
+        child = child.children[0]
+        if not _dense_plan_takes(node, child):
+            return node
+    fired.append(PARTIAL_AGGREGATE)
+    return node.replaced(child, partial=True)
+
+
+# ----------------------------------------------------------------------
 # 4. fused join -> groupby-SUM pushdown
 # ----------------------------------------------------------------------
 def _fuse_join_groupby(node: Node, fired: List[str]) -> Node:
     kids = [_fuse_join_groupby(c, fired) for c in node.children]
     node = node.with_children(kids) if node.children else node
-    if not isinstance(node, GroupBy):
+    if not isinstance(node, GroupBy) or node.partial:
         return node
     join = node.children[0]
     if not isinstance(join, Join) or join.how != "inner":
@@ -329,9 +386,7 @@ def _filter_as_mask(node: Node, fired: List[str]) -> Node:
     below = child.children[0]
     if above is not None:
         below = above.with_children([below])
-    return GroupBy(
-        below, node.keys, node.aggs, node.sorted_input, mask=child.expr
-    )
+    return node.replaced(below, mask=child.expr)
 
 
 # ----------------------------------------------------------------------
@@ -403,7 +458,10 @@ def _reuse_order(node: Node, fired: List[str]) -> Node:
                 fired.append(ORDER_REUSE)
                 return child.children[0]
         return node
-    if isinstance(node, GroupBy) and not node.sorted_input:
+    if (
+        isinstance(node, GroupBy) and not node.sorted_input
+        and not node.partial  # reduced where its rows lie: no order to use
+    ):
         from ..ordering import enabled
 
         join = node.children[0]
@@ -421,7 +479,7 @@ def _reuse_order(node: Node, fired: List[str]) -> Node:
             # the eager gate run-detects off the emitted descriptor
             fired.append(ORDER_REUSE)
             j2 = join.replaced(join.children, emit_key_order=True)
-            return GroupBy(j2, node.keys, node.aggs, sorted_input=True)
+            return node.replaced(j2, sorted_input=True)
     return node
 
 

@@ -108,6 +108,10 @@ def _rewrite(node: Node, memo: Dict[int, Tuple[Node, str]]) -> Tuple[Node, str]:
         # per-binding suffix order matches the serial sort's key order
         out = (Sort(child, (q,) + node.by, (True,) + node.ascending), q)
     elif isinstance(node, GroupBy):
+        if not node.keys:
+            # one row a binding also where none of its rows pass: a
+            # group-by on the qid would drop that binding's row
+            raise Unbatchable("aggregate without keys")
         child, q = _rewrite(node.children[0], memo)
         out = (GroupBy(child, (q,) + node.keys, node.aggs, mask=node.mask), q)
     elif isinstance(node, Join):

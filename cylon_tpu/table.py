@@ -111,6 +111,25 @@ def _bump_pack_ride(plan_sig, wire, n_pt: int) -> None:
     bump("shuffle.pack.ride_batches", rows=batches)
 
 
+#: what an aggregate without keys is refused with on the other path
+_KEYLESS_OPS = (
+    "an aggregate without keys takes sum, count, min, max and mean "
+    "(the dense path's ops); group by a key for any other"
+)
+
+
+def _agg_specs(agg) -> List[Tuple[str, int, str]]:
+    """An ``agg`` mapping (value column -> op or ops) as the flat list of
+    ``(column, op id, op name)`` the group-by kernels take."""
+    specs: List[Tuple[str, int, str]] = []
+    for col, ops in agg.items():
+        ops_list = ops if isinstance(ops, (list, tuple)) else [ops]
+        for o in ops_list:
+            oid = _g.agg_op_id(o)
+            specs.append((col, oid, o if isinstance(o, str) else _agg_name(oid)))
+    return specs
+
+
 def _scalar(x) -> jax.Array:
     """Per-shard [1] arrays carry scalars through shard_map."""
     return x.reshape(1) if hasattr(x, "reshape") else jnp.asarray([x])
@@ -2753,6 +2772,11 @@ class Table:
         rebased keys and every aggregate is a masked reduction a slot: no
         sort, no scatter, no gather, same output. The input decides.
 
+        No key at all (``by=[]``, the planner's ``LazyFrame.agg``): the
+        dense path with one slot, for sum, count, min, max and mean. The
+        result is exactly one row a shard, also where no row passes:
+        ``count`` 0 and every other aggregate null, as SQL has it.
+
         ``_mask`` (the planner's ``GroupBy(Filter)`` rewrite): a row mask
         as :meth:`filter` takes it; rows whose mask is false or null
         count in no aggregate and found no group, as if filtered first.
@@ -2760,14 +2784,7 @@ class Table:
         rows are filtered first. ``_dense=False`` (tests and
         measurements) keeps a call off the dense path."""
         key_names = self._resolve_cols(by)
-        # normalize agg spec -> list of (col, op_id, op_name)
-        specs: List[Tuple[str, int, str]] = []
-        for col, ops in agg.items():
-            ops_list = ops if isinstance(ops, (list, tuple)) else [ops]
-            for o in ops_list:
-                oid = _g.agg_op_id(o)
-                oname = o if isinstance(o, str) else _agg_name(oid)
-                specs.append((col, oid, oname))
+        specs = _agg_specs(agg)
         provably_sorted = _ord.covers_prefix(self._ordering, key_names)
         dense = None
         if _dense and not (_sorted or provably_sorted):
@@ -2776,6 +2793,8 @@ class Table:
             )
         if dense is not None:
             return self._groupby_dense(key_names, specs, dense, _mask)
+        if not key_names:
+            raise ValueError(_KEYLESS_OPS)
         if _mask is not None:
             return self.filter(_mask).groupby(
                 by, agg, ddof, quantile, _sorted, _dense=False
@@ -2846,11 +2865,14 @@ class Table:
         )
 
     def _groupby_result(
-        self, key_names, specs, out, nout, cap_out: int, canonical: bool
+        self, key_names, specs, out, nout, cap_out: int, canonical: bool,
+        scope: str = "shard",
     ) -> "Table":
         """The group-by's output table from a kernel's (key columns,
         aggregate columns, group count): names, dtypes, dictionaries,
-        stats and ordering descriptor, the same for either path."""
+        stats and ordering descriptor, the same for either path
+        (``scope`` "global" where the groups of the whole mesh lie on the
+        first shard; no descriptor without a key to order by)."""
         names_src: List[Tuple[str, Column]] = [
             (n, self._columns[n]) for n in key_names
         ]
@@ -2869,11 +2891,11 @@ class Table:
         res = res._attach_stats(
             {n: self._stats.get(n) for n in key_names}
         )
-        if canonical:
+        if canonical and key_names:
             res._attach_ordering(Ordering(
                 keys=tuple(key_names),
                 ascending=(True,) * len(key_names),
-                nulls_last=True, scope="shard", canonical=True,
+                nulls_last=True, scope=scope, canonical=True,
                 lexsort_exact=all(
                     self._columns[n].valid is None for n in key_names
                 ),
@@ -2901,15 +2923,16 @@ class Table:
             if c.dtype.is_dictionary:
                 # code 0 in the orderable encoding of an int32; the range
                 # is known without a measurement, and kept like one
-                cls, lo, width = "i32", 1 << 31, max(1, len(c.dictionary))
+                stat = _st.dictionary_stat(len(c.dictionary))
+                width = stat.hi - stat.lo + 1
                 if _st.enabled() and n not in self._stats:
-                    self._stats[n] = _st.ColStat(lo, lo + width - 1, cls)
+                    self._stats[n] = stat
             else:
                 stat = stats.get(n)
-                if stat is None or not _st.wire_narrowable(stat.cls):
+                width = _g.dense_span(stat)
+                if width is None:
                     return None
-                cls, lo = stat.cls, stat.lo
-                width = 1 << max(0, stat.hi - stat.lo).bit_length()
+            cls, lo = stat.cls, stat.lo
             los.append((np.uint64 if _st.is64(cls) else np.uint32)(lo))
             spans.append(width)
             meta.append((cls, str(c.data.dtype)))
@@ -2918,8 +2941,19 @@ class Table:
             return None
         return tuple(los), tuple(spans), tuple(meta)
 
-    def _groupby_dense(self, key_names, specs, plan, mask) -> "Table":
-        """The dense group-by: one program, ``jit_groupby_dense``."""
+    def _groupby_dense(
+        self, key_names, specs, plan, mask, combine: bool = False
+    ) -> "Table":
+        """The dense group-by: one program, ``jit_groupby_dense``.
+
+        ``combine`` (a mesh; :meth:`distributed_groupby`): every shard
+        reduces its own rows to the partial slot table, the tables are
+        combined over the mesh axis inside the same program
+        (``ops.groupby.dense_combine``, stage ``groupby.combine``) and the
+        groups are emitted once, on the first shard, in key order; no row
+        leaves its shard. Without it each shard emits the groups of its
+        own rows (what follows a shuffle, or one shard)."""
+        combine = combine and self.world_size > 1
         bump("groupby.dense_path")
         los, spans, meta = plan
         all_names = self.column_names
@@ -2928,28 +2962,54 @@ class Table:
         ops_t = tuple(oid for _, oid, _ in specs)
         flat = self._flat_cols()
         nullable = tuple(self._columns[n].valid is not None for n in key_names)
-        cap_out = round_cap(_g.dense_slots(spans, nullable))
+        slots = _g.dense_slots(spans, nullable)
+        cap_out = round_cap(slots)
         m = None if mask is None else self._as_mask(mask)
         key = (
             "groupby_dense", key_idx, val_idx, ops_t, len(flat), spans, meta,
             nullable, m is not None,
-        )
+        ) + (("combine",) if combine else ())
+        axis = self.ctx.axis_name
+        if combine:
+            bump("groupby.partial_path")
+            bump("groupby.partial.rows", rows=self._rows_hint() or 0)
+            bump("groupby.combine.slots", rows=slots)
 
         def build_emit():
             def kern(dp, rep):
                 (m, cols, counts) = dp
                 keys = [cols[i] for i in key_idx]
                 lo = list(rep)
-                gid = _g.dense_group_ids(keys, lo, spans, counts[0], m)
-                slots = _g.dense_slots(spans, nullable)
+                gid = _g.dense_group_ids(
+                    keys, lo, spans, counts[0], m, cap=cols[0][0].shape[0]
+                )
                 rows = _g.dense_rows(gid, slots)
-                aggs = [
-                    _g.dense_aggregate(oid, *cols[vi], gid, slots)
-                    for vi, oid in zip(val_idx, ops_t)
+                # without a key the one slot is emitted whatever it holds
+                empty = [
+                    not keys or cols[vi][1] is not None for vi in val_idx
                 ]
+                if combine:
+                    rows, parts = _g.dense_combine(rows, [
+                        (oid, *_g.dense_partial(oid, *cols[vi], gid, slots))
+                        for vi, oid in zip(val_idx, ops_t)
+                    ], axis)
+                    aggs = [
+                        _g.dense_finalize(*part, e)
+                        for part, e in zip(parts, empty)
+                    ]
+                else:
+                    # one shard holds every row of its groups: partial
+                    # and finalize back to back, an aggregate at a time
+                    aggs = [
+                        _g.dense_aggregate(oid, *cols[vi], gid, slots, e)
+                        for vi, oid, e in zip(val_idx, ops_t, empty)
+                    ]
                 out, ng = _g.dense_emit(
                     rows, aggs, meta, lo, spans, nullable, cap_out
                 )
+                if combine:
+                    # every shard holds the result; the first one emits it
+                    ng = jnp.where(jax.lax.axis_index(axis) == 0, ng, 0)
                 return out, _scalar(ng)
 
             return kern
@@ -2959,7 +3019,8 @@ class Table:
                 (m, flat, self.counts_dev), los
             )
         return self._groupby_result(
-            key_names, specs, out, nout, cap_out, True
+            key_names, specs, out, nout, cap_out, True,
+            scope="global" if combine else "shard",
         )
 
     @_obstrace.op("distributed_groupby")
@@ -2967,19 +3028,55 @@ class Table:
         self,
         by: Union[str, int, Sequence[Union[str, int]]],
         agg: Dict[str, Union[str, Sequence[str]]],
+        _mask=None,
         **kw,
     ) -> "Table":
-        """Reference DistributedHashGroupBy (groupby/groupby.cpp:33-91):
-        local pre-combine iff every op is associative {SUM,MIN,MAX}
-        (:24-31,57-67), shuffle on keys, final local groupby."""
+        """Group-by over the whole mesh (reference DistributedHashGroupBy,
+        groupby/groupby.cpp:33-91), one of two ways; the input decides.
+
+        **Combined in place.** Where the dense plan applies
+        (:meth:`_dense_groupby_plan`: every op one of sum, count, min,
+        max, mean, whatever the ops a column; every key dictionary-coded
+        or an integer or bool with a measured range; at most
+        ``ops.groupby.DENSE_MAX_SLOTS`` slots; also no key at all), every
+        shard reduces its own rows to a partial slot table (a row count
+        and a sum, minimum or maximum a slot; a mean as its sum and
+        count), the tables are combined across the mesh inside the same
+        program (counts and integer sums by ``psum``; float sums, minima
+        and maxima gathered and folded in shard order, so the result is
+        the same bits run to run) and finalized once. No row crosses the
+        mesh: what moves is slots x aggregates numbers. The groups lie on
+        the FIRST shard in key order and the other shards hold no row, so
+        ``row_count``, ``to_pydict`` and a following sort see one copy
+        (the ordering descriptor's scope is "global"). The reference
+        pre-combines locally for {SUM, MIN, MAX} (:24-31,57-67) and still
+        shuffles the partial rows; its scalar aggregates
+        (compute/aggregates.cpp:26-137) are this: local reduce, AllReduce.
+
+        **Shuffled.** Anything else (a key of many values or no known
+        range, a float key, var / std / nunique / quantile): a hash
+        shuffle on the keys, then the local group-by a shard, each shard
+        emitting its own groups; in front of the shuffle a local
+        pre-combine when every op is associative {SUM, MIN, MAX} and each
+        column has one op.
+
+        ``_mask`` (the planner): a row mask as :meth:`groupby` takes it;
+        it rides the combined reductions, and filters the rows before a
+        shuffle."""
         if self.world_size == 1:
-            return self.groupby(by, agg, **kw)
+            return self.groupby(by, agg, _mask=_mask, **kw)
         key_names = self._resolve_cols(by)
-        all_ops = []
-        for col, ops in agg.items():
-            ops_list = ops if isinstance(ops, (list, tuple)) else [ops]
-            all_ops += [_g.agg_op_id(o) for o in ops_list]
-        t = self
+        specs = _agg_specs(agg)
+        all_ops = [oid for _c, oid, _n in specs]
+        if kw.get("_dense", True) and not kw.get("_sorted", False):
+            dense = self._dense_groupby_plan(key_names, all_ops)
+            if dense is not None:
+                return self._groupby_dense(
+                    key_names, specs, dense, _mask, combine=True
+                )
+        if not key_names:
+            raise ValueError(_KEYLESS_OPS)
+        t = self if _mask is None else self.filter(_mask)
         if all(o in _g.ASSOCIATIVE for o in all_ops):
             pre = t.groupby(by, agg, **kw)
             # rename aggregated columns back to the source names so the final

@@ -406,3 +406,63 @@ def test_collect_emits_plan_spans_and_report(ctx8, rng):
     ta.lazy().select(["extra", "k"]).filter(col("extra") < 0.0).collect()
     stats = __import__("cylon_tpu").engine.plan_cache_stats()
     assert stats["hits"] >= 1 and stats["misses"] == misses0 + 1
+
+
+# ----------------------------------------------------------------------
+# partial_aggregate: an aggregate the dense plan takes is combined in place
+# ----------------------------------------------------------------------
+def _flag_table(ctx, rng, n=1500):
+    return ct.Table.from_pydict(ctx, {
+        "flag": rng.choice(np.array(["A", "N", "R"]), n),
+        "status": rng.choice(np.array(["F", "O"]), n),
+        "v": rng.random(n), "d": rng.integers(0, 100, n),
+    })
+
+
+def _q1_shape(t):
+    return (
+        t.lazy().filter(col("d") < 90)
+        .groupby(["flag", "status"], {"v": ["sum", "mean", "count"]})
+        .sort(["flag", "status"])
+    )
+
+
+def test_partial_aggregate_fires_on_a_mesh_and_not_on_one_shard(ctx8, local_ctx, rng):
+    text = _q1_shape(_flag_table(ctx8, rng)).explain()
+    assert f"{plan_rules.PARTIAL_AGGREGATE} x1" in text.split("Rewrites fired:")[1]
+    local = _q1_shape(_flag_table(local_ctx, rng)).explain()
+    assert plan_rules.PARTIAL_AGGREGATE not in local
+    assert "Shuffle" not in local.split("== Optimized plan ==")[1]
+
+
+def test_explain_names_the_partial_aggregate(ctx8, rng):
+    optimized = _q1_shape(_flag_table(ctx8, rng)).explain().split(
+        "== Optimized plan =="
+    )[1]
+    node = optimized.strip().splitlines()[0]
+    assert node.startswith("GroupBy [flag, status]")
+    assert "[partial aggregate: combined in place, no shuffle" in node
+    assert "mask (col('d') < 90)" in node  # the filter rides it
+    assert "@global" in node  # one copy, in key order, over the mesh
+    keyless = _flag_table(ctx8, rng).lazy().agg({"v": "sum"}).explain()
+    assert "Aggregate agg [sum(v)] [partial aggregate" in keyless
+
+
+def test_the_sort_over_a_partial_aggregate_is_elided_on_a_mesh(ctx8, rng):
+    t = _flag_table(ctx8, rng)
+    lf = _q1_shape(t)
+    text = lf.explain()
+    optimized = text.split("== Optimized plan ==")[1]
+    assert "Sort" not in optimized and "Shuffle" not in optimized
+    assert f"{plan_rules.ORDER_REUSE} x1" in text
+    tracing.reset_trace()
+    got = lf.collect().to_pandas()
+    rep = tracing.report()
+    assert not any(k.startswith("shuffle.") for k in rep), sorted(rep)
+    assert rep["groupby.partial_path"]["count"] == 1
+    assert rep[f"plan.rule.{plan_rules.PARTIAL_AGGREGATE}"]["count"] == 1
+    df = t.to_pandas()
+    want = df[df["d"] < 90].groupby(["flag", "status"]).agg(
+        v_sum=("v", "sum"), v_mean=("v", "mean"), v_count=("v", "count"),
+    ).reset_index()
+    _assert_frames_close(got, want, rtol=1e-12)

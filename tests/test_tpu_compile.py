@@ -679,6 +679,59 @@ def test_dense_groupby_compiles_for_tpu_without_sort_scatter_or_gather(one_chip)
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * ROWS
 
 
+def test_combined_dense_groupby_compiles_for_four_chips(mesh4):
+    """``tpch-q1-w4``'s aggregate: every chip's partial slot table, the
+    combine over the mesh axis and the finalize in one program. What
+    crosses the chips is one int32 all-reduce (the counts) and the float64
+    sums gathered as their 32-bit halves; no row does (no all-to-all)."""
+    from cylon_tpu.obs import stages
+
+    spans, nullable = (4, 2), (False, False)
+    meta = (("i32", "int32"), ("i32", "int32"))
+    ops = [_g.agg_op_id(o) for o in ("sum", "mean", "count", "min")]
+    slots = _g.dense_slots(spans, nullable)
+
+    def dense(flag, status, qty, price, mask, n, lo0, lo1):
+        lo = [lo0, lo1]
+        gid = _g.dense_group_ids(
+            [(flag, None), (status, None)], lo, spans, n[0], mask
+        )
+        rows, parts = _g.dense_combine(_g.dense_rows(gid, slots), [
+            (o, *_g.dense_partial(o, v, None, gid, slots))
+            for o, v in zip(ops, (qty, qty, qty, price))
+        ], "dp")
+        aggs = [_g.dense_finalize(*part, False) for part in parts]
+        out, ng = _g.dense_emit(rows, aggs, meta, lo, spans, nullable, 8)
+        return out, jnp.where(jax.lax.axis_index("dp") == 0, ng, 0)[None]
+
+    rows = NamedSharding(mesh4, PartitionSpec("dp"))
+    rep = NamedSharding(mesh4, PartitionSpec())
+    step = jax.jit(jax.shard_map(
+        dense, mesh=mesh4,
+        in_specs=(PartitionSpec("dp"),) * 6 + (PartitionSpec(),) * 2,
+        out_specs=PartitionSpec("dp"),
+    ))
+    n = WORLD * ROWS
+    compiled = step.lower(
+        _spec((n,), jnp.int32, rows), _spec((n,), jnp.int32, rows),
+        _spec((n,), jnp.float64, rows), _spec((n,), jnp.float64, rows),
+        _spec((n,), jnp.bool_, rows), _spec((WORLD,), jnp.int32, rows),
+        _spec((), jnp.uint32, rep), _spec((), jnp.uint32, rep),
+    ).compile()
+    text = compiled.as_text()
+    assert "all-to-all" not in text and "collective-permute" not in text
+    _module, table = stages.parse_compiled(text)
+    crossing = [
+        (ins.split(" = ")[1], op) for ins, op in table
+        if re.search(r" all-(reduce|gather)\(", ins)
+    ]
+    # one psum of the int32 counts, the float64 lanes as two gathers of
+    # float32 halves; nothing of a row's size
+    assert sorted(c.split("[")[0] for c, _op in crossing) == ["f32", "f32", "s32"]
+    assert all(f"{ROWS}" not in c.split("{")[0] for c, _op in crossing)
+    assert all(stages.GROUPBY_COMBINE in op.split("/") for _c, op in crossing)
+
+
 # ----------------------------------------------------------------------
 # (h) a forced Pallas kernel that does not lower raises the compiler's
 # error for a TPU device: the call sites pass interpret=False on a TPU
