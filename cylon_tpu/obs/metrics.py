@@ -425,6 +425,14 @@ STABLE_METRICS: Dict[str, Tuple[str, str]] = {
         "partitioner's histogram), bumped where a kernel is TRACED, not "
         "where it runs: .dense a compare and a sum (rows= the bins, up to "
         "DENSE_BINS_MAX), .scatter an int32 scatter-add past it"),
+    "gather.f64.": (
+        "counter", "how the float64 columns of a packed row gather "
+        "(ops.gather.pack_gather: the join emits, take, filter, the row "
+        "subsets) were carried, bumped where a kernel is TRACED, not where "
+        "it runs: .packed as two 32-bit lanes of the gather's matrix (rows= "
+        "the float64 columns; the source has at most F64_PACK_RATIO rows an "
+        "index row), .alone each by a gather of its own where the gather is "
+        "more selective than that"),
     "shuffle.overlap_efficiency": (
         "gauge", "fraction of the measured exchange device window "
         "(dispatch-open to the deferred round-count fetch return) spent "

@@ -9,16 +9,26 @@ than 4 separate 8.4M-row gathers on v5e).
 
 Packing discipline: every column is re-expressed as one or more int32 lanes
 (bitcast for 32-bit types, widening for narrower ints/bools, f16->f32->bitcast,
-hi/lo split for 64-bit) plus one lane per validity mask; all lanes are stacked
-into a [cap, L] matrix, gathered by row index, and unpacked losslessly.
+hi/lo split for 64-bit ints) plus one lane per validity mask; all lanes are
+stacked into a [cap, L] matrix, gathered by row index, and unpacked
+losslessly. A float64 has no lanes in the EXCHANGE's format (``lane_plan`` /
+``pack_cols``, the wire codec, the spill's host unpack: there it is a
+``passthrough`` column, moved whole), but it has two in the device-side row
+gather: :func:`pack_gather` carries it in the matrix as the two float32 the
+chip holds it as (:func:`_f64_to_lanes`), unless the gather is selective
+(:data:`F64_PACK_RATIO`).
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..engine import mesh_platform
+from ..obs.trace import bump
 
 KeyCol = Tuple[jax.Array, Optional[jax.Array]]
 
@@ -40,8 +50,9 @@ def _to_lanes(data: jax.Array) -> Tuple[List[jax.Array], str]:
         return [data.astype(jnp.int32)], str(dt)
     # 64-bit ints: split into hi/lo 32-bit lanes via arithmetic only (the TPU
     # X64-rewrite pass cannot lower 64-bit bitcast_convert; shifts/masks on
-    # emulated u64 are fine). float64 has no bit-level route at all on TPU —
-    # handled by the caller as a passthrough column.
+    # emulated u64 are fine). A float64 never comes here: no bitcast of one
+    # lowers for a TPU, so the exchange moves it whole (the caller's
+    # passthrough) and the row gather splits it by value (_f64_to_lanes).
     u = data.astype(jnp.uint64)
     hi = (u >> jnp.uint64(32)).astype(jnp.uint32)
     lo = (u & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
@@ -73,11 +84,85 @@ def _from_lanes(lanes: List[jax.Array], tag: str) -> jax.Array:
     return u.astype(dt)
 
 
+def _f64_two_float(platform: str) -> bool:
+    """Whether a float64 of a mesh of ``platform`` is a pair of float32.
+
+    A TPU holds one as (high, low) float32 whose sum is the value (about 48
+    bits of mantissa, float32's exponent range) and takes no bitcast of
+    it; every other backend holds an IEEE double, which two float32 cannot
+    carry and whose bits a bitcast gives."""
+    return platform == "tpu"
+
+
+def _f64_to_lanes(data: jax.Array, platform: str) -> List[jax.Array]:
+    """One float64 column as two int32 lanes for the row gather of a mesh
+    of ``platform``, value for value: :func:`_f64_from_lanes` gives back
+    what it was given (every finite value, both zeros, both infinities;
+    a NaN stays a NaN), but for a low half that is a float32 subnormal,
+    which :func:`_f64_low_half_may_flush` is there to find.
+
+    On a TPU the lanes are the two float32 the chip holds the value as,
+    had by arithmetic: the nearest float32, and what is left (exact: the
+    error of rounding a sum of two float32 to one is a float32). Elsewhere
+    they are the two words of its bits, as a 64-bit integer's are. The
+    lanes never leave the device that split them: a host cannot rebuild
+    an IEEE double from the chip's halves by the other rule."""
+    if not _f64_two_float(platform):
+        return _to_lanes(jax.lax.bitcast_convert_type(data, jnp.uint64))[0]
+    hi = data.astype(jnp.float32)
+    lo = (data - hi.astype(jnp.float64)).astype(jnp.float32)
+    # inf - inf is a NaN: an infinity (and a NaN) is its high half alone
+    lo = jnp.where(jnp.isfinite(hi), lo, jnp.float32(0))
+    return [
+        jax.lax.bitcast_convert_type(hi, jnp.int32),
+        jax.lax.bitcast_convert_type(lo, jnp.int32),
+    ]
+
+
+#: biased exponent of a float32 high half under which the low half of a
+#: float64 may be a float32 SUBNORMAL. A double loaded from a host has 53
+#: bits, so what the high half leaves is 0 or at least 2^-52 of it: a normal
+#: float32 (2^-126 and up) from a high half of 2^-74 on, biased exponent 53.
+#: A high half that is itself subnormal or zero (exponent field 0) leaves
+#: nothing, and the chip's own arithmetic makes no subnormal at all.
+_F64_LOW_NORMAL_EXP = 53
+
+
+def _f64_low_half_may_flush(hi_lane: jax.Array) -> jax.Array:
+    """Rows of a two-float split whose low half the chip's arithmetic may
+    have flushed, read from the HIGH half's bits alone.
+
+    The TPU's float32 arithmetic takes a subnormal for zero, coming and
+    going, and the low half of :func:`_f64_to_lanes` is had by arithmetic
+    (the high half is a plain conversion, which is one of the two words as
+    it lies): a float64 under about 5e-23 whose low half is a float32
+    subnormal (1.1% of 4,194,304 draws over sixty decades; none of the
+    cells') would come back with the low half gone, equal by every
+    comparison the chip can make and 2^-24 off to the host that fetches
+    it. Nothing on the chip can see such a low half, but only a row with a
+    small high half can have one."""
+    exp = (hi_lane >> 23) & 0xFF
+    return (exp > 0) & (exp < _F64_LOW_NORMAL_EXP)
+
+
+def _f64_from_lanes(lanes: List[jax.Array], platform: str) -> jax.Array:
+    """Inverse of :func:`_f64_to_lanes` under the same ``platform``."""
+    if not _f64_two_float(platform):
+        return _from_lanes(lanes, "float64")
+    hi = jax.lax.bitcast_convert_type(lanes[0], jnp.float32).astype(jnp.float64)
+    lo = jax.lax.bitcast_convert_type(lanes[1], jnp.float32)
+    # -0.0 + 0.0 is +0.0: a value with no low half is its high half as is
+    return jnp.where(lo == 0, hi, hi + lo.astype(jnp.float64))
+
+
 def lane_plan(cols: Sequence[KeyCol]):
     """The lane-codec PLAN of a column set from dtypes alone (no device
     work): (tag-or-None, n_lanes, has_valid) per column — a None tag marks
-    an f64 column that has no 32-bit lane route on TPU and must be
-    transported separately. Kernels that receive already-packed lane
+    an f64 column, which has no lanes in this format and is transported
+    separately. This is the EXCHANGE's and the host's format (the shuffle's
+    collectives, the wire codec, the spill's host unpack); the row gather
+    on one device carries an f64 in its matrix all the same
+    (:func:`pack_gather`). Kernels that receive already-packed lane
     buffers (the chunked shuffle's compact phase) rebuild the plan with
     this instead of re-encoding the columns."""
     plan = []
@@ -100,7 +185,9 @@ def pack_cols(cols: Sequence[KeyCol]):
     """Shared lane-plan builder: encode every column (+ validity) as int32
     lanes. Returns (plan, lanes, passthrough) where plan entries follow
     :func:`lane_plan` and passthrough maps column position -> its raw f64
-    data. NOTE: an f64 column's VALIDITY lane still rides ``lanes``."""
+    data, which the caller moves as its medium allows (a collective of its
+    own, a fetch of its own, two more lanes of :func:`pack_gather`'s
+    matrix). NOTE: an f64 column's VALIDITY lane still rides ``lanes``."""
     plan = lane_plan(cols)
     lanes: List[jax.Array] = []
     passthrough = {}
@@ -625,6 +712,19 @@ def host_unpack_cols(plan, lane_cols, handle_passthrough):
 #: it always was.
 PACK_GATHER_BLOCK = 1 << 23
 
+#: source rows per index row up to which a float64 column rides the packed
+#: gather as two lanes. Packing stacks the source: the ``[cap, L]`` matrix is
+#: written once a call, which is nothing where the index vector is of the
+#: source's order and everything where the gather is selective. The join
+#: emits draw 4,194,304 slots from 4,194,304 rows (``join-w1``), 8,388,608
+#: from 8,388,608 and from 262,144 (``join-skew-w4``), about 1M from 1-2M
+#: (``join-w4``): a ratio of 2 at most. ``reduce_by_hits`` in ``tpch-q3-w1``
+#: draws 524,288 rows from 67,108,864-row lineitem columns, a ratio of 128:
+#: a ``[67108864, 5]`` int32 matrix is 1.3 GB a query compact and 34 GB with
+#: its lanes padded to a tile. 8 lies a factor of 4 from the one and 16 from
+#: the other; past it the column is gathered alone, as it always was.
+F64_PACK_RATIO = 8
+
 
 def _gather_packed(packed: jax.Array, safe: jax.Array) -> List[jax.Array]:
     """The lanes of ``packed[safe]``, each ``[len(safe)]``: ONE gather, or
@@ -660,27 +760,74 @@ def pack_gather(
     skipped and mask-free source columns stay mask-free — the key-order join
     emit uses this to keep the output key columns' sortedness descriptor
     usable by downstream mask-sensitive fast paths.
+
+    A float64 column rides the same gather as two more lanes of the matrix
+    (:func:`_f64_to_lanes`, by the rule of the mesh the kernel is traced
+    for) where the source has at most :data:`F64_PACK_RATIO` rows an index
+    row; where the gather is more selective than that it is gathered alone,
+    which the chip does as one gather a float32 half. On a TPU mesh the
+    program holds both forms under one ``cond`` and takes the lone gathers
+    where a column holds a value whose low half the split could lose
+    (:func:`_f64_low_half_may_flush`: magnitudes under 5e-23 that are not 0;
+    a pass over the high halves' bits decides). The rollup counters
+    ``gather.f64.packed`` / ``gather.f64.alone`` (``rows=`` the float64
+    columns) say which a run's programs hold.
     """
     cap = cols[0][0].shape[0] if cols else extra_lanes[0].shape[0]
     plan, lanes, passthrough = pack_cols(cols)
-    n_extra = len(extra_lanes)
     lanes = lanes + list(extra_lanes)
+    n_own = len(lanes)
     safe = jnp.clip(idx, 0, cap - 1)
     ok = idx >= 0
-    if len(lanes) == 1:
-        g_cols = [lanes[0][safe]]
-    elif lanes:
-        g_cols = _gather_packed(jnp.stack(lanes, axis=1), safe)  # [cap, L]
-    else:
-        g_cols = []
 
     def make_valid(lane):
         if all_valid:
             return None if lane is None else lane.astype(jnp.bool_)
         return ok if lane is None else (ok & lane.astype(jnp.bool_))
 
-    out, pos = unpack_cols(
-        plan, g_cols, lambda ci: passthrough[ci][safe], make_valid
+    def gathered(f64_lanes, platform=None):
+        """(columns, extras) with the float64 columns as ``f64_lanes`` of
+        the matrix, two each in column order, or each gathered alone
+        where there are none."""
+        stacked = lanes + f64_lanes
+        if len(stacked) == 1:
+            g_cols = [stacked[0][safe]]
+        elif stacked:
+            g_cols = _gather_packed(jnp.stack(stacked, axis=1), safe)  # [cap, L]
+        else:
+            g_cols = []
+        if f64_lanes:
+            f64 = {
+                ci: _f64_from_lanes(g_cols[at : at + 2], platform)
+                for at, ci in zip(range(n_own, len(g_cols), 2), passthrough)
+            }
+        else:
+            f64 = {ci: data[safe] for ci, data in passthrough.items()}
+        out, pos = unpack_cols(plan, g_cols, f64.__getitem__, make_valid)
+        return out, g_cols[pos:n_own]
+
+    f64_packed = bool(passthrough) and cap <= F64_PACK_RATIO * idx.shape[0]
+    if passthrough:
+        bump(
+            "gather.f64.packed" if f64_packed else "gather.f64.alone",
+            rows=len(passthrough),
+        )
+    if not f64_packed:
+        return gathered([])
+    platform = mesh_platform()
+    f64_lanes = [
+        lane
+        for data in passthrough.values()
+        for lane in _f64_to_lanes(data, platform)
+    ]
+    if not _f64_two_float(platform):
+        return gathered(f64_lanes, platform)
+    # the two-float split is by arithmetic, which cannot see a low half the
+    # chip flushes: a table that holds such a value keeps the lone gathers
+    flushes = functools.reduce(
+        jnp.logical_or,
+        [jnp.any(_f64_low_half_may_flush(hi)) for hi in f64_lanes[::2]],
     )
-    extras = g_cols[pos : pos + n_extra]
-    return out, extras
+    return jax.lax.cond(
+        flushes, lambda: gathered([]), lambda: gathered(f64_lanes, platform)
+    )

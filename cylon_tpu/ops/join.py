@@ -505,12 +505,16 @@ def emit_gather(
     """Fused emit + payload gather: produce the joined output columns with a
     minimal number of XLA gathers (the TPU bottleneck — see ops/gather.py).
 
-    INNER/LEFT fast path does exactly three big gathers: the ``jnp.repeat``
-    for li, one packed left-row gather (payload + base/cnt lanes), and one
-    packed right-row gather against the r_order-permuted right payload
-    (see :func:`_emit_inner_left`). RIGHT/FULL_OUTER falls back to
-    :func:`emit_from_probe` indices + two packed gathers (the unmatched-right
-    scatter does not fuse).
+    INNER/LEFT fast path does exactly three big row-addressed operations:
+    the ``jnp.repeat`` for li, one packed left-row gather (payload +
+    base/cnt lanes), and one packed right-row gather against the
+    r_order-permuted right payload (see :func:`_emit_inner_left`). A float64
+    payload is two lanes of those gathers and no gather of its own: for an
+    int64 key and a float64 value a side the two are ``s32[cap_out, 6]``
+    (key 2, base, cnt, value 2) and ``s32[cap_out, 4]`` (key 2, value 2),
+    and the compiled program holds no other gather of ``cap_out`` rows.
+    RIGHT/FULL_OUTER falls back to :func:`emit_from_probe` indices + two
+    packed gathers (the unmatched-right scatter does not fuse).
 
     Returns (out_cols = left ++ right as (data, valid), n_out scalar).
     """
@@ -737,8 +741,8 @@ def _emit_inner_left_windowed(
         out_l, _ = unpack_cols(
             plan,
             g_lanes[:n_payload],
-            # f64 columns have no int32 lane route: gather them by the expanded
-            # original row id (their validity lane rode the expand)
+            # f64 columns have no lanes in pack_cols' format: gather them by
+            # the expanded original row id (their validity lane rode the expand)
             lambda ci: passthrough[ci][jnp.clip(orig_g, 0, cap_l - 1)],
             make_valid,
         )

@@ -27,8 +27,9 @@ Codecs (``codec_for`` picks by dtype + tolerance):
     <= 2^-9 per crossing, inf/NaN exact (bf16 shares f32's exponent
     range). Engages at ``tol >= QB16_TOL`` (2^-8).
 ``qf32``
-    f64 -> f32 demotion (f64 has NO exact 32-bit lane route on TPU, so
-    today it rides a per-column 8-byte passthrough collective): 32-bit
+    f64 -> f32 demotion (f64 has no lanes in the exchange's format,
+    ``ops.gather.lane_plan``: no bitcast of one lowers for a TPU, so
+    it rides a per-column 8-byte passthrough collective): 32-bit
     lanes, relative error <= 2^-24 per crossing; engages at
     ``tol >= QF32_TOL`` (2^-23). Values beyond f32 range saturate to
     inf — the error model assumes representable magnitudes (EQuARX's
@@ -128,8 +129,8 @@ def codec_for(np_dtype, tol: float) -> Optional[str]:
         if tol >= QB16_TOL:
             return "qb16"
         return None
-    # float64: no exact 32-bit lane route on TPU — every tier beats the
-    # 8-byte passthrough collective
+    # float64: no lanes in the exchange's format (lane_plan) — every tier
+    # beats the 8-byte passthrough collective
     if tol >= Q8_TOL:
         return "q8"
     if tol >= QB16_TOL:

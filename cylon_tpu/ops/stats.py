@@ -77,7 +77,7 @@ def enc_class(np_dtype) -> Optional[str]:
       sort fusion may use it, but lossy at the bit level (-0.0 and NaN
       payloads canonicalize), so the wire codec must not
       (:func:`wire_narrowable`);
-    - ``None``: f64 (no 32-bit lane route on TPU), anything else.
+    - ``None``: f64 (no bit-level 32-bit lane route on TPU), anything else.
     """
     dt = np.dtype(np_dtype)
     if dt == np.bool_:

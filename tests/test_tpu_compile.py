@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
+from cylon_tpu.engine import on_mesh
 from cylon_tpu.ops import groupby as _g
 from cylon_tpu.ops import join as _j
 from cylon_tpu.ops import pallas_codec as _codec
@@ -87,7 +88,16 @@ def _spec(shape, dtype, sharding):
 
 
 def _compile(fn, *specs):
-    return jax.jit(fn).lower(*specs).compile()
+    """Compile ``fn`` for the described devices its specs are placed on,
+    traced as a kernel of a mesh of them is (``engine.on_mesh``): what
+    follows the MESH's platform and not the process's, the row gather's
+    float64 lanes for one, takes its TPU form here as it does on the chip."""
+    dev = min(
+        jax.tree_util.tree_leaves(specs)[0].sharding.device_set,
+        key=lambda d: d.id,
+    )
+    traced = on_mesh(Mesh(np.array([dev]), ("traced",)), fn)
+    return jax.jit(traced).lower(*specs).compile()
 
 
 # ----------------------------------------------------------------------
@@ -331,6 +341,52 @@ def test_packed_gather_of_a_skewed_joins_slots_fits_the_chip(
     assert temp_gib() < 5.0
     monkeypatch.setattr(_gather, "PACK_GATHER_BLOCK", 1 << 25)
     assert temp_gib() > 7.5  # what one gather of all the rows would hold
+
+
+def test_skewed_joins_program_fits_the_chip_with_its_float64_packed(one_chip):
+    """The whole ``jit_join_spec`` at ``join-skew-w4``'s shard shapes (2^23
+    probe slots and output slots, 2^18 build slots; int64 key and float64
+    value a side): the float64 halves are lanes of the emit's two packed
+    gathers, ``s32[8388608, 6]`` and ``s32[8388608, 4]``; the halves'
+    lone ``f32[8388608]`` gathers stand only in the branch the guard takes
+    for a table that holds a value whose low half the split would lose
+    (``ops.gather._f64_low_half_may_flush``), and the program with both
+    branches fits a v5e's 15.75 GB (PERF.md section 6, PR 40)."""
+    cap_l, cap_r, cap_out = 1 << 23, 1 << 18, 1 << 23
+
+    def join(lk, lv, rk, rv, nl, nr):
+        left, right = [(lk, None), (lv, None)], [(rk, None), (rv, None)]
+        return _j.spec_join(
+            left[:1], right[:1], left, right, nl, nr, _j.INNER, cap_out
+        )
+
+    compiled = _compile(
+        join,
+        _spec((cap_l,), jnp.int64, one_chip),
+        _spec((cap_l,), jnp.float64, one_chip),
+        _spec((cap_r,), jnp.int64, one_chip),
+        _spec((cap_r,), jnp.float64, one_chip),
+        _spec((), jnp.int32, one_chip), _spec((), jnp.int32, one_chip),
+    )
+    # (shape, the branch of the guard's cond it was traced in: 0 packed,
+    # 1 the lone gathers) of every gather
+    gathers = re.findall(
+        r"= (\S+?\[[0-9,]*\])\S* gather\(.*?op_name=\"[^\"]*?"
+        r"(?:cond/branch_(\d)_fun)", compiled.as_text(),
+    )
+    packed = sorted(shape for shape, branch in gathers if branch == "0")
+    assert packed == [f"s32[{cap_out},4]", f"s32[{cap_out},6]"], gathers
+    lone = [shape for shape, branch in gathers if branch == "1"]
+    assert lone.count(f"f32[{cap_out}]") == 4, gathers
+    assert len(gathers) == len(
+        re.findall(r"\sgather\(", compiled.as_text())
+    ), "a gather outside the guard's branches"
+    mem = compiled.memory_analysis()
+    held = (
+        mem.temp_size_in_bytes + mem.argument_size_in_bytes
+        + mem.output_size_in_bytes
+    )
+    assert held < 15.75e9 * 0.75, held / 1e9
 
 
 # ----------------------------------------------------------------------
