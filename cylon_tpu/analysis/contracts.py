@@ -282,12 +282,14 @@ SYNC_SITE_BUDGETS: Dict[str, SyncBudget] = {
     ),
     # ops that own genuine host decisions
     "Table.join": SyncBudget(
-        4,
+        5,
         note="speculative stats fetch (overflow check) + exact-path probe "
         "stats fetch + the pallas_pk stats fetch + the semi-reduction's "
         "counts fetch (a selective join then skips the speculative one: "
-        "its row count is known) — each a packed single fetch; the emit "
-        "phases reuse the probe counts",
+        "its row count is known) + a semi or anti join's kept count (the "
+        "only fetch of such a join; none where its hit mask goes to an "
+        "aggregate) — each a packed single fetch; the emit phases reuse "
+        "the probe counts",
     ),
     "Table._fused_join": SyncBudget(1, note="fused-step stats fetch"),
     "table._shuffle_many": SyncBudget(

@@ -372,6 +372,22 @@ STABLE_METRICS: Dict[str, Tuple[str, str]] = {
         "fetch) + the emit census as counters, from numbers the host "
         "holds: emit_slots (rows= slots an emit wrote) and emit_rows "
         "(rows= rows live in them)"),
+    "join.semi_join": (
+        "span", "a semi or anti join as an operator (Table.join how='semi' "
+        "/ 'anti'): the keys-only program's dispatch and, where the rows "
+        "are compacted, the kept count's fetch (rows= both sides' "
+        "host-known rows)"),
+    "join.semi.left_rows": (
+        "counter", "left rows semi and anti joins were asked about (rows= "
+        "the left's host-known row count, 0 while it is deferred)"),
+    "join.semi.kept_rows": (
+        "counter", "left rows they kept (rows= the fetched count; nothing "
+        "where the hit mask was handed to an aggregate, which fetches no "
+        "count)"),
+    "join.semi.payload_rows": (
+        "counter", "rows a semi or anti join gathered or compacted (rows= "
+        "the kept rows on the eager path, 0 where the planner's "
+        "semi_as_mask handed the hit mask to the aggregate above)"),
     "setop.": ("span", "union/subtract/intersect dispatch"),
     "groupby.": (
         "mixed", "groupby phases as spans (emit) + the path a call took as "
@@ -482,6 +498,9 @@ STABLE_METRICS: Dict[str, Tuple[str, str]] = {
     "plan.execute": ("span", "lowered plan execution"),
     "plan.node.": ("span", "per-plan-node execution (node_id attr)"),
     "plan.rule.": ("counter", "one bump per optimizer rule firing"),
+    "plan.rule.semi_as_mask": (
+        "counter", "a semi or anti join directly under a dense group-by or "
+        "a keyless aggregate handed over its hit mask uncompacted"),
     "plan.cache.": ("counter", "plan-fingerprint executable cache hit/miss"),
     "plan.fingerprint.hash": (
         "counter", "fingerprint_key hashes performed (hoisted onto the "

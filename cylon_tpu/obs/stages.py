@@ -33,6 +33,7 @@ JOIN_RIGHT_SORT = "join.right_sort"
 JOIN_PROBE = "join.probe"
 JOIN_EMIT = "join.emit"
 JOIN_SEMI = "join.semi"
+JOIN_SEMI_MASK = "join.semi_mask"
 SORT_KEYS = "sort.keys"
 SORT_PERM = "sort.perm"
 SORT_TOPK = "sort.topk"
@@ -51,6 +52,7 @@ EXPR_EVAL = "expr.eval"
 
 VOCABULARY = (
     JOIN_KEY_IDS, JOIN_RIGHT_SORT, JOIN_PROBE, JOIN_EMIT, JOIN_SEMI,
+    JOIN_SEMI_MASK,
     SORT_KEYS, SORT_PERM, SORT_TOPK, SORT_ENGINE,
     SHUFFLE_COUNT, SHUFFLE_PACK, SHUFFLE_ALL_TO_ALL, SHUFFLE_COMPACT,
     SHUFFLE_REASSEMBLE,
@@ -91,6 +93,7 @@ FETCH_SITES = {
     "join.speculative": True,     # Table.join, the speculative program's totals
     "join.exact_counts": True,    # ... the exact path's probe counts
     "join.semi": True,            # Table._semi_reduced, the three counts
+    "join.semi_join": True,       # Table._semi_join, the kept rows' count
     "join.pallas_pk": True,       # Table._pallas_pk_join
     "join.fused": True,           # distributed_join(mode="fused")
     "shuffle.counts": True,       # _shuffle_many, the count kernel's matrix

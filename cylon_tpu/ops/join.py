@@ -245,9 +245,13 @@ def _repeat_ss(ends: jax.Array, cap_out: int) -> jax.Array:
     return (comb[n:] - pos).astype(jnp.int32)
 
 
-INNER, LEFT, RIGHT, FULL_OUTER = 0, 1, 2, 3
+INNER, LEFT, RIGHT, FULL_OUTER, SEMI, ANTI = 0, 1, 2, 3, 4, 5
 _JOIN_TYPES = {"inner": INNER, "left": LEFT, "right": RIGHT, "fullouter": FULL_OUTER,
-               "outer": FULL_OUTER, "full_outer": FULL_OUTER}
+               "outer": FULL_OUTER, "full_outer": FULL_OUTER,
+               "semi": SEMI, "left_semi": SEMI, "anti": ANTI, "left_anti": ANTI}
+#: the types that keep or drop LEFT rows by whether they have a partner and
+#: emit nothing of the right side (EXISTS / NOT EXISTS): :func:`semi_rows`
+SEMI_TYPES = (SEMI, ANTI)
 
 
 def join_type_id(how: str) -> int:
@@ -868,33 +872,40 @@ def semi_capable(cap_l: int, cap_r: int) -> bool:
     return cap_l + cap_r < _SEMI_DEAD
 
 
-def semi_hits(
-    l_ids: jax.Array, r_ids: jax.Array, l_live: jax.Array, r_live: jax.Array
-) -> Tuple[jax.Array, jax.Array]:
-    """The rows of an INNER join that have a partner, found from the key
-    ids alone, before any payload moves: the semi-reduction in front of a
-    selective join (stage ``join.semi``).
+def _semi_scan(
+    l_ids: jax.Array, r_ids: jax.Array, l_live: jax.Array, r_live: jax.Array,
+    need_right: bool, wide: bool = False,
+):
+    """What :func:`semi_hits` (both sides' hits, in front of an INNER
+    join) and :func:`semi_rows` (the semi and anti join themselves) share,
+    in sorted order: one sort of [right ids ++ left ids] with the row's
+    position as the second key (the order of :func:`_merged_counts`'
+    stable kv-sort; a row that is not live, a padding slot or one its
+    mask dropped, carries the dead bit and counts for nothing) and the
+    blocked run scans (:func:`ops.sort.run_reduce`) for the live partners
+    of each row. Called inside the caller's ``join.semi`` scope.
 
-    One sort of [right ids ++ left ids] with the row's position as the
-    second key (the order of :func:`_merged_counts`' stable kv-sort; a row
-    that is not live, a padding slot or one its mask dropped, carries the
-    dead bit and counts for nothing), two blocked run scans
-    (:func:`ops.sort.run_reduce`) for the live partners of each row, and
-    ONE single-operand sort that brings the positions of the
-    rows with a partner to the front: the rights' ascending, then the lefts' (a left's
-    position is ``cap_r`` plus its row, so it sorts after every right).
-
-    Returns (``hits`` [cap_l + cap_r] int32, ``stats`` [4] int32: rights
-    with a partner, lefts with a partner, the join's exact row count, and
-    the float32 shadow of that count's bits, see
-    :func:`count_overflow_check`)."""
+    Returns ``(pos, l_liv, r_liv, cnt, l_after)``: a sorted slot's
+    position (a left's is ``cap_r`` plus its row), whether it is a live
+    left / right, a live left's count of live rights in its run (0
+    elsewhere), and a right's count of live lefts (``None`` without
+    ``need_right``: the scan is not run). ``wide`` (positions that do not
+    fit under the dead bit, :func:`semi_capable`): the dead flag rides the
+    sort as an operand of its own."""
     from .sort import run_reduce
 
     cap_l, cap_r = l_ids.shape[0], r_ids.shape[0]
-    with jax.named_scope(_stages.JOIN_SEMI):
+    keys = jnp.concatenate([r_ids, l_ids])  # rights FIRST, as the probe's
+    live = jnp.concatenate([r_live, l_live])
+    if wide:
+        pay = jnp.arange(cap_r + cap_l, dtype=jnp.int32)
+        with jax.named_scope(_stages.SORT_ENGINE):
+            skey, pos, s_dead = jax.lax.sort(
+                (keys, pay, ~live), num_keys=2, is_stable=False
+            )
+        s_live = ~s_dead
+    else:
         dead = jnp.int32(_SEMI_DEAD)
-        keys = jnp.concatenate([r_ids, l_ids])  # rights FIRST, as the probe's
-        live = jnp.concatenate([r_live, l_live])
         pay = jnp.arange(cap_r + cap_l, dtype=jnp.int32) | jnp.where(
             live, jnp.int32(0), dead
         )
@@ -909,23 +920,48 @@ def semi_hits(
             )
         s_live = spay < dead
         pos = spay & (dead - 1)
-        is_l = pos >= cap_r
-        l_liv, r_liv = s_live & is_l, s_live & ~is_l
-        new_run = jnp.concatenate(
-            [jnp.ones((1,), bool), skey[1:] != skey[:-1]]
-        )
+    is_l = pos >= cap_r
+    l_liv, r_liv = s_live & is_l, s_live & ~is_l
+    new_run = jnp.concatenate(
+        [jnp.ones((1,), bool), skey[1:] != skey[:-1]]
+    )
+    # rights precede lefts inside a run: a right sees every live left
+    # of its run at or after it, and a left every live right at or
+    # before it, which is the same scan over the flipped order (a
+    # run's start is its end there). The blocked scan and no
+    # ``jnp.cumsum``: its passes carry this scope's name into the
+    # trace, a prefix sum's ``reduce-window`` pieces carry none
+    l_after = None
+    if need_right:
         run_end = jnp.concatenate([new_run[1:], jnp.ones((1,), bool)])
-        # rights precede lefts inside a run: a right sees every live left
-        # of its run at or after it, and a left every live right at or
-        # before it, which is the same scan over the flipped order (a
-        # run's start is its end there). The blocked scan and no
-        # ``jnp.cumsum``: its passes carry this scope's name into the
-        # trace, a prefix sum's ``reduce-window`` pieces carry none
         (l_after,) = run_reduce(run_end, [l_liv.astype(jnp.int32)], ["sum"])
-        (r_upto,) = run_reduce(
-            jnp.flip(new_run), [jnp.flip(r_liv.astype(jnp.int32))], ["sum"]
+    (r_upto,) = run_reduce(
+        jnp.flip(new_run), [jnp.flip(r_liv.astype(jnp.int32))], ["sum"]
+    )
+    cnt = jnp.where(l_liv, jnp.flip(r_upto), 0)
+    return pos, l_liv, r_liv, cnt, l_after
+
+
+def semi_hits(
+    l_ids: jax.Array, r_ids: jax.Array, l_live: jax.Array, r_live: jax.Array
+) -> Tuple[jax.Array, jax.Array]:
+    """The rows of an INNER join that have a partner, found from the key
+    ids alone, before any payload moves: the semi-reduction in front of a
+    selective join (stage ``join.semi``).
+
+    The merged sort and the two run scans of :func:`_semi_scan`, and
+    ONE single-operand sort that brings the positions of the
+    rows with a partner to the front: the rights' ascending, then the lefts' (a left's
+    position is ``cap_r`` plus its row, so it sorts after every right).
+
+    Returns (``hits`` [cap_l + cap_r] int32, ``stats`` [4] int32: rights
+    with a partner, lefts with a partner, the join's exact row count, and
+    the float32 shadow of that count's bits, see
+    :func:`count_overflow_check`)."""
+    with jax.named_scope(_stages.JOIN_SEMI):
+        pos, _l_liv, r_liv, cnt, l_after = _semi_scan(
+            l_ids, r_ids, l_live, r_live, need_right=True
         )
-        cnt = jnp.where(l_liv, jnp.flip(r_upto), 0)
         r_hit = r_liv & (l_after > 0)
         l_hit = cnt > 0
         big = jnp.int32(2**31 - 1)
@@ -939,6 +975,48 @@ def semi_hits(
             ),
         ])
         return hits, stats
+
+
+def semi_rows(
+    l_ids: jax.Array, r_ids: jax.Array, l_live: jax.Array, r_live: jax.Array,
+    anti: bool, as_mask: bool, wide: bool = False,
+) -> Tuple[jax.Array, jax.Array]:
+    """The semi join (``anti``: the anti join) as an operator: which LEFT
+    rows are live and have a live partner (``anti``: are live and have
+    none), from the key ids alone. Nothing of the right side comes back
+    and no row moves: no right hits, no join count, no payload.
+
+    The keys-only work is :func:`semi_hits`' (:func:`_semi_scan`, with the
+    one scan a left needs; stage ``join.semi``). It leaves the verdicts in
+    sorted order; ONE single-operand sort brings them back to row order
+    (stage ``join.semi_mask``), in the form the caller asks for:
+
+    - ``as_mask``: a bool mask in the padded row layout (a left's word is
+      twice its row plus its verdict; every left slot has one, so the
+      first ``cap_l`` sorted words are the rows in order). What a planned
+      aggregate above the join takes as its row mask (``semi_as_mask``);
+    - else the kept rows' positions ascending, ``cap_l`` past the last:
+      what a compaction at ``round_cap`` of the count gathers by, with no
+      sort of its own.
+
+    Returns (the mask or the positions [cap_l], the kept count int32)."""
+    cap_l, cap_r = l_ids.shape[0], r_ids.shape[0]
+    with jax.named_scope(_stages.JOIN_SEMI):
+        pos, l_liv, _r_liv, cnt, _ = _semi_scan(
+            l_ids, r_ids, l_live, r_live, need_right=False, wide=wide
+        )
+        keep = (l_liv & (cnt == 0)) if anti else (cnt > 0)
+        kept = jnp.sum(keep).astype(jnp.int32)
+    with jax.named_scope(_stages.JOIN_SEMI_MASK):
+        lrow = (pos - cap_r).astype(jnp.uint32)
+        big = jnp.uint32(0xFFFFFFFF)
+        if as_mask:
+            word = jnp.where(
+                pos >= cap_r, (lrow << 1) | keep.astype(jnp.uint32), big
+            )
+            return (_sort_one(word)[:cap_l] & 1).astype(bool), kept
+        rows = _sort_one(jnp.where(keep, lrow, big))[:cap_l]
+        return jnp.minimum(rows, jnp.uint32(cap_l)).astype(jnp.int32), kept
 
 
 def _sort_one(key: jax.Array) -> jax.Array:

@@ -104,9 +104,15 @@ def join_filter_sides(how: str) -> Optional[str]:
     - left:  right side only (every left row emits, matched or not);
     - right: left side only (mirror);
     - full outer: nothing — every row of both sides emits, so
-      false-positive-only pruning has nothing it may remove.
+      false-positive-only pruning has nothing it may remove;
+    - semi: left side only (a left row without a partner is dropped
+      anyway; the right side already ships its keys alone);
+    - anti: nothing — a left row WITHOUT a partner is what emits.
     """
-    return {"inner": "both", "left": "b", "right": "a"}.get(how)
+    return {
+        "inner": "both", "left": "b", "right": "a",
+        "semi": "a", "left_semi": "a",
+    }.get(how.replace("-", "_").lower())
 
 
 def setop_filter_sides(op: str) -> Optional[str]:

@@ -17,6 +17,8 @@ two ``searchsorted`` bounds on the host and a code compare on device.
 """
 from __future__ import annotations
 
+import functools
+
 import operator
 from typing import FrozenSet, Mapping, Optional, Tuple
 
@@ -356,11 +358,24 @@ def _promotes_as(expr: Expr, dtype_of):
         return _promotes_as(expr.operand, dtype_of)
     if expr.op in _CMP:
         return np.dtype(bool)
+    return _binop_dtype(
+        expr.op, bool(jax.config.jax_enable_x64),
+        *(_promotes_as(e, dtype_of) for e in (expr.left, expr.right)),
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _binop_dtype(op: str, x64: bool, left, right) -> np.dtype:
+    """What ``left op right`` promotes to (each a dtype, or the python type
+    of a weak scalar), asked of JAX once a combination: ``jax.eval_shape``
+    traces a jaxpr, and a plan built anew for every query (a report whose
+    literal is a parameter) would trace one for every arithmetic node of
+    its schema, every time."""
     sides = [
         d() if isinstance(d, type) else jax.ShapeDtypeStruct((), d)
-        for d in (_promotes_as(e, dtype_of) for e in (expr.left, expr.right))
+        for d in (left, right)
     ]
-    return np.dtype(jax.eval_shape(_OPS[expr.op], *sides).dtype)
+    return np.dtype(jax.eval_shape(_OPS[op], *sides).dtype)
 
 
 def result_dtype(expr: Expr, dtype_of) -> str:

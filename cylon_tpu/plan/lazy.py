@@ -172,6 +172,18 @@ class LazyFrame:
         right_on: Optional[TUnion[str, Sequence[str]]] = None,
         suffixes: Tuple[str, str] = ("_x", "_y"),
     ) -> "LazyFrame":
+        """An equi-join plan node; ``how`` as :meth:`Table.join` takes it:
+        inner, left, right, outer, and **semi** / **anti** (also
+        ``left_semi`` / ``left_anti``): the rows of this frame that have
+        (have no) partner in ``other`` on the keys, EXISTS / NOT EXISTS.
+        A semi or anti join's output is this frame's columns under their
+        own names (no suffix), each row at most once however many partners
+        it has, in this frame's order; a null key has no partner (semi
+        drops the row, anti keeps it: NOT EXISTS, not NOT IN). Filters on
+        either side ride such a join as row masks (``join_mask``), and
+        directly under a ``groupby`` the dense plan takes, or under
+        :meth:`agg`, the join compacts nothing: its hit mask is the
+        aggregate's row mask (``semi_as_mask`` in ``explain()``)."""
         if not isinstance(other, LazyFrame):
             raise TypeError("join expects another LazyFrame (use .lazy())")
         if other._ctx is not self._ctx:

@@ -24,6 +24,7 @@ from ..column import Column
 from ..engine import get_kernel
 from ..obs import stages as _stages
 from ..obs import trace as _obstrace
+from ..ops.join import join_type_id
 from ..utils.tracing import span
 from .expr import keep_mask, numeric_literals
 from .nodes import (
@@ -262,6 +263,23 @@ def _globally_ordered(t, keys) -> bool:
     )
 
 
+class _MaskedRows:
+    """What a Join that ``semi_as_mask`` marked hands the aggregate
+    directly above it: the left side's table, no row moved, and the
+    join's verdicts as a row mask over its padded layout."""
+
+    __slots__ = ("table", "mask")
+
+    def __init__(self, table, mask):
+        self.table, self.mask = table, mask
+
+    def _materialize(self):
+        return self.table._materialize()
+
+    def _rows_hint(self):
+        return None  # the kept rows are never counted
+
+
 def _lower_one(node: Node, ex, tables):
     if isinstance(node, Scan):
         return tables[node.ordinal]
@@ -292,9 +310,16 @@ def _lower_one(node: Node, ex, tables):
         spec: Dict[str, list] = {}
         for c, op in node.aggs:
             spec.setdefault(c, []).append(op)
+        # semi_as_mask rewrite: the join below kept its rows where they
+        # lie and hands over which of them it keeps
+        hits = None
+        if isinstance(t, _MaskedRows):
+            t, hits = t.table, t.mask
         # filter_as_mask rewrite: the table takes the predicate as the
         # aggregate's row mask (and still picks its own kernel)
         mask = None if node.mask is None else _expr_mask(t, node.mask)
+        if hits is not None:
+            mask = hits if mask is None else hits & mask
         keys = list(node.keys)
         if node.partial or not keys:
             # partial_aggregate rewrite: no Shuffle stands under the node;
@@ -332,6 +357,11 @@ def _lower_one(node: Node, ex, tables):
         lt, rt = _prepare_join_inputs(
             lt, rt, l_keys, r_keys, l_shuf, r_shuf, semi=node.semi_filter
         )
+        if node.hit_mask:
+            return _MaskedRows(*lt._semi_join(
+                rt, l_keys, r_keys, join_type_id(node.how), l_mask, r_mask,
+                as_mask=True,
+            ))
         return lt.join(
             rt, left_on=l_keys, right_on=r_keys, how=node.how,
             suffixes=node.suffixes,

@@ -312,11 +312,21 @@ class WithColumns(Node):
         return f"WithColumns [{spec}]"
 
 
+#: other spellings of the two join types that emit left rows only
+_SEMI_SPELLINGS = {"left_semi": "semi", "left_anti": "anti"}
+SEMI_HOWS = ("semi", "anti")
+
+
 class Join(Node):
     """Equi-join. Output names are fixed at BUILD time from the full child
     schemas (``_suffix_names``, the eager Table.join convention) and kept
     through rewrites: lowering renames each side to its out-names before
-    joining, so later column pruning cannot change the naming."""
+    joining, so later column pruning cannot change the naming.
+
+    ``how`` "semi" / "anti" (EXISTS / NOT EXISTS): the output is the LEFT
+    child's schema under the left's own names (no suffix: nothing of the
+    right side comes out), its rows a subset of the left's in their
+    order."""
 
     def __init__(
         self,
@@ -333,11 +343,16 @@ class Join(Node):
         keep: Tuple[Optional[Tuple[str, ...]], Optional[Tuple[str, ...]]] = (
             None, None,
         ),
+        hit_mask: bool = False,
     ):
         self.children = (left, right)
         self.l_on = tuple(l_on)
         self.r_on = tuple(r_on)
-        self.how = how
+        self.how = _SEMI_SPELLINGS.get(how.replace("-", "_").lower(), how)
+        # set by the semi_as_mask rewrite on a semi or anti join directly
+        # under an aggregate: the join compacts nothing and hands its hit
+        # mask, over the left side's rows where they lie, to the aggregate
+        self.hit_mask = bool(hit_mask)
         self.suffixes = tuple(suffixes)
         # set by the join_mask rewrite: the predicate of a Filter that stood
         # directly under a side of this INNER join, over that side's column
@@ -356,17 +371,20 @@ class Join(Node):
         # prune against the other side's key sketch ('both'/'left'/'right';
         # None = ineligible or disabled) — see ops/sketch.join_filter_sides
         self.semi_filter = semi_filter
-        if _renames is None:
+        if _renames is not None:
+            self.l_rename, self.r_rename = _renames
+        elif self.how in SEMI_HOWS:
+            self.l_rename = {n: n for n in left.names}
+            self.r_rename = {n: n for n in right.names}
+        else:
             lnames, rnames = left.names, right.names
             out = _suffix_names(lnames, rnames, suffixes)
             self.l_rename = dict(zip(lnames, out[: len(lnames)]))
             self.r_rename = dict(zip(rnames, out[len(lnames):]))
-        else:
-            self.l_rename, self.r_rename = _renames
-        self.schema = tuple(
-            [(self.l_rename[n], t, p) for n, t, p in left.schema]
-            + [(self.r_rename[n], t, p) for n, t, p in right.schema]
-        )
+        schema = [(self.l_rename[n], t, p) for n, t, p in left.schema]
+        if self.how not in SEMI_HOWS:
+            schema += [(self.r_rename[n], t, p) for n, t, p in right.schema]
+        self.schema = tuple(schema)
 
     def with_children(self, kids):
         return self.replaced(kids)
@@ -380,6 +398,7 @@ class Join(Node):
             "emit_key_order": self.emit_key_order,
             "semi_filter": self.semi_filter,
             "masks": self.masks, "keep": self.keep,
+            "hit_mask": self.hit_mask,
         }
         notes.update(changes)
         return Join(
@@ -397,6 +416,8 @@ class Join(Node):
 
     def partitioning(self) -> Partitioning:
         left, right = self.children
+        if self.how in SEMI_HOWS:
+            return left.partitioning()  # a subset of its rows, where they lie
         l_ok = _placed_by(left.partitioning(), self.l_on)
         r_ok = _placed_by(right.partitioning(), self.r_on)
         if not (l_ok and r_ok):
@@ -421,9 +442,10 @@ class Join(Node):
                 nulls_last=True, scope="shard", canonical=True,
                 lexsort_exact=False,
             )
-        if self.how in ("inner", "left"):
-            # the emit repeats left rows in left order: the left input's
-            # descriptor survives, under the join's output names
+        if self.how in ("inner", "left") + SEMI_HOWS:
+            # the emit repeats left rows in left order (semi, anti: keeps
+            # some of them): the left input's descriptor survives, under
+            # the join's output names
             return _ord.rename(self.children[0].ordering(), self.l_rename)
         return None
 
@@ -434,6 +456,8 @@ class Join(Node):
         out: Dict[str, object] = {}
         for n, v in self.children[0].col_stats().items():
             out[self.l_rename.get(n, n)] = v
+        if self.how in SEMI_HOWS:
+            return out
         for n, v in self.children[1].col_stats().items():
             out[self.r_rename.get(n, n)] = v
         return out
@@ -448,7 +472,7 @@ class Join(Node):
             tuple(sorted(self.r_rename.items())),
             self.emit_key_order, self.semi_filter,
             tuple(None if m is None else m.key() for m in self.masks),
-            self.keep,
+            self.keep, self.hit_mask,
         )
 
     def label(self) -> str:
@@ -459,7 +483,14 @@ class Join(Node):
         for side, m in zip(("left", "right"), self.masks):
             if m is not None:
                 tail += f" {side}-mask {m!r}"
-        if any(m is not None for m in self.masks):
+        if self.hit_mask:
+            tail += (
+                " [semi_as_mask: no row compacted, the hit mask goes to the"
+                " aggregate]"
+            )
+        elif self.how in SEMI_HOWS:
+            tail += " [capacity: keys only, then round_cap of the kept rows]"
+        elif any(m is not None for m in self.masks):
             tail += (
                 " [capacity: semi-reduce, then round_cap of the counted rows]"
             )

@@ -45,9 +45,9 @@ the rewritten plan plus the ordered list of rule firings (surfaced by
 6. ``semi_filter`` — annotate Join / FusedJoinGroupBySum nodes whose input
    Shuffles both still stand with their semi-join filter eligibility by
    join type (inner: both sides; left: right side only; right: left side
-   only; outer: never — false-positive-only pruning must not touch rows
-   that emit unconditionally). Lowering threads the annotation into the
-   pair shuffle (``table._shuffle_pair(semi=...)``), where each eligible
+   only; semi: left side only; outer and anti: never — false-positive-only
+   pruning must not touch rows that emit unconditionally). Lowering
+   threads the annotation into the pair shuffle (``table._shuffle_pair(semi=...)``), where each eligible
    side's rows are probed against the OTHER side's broadcast key sketch
    (ops/sketch.py) before they are packed; printed by ``.explain()`` and
    part of the plan fingerprint. CYLON_TPU_NO_SEMI_FILTER=1 disables;
@@ -59,7 +59,11 @@ GroupBy into the aggregate's row mask (``GroupBy.mask``): the table skips
 the rows in its reductions instead of compacting every column first; and
 ``join_mask`` does the same for a Filter directly under a side of an INNER
 Join (``Join.masks``): the join's keys-only semi-reduction treats a masked
-row as dead, and nothing is gathered at the table's capacity.
+row as dead, and nothing is gathered at the table's capacity. A semi or an
+anti Join takes the filters of both sides the same way (a right-side
+filter narrows the set that is searched), and ``semi_as_mask`` then spares
+such a Join directly under an aggregate its one compaction: the aggregate
+takes the join's hit mask as its row mask (``Join.hit_mask``).
 """
 from __future__ import annotations
 
@@ -72,6 +76,7 @@ from .nodes import (
     FusedJoinGroupBySum,
     GroupBy,
     Join,
+    SEMI_HOWS,
     Limit,
     Node,
     Project,
@@ -95,6 +100,7 @@ PROJECTION_PUSHDOWN = "projection_pushdown"
 TOPK = "topk"
 JOIN_MASK = "join_mask"
 PARTIAL_AGGREGATE = "partial_aggregate"
+SEMI_AS_MASK = "semi_as_mask"
 
 
 def optimize(root: Node, world_size: int) -> Tuple[Node, List[str]]:
@@ -109,6 +115,7 @@ def optimize(root: Node, world_size: int) -> Tuple[Node, List[str]]:
     root = _fuse_join_groupby(root, fired)
     root = _filter_as_mask(root, fired)
     root = _filter_as_join_mask(root, fired)
+    root = _semi_as_mask(root, fired)
     root = _reuse_order(root, fired)
     if world_size > 1:
         root = _annotate_semi_filter(root, fired)
@@ -174,8 +181,9 @@ def _push_filters(node: Node, fired: List[str]) -> Node:
         inv_l = {v: k for k, v in child.l_rename.items()}
         inv_r = {v: k for k, v in child.r_rename.items()}
         # pushing below a side is only sound when that side's rows survive
-        # the join unconditionally filtered (not resurrected as outer nulls)
-        if cols <= l_out and child.how in ("inner", "left"):
+        # the join unconditionally filtered (not resurrected as outer
+        # nulls); a semi or anti join's output is left columns alone
+        if cols <= l_out and child.how in ("inner", "left") + SEMI_HOWS:
             fired.append(FILTER_PUSHDOWN)
             left = _push_filters(
                 Filter(child.children[0], expr.rename(inv_l)), fired
@@ -394,8 +402,11 @@ def _filter_as_mask(node: Node, fired: List[str]) -> Node:
 # ----------------------------------------------------------------------
 def _filter_as_join_mask(node: Node, fired: List[str]) -> Node:
     """``Join(Filter(x, p), y)`` -> ``Join(x, y, masks=(p, None))``, either
-    side, INNER joins only (an outer join would bring a dropped row back
-    as an unmatched one). The filter no longer compacts every column of
+    side, INNER, semi and anti joins (an outer join would bring a dropped
+    row back as an unmatched one; a semi or anti join keeps no left row
+    its filter dropped, and a right row its filter dropped is no partner:
+    the filter narrows the set that is searched, which is what it did
+    under the join). The filter no longer compacts every column of
     its table at the table's capacity: the join's keys-only semi-reduction
     treats a masked row as dead, and only the rows that have a partner are
     ever gathered (``Table.join``'s docstring has the capacity rule). Runs
@@ -404,7 +415,7 @@ def _filter_as_join_mask(node: Node, fired: List[str]) -> Node:
     a side is masked only where its rows do not move."""
     kids = [_filter_as_join_mask(c, fired) for c in node.children]
     node = node.with_children(kids) if node.children else node
-    if not isinstance(node, Join) or node.how != "inner":
+    if not isinstance(node, Join) or node.how not in ("inner",) + SEMI_HOWS:
         return node
     kids, masks = list(node.children), list(node.masks)
     before = len(fired)
@@ -419,6 +430,32 @@ def _filter_as_join_mask(node: Node, fired: List[str]) -> Node:
     if len(fired) == before:
         return node
     return node.replaced(kids, masks=tuple(masks))
+
+
+# ----------------------------------------------------------------------
+# 4d. a semi or anti join directly under an aggregate hands over its mask
+# ----------------------------------------------------------------------
+def _semi_as_mask(node: Node, fired: List[str]) -> Node:
+    """``GroupBy(Join how=semi|anti)`` -> the same with ``Join.hit_mask``,
+    where the dense plan takes the group-by (:func:`_dense_plan_takes`) or
+    it has no keys: the join compacts nothing, and its verdicts over the
+    left side's rows, where they lie, are the aggregate's row mask, as
+    ``filter_as_mask`` does for a filter. Any other group-by sorts its
+    rows, which is cheaper on the few a compaction leaves: declined."""
+    kids = [_semi_as_mask(c, fired) for c in node.children]
+    node = node.with_children(kids) if node.children else node
+    if not isinstance(node, GroupBy):
+        return node
+    join = node.children[0]
+    if (
+        not isinstance(join, Join) or join.how not in SEMI_HOWS
+        or join.hit_mask
+    ):
+        return node
+    if node.keys and not _dense_plan_takes(node, join):
+        return node
+    fired.append(SEMI_AS_MASK)
+    return node.replaced(join.replaced(join.children, hit_mask=True))
 
 
 # ----------------------------------------------------------------------
@@ -598,6 +635,10 @@ def _prune(node: Node, req: Set[str], fired: List[str]) -> Node:
     if isinstance(node, Join):
         l_req = {s for s, o in node.l_rename.items() if o in req} | set(node.l_on)
         r_req = {s for s, o in node.r_rename.items() if o in req} | set(node.r_on)
+        if node.how in SEMI_HOWS:
+            # nothing of the right side comes out: its keys, and below
+            # what its mask reads
+            r_req = set(node.r_on)
         kids, keep = [], list(node.keep)
         for side, need in enumerate((l_req, r_req)):
             mask = node.masks[side]

@@ -1604,8 +1604,25 @@ class Table:
         _right_mask: Optional[jax.Array] = None,
         _totals: Optional[np.ndarray] = None,
     ) -> "Table":
-        """Per-shard (local) equi-join — all 4 types (reference Join,
-        table.cpp:428-480; join/hash_join.cpp + sort_join.cpp).
+        """Per-shard (local) equi-join — the reference's 4 types (Join,
+        table.cpp:428-480; join/hash_join.cpp + sort_join.cpp) and two it
+        lacks, **semi** and **anti**.
+
+        ``how``: 'inner', 'left', 'right', 'outer' ('fullouter',
+        'full_outer') emit both sides' columns, suffixed on a name clash.
+        'semi' / 'anti' ('left_semi' / 'left_anti') are EXISTS / NOT
+        EXISTS: this table's rows that have (have no) partner in
+        ``other`` on the keys. Their output keeps this table's columns
+        under their own names (no suffix: nothing of ``other`` comes out),
+        each row at most once however many partners it has, in this
+        table's row order, with its ordering descriptor and column stats
+        as a filter keeps them. A null key has no partner on either side:
+        semi drops the row, anti keeps it (NOT EXISTS, not NOT IN). They
+        read ``other``'s key columns alone and move no payload before the
+        rows are known: one keys-only program, one fetch (the kept
+        count), one compaction at ``round_cap`` of it
+        (:meth:`_semi_join`); ``emit_order='key'`` and
+        ``algorithm='pallas_pk'`` do not apply to them.
 
         **Capacity of the result.** A join emits into a capacity it must
         choose before it knows its row count. By default it speculates:
@@ -1676,6 +1693,10 @@ class Table:
                 )
             return self._pallas_pk_join(other, l_names, r_names, how, suffixes)
         howi = _j.join_type_id(how)
+        if howi in _j.SEMI_TYPES:
+            return self._semi_join(
+                other, l_names, r_names, howi, _left_mask, _right_mask
+            )
         # sorted-run reuse gate, read BEFORE dictionary unification/promotion
         # (both preserve value order, so the descriptor's claim survives
         # them; the _replace they perform drops the attribute itself)
@@ -1707,7 +1728,9 @@ class Table:
         ) + _j.impl_tag()
         masked = _left_mask is not None or _right_mask is not None
         if masked and howi != _j.INNER:
-            raise ValueError("a row mask rides an inner join only")
+            raise ValueError(
+                "a row mask rides an inner join only (or a semi or anti join)"
+            )
         if masked:
             if not _j.semi_capable(left.shard_cap, right.shard_cap):
                 # a mask never falls through unread: filter, then join
@@ -1939,18 +1962,9 @@ class Table:
 
         def build_semi():
             def kern(dp, rep):
-                (lk, rk, nl, nr, masks) = dp
-                cap_l, cap_r = lk[0][0].shape[0], rk[0][0].shape[0]
-                l_ids, r_ids = _j._canonical_ids(
-                    lk, rk, nl[0], nr[0], cap_l, cap_r, fuse=join_fuse
+                l_ids, r_ids, l_live, r_live, _rk = _key_ids_and_live(
+                    dp, l_on, r_on, join_fuse
                 )
-                masks = list(masks)
-                l_live = jnp.arange(cap_l, dtype=jnp.int32) < nl[0]
-                r_live = jnp.arange(cap_r, dtype=jnp.int32) < nr[0]
-                if l_on:
-                    l_live = l_live & masks.pop(0)
-                if r_on:
-                    r_live = r_live & masks.pop(0)
                 hits, stats = _j.semi_hits(l_ids, r_ids, l_live, r_live)
                 return hits, stats
 
@@ -2001,6 +2015,118 @@ class Table:
             out_r, n_r, cap_ro,
         )._attach_ordering(other._ordering)._attach_stats(other._stats)
         return left, right, totals
+
+    def _semi_join(
+        self, other: "Table", l_names, r_names, howi: int, l_mask, r_mask,
+        as_mask: bool = False,
+    ):
+        """The semi join (``howi`` ANTI: the anti join) of :meth:`join`:
+        this table's rows that have (have no) partner in ``other``, each at
+        most once, in row order. ``l_mask`` / ``r_mask`` ride it as they
+        ride an INNER join: a row they drop is as dead as a padding slot (a
+        dropped left row is not kept, a dropped right row is no partner).
+        A null key has no partner on either side.
+
+        One keys-only program (``join_semi_rows``: ``ops.join.semi_rows``
+        over the key columns and the masks), which reads no payload column
+        and nothing of ``other`` but its keys. Then one of two:
+
+        - the rows are compacted: the kept count is fetched (the one
+          sync) and ``join_semi_take`` gathers this table's columns at
+          ``round_cap`` of it, by positions the first program already
+          sorted. The result is a row subset in row order, so names,
+          ordering and stats are kept as a filter keeps them;
+        - ``as_mask`` (the planner's ``semi_as_mask``): nothing is fetched
+          or gathered; ``(self, mask)`` comes back, the verdicts as a row
+          mask over this table's padded layout, for the aggregate above.
+
+        Where both sides' positions do not fit the keys-only program's
+        word (``ops.join.semi_capable``) the same program carries the
+        dead flag through its sort as an operand of its own (``wide``)."""
+        anti = howi == _j.ANTI
+        left, right = _unify_dict_pair(self, other, l_names, r_names)
+        join_fuse = _plan_join_fusion(left, l_names, right, r_names)
+        l_on, r_on = l_mask is not None, r_mask is not None
+        wide = not _j.semi_capable(left.shard_cap, right.shard_cap)
+        sig = (
+            tuple(left.column_names.index(n) for n in l_names),
+            tuple(right.column_names.index(n) for n in r_names),
+        )
+
+        def build_rows():
+            def kern(dp, rep):
+                l_ids, r_ids, l_live, r_live, rk = _key_ids_and_live(
+                    dp, l_on, r_on, join_fuse
+                )
+                # EXISTS: a null key equals nothing, a null of the other
+                # side included (the INNER join pairs them, as pandas
+                # does). A right row with one is no partner; a left row
+                # with one stays live and finds none (anti keeps it)
+                for _d, v in rk:
+                    r_live = r_live if v is None else r_live & v
+                out, kept = _j.semi_rows(
+                    l_ids, r_ids, l_live, r_live, anti, as_mask, wide
+                )
+                return out, _scalar(kept)
+
+            return kern
+
+        l_rows, r_rows = self._rows_hint(), other._rows_hint()
+        bump("join.semi.left_rows", rows=l_rows or 0)
+        with span("join.semi_join", rows=l_rows, right_rows=r_rows):
+            rows, kept = get_kernel(
+                self.ctx,
+                ("join_semi_rows", sig, l_on, r_on, join_fuse, anti,
+                 as_mask, wide),
+                build_rows,
+            )(
+                (
+                    left._flat_cols(l_names), right._flat_cols(r_names),
+                    left.counts_dev, right.counts_dev,
+                    [m for m in (l_mask, r_mask) if m is not None],
+                ),
+                (),
+            )
+            if as_mask:
+                bump("join.semi.payload_rows", rows=0)
+                return self, rows
+            counts = _fetch(kept, "join.semi_join").reshape(-1).astype(
+                np.int64
+            )
+        kept_rows = int(counts.sum())
+        bump("join.semi.kept_rows", rows=kept_rows)
+        bump("join.semi.payload_rows", rows=kept_rows)
+        cap_out = round_cap(int(counts.max()))
+        flat = self._flat_cols()
+
+        def build_take():
+            def kern(dp, rep):
+                (rows, kept, cols) = dp
+                (dummy,) = rep
+                co, cap = dummy.shape[0], rows.shape[0]
+                if co <= cap:
+                    idx = rows[:co]
+                else:
+                    idx = jnp.concatenate(
+                        [rows, jnp.full((co - cap,), -1, jnp.int32)]
+                    )
+                idx = jnp.where(
+                    jnp.arange(co, dtype=jnp.int32) < kept[0], idx, -1
+                )
+                # every -1 lands past the kept rows: a column without a
+                # validity lane keeps none
+                out, _ = _g_pack.pack_gather(list(cols), idx, all_valid=True)
+                return out
+
+            return kern
+
+        out = get_kernel(
+            self.ctx, ("join_semi_take", len(flat)), build_take
+        )((rows, kept, flat), (jnp.zeros((cap_out,), jnp.int8),))
+        return self._rebuild_cols(
+            list(zip(self.column_names, self._columns.values())),
+            out, counts, cap_out,
+        )._attach_ordering(self._ordering)._attach_stats(self._stats)
 
     def _pallas_pk_join(
         self,
@@ -2154,6 +2280,10 @@ class Table:
         l_names, r_names = self._resolve_join_keys(
             other, kwargs.get("on"), kwargs.get("left_on"), kwargs.get("right_on")
         )
+        if _j.join_type_id(kwargs["how"]) in _j.SEMI_TYPES:
+            # a semi or anti join reads the right side's keys alone: no
+            # other column of it is packed, exchanged or compacted
+            other = other.project(r_names)
         left, right = _unify_dict_pair(self, other, l_names, r_names)
         # promote key dtype pairs BEFORE hashing: the shuffle hashes each side
         # independently, and murmur words depend on the physical dtype — an
@@ -2164,7 +2294,8 @@ class Table:
         # the other) instead of serializing table-by-table. The semi-join
         # sketch filter prunes provably partnerless rows before the payload
         # exchange, gated by join type (inner: both sides; left/right: the
-        # other side only; outer: off — ops/sketch.join_filter_sides)
+        # other side only; semi: the left side; outer and anti: off —
+        # ops/sketch.join_filter_sides)
         ls, rs = _shuffle_pair(
             left, l_names, right, r_names,
             semi=_sketch.join_filter_sides(kwargs.get("how", "inner")),
@@ -2204,6 +2335,11 @@ class Table:
         world = ctx.world_size
         l_names, r_names = self._resolve_join_keys(other, on, left_on, right_on)
         howi = _j.join_type_id(how)
+        if howi in _j.SEMI_TYPES:
+            raise ValueError(
+                "mode='fused' emits both sides' columns; a semi or anti "
+                "join needs mode='eager'"
+            )
         left, right = _unify_dict_pair(self, other, l_names, r_names)
         left, right = _promote_key_pair(left, right, l_names, r_names)
         lk_idx = tuple(left.column_names.index(n) for n in l_names)
@@ -5530,6 +5666,28 @@ def _plan_join_fusion(left: "Table", l_names, right: "Table", r_names):
         specs, pad_bits=1, prefix_bits=0,
         allow64=bool(jax.config.jax_enable_x64),
     )
+
+
+def _key_ids_and_live(dp, l_on: bool, r_on: bool, join_fuse):
+    """The opening of both keys-only programs (``join_semi``,
+    ``join_semi_rows``), traced inside their kernels: the canonical key
+    ids of both sides, and which rows are live, a padding slot and a row
+    its side's mask dropped being neither. ``dp`` is ``(left keys, right
+    keys, left count, right count, [the masks that are on])``. Returns
+    (l_ids, r_ids, l_live, r_live, the right key columns)."""
+    (lk, rk, nl, nr, masks) = dp
+    cap_l, cap_r = lk[0][0].shape[0], rk[0][0].shape[0]
+    l_ids, r_ids = _j._canonical_ids(
+        lk, rk, nl[0], nr[0], cap_l, cap_r, fuse=join_fuse
+    )
+    masks = list(masks)
+    l_live = jnp.arange(cap_l, dtype=jnp.int32) < nl[0]
+    r_live = jnp.arange(cap_r, dtype=jnp.int32) < nr[0]
+    if l_on:
+        l_live = l_live & masks.pop(0)
+    if r_on:
+        r_live = r_live & masks.pop(0)
+    return l_ids, r_ids, l_live, r_live, rk
 
 
 def _check_join_count(totals: np.ndarray, shadows: np.ndarray) -> None:
