@@ -4,8 +4,17 @@ Reference analog: CTest runs every suite under ``mpirun -np {1,2,4}``
 (cpp/test/CMakeLists.txt:44-117). Here a single process gets 8 virtual XLA CPU
 devices (SURVEY.md §4.3) and the same tests run on 1-, 2-, 4- and 8-device
 meshes via the ``ctx`` fixtures.
+
+Every test runs under a limit of its own (``LIMIT_S``, or what its
+``@pytest.mark.limit(seconds)`` says): past it an alarm fails the test by
+name. The alarm's exception waits while the main thread is inside native
+code (an XLA compile is not interrupted), so a second later every thread's
+stack is dumped to the run's own stderr, which names the test that is stuck.
 """
+import faulthandler
 import os
+import signal
+import sys
 
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
@@ -19,6 +28,50 @@ import numpy as np
 import pytest
 
 import cylon_tpu as ct
+
+#: seconds a test may take: three times the longest tier-1 test (ROADMAP D7)
+LIMIT_S = 180
+
+_DUMP_FD = pytest.StashKey[int]()
+
+
+def pytest_configure(config):
+    # here the capture is suspended, so fd 2 is the run's own stderr: a dump
+    # written there is read while the stuck test is still running
+    config.stash[_DUMP_FD] = os.dup(sys.__stderr__.fileno())
+
+
+def pytest_unconfigure(config):
+    os.close(config.stash[_DUMP_FD])
+
+
+@pytest.fixture(scope="session")
+def limit_dump_fd(request):
+    """The descriptor a test past its limit dumps the stacks to."""
+    return request.config.stash[_DUMP_FD]
+
+
+@pytest.fixture(autouse=True)
+def _limit(request, limit_dump_fd):
+    """Fails the test once its seconds have passed and the main thread is in
+    Python (``pytest.fail``'s exception is no ``Exception``, so the code
+    under test does not swallow it); where the test still runs a second
+    later, dumps every thread's stack."""
+    marker = request.node.get_closest_marker("limit")
+    seconds = marker.args[0] if marker else LIMIT_S
+
+    def on_alarm(signum, frame):
+        pytest.fail(f"{request.node.nodeid} exceeded {seconds} s", pytrace=False)
+
+    was = signal.signal(signal.SIGALRM, on_alarm)
+    faulthandler.dump_traceback_later(seconds + 1, exit=False, file=limit_dump_fd)
+    signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        faulthandler.cancel_dump_traceback_later()
+        signal.signal(signal.SIGALRM, was)
 
 
 @pytest.fixture(scope="session")

@@ -78,7 +78,7 @@ def test_c_client_end_to_end(tmp_path):
         [exe, so, lp, rp, out],
         capture_output=True,
         text=True,
-        timeout=600,
+        timeout=30,  # three times its measured run
         env=env,
     )
     assert res.returncode == 0, f"stdout={res.stdout}\nstderr={res.stderr[-2000:]}"

@@ -71,7 +71,7 @@ def test_java_abi_sequence(tmp_path):
 
     res = subprocess.run(
         [exe, so, lp, rp, out],
-        capture_output=True, text=True, timeout=600, env=_subprocess_env(),
+        capture_output=True, text=True, timeout=30, env=_subprocess_env(),  # three times its measured run
     )
     assert res.returncode == 0, (
         f"stdout={res.stdout}\nstderr={res.stderr[-2000:]}"

@@ -137,40 +137,44 @@ def test_batched_equals_serial_string_nulls(serve_ctx, rng):
         _assert_same(t.to_pydict(), oracle[i], f"string binding {i}")
 
 
-def test_batched_equals_serial_tails(serve_ctx, rng):
+_TAILS = {
+    "sort": lambda ta, tb: ta.lazy()
+    .join(tb.lazy(), left_on="k", right_on="rk")
+    .sort(["k", "v"]),
+    "left-project": lambda ta, tb: ta.lazy()
+    .join(tb.lazy(), left_on="k", right_on="rk", how="left")
+    .select(["k", "w"]),
+    "right": lambda ta, tb: ta.lazy().join(
+        tb.lazy(), left_on="k", right_on="rk", how="right"
+    ),
+    "multi-agg": lambda ta, tb: ta.lazy()
+    .filter(col("v") > 0.0)
+    .groupby("k", {"v": ["min", "count", "mean"]}),
+}
+
+
+@pytest.mark.parametrize("name", list(_TAILS))
+def test_batched_equals_serial_tails(serve_ctx, rng, name):
     """Non-q3 batchable shapes: sort tail, left-join + project,
     right join, multi-aggregate groupby."""
-    mk = lambda i: _mk_binding(serve_ctx, rng, 100 + 13 * i)  # noqa: E731
-    shapes = {
-        "sort": lambda ta, tb: ta.lazy()
-        .join(tb.lazy(), left_on="k", right_on="rk")
-        .sort(["k", "v"]),
-        "left-project": lambda ta, tb: ta.lazy()
-        .join(tb.lazy(), left_on="k", right_on="rk", how="left")
-        .select(["k", "w"]),
-        "right": lambda ta, tb: ta.lazy().join(
-            tb.lazy(), left_on="k", right_on="rk", how="right"
-        ),
-        "multi-agg": lambda ta, tb: ta.lazy()
-        .filter(col("v") > 0.0)
-        .groupby("k", {"v": ["min", "count", "mean"]}),
-    }
-    for name, build in shapes.items():
-        plans = [build(*mk(i)) for i in range(3)]
-        oracle = [p.collect().to_pydict() for p in plans]
-        for i, t in enumerate(_run_batched(serve_ctx, plans)):
-            got = t.to_pydict()
-            _assert_same(got, oracle[i], f"{name} binding {i}")
-            if name == "sort":
-                # RAW order, not just the canonicalized set: each
-                # binding's slice must come out in its requested sort
-                # order (qid-leading batched sort + stable split)
-                order = np.lexsort(
-                    (np.asarray(got["v"]), np.asarray(got["k"]))
-                )
-                assert np.array_equal(
-                    order, np.arange(len(got["k"]))
-                ), f"sort binding {i} rows not in (k, v) order"
+    build = _TAILS[name]
+    plans = [
+        build(*_mk_binding(serve_ctx, rng, 100 + 13 * i)) for i in range(3)
+    ]
+    oracle = [p.collect().to_pydict() for p in plans]
+    for i, t in enumerate(_run_batched(serve_ctx, plans)):
+        got = t.to_pydict()
+        _assert_same(got, oracle[i], f"{name} binding {i}")
+        if name == "sort":
+            # RAW order, not just the canonicalized set: each
+            # binding's slice must come out in its requested sort
+            # order (qid-leading batched sort + stable split)
+            order = np.lexsort(
+                (np.asarray(got["v"]), np.asarray(got["k"]))
+            )
+            assert np.array_equal(
+                order, np.arange(len(got["k"]))
+            ), f"sort binding {i} rows not in (k, v) order"
 
 
 def test_unbatchable_limit_falls_back_to_singles(sctx4, rng):

@@ -13,11 +13,33 @@ the bring-up established:
   resolves both codec stages to XLA (ops/pallas_codec.py) and why a forced
   kernel raises on a TPU mesh.
 
+Which facts need which shape. What a test reads from the compiled text
+(which operations the program has, their operands and stages, the branch a
+gather stands in) does not depend on the capacity, and is compiled at
+``ROWS``: the suite's sort of 2^20 rows takes the compiler 90 s, of 2^14
+rows 17 and of 2^13 rows 3, and the text has the same operations, one for
+one. Bytes held against the chip's memory and seconds of compile
+are facts of a cell's full shard shape: they are compiled at that shape
+(``CELL_ROWS``, or the cell's own capacities), and those that take over a
+minute are marked ``slow``, beside a test of the structure at the small
+shape that stays in tier-1. The chip guards them on every PR, since a cell
+whose program does not fit does not compile (ROADMAP.md, under the tier-1
+line, says when a builder owes ``-m slow`` here). A new compile test goes in
+at ``ROWS`` unless it asserts bytes.
+
 This is the only file that describes a TPU topology, and it does so only
 inside the module-scoped ``topo`` fixture: one process at a time may load
 the TPU's library, so the call must not run while any module is imported
 (each pytest worker imports every test file) and the compiles run in this
-process, never in a child.
+process, never in a child. The process that has described the topology
+holds ``/tmp/libtpu_lockfile`` until it exits: a second one that tries
+meanwhile is refused ("Internal error when accessing libtpu multi-process
+lockfile"), which ``topo`` turns into a skip of every test here. Under
+``ALLOW_MULTIPLE_LIBTPU_LOAD=1``, which the driver's tier-1 command sets and
+ROADMAP.md's own line does not, two processes described it and compiled at
+once (tried at PR 42). So this stays ONE file, which ``--dist loadfile``
+keeps on one worker: split by program it would skip in all workers but one
+wherever the variable is not set.
 """
 import re
 from functools import partial
@@ -38,11 +60,16 @@ from cylon_tpu.ops import sort as _sort
 from cylon_tpu.parallel import shuffle as _sh
 from cylon_tpu.parallel.pipeline import make_distributed_join_step
 
-#: rows of the single-kernel compiles (the issue's real width)
-ROWS = 1 << 20
-#: rows a shard of the whole-program compiles: the smallest capacity that
-#: keeps every stage of the program (sorts, scans, gathers, collectives)
-STEP_ROWS = 1 << 13
+#: rows (a shard, in a program over the mesh) of the compiles that read the
+#: program's text: the smallest capacity that keeps every stage of the whole
+#: programs (sorts, scans, gathers, collectives; at 2^10 the skewed join's
+#: build side is 32 slots and its gathers are others). Four digits at least:
+#: ``_assert_pack_rides_a_sort`` tells a row-sized scatter from a
+#: ``[WORLD]``-sized one by its digits
+ROWS = 1 << 13
+#: rows of the compiles that assert bytes or seconds, which are a cell's
+#: shard's and not a small shape's
+CELL_ROWS = 1 << 20
 WORLD = 4
 
 
@@ -184,8 +211,11 @@ def test_sorted_pack_compiles_for_tpu_without_a_row_sized_scatter(one_chip):
     text = compiled.as_text()
     assert "tpu_custom_call" not in text
     _assert_pack_rides_a_sort(text, ROWS)
-    # the scatter chain held the slots, the order and both buffers' updates
-    assert compiled.memory_analysis().temp_size_in_bytes < 4 * ROWS
+    # the scatter chain held the slots, the order and both buffers' updates,
+    # 16 bytes a row and more; the sort holds no temporary of the rows' size:
+    # under 4 bytes a row above its scratch, which does not grow with the rows
+    # (387,072 bytes at every capacity from 2^13 to 2^20)
+    assert compiled.memory_analysis().temp_size_in_bytes < (3 << 17) + 4 * ROWS
 
 
 def test_sorted_pack_compiles_inside_the_four_chip_shard_map(mesh4):
@@ -199,12 +229,12 @@ def test_sorted_pack_compiles_inside_the_four_chip_shard_map(mesh4):
         out_specs=PartitionSpec("dp"),
     ))
     compiled = step.lower(
-        _spec((WORLD * STEP_ROWS,), jnp.int64, rows),
-        _spec((WORLD * STEP_ROWS,), jnp.float64, rows),
+        _spec((WORLD * ROWS,), jnp.int64, rows),
+        _spec((WORLD * ROWS,), jnp.float64, rows),
         _spec((WORLD,), jnp.int32, rows),
         _spec((WORLD,), jnp.int32, rows),
     ).compile()
-    _assert_pack_rides_a_sort(compiled.as_text(), STEP_ROWS)
+    _assert_pack_rides_a_sort(compiled.as_text(), ROWS)
 
 
 # ----------------------------------------------------------------------
@@ -343,16 +373,10 @@ def test_packed_gather_of_a_skewed_joins_slots_fits_the_chip(
     assert temp_gib() > 7.5  # what one gather of all the rows would hold
 
 
-def test_skewed_joins_program_fits_the_chip_with_its_float64_packed(one_chip):
-    """The whole ``jit_join_spec`` at ``join-skew-w4``'s shard shapes (2^23
-    probe slots and output slots, 2^18 build slots; int64 key and float64
-    value a side): the float64 halves are lanes of the emit's two packed
-    gathers, ``s32[8388608, 6]`` and ``s32[8388608, 4]``; the halves'
-    lone ``f32[8388608]`` gathers stand only in the branch the guard takes
-    for a table that holds a value whose low half the split would lose
-    (``ops.gather._f64_low_half_may_flush``), and the program with both
-    branches fits a v5e's 15.75 GB (PERF.md section 6, PR 40)."""
-    cap_l, cap_r, cap_out = 1 << 23, 1 << 18, 1 << 23
+def _skew_join(one_chip, cap_l, cap_r, cap_out):
+    """The whole ``jit_join_spec`` of ``join-skew-w4``'s columns (int64 key
+    and float64 value a side), compiled at the given probe, build and output
+    slots."""
 
     def join(lk, lv, rk, rv, nl, nr):
         left, right = [(lk, None), (lv, None)], [(rk, None), (rv, None)]
@@ -360,7 +384,7 @@ def test_skewed_joins_program_fits_the_chip_with_its_float64_packed(one_chip):
             left[:1], right[:1], left, right, nl, nr, _j.INNER, cap_out
         )
 
-    compiled = _compile(
+    return _compile(
         join,
         _spec((cap_l,), jnp.int64, one_chip),
         _spec((cap_l,), jnp.float64, one_chip),
@@ -368,19 +392,47 @@ def test_skewed_joins_program_fits_the_chip_with_its_float64_packed(one_chip):
         _spec((cap_r,), jnp.float64, one_chip),
         _spec((), jnp.int32, one_chip), _spec((), jnp.int32, one_chip),
     )
+
+
+def _assert_float64_rides_the_packed_gathers(text, cap_out):
+    """The float64 halves are lanes of the emit's two packed gathers,
+    ``s32[cap_out, 6]`` and ``s32[cap_out, 4]``; the halves' lone
+    ``f32[cap_out]`` gathers stand only in the branch the guard takes for a
+    table that holds a value whose low half the split would lose
+    (``ops.gather._f64_low_half_may_flush``)."""
     # (shape, the branch of the guard's cond it was traced in: 0 packed,
     # 1 the lone gathers) of every gather
     gathers = re.findall(
         r"= (\S+?\[[0-9,]*\])\S* gather\(.*?op_name=\"[^\"]*?"
-        r"(?:cond/branch_(\d)_fun)", compiled.as_text(),
+        r"(?:cond/branch_(\d)_fun)", text,
     )
     packed = sorted(shape for shape, branch in gathers if branch == "0")
     assert packed == [f"s32[{cap_out},4]", f"s32[{cap_out},6]"], gathers
     lone = [shape for shape, branch in gathers if branch == "1"]
     assert lone.count(f"f32[{cap_out}]") == 4, gathers
     assert len(gathers) == len(
-        re.findall(r"\sgather\(", compiled.as_text())
+        re.findall(r"\sgather\(", text)
     ), "a gather outside the guard's branches"
+
+
+def test_skewed_joins_program_gathers_its_float64_packed(one_chip):
+    """Which gathers the join's program holds, and in which branch, does not
+    depend on the capacity: here at ``join-skew-w4``'s proportions (a build
+    side a thirty-second of the probe side and of the output)."""
+    compiled = _skew_join(one_chip, ROWS, ROWS >> 5, ROWS)
+    _assert_float64_rides_the_packed_gathers(compiled.as_text(), ROWS)
+
+
+@pytest.mark.slow  # 150-210 s in this sandbox; the cell itself guards it
+@pytest.mark.limit(900)
+def test_skewed_joins_program_fits_the_chip_with_its_float64_packed(one_chip):
+    """At ``join-skew-w4``'s shard shapes (2^23 probe slots and output
+    slots, 2^18 build slots) the program with both of the guard's branches
+    fits a v5e's 15.75 GB (PERF.md section 6, PR 40). On the chip the cell
+    does not compile where it does not fit."""
+    cap_out = 1 << 23
+    compiled = _skew_join(one_chip, 1 << 23, 1 << 18, cap_out)
+    _assert_float64_rides_the_packed_gathers(compiled.as_text(), cap_out)
     mem = compiled.memory_analysis()
     held = (
         mem.temp_size_in_bytes + mem.argument_size_in_bytes
@@ -398,8 +450,8 @@ def _join_step_specs(mesh, key_dtype, val_dtype):
     world = mesh.size
     rows = NamedSharding(mesh, PartitionSpec("dp"))
     cols = [
-        (_spec((world * STEP_ROWS,), key_dtype, rows), None),
-        (_spec((world * STEP_ROWS,), val_dtype, rows), None),
+        (_spec((world * ROWS,), key_dtype, rows), None),
+        (_spec((world * ROWS,), val_dtype, rows), None),
     ]
     counts = _spec((world,), jnp.int32, rows)
     return (cols, counts, cols, counts), ()
@@ -472,16 +524,17 @@ def test_semi_reduced_sides_gather_at_their_own_capacity(one_chip):
 def test_local_sort_join_compiles_for_tpu(mesh1, key_dtype, val_dtype):
     step = make_distributed_join_step(
         mesh1, "dp", (0,), (0,), _j.INNER,
-        bucket_cap=STEP_ROWS, join_cap=2 * STEP_ROWS,
+        bucket_cap=ROWS, join_cap=2 * ROWS,
     )
     compiled = step.lower(*_join_step_specs(mesh1, key_dtype, val_dtype)).compile()
     assert "tpu_custom_call" not in compiled.as_text()
 
 
 def test_distributed_join_step_compiles_for_four_chips(mesh4):
+    # a send bucket of what a shard's rows, spread evenly, send one chip
     step = make_distributed_join_step(
         mesh4, "dp", (0,), (0,), _j.INNER,
-        bucket_cap=STEP_ROWS, join_cap=2 * STEP_ROWS,
+        bucket_cap=ROWS // WORLD, join_cap=2 * ROWS,
     )
     compiled = step.lower(
         *_join_step_specs(mesh4, jnp.int32, jnp.float32)
@@ -529,7 +582,7 @@ def _custom_fusions(text):
 
 def _range_count_text(mesh4, num_bins):
     """``sort-w4``'s count: the range partition ids of an int64 key (x64
-    on), 2^20 rows a shard, then the bucket counts."""
+    on), ``ROWS`` rows a shard, then the bucket counts."""
     rows = NamedSharding(mesh4, PartitionSpec("dp"))
 
     def kern(key, counts):
@@ -649,6 +702,7 @@ def test_groupby_cell_shape_compiles_without_scatter(one_chip):
 
 
 @pytest.mark.slow  # two to three minutes a case in this sandbox
+@pytest.mark.limit(1200)  # its own bound on the compile is 900 s
 @pytest.mark.parametrize("columns", [16, 32])
 def test_groupby_of_many_float64_columns_compiles_in_bounded_time(
     one_chip, columns
@@ -667,15 +721,15 @@ def test_groupby_of_many_float64_columns_compiles_in_bounded_time(
     def groupby_sums(key, vals, n):
         return _g.groupby_aggregate(
             [(key, None)], [(v, None) for v in vals],
-            [(_g.agg_op_id("sum"), j) for j in range(columns)], n, ROWS,
+            [(_g.agg_op_id("sum"), j) for j in range(columns)], n, CELL_ROWS,
             fuse=fuse,
         )
 
     t0 = time.monotonic()
     compiled = _compile(
         groupby_sums,
-        _spec((ROWS,), jnp.int64, one_chip),
-        [_spec((ROWS,), jnp.float64, one_chip)] * columns,
+        _spec((CELL_ROWS,), jnp.int64, one_chip),
+        [_spec((CELL_ROWS,), jnp.float64, one_chip)] * columns,
         _spec((), jnp.int32, one_chip),
     )
     seconds = time.monotonic() - t0
