@@ -35,6 +35,18 @@ Four modules, layered bottom-up:
   accounting via per-Table weakref finalizers, host/disk arena and
   serving-lease watermarks, per-fingerprint footprint attribution (the
   admission re-coster's evidence), and the query-scoped leak detector.
+  The collector runs a finalizer at any allocation, on a thread that may
+  hold any lock (the metrics registry's, the finalizer's own), so THE
+  FINALIZER RULE holds for every one in the package: *a finalizer
+  appends to a deque and frees what it alone owns; it takes no lock,
+  emits no metric, opens no span.* The next explicit touch of the
+  accounts drains the deque under their lock and reports the gauges
+  outside it. The three that give bytes back all do so: a table
+  (``ResourceLedger._unregister``), a spill arena
+  (``parallel/spill.HostArena.__del__``, which also unlinks its own
+  files) and a dropped serving future's lease
+  (``serve/scheduler.ServeScheduler._dropped``);
+  ``tests/test_finalizers.py`` holds each lock in turn and drops one.
 - :mod:`.slo` — rolling-window SLO rules (p99 burn vs target, shed
   rate, leak, resource headroom) with OK/WARN/BREACH transitions into
   the flight ring; the ``/healthz`` substrate.

@@ -173,12 +173,9 @@ class ResourceLedger:
         self._bufs: Dict[int, List[int]] = {}
         # table identity -> {bytes, site, t, qid, obs_key, ref}
         self._tables: Dict[int, Dict[str, Any]] = {}
-        # finalizer hand-off: a weakref/GC finalizer can fire
-        # SYNCHRONOUSLY on whatever thread happens to be allocating —
-        # including one already holding this ledger's lock or the
-        # metrics module lock — so the finalizer itself takes NO locks:
-        # it appends to this deque (atomic) and the next ledger
-        # operation drains it under the lock
+        # finalizer hand-off (the finalizer rule, obs/__init__.py): the
+        # table finalizer appends here and the next ledger operation
+        # drains it under the lock
         self._dead: "deque" = deque()
         self.device_bytes = 0
         self.device_peak = 0
@@ -267,9 +264,8 @@ class ResourceLedger:
         self._register(table, attrib=attrib)
 
     def _unregister(self, tid: int, keys) -> None:
-        """The table finalizer. MUST stay lock-free and allocation-lean:
-        it can run mid-GC on a thread holding arbitrary locks (the
-        metrics registry's, even this ledger's own)."""
+        """The table finalizer, under the finalizer rule
+        (``obs/__init__.py``): one append, no lock, no metric."""
         self._dead.append((tid, keys))
 
     def _release_keys_locked(self, keys) -> None:

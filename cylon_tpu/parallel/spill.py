@@ -63,7 +63,7 @@ import shutil
 import tempfile
 import threading
 import time as _time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
@@ -434,35 +434,40 @@ _ARENA_LIVE_BYTES = 0
 _ARENA_PEAK_BYTES = 0
 _ARENA_DISK_BYTES = 0
 _ARENA_DISK_PEAK = 0
+#: ``(bytes, disk bytes)`` of arenas the collector took unclosed. Their
+#: ``__del__`` only appends here (the finalizer rule, ``obs/__init__.py``);
+#: the next touch of the accounts below gives the bytes back
+_ARENA_DEAD: "deque" = deque()
 
 
-def _arena_adjust(delta: int) -> None:
-    """Track total live arena bytes; the gauge's max is the process peak
+def _arena_adjust(delta: int, disk_delta: int) -> None:
+    """Track total live arena bytes and, separately, their memmap-backed
+    (tier-2) slice, so the resource ledger reports host RAM and spill
+    disk as distinct watermarks; each gauge's max is the process peak
     (the satellite's 'report peak host bytes' evidence)."""
     global _ARENA_LIVE_BYTES, _ARENA_PEAK_BYTES
-    with _arena_lock:
-        _ARENA_LIVE_BYTES += delta
-        _ARENA_PEAK_BYTES = max(_ARENA_PEAK_BYTES, _ARENA_LIVE_BYTES)
-        live = _ARENA_LIVE_BYTES
-    gauge("shuffle.spill.host_bytes", live)
-
-
-def _disk_adjust(delta: int) -> None:
-    """Track the memmap-backed (tier-2) slice of the live arena bytes
-    separately, so the resource ledger can report host RAM and spill
-    disk as distinct watermarks."""
     global _ARENA_DISK_BYTES, _ARENA_DISK_PEAK
     with _arena_lock:
-        _ARENA_DISK_BYTES += delta
+        while _ARENA_DEAD:  # popped under the lock only: no other taker
+            nbytes, disk = _ARENA_DEAD.popleft()
+            delta -= nbytes
+            disk_delta -= disk
+        _ARENA_LIVE_BYTES += delta
+        _ARENA_PEAK_BYTES = max(_ARENA_PEAK_BYTES, _ARENA_LIVE_BYTES)
+        _ARENA_DISK_BYTES += disk_delta
         _ARENA_DISK_PEAK = max(_ARENA_DISK_PEAK, _ARENA_DISK_BYTES)
-        disk = _ARENA_DISK_BYTES
+        live, disk = _ARENA_LIVE_BYTES, _ARENA_DISK_BYTES
+    gauge("shuffle.spill.host_bytes", live)
     gauge("shuffle.spill.disk_bytes", disk)
 
 
 def arena_bytes() -> tuple:
     """(live, peak, disk_live, disk_peak) total arena bytes — the
     resource ledger's host/disk axis (obs/resource.py wraps these beside
-    the ``shuffle.spill.*`` gauges)."""
+    the ``shuffle.spill.*`` gauges). A collected arena's bytes leave the
+    live figures, and the gauges, here at the latest."""
+    if _ARENA_DEAD:
+        _arena_adjust(0, 0)
     with _arena_lock:
         return (
             _ARENA_LIVE_BYTES, _ARENA_PEAK_BYTES,
@@ -480,7 +485,14 @@ class HostArena:
     spill dir when ``backing=TIER_DISK`` or when total live arena bytes
     exceed the host spill budget (automatic tier-1 -> tier-2 promotion).
     Object-dtype columns (decoded dictionary values) always stay in RAM
-    — only fixed-width columns can spill to disk."""
+    — only fixed-width columns can spill to disk.
+
+    :meth:`close` is how an operator gives the bytes back, with the
+    gauges reported at once. An arena the collector takes unclosed
+    follows the finalizer rule (``obs/__init__.py``): ``__del__`` takes
+    no lock, reports no gauge and touches no trace; it unlinks its files
+    and its directory and leaves its byte counts on ``_ARENA_DEAD`` for
+    the next touch of the accounts."""
 
     def __init__(
         self,
@@ -525,7 +537,7 @@ class HostArena:
         if self._no_disk:
             want_disk = False  # degraded arena: disk is pinned off
             hb = host_spill_budget()
-            if hb is not None and _ARENA_LIVE_BYTES >= hb:
+            if hb is not None and arena_bytes()[0] >= hb:
                 # the degradation escape is closed (this arena already
                 # fled a failing volume) AND the host budget is spent:
                 # growing regardless would trade a typed query failure
@@ -544,7 +556,7 @@ class HostArena:
             want_disk = self.backing == TIER_DISK
             if not want_disk:
                 hb = host_spill_budget()
-                if hb is not None and _ARENA_LIVE_BYTES >= hb:
+                if hb is not None and arena_bytes()[0] >= hb:
                     want_disk = True
                     bump("shuffle.spill.tier2_promotions")
         if want_disk and dtype != np.dtype(object):
@@ -582,8 +594,7 @@ class HostArena:
                 total += v.nbytes
                 if isinstance(v, np.memmap):
                     disk += v.nbytes
-        _arena_adjust(total - self._bytes)
-        _disk_adjust(disk - self._disk)
+        _arena_adjust(total - self._bytes, disk - self._disk)
         self._bytes = total
         self._disk = disk
 
@@ -692,24 +703,34 @@ class HostArena:
         return self._bytes
 
     def close(self) -> None:
-        _arena_adjust(-self._bytes)
-        _disk_adjust(-self._disk)
+        _arena_adjust(-self._bytes, -self._disk)
         self._bytes = 0
         self._disk = 0
-        for pair in self._bufs:
-            self._release_buf(pair[0])
-            self._release_buf(pair[1])
+        self._free()
         self._bufs = [[None, None] for _ in self.schema]
         self._cap = 0
         self.rows = 0
+
+    def _free(self) -> None:
+        """Release what only this arena owns: its memmap files and the
+        spill directory it made. No lock is behind either (``os.unlink``
+        and ``shutil.rmtree`` are plain system calls)."""
+        for pair in self._bufs:
+            self._release_buf(pair[0])
+            self._release_buf(pair[1])
         if self._owns_dir and self._dir is not None:
             shutil.rmtree(self._dir, ignore_errors=True)
             self._dir = None
             self._owns_dir = False
 
-    def __del__(self):  # pragma: no cover - best-effort cleanup
+    def __del__(self):
+        # the finalizer rule (obs/__init__.py): the collector runs this
+        # on a thread that may hold any lock, the accounts' included
         try:
-            self.close()
+            if self._bytes:  # the disk bytes are a part of them
+                _ARENA_DEAD.append((self._bytes, self._disk))
+                self._bytes = self._disk = 0
+            self._free()
         except Exception:
             pass
 

@@ -87,6 +87,7 @@ from __future__ import annotations
 import threading
 import time
 import weakref
+from collections import deque
 from typing import Callable, List, Optional
 
 from .. import engine as _engine
@@ -208,17 +209,11 @@ class ServeScheduler:
 
     def __init__(self, ctx, auto_start: bool = True):
         self._ctx = ctx
-        # RLock, NOT Lock: the dropped-future GC finalizer
-        # (weakref.finalize(fut, self._release, lease)) can fire at any
-        # allocation point in any thread — including a thread currently
-        # INSIDE one of this scheduler's critical sections (observed:
-        # Thread.__init__ inside _spawn_worker_locked triggering GC) —
-        # and a non-reentrant lock self-deadlocks there, hanging every
-        # submitter forever. Re-entrant _release_locked is safe: the
-        # release flag is idempotent, the mutations are self-contained
-        # counter decrements, and an in-flight record's lease can never
-        # be the one collected (its _Record strongly holds the future).
-        self._lock = threading.RLock()
+        self._lock = threading.Lock()
+        #: leases of futures the collector took unconsumed. The finalizer
+        #: only appends here (the finalizer rule, obs/__init__.py); the
+        #: next submit, release or stats() returns their bytes
+        self._dropped: "deque" = deque()
         self._work = threading.Condition(self._lock)
         self._space = threading.Condition(self._lock)
         self._queue: List[_Record] = []
@@ -319,6 +314,7 @@ class ServeScheduler:
                     f"CYLON_TPU_SERVE_INFLIGHT_BYTES={cap}"
                 )
             while not self._closed:
+                self._drain_dropped_locked()
                 over = self._inflight_bytes + est > cap
                 if len(self._queue) < depth and not over:
                     break
@@ -388,13 +384,20 @@ class ServeScheduler:
         # it; a future dropped unconsumed releases via GC (the finalizer
         # holds the lease, never the future, so collection can happen)
         fut._release_cb = lambda: self._release(lease)
-        weakref.finalize(fut, self._release, lease)
+        weakref.finalize(fut, self._dropped.append, lease)
         return fut
 
     # -- budget release (consumption / failure / GC) --------------------
     def _release(self, lease: _Lease) -> None:
         with self._lock:
+            self._drain_dropped_locked()
             self._release_locked(lease)
+
+    def _drain_dropped_locked(self) -> None:
+        """Release what the collector left (caller holds the lock: the
+        only taker, so the test and the pop cannot be parted)."""
+        while self._dropped:
+            self._release_locked(self._dropped.popleft())
 
     def _release_locked(self, lease: _Lease) -> None:
         if lease.released:
@@ -529,6 +532,7 @@ class ServeScheduler:
         ``inflight_bytes`` counts admitted-but-unconsumed queries —
         queued, executing, or fulfilled with the result not yet read."""
         with self._lock:
+            self._drain_dropped_locked()
             return {
                 "queue_depth": len(self._queue),
                 "inflight_bytes": self._inflight_bytes,

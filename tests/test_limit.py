@@ -1,9 +1,14 @@
 """The limit every test runs under (``tests/conftest.py``)."""
 import hashlib
 import os
+import subprocess
+import sys
+import textwrap
 import time
 
 import pytest
+
+from conftest import GRACE_S
 
 
 @pytest.fixture
@@ -46,4 +51,56 @@ def test_a_test_stuck_in_native_code_is_named_by_the_dump(request, dumped):
     with pytest.raises(pytest.fail.Exception, match="exceeded 1 s"):
         hashlib.pbkdf2_hmac("sha256", b"", b"", rounds)
     stacks = dumped()
-    assert "Timeout (0:00:02)!" in stacks and f"in {request.node.name}" in stacks
+    assert "Past its limit (1 s)!" in stacks and f"in {request.node.name}" in stacks
+
+
+STUCK_FOR_GOOD = """
+    import threading
+
+    import pytest
+
+
+    @pytest.mark.limit(2)
+    def test_stuck_where_the_alarm_is_lost():
+        lock = threading.Lock()
+        lock.acquire()
+
+        class Held:
+            def __del__(self):
+                lock.acquire()
+
+        Held()  # the alarm's exception is raised in this __del__: discarded
+        Held()  # and the alarm was armed once: nothing interrupts this one
+
+
+    def test_after_it():
+        pass
+"""
+
+
+@pytest.mark.limit(2 + GRACE_S + 90)
+def test_a_test_that_loses_its_alarm_ends_its_worker_not_the_run(tmp_path):
+    """PR 42's hang, as the driver runs the suite (xdist, ``loadfile``): the
+    stuck test's worker is ended ``GRACE_S`` after its limit, xdist fails
+    that test by name, once, and a new worker runs the rest of the file. The
+    seconds over ``limit + GRACE_S`` are three workers' start-up (22 s inside
+    a whole run on eight cores); a second hang would show as a second failure."""
+    (tmp_path / "test_stuck.py").write_text(textwrap.dedent(STUCK_FOR_GOOD))
+    tests = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [tests, os.path.dirname(tests), os.environ.get("PYTHONPATH", "")]
+    ))
+    t0 = time.monotonic()
+    child = subprocess.run(
+        [sys.executable, "-m", "pytest", "test_stuck.py", "-q", "-p", "conftest",
+         "-p", "xdist", "-n", "2", "--dist", "loadfile", "-p", "no:cacheprovider",
+         "-p", "no:randomly"],
+        cwd=tmp_path, env=env, capture_output=True, text=True,
+        timeout=2 + GRACE_S + 80,
+    )
+    took = time.monotonic() - t0
+    said = child.stdout + child.stderr
+    assert took < 2 + GRACE_S + 70, said
+    assert "crashed while running 'test_stuck.py::test_stuck_where" in said, said
+    assert "1 failed, 1 passed" in said and "node down" in said, said
+    assert f"Timeout (0:00:{2 + GRACE_S})!" in said, said
