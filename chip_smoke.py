@@ -33,7 +33,6 @@ import pandas as pd
 
 import cylon_tpu as ct
 from cylon_tpu import native
-from cylon_tpu.ops import pallas_codec
 
 #: rows a side of the wide phase, and of the cross-chip phase over all chips
 ROWS = 1 << 24
@@ -271,7 +270,6 @@ def _phase(ctx, name, rows, seed, key_dtype, val_dtype, load) -> dict:
     obs.update(
         peak_bytes_in_use=_peak_bytes(ctx),
         native_reader_loaded=native.available(),
-        codec_impl=pallas_codec.resolved_impl(),
         compile_cache_dir=jax.config.jax_compilation_cache_dir,
     )
     return obs

@@ -228,24 +228,6 @@ Q3_DISPATCH_OPS = (
     "Table._join_sum_pushdown",
 )
 
-# ---------------------------------------------------------------------------
-# shuffle-codec row-pass census (pallas codec campaign, ISSUE 20)
-# ---------------------------------------------------------------------------
-#: row passes one send-side pack costs per scanned row, per impl: the
-#: XLA chain walks each row three times (partition-id hash + bucket
-#: histogram + send-slot scatter), the hash-fused Pallas kernel once,
-#: and the pid-input kernel mode (range/task/semi packs, whose pid the
-#: kernel cannot replay) twice — one XLA pid pass plus the kernel pass
-#: (ops/pallas_codec.PACK_ROW_PASSES pins the same literals and
-#: obs/prof.PACK_WEIGHT_BY_IMPL is their cost-model twin;
-#: tools/codec_smoke.py cross-checks all three)
-CODEC_PACK_ROW_PASSES: Dict[str, int] = {"xla": 3, "pallas": 1, "pallas_pid": 2}
-
-#: receive-side compact row passes per impl: both lowerings read each
-#: received row once — the fused kernel's win is the deleted mask/
-#: argsort/gather traffic, not the pass count
-CODEC_COMPACT_ROW_PASSES: Dict[str, int] = {"xla": 1, "pallas": 1}
-
 
 @dataclass(frozen=True)
 class SyncBudget:
@@ -348,14 +330,6 @@ SYNC_SITE_BUDGETS: Dict[str, SyncBudget] = {
     "obs.prof.record_fused": SyncBudget(
         0, note="dispatch-time shape-derived work units; the window "
         "resolves later at the existing deferred count fetch",
-    ),
-    # the shuffle codec engine (pallas codec campaign): the per-round
-    # impl evidence is dispatch-wall stamps + the static row-pass census
-    # — a fused-codec round keeps the exact same sync census as the XLA
-    # codec it replaces
-    "obs.store.note_codec": SyncBudget(
-        0, note="impl tag + modeled row passes + perf_counter walls into "
-        "the exec contextvar record; pure host dict math",
     ),
     "obs.prof.finalize": SyncBudget(
         0, note="derives pending stage seconds AFTER resolve_table "

@@ -65,19 +65,10 @@ def record_kernels(enable: bool) -> None:
 
 
 def recorded_kernels():
-    return [(fn, spec) for _key, fn, spec in (_KERNEL_RECORD or [])]
-
-
-def recorded_kernel_entries():
-    """Recorded dispatches WITH their cache keys: (key, fn, spec) triples.
-    The key is the logical dispatch identity (None for dispatches that
-    bypass get_kernel), which lets stage-level analyzers classify each
-    recorded program — tools/codec_smoke.py buckets pack vs compact
-    traffic by key prefix this way."""
     return list(_KERNEL_RECORD or [])
 
 
-def record_dispatch(fn, *args, key=None) -> None:
+def record_dispatch(fn, *args) -> None:
     """Record a kernel dispatch for the roofline analyzer — the ONE copy of
     the recording discipline, used both by get_kernel's wrapper and by
     dispatches that bypass get_kernel (the fused-join step is cached
@@ -94,7 +85,7 @@ def record_dispatch(fn, *args, key=None) -> None:
     spec = _stages.arg_spec(args)
     # lint: guarded=gil -- list.append is GIL-atomic and the recorder is a
     # single-threaded bench/analysis harness, never enabled while serving
-    _KERNEL_RECORD.append((key, fn, spec))
+    _KERNEL_RECORD.append((fn, spec))
 
 
 # platform of the devices the kernel being traced is for (None outside
@@ -261,7 +252,7 @@ def get_kernel(
     def noting(*args, _fn=fn, _key=key):
         if missed:
             _stages.register_dispatch(ctx, _key, _fn, args)
-        record_dispatch(_fn, *args, key=_key)
+        record_dispatch(_fn, *args)
         return _fn(*args)
 
     return noting

@@ -80,9 +80,10 @@ from . import metrics as _metrics
 #: the straggler ratios and critical-path SHARES are weight-independent
 #: within a stage; the weights only arbitrate BETWEEN stages):
 #:
-#: - ``pack``:       3.0 per locally scanned row per round (partition-id
-#:                   hash + bucket counts + send-slot scatter are three
-#:                   row passes). The 3x also keeps the pack-vs-
+#: - ``pack``:       3.0 per locally scanned row per round (the
+#:                   partition-id hash, the bucket counts and the sort
+#:                   by destination every lane rides; a modelled weight,
+#:                   not a measured one). The 3x also keeps the pack-vs-
 #:                   collective verdict stable on uniform shapes: the
 #:                   collective's pow2 bucket rounding can inflate its
 #:                   slots up to 2x the live rows, and a weight of 2
@@ -104,22 +105,6 @@ STAGE_WEIGHTS: Dict[str, float] = {
     "compact": 1.0,
     "relay": 4.0,
 }
-
-#: impl-aware pack/compact calibration (ISSUE 20): the fused Pallas pack
-#: (ops/pallas_codec kernel 1) folds the hash + histogram + slot chain
-#: into ONE row pass, so pricing it at the XLA path's 3.0 would
-#: misattribute 3x pack time — and misread stragglers — the moment the
-#: kernel engages. Both compact lowerings read each received row once
-#: (the pallas win there is deleted gather/sort traffic, not pass
-#: count). Keyed by the per-table engaged impl the dispatch loop records
-#: (``parts`` 6th element); these constants are the cost-model twin of
-#: ops/pallas_codec.PACK_ROW_PASSES — analysis/contracts.py pins both.
-PACK_WEIGHT_BY_IMPL: Dict[str, float] = {
-    "xla": 3.0,
-    "pallas": 1.0,  # hash-fused: one kernel pass replaces all three
-    "pallas_pid": 2.0,  # pid-input mode: XLA pid pass + kernel pass
-}
-COMPACT_WEIGHT_BY_IMPL: Dict[str, float] = {"xla": 1.0, "pallas": 1.0}
 
 #: render/lay-out order of the stage tracks (pipeline order). A two-hop
 #: topology shuffle (parallel/topo.py) splits the single ``collective``
@@ -227,27 +212,23 @@ class StageProfile:
 
 
 def shuffle_units(
-    parts: Iterable[Tuple[Any, int, int, Optional[np.ndarray]]],
+    parts: Iterable[
+        Tuple[Any, int, int, Optional[np.ndarray], Optional[tuple]]
+    ],
     world: int,
 ) -> Dict[str, np.ndarray]:
     """Per-shard weighted work units of one ``_shuffle_many`` call from
     its host-known plan: ``parts`` is one ``(send_counts [src, dst],
-    n_rounds, bucket_cap, relay-or-None, topo_plan-or-None,
-    codec_impls-or-absent)`` tuple per shuffled table (``topo_plan`` =
-    the two-hop ``(outer, inner, cap_o, n_header)`` when the 2-D
-    topology decomposed the exchange; ``codec_impls`` = the engaged
-    ``(pack_impl, compact_impl)`` pair selecting the impl-aware stage
-    weights — len-5 tuples from older callers price the XLA path). Pure
+    n_rounds, bucket_cap, relay-or-None, topo_plan-or-None)`` tuple per
+    shuffled table (``topo_plan`` = the two-hop ``(outer, inner, cap_o,
+    n_header)`` when the 2-D topology decomposed the exchange). Pure
     numpy over counts the phase-0 fetch already returned."""
     units = {s: np.zeros(world, np.float64) for s in STAGE_ORDER}
-    for part in parts:
-        send_counts, n_rounds, bucket_cap, relay, topo_plan = part[:5]
-        pk_impl, cp_impl = part[5] if len(part) > 5 else ("xla", "xla")
+    for send_counts, n_rounds, bucket_cap, relay, topo_plan in parts:
         m = np.asarray(send_counts, np.float64).reshape(-1, world)
         k = max(int(n_rounds), 1)
-        # pack scans the local table once per round (3 row passes under
-        # the XLA chain, 1 under the fused pallas kernel)
-        units["pack"] += PACK_WEIGHT_BY_IMPL[pk_impl] * k * m.sum(axis=1)
+        # pack scans the local table once per round
+        units["pack"] += STAGE_WEIGHTS["pack"] * k * m.sum(axis=1)
         # the collective ships K x world x cap padded slots per shard —
         # uniform by construction (the padding IS the skew cost). A
         # two-hop plan splits the clock per axis: the inner grouped
@@ -269,7 +250,7 @@ def shuffle_units(
                 STAGE_WEIGHTS["collective"] * k * world * int(bucket_cap)
             )
         # compact front-packs what each shard received
-        units["compact"] += COMPACT_WEIGHT_BY_IMPL[cp_impl] * m.sum(axis=0)
+        units["compact"] += STAGE_WEIGHTS["compact"] * m.sum(axis=0)
         if relay is not None:
             r = np.asarray(relay, np.float64).reshape(-1, world)
             units["relay"] += STAGE_WEIGHTS["relay"] * r.sum(axis=0)

@@ -350,18 +350,6 @@ def _absorb_record(profiles: Dict, hists: Dict, rec: Dict, seq: int) -> int:
             agg[0] += 1
             agg[1] = round(agg[1] + float(ms), 3)
             agg[2] = max(agg[2], float(ratio))
-        # shuffle-codec evidence (note_codec): per-impl
-        # [n, ms_sum, row_passes_sum, alt_row_passes_sum]
-        # pack+compact dispatch clocks the codec_impl re-coster judges
-        # xla-vs-pallas on
-        for impl, (n_c, ms, passes, alt) in (rec.get("codec") or {}).items():
-            ev = p.setdefault("codec_ev", {}).setdefault(
-                impl, [0, 0.0, 0, 0]
-            )
-            ev[0] += int(n_c)
-            ev[1] = round(ev[1] + float(ms), 3)
-            ev[2] += int(passes)
-            ev[3] += int(alt)
         # footprint: device bytes the resource ledger attributed to this
         # execution (a batched exec divides by its query count, so the
         # distribution stays per-query)
@@ -909,27 +897,6 @@ def note_stages(stages: Dict[str, tuple]) -> None:
         e[1] = max(e[1], round(float(ratio), 3))
         worst = max(worst, float(ratio))
     rec["strag"] = round(worst, 3)
-
-
-def note_codec(
-    impl: str, sec: float, passes: int, alt_passes: int
-) -> None:
-    """Fold one shuffle round's codec-impl evidence into the active exec
-    record: pack+compact dispatch-wall seconds under the RESOLVED impl
-    plus both impls' modeled row-pass counts for this shape
-    (ops/pallas_codec.pack_row_passes/compact_row_passes — ``alt_passes``
-    is what the OTHER impl would have paid, so one-sided profiles can
-    still walk back through the per-pass cost model). The ``codec_impl``
-    re-coster reads the per-impl aggregate
-    (plan/feedback._codec_impl_proposal). Contextvar + dict math only."""
-    rec = _EXEC.get()
-    if rec is None:
-        return
-    ev = rec.setdefault("codec", {}).setdefault(impl, [0, 0.0, 0, 0])
-    ev[0] += 1
-    ev[1] = round(ev[1] + float(sec) * 1e3, 3)
-    ev[2] += int(passes)
-    ev[3] += int(alt_passes)
 
 
 def note_dev_bytes(n: int) -> None:

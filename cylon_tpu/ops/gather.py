@@ -434,7 +434,8 @@ def wire_pack_cols(
     ``qscales``: [cap, n_q8] per-row f32 block scales for the plan's
     'q8' fields in field order (the caller broadcasts each row's
     destination-chunk scale; scales themselves ride the exchange header
-    rows — shuffle.quant_chunk_scales)."""
+    rows: shuffle.quant_chunk_scales_sorted, and the row-space
+    shuffle.quant_chunk_scales in the fused pipeline)."""
     from . import quant as _q
     from .stats import assemble_words, encode_enc, layout_words
 

@@ -14,13 +14,20 @@ memory is O(budget) instead of O(max-shard padding), hot buckets drain over
 ride HEADER ROWS of the packed lane buffer (:func:`pack_by_sort` /
 :func:`split_header`) — one collective per round moves the payload AND the
 counts, so a distributed join issues 2 collectives, not 4. The pack moves
-rows by a sort keyed by destination, never by a row-sized scatter
-(:func:`pack_lane_buffer` and :func:`scatter_send` serve the in-program
-pipeline and the forced Pallas codec). "Reassembly" is
-a lane-level compaction argsort (:func:`compact_received_lanes`). The
-round scheduler and double-buffered dispatch live in
-``table.py _shuffle_many``; the fused pipeline composes the same
-primitives in-program via :func:`exchange_columns_fused`.
+rows by a sort keyed by destination, never by a row-sized scatter.
+"Reassembly" is a lane-level compaction argsort
+(:func:`compact_received_lanes`). The round scheduler and double-buffered
+dispatch live in ``table.py _shuffle_many``.
+
+The scatter chain that the sorted pack replaced stays for two reasons and
+no other: :func:`build_send_slots_round`, :func:`pack_lane_buffer`,
+:func:`scatter_send` and the row-space :func:`quant_chunk_scales` are the
+bit-identity reference ``tests/test_shuffle_pack_sorted.py`` holds
+:func:`pack_by_sort` to, and they are still the pack of the paths that
+have not been converted: the relay and ring kernels of ``table.py``
+(:func:`scatter_send` over :func:`relay_send_slots`) and the fused
+in-program pipeline (``parallel/pipeline.py`` through
+:func:`exchange_columns_fused`).
 
 Runs inside ``shard_map``; every function here is per-shard code.
 """
@@ -364,15 +371,17 @@ def plan_rounds(
 
 # ----------------------------------------------------------------------
 # send-side pack / collective / receive-side split (the three phases of a
-# chunked round; the fused pipeline composes them in one program, the eager
-# engine dispatches them as separate overlapped programs)
+# chunked round; the fused pipeline composes them in one program, by the
+# scatter chain, the eager engine dispatches them as separate overlapped
+# programs and packs by sort)
 # ----------------------------------------------------------------------
 
 def scatter_send(
     data: jax.Array, dest: jax.Array, num_partitions: int, bucket_cap: int
 ) -> jax.Array:
     """Scatter one column into its padded [P * bucket_cap, *trailing] send
-    buffer (the pack phase of an un-headered exchange)."""
+    buffer: the relay and ring kernels' pack, the fused pipeline's float64
+    columns, and the reference of :func:`pack_by_sort`'s ``pts``."""
     with jax.named_scope(_stages.SHUFFLE_PACK):
         trailing = data.shape[1:]
         return jnp.zeros((num_partitions * bucket_cap, *trailing), data.dtype).at[
@@ -430,7 +439,8 @@ def pack_lane_buffer(
     header_extra: Optional[jax.Array] = None,
     n_header: int = HEADER_ROWS,
 ) -> jax.Array:
-    """Stack the int32 lanes and scatter them into the header-augmented
+    """The fused pipeline's pack and the reference of :func:`pack_by_sort`'s
+    ``head``: stack the int32 lanes and scatter them into the header-augmented
     send buffer [P * (bucket_cap + n_header), L]; the header rows of each
     destination chunk carry this shard's round send count for that
     destination (lane 0) followed by ``header_extra`` — [P, E] int32
@@ -603,7 +613,9 @@ def quant_chunk_scales(
     cols: Cols, wplan, dest: jax.Array, num_partitions: int,
     bucket_cap: int,
 ) -> jax.Array:
-    """[P, nq8] strictly-positive f32 block scales: the finite max-abs of
+    """[P, nq8] strictly-positive f32 block scales from row-space slots
+    (the fused pipeline's, and the reference of
+    :func:`quant_chunk_scales_sorted`): the finite max-abs of
     every q8 column over THIS round's rows bound for each destination
     chunk (rows outside the round window carry the dropped sentinel and
     never contribute — their magnitudes belong to their own round's or
@@ -662,51 +674,6 @@ def recv_row_scales(
     return scales_recv[src]
 
 
-def exchange_column(
-    data: jax.Array, dest: jax.Array, num_partitions: int, bucket_cap: int,
-    axis_name: str,
-) -> jax.Array:
-    """Scatter one column into the padded send buffer and all_to_all it.
-
-    ``data`` may have trailing dims (packed lane matrices ride the same
-    exchange). Output: [P * bucket_cap, *trailing]; chunk s holds the rows
-    sent by source shard s (front-packed within the chunk, garbage after its
-    count).
-    """
-    buf = scatter_send(data, dest, num_partitions, bucket_cap)
-    return exchange_buffer(buf, num_partitions, axis_name)
-
-
-def exchange_columns(
-    cols: Cols, dest: jax.Array, num_partitions: int, bucket_cap: int,
-    axis_name: str,
-) -> List[Tuple[jax.Array, Optional[jax.Array]]]:
-    """Exchange EVERY column in one packed scatter + ONE all_to_all.
-
-    Per-element overhead dominates TPU scatter cost and each collective has
-    fixed launch latency, so packing all data + validity lanes into a single
-    [cap, L] int32 matrix (ops/gather lane codec) moves the whole table with
-    one scatter and one collective instead of one pair per column. float64
-    columns (no 32-bit lane route on TPU) fall back to the per-column path.
-    """
-    plan, lanes, passthrough = pack_cols(cols)
-    out_lanes: List[jax.Array] = []
-    if lanes:
-        packed = jnp.stack(lanes, axis=1)  # [cap, L]
-        got = exchange_column(packed, dest, num_partitions, bucket_cap, axis_name)
-        out_lanes = [got[:, j] for j in range(packed.shape[1])]
-
-    out, _ = unpack_cols(
-        plan,
-        out_lanes,
-        lambda ci: exchange_column(
-            passthrough[ci], dest, num_partitions, bucket_cap, axis_name
-        ),
-        lambda lane: None if lane is None else lane.astype(jnp.bool_),
-    )
-    return out
-
-
 def exchange_columns_fused(
     cols: Cols,
     dest: jax.Array,
@@ -718,11 +685,14 @@ def exchange_columns_fused(
     bases: Optional[jax.Array] = None,
     topo=None,
 ) -> Tuple[List[Tuple[jax.Array, Optional[jax.Array]]], jax.Array]:
-    """:func:`exchange_columns` with the COUNT EXCHANGE FUSED into the
-    payload collective: the per-destination round send counts ride the
-    header row of the packed lane buffer, so one all_to_all moves the whole
-    table AND the counts (vs a dedicated count collective per round — this
-    is what takes a distributed join from 4 collectives to 2).
+    """Exchange every column in one packed scatter and ONE all_to_all (the
+    fused pipeline's round; float64 columns, which have no 32-bit lane
+    route on TPU, take a scatter and a collective each) with the COUNT
+    EXCHANGE FUSED into the payload collective: the per-destination round
+    send counts ride the header row of the packed lane buffer, so one
+    all_to_all moves the whole table AND the counts (vs a dedicated count
+    collective per round — this is what takes a distributed join from 4
+    collectives to 2).
 
     ``wire``: an optional :class:`~cylon_tpu.ops.gather.WirePlan` — the
     exchanged lanes are then the plan's bit-packed words (validity masks

@@ -125,16 +125,6 @@ class Decisions(NamedTuple):
     #: whenever a topology is declared). Policy only: both modes are
     #: row-exact, the CYLON_TPU_NO_TOPO oracle pins it.
     hop_mode: Optional[str] = None
-    #: shuffle codec impl (ops/pallas_codec.py): ``"xla"`` walks a shape
-    #: back to the XLA pack/compact lowerings when its journaled codec
-    #: dispatch clocks show the fused Pallas kernels not beating them
-    #: (a kernel must beat its XLA lowering to stay); ``"pallas"``
-    #: pins the fused tier. None = the static default (XLA; the
-    #: proposer below only ever walks back to it, so the autopilot never
-    #: selects a kernel the chip's compiler refuses). Policy only: the
-    #: codec is bit-lossless on non-quant lanes and the CYLON_TPU_NO_PALLAS_CODEC
-    #: oracle pins exact equality — only milliseconds move.
-    codec_impl: Optional[str] = None
 
 
 DECISIONS_OFF = Decisions()
@@ -279,11 +269,6 @@ def tuned_hop_mode() -> Optional[str]:
     return d.hop_mode if d is not None else None
 
 
-def tuned_codec_impl() -> Optional[str]:
-    d = _APPLIED.get()
-    return d.codec_impl if d is not None else None
-
-
 # ----------------------------------------------------------------------
 # proposers + hysteresis (called by the store as observations absorb)
 # ----------------------------------------------------------------------
@@ -301,10 +286,6 @@ def effective_decisions(p: Dict[str, Any]) -> tuple:
         sm = "explore"
     elif sm == STATIC:
         sm = None
-    ci = dec.get("codec_impl")
-    if ci == STATIC:
-        # decided: nothing to walk back, keep the static default
-        ci = None
     return (
         dec.get("shuffle_budget"),
         sm,
@@ -313,7 +294,6 @@ def effective_decisions(p: Dict[str, Any]) -> tuple:
         dec.get("footprint"),
         dec.get("skew_trigger"),
         dec.get("hop_mode"),
-        ci,
     )
 
 
@@ -341,23 +321,14 @@ def update_profile_decisions(p: Dict[str, Any], kind: str = "exec") -> None:
         else:
             pe = pend[field] = [enc, 1]
         if pe[1] >= m and margin_ok and not flipped:
-            # at most ONE re-keying flip per observation: every counted
-            # flip re-keys the plan, and the recompile pin (exactly one
+            # at most ONE field flips per observation: every flip
+            # re-keys the plan, and the recompile pin (exactly one
             # plan-cache miss per flip) must hold even when two gates'
             # hysteresis streaks mature on the same record — the
             # runner-up keeps its matured streak and flips on the next
-            # gate-relevant observation. A decision that leaves the
-            # EFFECTIVE tuple unchanged (codec_impl settling an
-            # unset incumbent to STATIC — both carry None in the
-            # fingerprint by design, the no-exploratory-recompile
-            # principle) is recorded in ``dec`` so re-judging stops, but
-            # is NOT a flip: it neither recompiles nor consumes the
-            # one-flip slot
-            before = effective_decisions(p)
+            # gate-relevant observation
             dec[field] = cand
             pend.pop(field, None)
-            if effective_decisions(p) == before:
-                continue
             flipped = True
             p["flips"] = p.get("flips", 0) + 1
             if field == "serve_bucket":
@@ -424,18 +395,6 @@ def _proposals(
         if p.get("hop_n", 0) >= m and p.get("topo"):
             cand, ok = _hop_mode_proposal(p, mg)
             out["hop_mode"] = (cand, ok)
-
-        # -- shuffle codec impl: the fused pallas pack/compact must beat
-        # their XLA lowerings, judged on the journaled per-stage codec
-        # dispatch clocks (table dispatch -> store.note_codec). Every
-        # observation also carries BOTH impls' modeled row-pass counts
-        # (ops/pallas_codec row-pass census), so a one-sided profile
-        # walks back through the per-pass cost model without an
-        # exploratory recompile --------------------------------------
-        if p.get("codec_ev"):
-            cand, ok = _codec_impl_proposal(p, mg, m)
-            if ok is not None:
-                out["codec_impl"] = (cand, ok)
 
         # -- admission footprint: lease observed bytes, not the static
         # input-size estimate. The p95 of the ledger-attributed per-query
@@ -586,60 +545,6 @@ def _hop_mode_proposal(p: Dict[str, Any], mg: float) -> Tuple[Any, bool]:
     return (None, True)
 
 
-def _codec_impl_proposal(
-    p: Dict[str, Any], mg: float, m: int
-) -> Tuple[Any, Optional[bool]]:
-    """Candidate shuffle codec impl from the per-impl dispatch-clock
-    evidence ``p["codec_ev"] = {impl: [n, ms_sum, row_passes_sum,
-    alt_row_passes_sum]}``, two-way xla|pallas.
-
-    Both impls measured: "xla" when the XLA lowerings win by the
-    margin, STATIC when the fused kernels hold (decision MADE: keep the
-    resolver's static default and stop re-judging). That default is XLA
-    too (ops/pallas_codec.py: the pack kernel does not lower for TPU),
-    so today both outcomes resolve to XLA; the proposer never pins
-    "pallas", because a decision learned on one mesh must not select it
-    on another. One impl measured: model the other through the row-pass
-    ratio the observation carried (a pallas round knows the 3-pass XLA
-    pack its shape would have paid, and vice versa). Returns
-    ``(None, None)`` when the evidence floor is not met."""
-
-    def _ev(impl):
-        ev = (p.get("codec_ev") or {}).get(impl)
-        if not ev or ev[0] < m:
-            return None
-        n, ms, passes, alt = ev
-        return ms / n, passes / max(n, 1), alt / max(n, 1)
-
-    xla = _ev("xla")
-    pls = _ev("pallas")
-    if xla is not None and pls is not None:
-        if xla[0] <= pls[0] * (1.0 - mg):
-            return ("xla", True)
-        if pls[0] <= xla[0] * (1.0 - mg):
-            return (STATIC, True)
-        return (None, True)  # within the margin: keep the static default
-    if pls is not None:
-        ms, passes, alt = pls
-        if passes <= 0 or alt <= 0:
-            return (None, True)
-        modeled_xla = ms / passes * alt
-        if ms > modeled_xla * (1.0 + mg):
-            return ("xla", True)
-        return (STATIC, True)
-    if xla is not None:
-        ms, passes, alt = xla
-        if passes <= 0 or alt <= 0:
-            # alt == passes would mean no fusable stage — nothing to
-            # decide; alt <= 0 is the no-evidence degenerate
-            return (None, True)
-        modeled_pallas = ms / passes * alt
-        if modeled_pallas > ms * (1.0 + mg):
-            return ("xla", True)
-        return (STATIC, True)
-    return (None, None)
-
-
 def _serve_bucket_proposal(
     p: Dict[str, Any], target: float, mg: float
 ) -> Tuple[Any, bool]:
@@ -719,13 +624,5 @@ def describe(base: tuple) -> list:
         lines.append(
             f"hop_mode tuned: {d.hop_mode} "
             f"(was 2hop-on-topology, n={p.get('hop_n', 0)})"
-        )
-    if d.codec_impl is not None:
-        n_codec = sum(
-            ev[0] for ev in (p.get("codec_ev") or {}).values()
-        )
-        lines.append(
-            f"codec_impl tuned: {d.codec_impl} "
-            f"(was pallas-where-supported, n={n_codec})"
         )
     return lines

@@ -53,7 +53,6 @@ def gated_fingerprint(plan: Node) -> tuple:
     the plan cache with it and the serving scheduler groups/keys batches
     with it (graft-lint L1 sees the gate reads threaded into both cache
     keys through this carrier)."""
-    from ..ops.pallas_codec import gate_state as _codec_gate
     from ..ops.quant import gate_state as _quant_gate
     from ..ops.sketch import enabled as _semi_enabled
     from ..ops.stats import enabled as _pack_enabled
@@ -76,13 +75,9 @@ def gated_fingerprint(plan: Node) -> tuple:
     # decide whether every lowered exchange is flat or two-hop — a
     # mid-process flip re-optimizes instead of aliasing a two-hop
     # executor onto a flat run (parallel/topo.py)
-    # the codec component carries the fused-shuffle-codec kill switch +
-    # forcing env (ops/pallas_codec.py); the tuned per-shape codec_impl
-    # rides the feedback component below, NOT this one — the store keys
-    # profiles by `base`, which must hold still across decision flips
     base = (
         plan.fingerprint(), _ord_enabled(), _semi_enabled(), _pack_enabled(),
-        _spill_gate(), _quant_gate(), _topo_gate(), _codec_gate(),
+        _spill_gate(), _quant_gate(), _topo_gate(),
     )
     # the feedback component: (autotune active, tuned Decisions) — every
     # telemetry-driven override (shuffle budget, semi mode, serve bucket,

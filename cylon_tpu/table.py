@@ -48,7 +48,6 @@ from .ops import setops as _s
 from .ops import gather as _g_pack
 from .ops import quant as _quant
 from .ops import sketch as _sketch
-from .ops import pallas_codec as _codec
 from .ops import sort as _sort_mod
 from .ops import stats as _st
 from .fault import errors as _fault_errors
@@ -4031,7 +4030,7 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
         quant_sig, ("topo", tuple(topo_cfg) if topo_cfg else None),
     ) + (
         ("semi", spec.probe_row, spec.use_range) if semi else ()
-    ) + _codec.impl_tag()
+    )
     has_lanes = any(
         tag is not None or has_valid for tag, _nl, has_valid in plan_sig
     )
@@ -4099,33 +4098,7 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
                     _sh.wire_header_rows(wire) if wire is not None
                     else _sh.HEADER_ROWS
                 )
-                # rows reach the send buffer by a sort keyed by destination
-                # that every lane rides, each destination's chunk a window
-                # of the sorted rows (parallel/shuffle.pack_by_sort); only
-                # a forced Pallas codec yields row-space slots and scatters
-                dest = None
-                if _codec.pack_engaged(kind, semi, has_lanes, n_header, world):
-                    # fused hash→partition→slot kernel (ops/pallas_codec):
-                    # dest/cnt come out of ONE VMEM pass over the key words;
-                    # the lane-buffer scatter below is the XLA chain's, so
-                    # `head` is bit-identical by construction. Range/task/
-                    # semi packs can't replay the pid in Mosaic — the XLA
-                    # pid lane (incl. the semi probe rewrite above) feeds
-                    # the same kernel and histogram + rank + slot still
-                    # fuse; in hash mode `pid` above is dead and DCE'd.
-                    if _codec.pack_fuses_hash(kind, semi):
-                        words, valids, hv = _codec.hash_operands(list(kcols))
-                        dest, cnt = _codec.fused_pack_dest(
-                            words, valids, hv, n, rnd, world, bc,
-                            interpret=ctx.platform == "cpu",
-                        )
-                    else:
-                        dest, cnt = _codec.fused_pack_dest(
-                            [], [], (), n, rnd, world, bc, pid=pid,
-                            interpret=ctx.platform == "cpu",
-                        )
-                else:
-                    cnt = _sh.bucket_counts(pid, world)
+                cnt = _sh.bucket_counts(pid, world)
                 hx = None
                 if wire is not None:
                     # bit-width-adaptive wire narrowing: lanes are the packed
@@ -4137,17 +4110,11 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
                     # (widened) header rows beside the counts (n_header above).
                     qrows = None
                     if _g_pack.wire_q8_cols(wire):
-                        if dest is None:
-                            # a row's chunk is its destination
-                            scales = _sh.quant_chunk_scales_sorted(
-                                cols, wire, pid, cnt, world, bc, rnd
-                            )
-                            qrows = _sh.send_row_scales(scales, pid, 1)
-                        else:
-                            scales = _sh.quant_chunk_scales(
-                                cols, wire, dest, world, bc
-                            )
-                            qrows = _sh.send_row_scales(scales, dest, bc)
+                        # a row's chunk is its destination
+                        scales = _sh.quant_chunk_scales_sorted(
+                            cols, wire, pid, cnt, world, bc, rnd
+                        )
+                        qrows = _sh.send_row_scales(scales, pid, 1)
                         hx = jax.lax.bitcast_convert_type(scales, jnp.int32)
                     lanes, passthrough = _g_pack.wire_pack_cols(
                         list(cols), wire, bases, qscales=qrows
@@ -4156,23 +4123,16 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
                 else:
                     _plan, lanes, passthrough = _g_pack.pack_cols(list(cols))
                     pt_eff = pt_order
-                if dest is None:
-                    # the fused count/payload exchange: this round's per-
-                    # destination send counts ride the lane buffer's header
-                    # row (a pure-f64 table has none: `head` is the counts)
-                    return _sh.pack_by_sort(
-                        lanes, [passthrough[ci] for ci in pt_eff], pid, cnt,
-                        world, bc, rnd, header_extra=hx, n_header=n_header,
-                    )
-                head = _sh.pack_lane_buffer(
-                    lanes, dest, _sh.round_counts(cnt, bc, rnd), world, bc,
-                    header_extra=hx, n_header=n_header,
+                # rows reach the send buffer by a sort keyed by destination
+                # that every lane rides, each destination's chunk a window
+                # of the sorted rows (parallel/shuffle.pack_by_sort). The
+                # fused count/payload exchange: this round's per-destination
+                # send counts ride the lane buffer's header row (a pure-f64
+                # table has none: `head` is the counts)
+                return _sh.pack_by_sort(
+                    lanes, [passthrough[ci] for ci in pt_eff], pid, cnt,
+                    world, bc, rnd, header_extra=hx, n_header=n_header,
                 )
-                pts = tuple(
-                    _sh.scatter_send(passthrough[ci], dest, world, bc)
-                    for ci in pt_eff
-                )
-                return head, pts
 
         return kern
 
@@ -4398,67 +4358,6 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
                     lane_rows, recv_counts = None, head
                     bc = pts[0].shape[0] // world
                     pt_cols = dict(zip(pt_order, pts))
-                nml = 0
-                if lane_rows is not None:
-                    nml = (
-                        lane_rows.shape[1]
-                        + (qsc_rows.shape[1] if qsc_rows is not None else 0)
-                        + (1 if pt_cols else 0)
-                    )
-                if _codec.compact_engaged(
-                    lane_rows is not None, False, world, bc, nml
-                ):
-                    # fused front-pack (ops/pallas_codec): ONE masked block-
-                    # copy pass replaces the liveness mask + stable argsort +
-                    # 400x-priced row gather. q8 scale rows ride the move
-                    # matrix bitcast; f64 passthrough columns (no i32 lane
-                    # route on TPU) gather by a carried row-index lane that
-                    # equals the argsort order bit-for-bit, dead rows included
-                    parts = [lane_rows]
-                    if qsc_rows is not None:
-                        parts.append(
-                            jax.lax.bitcast_convert_type(qsc_rows, jnp.int32)
-                        )
-                    if pt_cols:
-                        parts.append(
-                            jnp.arange(
-                                world * bc, dtype=jnp.int32
-                            ).reshape(-1, 1)
-                        )
-                    moved, total = _codec.fused_compact_move(
-                        jnp.concatenate(parts, axis=1), recv_counts, world, bc,
-                        interpret=ctx.platform == "cpu",
-                    )
-                    nw = lane_rows.shape[1]
-                    word_lanes = [moved[:, j] for j in range(nw)]
-                    qsc = None
-                    if qsc_rows is not None:
-                        nq8 = qsc_rows.shape[1]
-                        qsc = jax.lax.bitcast_convert_type(
-                            moved[:, nw : nw + nq8], jnp.float32
-                        )
-                        nw += nq8
-                    if pt_cols:
-                        order = moved[:, nw]
-                        sorted_pt = {ci: d[order] for ci, d in pt_cols.items()}
-                    else:
-                        sorted_pt = {}
-                    mk_valid = (
-                        lambda lane: None if lane is None
-                        else lane.astype(jnp.bool_)
-                    )
-                    if wire is not None:
-                        (bases,) = rep
-                        out = _g_pack.wire_unpack_cols(
-                            word_lanes, wire, bases,
-                            lambda ci: sorted_pt[ci], mk_valid, qscales=qsc,
-                        )
-                    else:
-                        out, _ = _g_pack.unpack_cols(
-                            list(plan_sig), word_lanes,
-                            lambda ci: sorted_pt[ci], mk_valid,
-                        )
-                    return out, _scalar(total)
                 mask, total = _sh.received_row_mask(recv_counts, world, bc)
                 if wire is not None:
                     (bases,) = rep
@@ -5084,9 +4983,10 @@ def _shuffle_many_rounds(states, rows_total) -> List["Table"]:
                     head, pts = get_kernel(
                         ctx, st["key"] + ("pack", st["wire"]),
                         st["build_pack"], name="shuffle_pack",
-                        **_codec.kernel_kwargs(),
                     )(dp, rep)
-                codec_s = _obstrace.last_dispatch_s()
+                _bump_pack_ride(
+                    st["plan_sig"], st["wire"], len(st["pt_eff"])
+                )
                 # the two-hop plan joins both dispatch keys: its cap_o /
                 # header statics are baked into the kernel bodies, so a
                 # plan (or kill-switch) flip compiles its own program
@@ -5106,68 +5006,11 @@ def _shuffle_many_rounds(states, rows_total) -> List["Table"]:
                     out, nout = get_kernel(
                         ctx,
                         ("shuffle_compact", st["plan_sig"],
-                         st["has_lanes"], st["wire"], tp_key)
-                        + _codec.impl_tag(),
-                        st["build_compact"], **_codec.kernel_kwargs(),
+                         st["has_lanes"], st["wire"], tp_key),
+                        st["build_compact"],
                     )(
                         coll_out,
                         (st["bases"],) if st["wire"] is not None else (),
-                    )
-                codec_s += _obstrace.last_dispatch_s()
-                # codec-impl evidence for the autopilot: the resolved
-                # impl's pack+compact dispatch walls (the clock readings
-                # every program call leaves with obs.trace) + BOTH impls'
-                # modeled row-pass counts for this shape, so a one-sided
-                # profile can walk back through the per-pass cost model
-                # (plan/feedback._codec_impl_proposal). Pure host
-                # arithmetic + contextvars — 0 sync sites; note_codec
-                # no-ops outside plan executions.
-                n_header = (
-                    _sh.wire_header_rows(st["wire"])
-                    if st["wire"] is not None else _sh.HEADER_ROWS
-                )
-                fuse_hash = _codec.pack_fuses_hash(
-                    st["spec"].kind, st["spec"].sketch is not None
-                )
-                pk_sup = _codec.pack_supported(
-                    st["spec"].kind, st["spec"].sketch is not None,
-                    st["has_lanes"], n_header, st["world"],
-                )
-                cp_sup = tp_key is None and _codec.compact_supported(
-                    st["has_lanes_eff"], False, st["world"],
-                    st["bucket_cap"],
-                    _codec.move_lane_count(
-                        st["plan_sig"], st["wire"], len(st["pt_eff"])
-                    ),
-                )
-
-                def _codec_units(impl):
-                    return _codec.pack_row_passes(
-                        "pallas" if impl == "pallas" and pk_sup else "xla",
-                        fuse_hash,
-                    ) + _codec.compact_row_passes(
-                        "pallas" if impl == "pallas" and cp_sup else "xla"
-                    )
-
-                cimpl = _codec.resolved_impl()
-                if not (cimpl == "pallas" and pk_sup):
-                    # the pack just dispatched was the sort-and-slice one
-                    _bump_pack_ride(
-                        st["plan_sig"], st["wire"], len(st["pt_eff"])
-                    )
-                st["codec_impls"] = (
-                    ("pallas" if fuse_hash else "pallas_pid")
-                    if cimpl == "pallas" and pk_sup else "xla",
-                    "pallas" if cimpl == "pallas" and cp_sup else "xla",
-                )
-                if pk_sup or cp_sup:
-                    _obsstore.note_codec(
-                        cimpl,
-                        codec_s,
-                        _codec_units(cimpl),
-                        _codec_units(
-                            "xla" if cimpl == "pallas" else "pallas"
-                        ),
                     )
                 if st["tier"] != _spill.TIER_HBM:
                     # tier 1/2: this round's compacted output streams into
@@ -5346,8 +5189,7 @@ def _shuffle_many_rounds(states, rows_total) -> List["Table"]:
             [
                 (st["send_counts"], st["n_rounds"], st["bucket_cap"],
                  st["sched"].relay,
-                 tuple(st["topo_plan"]) if st["topo_plan"] else None,
-                 st.get("codec_impls", ("xla", "xla")))
+                 tuple(st["topo_plan"]) if st["topo_plan"] else None)
                 for st in states
             ],
             states[0]["world"], t0, t_dev,

@@ -173,13 +173,6 @@ EXPAND_GATHER = EnvKnob(
     keyed_via="ops.join.impl_tag appended to every join-family cache key",
     note="in-kernel gather flavor of the Pallas windowed expand",
 )
-CODEC_IMPL = EnvKnob(
-    "CYLON_TPU_CODEC_IMPL", "auto", kind="impl",
-    keyed_via="ops.pallas_codec.impl_tag appended to every shuffle-family "
-    "cache key; plan fingerprints carry ops.pallas_codec.gate_state",
-    note="shuffle codec engine: 'auto' (fused Pallas pack/compact where "
-    "the structural predicates accept), 'xla', 'pallas'",
-)
 FORCE_SHARD_MAP = EnvKnob(
     "CYLON_TPU_FORCE_SHARD_MAP", "0", kind="impl",
     keyed_via="engine.get_kernel appends its wrapping flags "

@@ -31,8 +31,6 @@ def test_phase_matches_reference_on_cpu_mesh(devices, phase, world):
     assert obs["world"] == world and obs["rows_a_side"] == 4096
     assert obs["join_rows"] > 0 and obs["groups"] > 0
     assert obs["sorted_rows"] == 4096
-    # the default path on every platform: no kernel the chip refuses
-    assert obs["codec_impl"] == "xla"
     if world > 1:
         assert len(obs["join_rows_per_shard"]) == world
 

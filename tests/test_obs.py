@@ -565,14 +565,8 @@ def test_explain_analyze_crit_column(ctx8, rng):
 def test_traceview_critical_report(ctx8, rng, profiled, tmp_path, capsys):
     """traceview --critical names the bottleneck stage: a skew-side
     stage (relay/collective) on the one-hot shape, a local stage
-    (pack/compact) on the uniform shape.
-
-    The uniform leg runs under the codec kill switch: "local stages
-    dominate a uniform shuffle" is an XLA-codec stage-algebra claim
-    (3-pass pack), and the fused pallas codec exists precisely to shrink
-    those stages below the collective."""
+    (pack/compact) on the uniform shape."""
     import tools.traceview as tv
-    from cylon_tpu.ops import pallas_codec as _pc
 
     n = 8000
     out = {}
@@ -581,8 +575,7 @@ def test_traceview_critical_report(ctx8, rng, profiled, tmp_path, capsys):
         ("one-hot", np.zeros(n, np.int32)),
     ):
         obs_export.reset_ring()
-        with _pc.disabled():
-            ct.Table.from_pydict(ctx8, {"k": keys}).shuffle(["k"])
+        ct.Table.from_pydict(ctx8, {"k": keys}).shuffle(["k"])
         path = str(tmp_path / f"{name}.json")
         obs_export.write_chrome(path)
         assert tv.main([path, "--critical"]) == 0

@@ -6,7 +6,7 @@ rows) must lay the send buffers bit for bit as ``build_send_slots_round``
 + ``pack_lane_buffer`` + ``scatter_send`` do: the collective, the compact
 kernel, the two-hop plan and the host's round planning all read that
 layout. The old chain stays in the tree (the fused pipeline, the relay and
-ring kernels, the forced Pallas codec) and is the oracle here.
+ring kernels) and is the oracle here.
 """
 from functools import partial
 

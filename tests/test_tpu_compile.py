@@ -4,14 +4,8 @@ The TPU's compiler is installed beside the CPU backend and compiles for a
 topology that is described, not attached. Nothing here runs: a compile that
 passes says the chip's compiler takes the program, never that it is right
 or fast (``chip_smoke.py`` on the chip says that). These tests guard what
-the bring-up established:
-
-- every program the default path is made of lowers for v5e, on one chip and
-  on the 2x2 mesh;
-- the fused compact kernel lowers as a Mosaic kernel; the fused pack kernel
-  is refused, with the compiler's own message — which is why ``auto``
-  resolves both codec stages to XLA (ops/pallas_codec.py) and why a forced
-  kernel raises on a TPU mesh.
+the bring-up established: every program the default path is made of lowers
+for v5e, on one chip and on the 2x2 mesh.
 
 Which facts need which shape. What a test reads from the compiled text
 (which operations the program has, their operands and stages, the branch a
@@ -42,7 +36,6 @@ keeps on one worker: split by program it would skip in all workers but one
 wherever the variable is not set.
 """
 import re
-from functools import partial
 
 import numpy as np
 import pytest
@@ -54,7 +47,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceShardin
 from cylon_tpu.engine import on_mesh
 from cylon_tpu.ops import groupby as _g
 from cylon_tpu.ops import join as _j
-from cylon_tpu.ops import pallas_codec as _codec
 from cylon_tpu.ops import partition as _p
 from cylon_tpu.ops import sort as _sort
 from cylon_tpu.parallel import shuffle as _sh
@@ -125,23 +117,6 @@ def _compile(fn, *specs):
     )
     traced = on_mesh(Mesh(np.array([dev]), ("traced",)), fn)
     return jax.jit(traced).lower(*specs).compile()
-
-
-# ----------------------------------------------------------------------
-# (a) the fused compact kernel lowers as a Mosaic kernel
-# ----------------------------------------------------------------------
-
-def test_fused_compact_move_lowers_for_tpu(one_chip):
-    bc = 4096
-    fn = partial(
-        _codec.fused_compact_move, world=WORLD, bucket_cap=bc, interpret=False
-    )
-    compiled = _compile(
-        fn,
-        _spec((WORLD * bc, 3), jnp.int32, one_chip),
-        _spec((WORLD,), jnp.int32, one_chip),
-    )
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 # ----------------------------------------------------------------------
@@ -841,23 +816,3 @@ def test_combined_dense_groupby_compiles_for_four_chips(mesh4):
     assert all(f"{ROWS}" not in c.split("{")[0] for c, _op in crossing)
     assert all(stages.GROUPBY_COMBINE in op.split("/") for _c, op in crossing)
 
-
-# ----------------------------------------------------------------------
-# (h) a forced Pallas kernel that does not lower raises the compiler's
-# error for a TPU device: the call sites pass interpret=False on a TPU
-# mesh (engine.mesh_platform / the context's mesh), so forcing never
-# declines to XLA or to interpret mode there
-# ----------------------------------------------------------------------
-
-def test_forced_pallas_pack_raises_for_tpu(one_chip):
-    def pack(pid, n):
-        return _codec.fused_pack_dest(
-            [], [], (), n, 0, WORLD, ROWS // WORLD, pid=pid, interpret=False
-        )
-
-    with pytest.raises(Exception, match="block shape"):
-        _compile(
-            pack,
-            _spec((ROWS,), jnp.int32, one_chip),
-            _spec((), jnp.int32, one_chip),
-        )

@@ -819,107 +819,6 @@ def packing_round_once(seed) -> bool:
     return ok
 
 
-def _codec_off(fn):
-    """Run ``fn`` with the fused Pallas shuffle codec kill-switched
-    (CYLON_TPU_NO_PALLAS_CODEC=1) — the bit-exact differential oracle:
-    the codec is lossless by contract, quantized lanes included (both
-    impls ship the same q8 codes and scales)."""
-    from cylon_tpu.ops.pallas_codec import disabled
-
-    with disabled():
-        return fn()
-
-
-def codec_round_once(seed) -> bool:
-    """Fused shuffle-codec oracle round (ISSUE 20): random key dtype
-    mixes / bit widths / null densities, world sizes (pow2 AND the
-    non-pow2 decline via world 1..8 draws through a topo mesh), a
-    random quant tolerance (multi-header wire packs decline the pack
-    kernel, keep the fused compact) and an optionally 2-D mesh (the
-    compact kernel must decline the topo branch); distributed join /
-    groupby / sort each differential-checked against the
-    CYLON_TPU_NO_PALLAS_CODEC=1 oracle on the same inputs. Sort is
-    checked in exact emitted order — the fused pack/compact reproduce
-    the XLA chain's row order bit-for-bit, not just its row set."""
-    rng = np.random.default_rng(seed)
-    n = int(rng.integers(4, MAX_N))
-    topo_mesh = None
-    world = int(rng.choice([1, 2, 4, 8]))
-    if world >= 4 and rng.random() < 0.25:
-        topo_mesh = "2x2" if world == 4 else str(rng.choice(["4x2", "2x4"]))
-    null_p = float(rng.choice([0.0, 0.1, 0.3]))
-    nkeys = int(rng.integers(1, 4))
-    kinds = ["i8", "i16", "i32", "i64", "bool", "str", "f32", "f64"]
-    specs = [
-        (str(rng.choice(kinds)), int(rng.integers(1, 21)))
-        for _ in range(nkeys)
-    ]
-    quant_tol = str(rng.choice(["", "1e-2"]))
-    impl = str(rng.choice(["auto", "pallas"]))
-    params = dict(seed=seed, profile="codec", n=n, world=world,
-                  topo_mesh=topo_mesh, null_p=null_p, specs=specs,
-                  quant_tol=quant_tol, impl=impl)
-    ctx = topo_ctx_for(world, topo_mesh) if topo_mesh else ctx_for(world)
-    knames = [f"k{i}" for i in range(nkeys)]
-    data = {kn: _rand_key_col(rng, n, sp, null_p)
-            for kn, sp in zip(knames, specs)}
-    data["v"] = rng.normal(size=n).astype(np.float32)
-    data["p"] = rng.normal(size=n)  # f64 passthrough lane
-    df = pd.DataFrame(data)
-    rdf = pd.DataFrame({
-        **{kn: _rand_key_col(rng, max(n // 2, 1), sp, null_p)
-           for kn, sp in zip(knames, specs)},
-        "w": rng.normal(size=max(n // 2, 1)).astype(np.float32),
-    })
-    ok = True
-    saved = {k: os.environ.get(k)
-             for k in ("CYLON_TPU_CODEC_IMPL", "CYLON_TPU_QUANT_TOL")}
-    if impl == "auto":
-        os.environ.pop("CYLON_TPU_CODEC_IMPL", None)
-    else:
-        os.environ["CYLON_TPU_CODEC_IMPL"] = impl
-    if quant_tol:
-        os.environ["CYLON_TPU_QUANT_TOL"] = quant_tol
-    else:
-        os.environ.pop("CYLON_TPU_QUANT_TOL", None)
-    try:
-        t = ct.Table.from_pandas(ctx, df)
-        rt = ct.Table.from_pandas(ctx, rdf)
-
-        got = t.distributed_join(rt, on=knames, how="inner").to_pandas()
-        want = _codec_off(
-            lambda: ct.Table.from_pandas(ctx, df).distributed_join(
-                ct.Table.from_pandas(ctx, rdf), on=knames, how="inner"
-            ).to_pandas()
-        )
-        ok &= check(got, want, "codec/join", params)
-
-        got = t.distributed_groupby(knames, {"v": "sum"}).to_pandas()
-        want = _codec_off(
-            lambda: ct.Table.from_pandas(ctx, df)
-            .distributed_groupby(knames, {"v": "sum"}).to_pandas()
-        )
-        ok &= check(got, want, "codec/groupby", params)
-
-        got = t.distributed_sort(knames).to_pandas()
-        want = _codec_off(
-            lambda: ct.Table.from_pandas(ctx, df)
-            .distributed_sort(knames).to_pandas()
-        )
-        g = got.astype(str).reset_index(drop=True)
-        w = want.astype(str).reset_index(drop=True)
-        if len(g) != len(w) or not g.equals(w):
-            print(f"MISMATCH codec/sort_order params={params}", flush=True)
-            ok = False
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-    return ok
-
-
 def quant_round_once(seed) -> bool:
     """Quantized-wire oracle round (ISSUE 13): random tolerance tier
     (q8 / qb16 / qf32 / off), dtype mix (f32 / f64 / f16 payloads beside
@@ -1668,7 +1567,7 @@ def main():
                     choices=["default", "skew", "plan", "shuffle",
                              "ordering", "semi", "packing", "serve",
                              "spill", "autotune", "quant", "chaos",
-                             "stream", "topo", "codec"],
+                             "stream", "topo"],
                     default="default",
                     help="'skew': adversarial hot-key rounds (one key ~50%% "
                          "of rows, world {4,8}, undersized fused capacities); "
@@ -1709,13 +1608,7 @@ def main():
                          "(random 2x2/4x2/2x4 mesh factorization, dtype "
                          "mix, nulls, skew, K, ISSUE 17) — shuffle + "
                          "distributed join vs the CYLON_TPU_NO_TOPO "
-                         "flat-exchange oracle, exact row equality; "
-                         "'codec': fused Pallas shuffle-codec "
-                         "rounds (random dtype/width/null mixes, pow2 "
-                         "worlds, quant tolerance, optional 2-D topo "
-                         "mesh, forced impl) — join/groupby by rows, "
-                         "sort in exact emitted order, vs the "
-                         "CYLON_TPU_NO_PALLAS_CODEC=1 oracle")
+                         "flat-exchange oracle, exact row equality")
     args = ap.parse_args()
     global MAX_N
     MAX_N = args.max_n
@@ -1730,8 +1623,7 @@ def main():
           "quant": quant_round_once,
           "chaos": chaos_round_once,
           "stream": stream_round_once,
-          "topo": topo_round_once,
-          "codec": codec_round_once}.get(args.profile, round_once)
+          "topo": topo_round_once}.get(args.profile, round_once)
     t_end = time.time() + args.minutes * 60
     seed = args.seed0
     failures = 0

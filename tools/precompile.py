@@ -55,29 +55,7 @@ def main():
     ap.add_argument("--topo", type=str, default="",
                     help="OxI 2-D mesh (e.g. 4x2): warm the two-hop "
                          "shuffle kernels on a world of O*I devices")
-    ap.add_argument("--codec-impl", type=str, default="",
-                    help="comma list from {xla,pallas} or 'all': warm the "
-                         "requested ops once per shuffle-codec impl (the "
-                         "impl tag rides every shuffle-family cache key, "
-                         "so a runtime CYLON_TPU_CODEC_IMPL flip on a "
-                         "pre-baked image is compile-free)")
     args = ap.parse_args()
-
-    # a literal (not imported from ops.pallas_codec): cylon_tpu must not
-    # import before _force_cpu_mesh has declared the virtual mesh
-    _CODEC_IMPLS = ("xla", "pallas")
-    codec_impls = [None]
-    if args.codec_impl:
-        codec_impls = (
-            list(_CODEC_IMPLS) if args.codec_impl.strip() == "all"
-            else [x.strip() for x in args.codec_impl.split(",") if x.strip()]
-        )
-        bad = [x for x in codec_impls if x not in _CODEC_IMPLS]
-        if bad:
-            raise SystemExit(
-                f"--codec-impl: unknown impl(s) {bad}; choose from "
-                f"{sorted(_CODEC_IMPLS)} or 'all'"
-            )
 
     world = 1
     if args.topo:
@@ -138,42 +116,34 @@ def main():
             wall = time.perf_counter() - t0
             line = {"op": name, "cap": cap, "platform": platform,
                     "wall_s": round(wall, 2)}
-            if cimpl:
-                line["codec_impl"] = cimpl
             if err:
                 line["error"] = err
             print(json.dumps(line), flush=True)
 
-        for cimpl in codec_impls:
-            if cimpl is not None:
-                os.environ["CYLON_TPU_CODEC_IMPL"] = cimpl
-
-            if "join" in ops:
-                t("join_inner", lambda: left.join(right, on="k"))
-                t("join_left", lambda: left.join(right, on="k", how="left"))
-                t(
-                    "dist_join",
-                    lambda: left.distributed_join(right, on="k"),
-                )
-                t(
-                    "dist_join_fused",
-                    lambda: left.distributed_join(right, on="k", mode="fused"),
-                )
-            if "sort" in ops:
-                t("sort", lambda: left.sort("v"))
-                t("dist_sort", lambda: left.distributed_sort("v"))
-            if "setops" in ops:
-                lk = left.project(["k"])
-                rk = right.project(["k"])
-                t("union", lambda: lk.union(rk))
-                t("subtract", lambda: lk.subtract(rk))
-            if "groupby" in ops:
-                t(
-                    "groupby_sum",
-                    lambda: left.distributed_groupby("k", {"v": "sum"}),
-                )
-        if args.codec_impl:
-            os.environ.pop("CYLON_TPU_CODEC_IMPL", None)
+        if "join" in ops:
+            t("join_inner", lambda: left.join(right, on="k"))
+            t("join_left", lambda: left.join(right, on="k", how="left"))
+            t(
+                "dist_join",
+                lambda: left.distributed_join(right, on="k"),
+            )
+            t(
+                "dist_join_fused",
+                lambda: left.distributed_join(right, on="k", mode="fused"),
+            )
+        if "sort" in ops:
+            t("sort", lambda: left.sort("v"))
+            t("dist_sort", lambda: left.distributed_sort("v"))
+        if "setops" in ops:
+            lk = left.project(["k"])
+            rk = right.project(["k"])
+            t("union", lambda: lk.union(rk))
+            t("subtract", lambda: lk.subtract(rk))
+        if "groupby" in ops:
+            t(
+                "groupby_sum",
+                lambda: left.distributed_groupby("k", {"v": "sum"}),
+            )
         # drop per-bucket jit caches so memory stays bounded across buckets
         ctx.__dict__.get("_jit_cache", {}).clear()
         jax.clear_caches()
