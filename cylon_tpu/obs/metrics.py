@@ -391,11 +391,30 @@ STABLE_METRICS: Dict[str, Tuple[str, str]] = {
     "setop.": ("span", "union/subtract/intersect dispatch"),
     "groupby.": (
         "mixed", "groupby phases as spans (emit) + the path a call took as "
-        "counters: dense_path, factorize_path, partial_path"),
+        "counters: dense_path, factorize_path, partial_path, "
+        "raw_shuffle_path"),
     "groupby.partial_path": (
-        "counter", "group-bys on a mesh whose shards' partial states were "
-        "combined in place (Table.distributed_groupby on the dense plan: "
-        "no exchange of rows); bumped beside groupby.dense_path"),
+        "counter", "group-bys on a mesh that reduced every shard's own rows "
+        "to a partial state first: combined in place on the dense plan (no "
+        "exchange of rows; bumped beside groupby.dense_path and "
+        "groupby.partial.rows), or shipped as one partial row a group a "
+        "shard (beside groupby.precombine.*)"),
+    "groupby.raw_shuffle_path": (
+        "counter", "group-bys on a mesh that shuffled their input rows: an "
+        "op without a partial state (var / std / nunique / quantile)"),
+    "groupby.precombine.rows_in": (
+        "counter", "input rows of the group-bys that shipped partial rows "
+        "(rows= the table's host-known row count, 0 while it is deferred); "
+        "shuffle.coll_rows over it is the share that crossed the mesh"),
+    "groupby.precombine.rows_out": (
+        "counter", "partial rows their pre-combines left, over all shards "
+        "(rows= the fetched counts' sum)"),
+    "groupby.precombine.fullest": (
+        "counter", "the same of the fullest shard (rows= the fetched "
+        "counts' largest: what the capacity is counted from)"),
+    "groupby.precombine.slots": (
+        "counter", "slots a shard's partial table crossed the mesh in "
+        "(rows= round_cap of the fullest shard's count)"),
     "groupby.partial.rows": (
         "counter", "input rows those calls aggregated without an exchange "
         "(rows= the table's host-known row count, 0 while it is deferred); "

@@ -48,6 +48,13 @@ GROUPBY_SEGMENT_SUM = "groupby.segment_sum"
 GROUPBY_KEY_IDS = "groupby.key_ids"
 GROUPBY_DENSE_AGG = "groupby.dense_agg"
 GROUPBY_COMBINE = "groupby.combine"
+#: the two programs either side of a group-by's exchange of partial rows
+#: (``Table.distributed_groupby``): a shard's rows reduced to one partial
+#: row a group, and the received partial rows combined and finished. The
+#: sort-and-segment stages nest inside both, so in a trace of such a query
+#: these two are the outermost names and take the time.
+GROUPBY_PARTIAL = "groupby.partial"
+GROUPBY_MERGE = "groupby.merge"
 EXPR_EVAL = "expr.eval"
 
 VOCABULARY = (
@@ -57,7 +64,7 @@ VOCABULARY = (
     SHUFFLE_COUNT, SHUFFLE_PACK, SHUFFLE_ALL_TO_ALL, SHUFFLE_COMPACT,
     SHUFFLE_REASSEMBLE,
     SEMI_SKETCH, GROUPBY_SEGMENT_SUM, GROUPBY_KEY_IDS, GROUPBY_DENSE_AGG,
-    GROUPBY_COMBINE, EXPR_EVAL,
+    GROUPBY_COMBINE, GROUPBY_PARTIAL, GROUPBY_MERGE, EXPR_EVAL,
 )
 _VOCABULARY = frozenset(VOCABULARY)
 

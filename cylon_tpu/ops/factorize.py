@@ -34,6 +34,7 @@ def factorize_runs(
     payloads: Sequence[jax.Array],
     fuse=None,
     presorted: bool = False,
+    keep: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, list, Optional[list]]:
     """Factorization for a consumer that stays in SORTED space (the
     group-by): the rows are brought into canonical key order with
@@ -51,13 +52,19 @@ def factorize_runs(
     ``presorted``: the rows already are in that order (the caller's
     contract, or the table's ordering descriptor): no sort at all, the
     runs are read off the key columns (reference PipelineGroupBy,
-    groupby/pipeline_groupby.cpp:30-90)."""
+    groupby/pipeline_groupby.cpp:30-90).
+
+    ``keep`` ([cap] bool; not with ``presorted``): the rows that count
+    where they are not the first ``n`` (a row mask rides the sort): a row
+    it drops sorts with the padding, and ``n`` is the number it keeps."""
     idx = jnp.arange(cap, dtype=jnp.int32)
     live = idx < n
     if presorted:
         new_run, spays, slanes = rows_differ(key_cols, cap), payloads, None
     else:
-        lanes = canonical_row_lanes(key_cols, live, fuse=fuse)  # msb first
+        lanes = canonical_row_lanes(
+            key_cols, live if keep is None else keep, fuse=fuse
+        )  # msb first
         new_run, spays, slanes = sorted_runs_payload(lanes, payloads)
     # the first padding row opens a run of its own whatever it holds, so
     # the last live row closes one

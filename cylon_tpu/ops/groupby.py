@@ -87,6 +87,7 @@ def groupby_aggregate(
     presorted: bool = False,
     ddof: int = 1,
     quantile: float = 0.5,
+    mask: Optional[jax.Array] = None,
 ):
     """The sort-and-segment group-by of one shard: ``ops`` are
     ``(aggregation op, position in val_cols)`` pairs.
@@ -109,9 +110,17 @@ def groupby_aggregate(
 
     ``fuse``: stats-driven sort-word fusion plan for the factorize lanes
     (ops/sort.FusePlan; Table.groupby derives it from the key columns'
-    range stats): the same runs in fewer chained sort passes."""
+    range stats): the same runs in fewer chained sort passes.
+
+    ``mask`` ([cap] bool; not with ``presorted``): a row whose mask is
+    false counts in no aggregate and founds no group, as if filtered
+    first. It rides the factorize sort as padding: no compaction in front."""
     key_cols, val_cols = list(key_cols), list(val_cols)
     cap = key_cols[0][0].shape[0]
+    keep = None  # the rows that count, where they are not the first n
+    if mask is not None:
+        keep = (jnp.arange(cap, dtype=jnp.int32) < n) & mask
+        n = jnp.sum(keep, dtype=jnp.int32)
     # a key the fused sort words hold bit for bit is read back out of them
     # at the groups' slots; any other rides both sorts beside the values
     in_words = (
@@ -122,7 +131,7 @@ def groupby_aggregate(
     with jax.named_scope(_stages.GROUPBY_KEY_IDS):
         start, run_end, flat, words = factorize_runs(
             key_cols, n, cap, flatten_cols(riders + val_cols),
-            fuse=fuse, presorted=presorted,
+            fuse=fuse, presorted=presorted, keep=keep,
         )
     words = list(words) if any(in_words) else []
     cols = unflatten_cols(riders + val_cols, flat)
@@ -132,9 +141,10 @@ def groupby_aggregate(
             cols[len(riders):], ops, cap_out, ddof, quantile,
         )
         gmask = jnp.arange(cap_out, dtype=jnp.int32) < num_groups
+        # the decode wants the rows the words were made of, unsorted
         decoded = fused_key_decode(
             fuse, carried[: len(words)], key_cols,
-            jnp.arange(cap, dtype=jnp.int32) < n,
+            jnp.arange(cap, dtype=jnp.int32) < n if keep is None else keep,
         ) if words else []
         rode = iter(unflatten_cols(riders, carried[len(words):]))
         keys = [decoded[i] if w else next(rode) for i, w in enumerate(in_words)]
@@ -275,10 +285,75 @@ def _aggregate_runs(
     return packed[: len(carry)], out, num_groups
 
 
-# ops that can be pre-combined locally before the shuffle (reference
-# ASSOCIATIVE_OPS = {SUM, MIN, MAX}, groupby/groupby.cpp:24-31; COUNT combines
-# as SUM of partial counts)
-ASSOCIATIVE = frozenset({SUM, MIN, MAX})
+# ----------------------------------------------------------------------
+# the partial state of the sort-and-segment path: what a shard reduces its
+# own rows to before the rows of a group meet on one shard
+# ----------------------------------------------------------------------
+#: ops whose state over disjoint sets of rows combines group by group: a
+#: sum as its sum, a count as its count, a minimum and a maximum as
+#: themselves, a mean as its sum and its count (the reference pre-combines
+#: {SUM, MIN, MAX} alone, groupby/groupby.cpp:24-31). var / std / nunique /
+#: quantile have no such state here and see every row.
+PARTIAL_OPS = frozenset({SUM, COUNT, MIN, MAX, MEAN})
+
+#: how a state combines: the op that reduces the shards' partial rows
+_STATE_COMBINE = {SUM: SUM, COUNT: SUM, MIN: MIN, MAX: MAX}
+
+
+def partial_states(ops: Sequence[Tuple[int, int]], wide: Sequence[bool]):
+    """The partial state of ``ops`` (``(op, value column)`` pairs, every op
+    of :data:`PARTIAL_OPS`): ``(states, reads)``. ``states`` are the
+    distinct ``(op, value column, widen)`` a shard reduces its rows to, op
+    one of SUM, COUNT, MIN, MAX, each computed once however many ops read
+    it; ``widen`` marks a mean's sum over a column narrower than
+    ``wide_float`` (``wide[column]`` false), which adds in ``wide_float``
+    as the local mean does. ``reads[i]`` are the positions in ``states``
+    that ``ops[i]`` is finished from: (sum, count) for a mean, else one."""
+    states: list = []
+
+    def state(op, j, widen=False):
+        if (op, j, widen) not in states:
+            states.append((op, j, widen))
+        return states.index((op, j, widen))
+
+    reads = []
+    for op, j in ops:
+        if op == MEAN:
+            reads.append((state(SUM, j, not wide[j]), state(COUNT, j)))
+        elif op in _STATE_COMBINE:
+            reads.append((state(op, j),))
+        else:
+            raise ValueError(f"aggregation op {op} has no partial state")
+    return tuple(states), tuple(reads)
+
+
+def widened(col: KeyCol) -> KeyCol:
+    """A value column as the ``wide_float`` lane a mean adds it in."""
+    data, valid = col
+    return data.astype(wide_float()), valid
+
+
+def combine_ops(states) -> list:
+    """``(op, position)`` pairs that reduce the state columns of
+    :func:`partial_states`, in their order, over a group's partial rows."""
+    return [(_STATE_COMBINE[op], i) for i, (op, _j, _w) in enumerate(states)]
+
+
+def finish_states(ops: Sequence[Tuple[int, int]], reads, combined):
+    """The aggregates of ``ops`` from a group's ``combined`` state columns
+    (``(out, valid)`` a state, as :func:`groupby_aggregate` reduced them),
+    typed and null as :func:`_aggregate_runs` gives them: a mean is its
+    sum over its count, null where the count is 0; any other op is its
+    state."""
+    out = []
+    for (op, _j), read in zip(ops, reads):
+        if op == MEAN:
+            (total, _tv), (cnt, _cv) = combined[read[0]], combined[read[1]]
+            mean = total.astype(wide_float()) / jnp.maximum(cnt, 1)
+            out.append((mean, cnt > 0))
+        else:
+            out.append(combined[read[0]])
+    return out
 
 
 # ----------------------------------------------------------------------

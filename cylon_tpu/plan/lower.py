@@ -306,7 +306,17 @@ def _lower_one(node: Node, ex, tables):
             kind="range", key_names=[node.keys[0]], asc0=node.asc0
         )
     if isinstance(node, GroupBy):
-        t = ex(node.children[0])
+        # a hash Shuffle on exactly the node's keys belongs to the
+        # group-by's own recipe (as a join owns its inputs'): the table
+        # ships a partial row a group where the ops have a partial state
+        # and the rows themselves where they have none, and either way
+        # every shard ends up owning the groups whose keys hash to it
+        child = node.children[0]
+        exchange = (
+            isinstance(child, Shuffle) and child.kind == "hash"
+            and child.keys == node.keys and not node.sorted_input
+        )
+        t = ex(child.children[0] if exchange else child)
         spec: Dict[str, list] = {}
         for c, op in node.aggs:
             spec.setdefault(c, []).append(op)
@@ -330,6 +340,8 @@ def _lower_one(node: Node, ex, tables):
                 # the node claimed global key order (a Sort above it may be
                 # gone): hold the table to it if it took the other path
                 res = res.distributed_sort(keys)
+        elif exchange and t.world_size > 1:
+            res = t._groupby_exchange(keys, spec, mask)
         else:
             res = t.groupby(keys, spec, _mask=mask)
         # multiple ops per column group in dict order; restore plan order

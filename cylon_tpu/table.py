@@ -25,6 +25,7 @@ the end — the dispatch-async discipline graft-lint's L3 sync budgets pin
 """
 from __future__ import annotations
 
+import contextlib
 import numbers
 import threading
 from collections import OrderedDict
@@ -127,6 +128,69 @@ def _agg_specs(agg) -> List[Tuple[str, int, str]]:
             oid = _g.agg_op_id(o)
             specs.append((col, oid, o if isinstance(o, str) else _agg_name(oid)))
     return specs
+
+
+class _PartialAgg(NamedTuple):
+    """A group-by's aggregates as partial state (``ops.groupby.
+    partial_states``): what :meth:`Table.distributed_groupby` has each
+    shard reduce its rows to, ship, and finish the aggregates from."""
+
+    specs: tuple   # the caller's (column, op id, op name)
+    ops: tuple     # the same as (op id, position in the distinct columns)
+    states: tuple  # (op id, position, widen) a state column
+    reads: tuple   # the state columns each of ``ops`` is finished from
+
+    @property
+    def columns(self) -> list:
+        """The distinct value columns, in the caller's order."""
+        return list(dict.fromkeys(c for c, _o, _n in self.specs))
+
+    @classmethod
+    def of(cls, table: "Table", specs) -> "_PartialAgg":
+        names = cls(tuple(specs), (), (), ()).columns
+        ops = tuple((oid, names.index(c)) for c, oid, _n in specs)
+        wide = [
+            table._columns[c].data.dtype == _sort_mod.wide_float()
+            for c in names
+        ]
+        states, reads = _g.partial_states(ops, wide)
+        return cls(tuple(specs), ops, states, reads)
+
+    def state_specs(self) -> list:
+        """The pre-combine's ``(column, op id, name)``: a state column is
+        named ``<column>_<op>``, a widened sum ``<column>_fsum``."""
+        names = self.columns
+        return [
+            (names[j], op, ("f" if widen else "") + _agg_name(op))
+            for op, j, widen in self.states
+        ]
+
+    def merge_specs(self) -> list:
+        """The combine's ``(state column, op id, name)`` over the partial
+        table that :meth:`state_specs` names."""
+        columns = [f"{c}_{n}" for c, _o, n in self.state_specs()]
+        return [
+            (columns[i], op, _agg_name(op))
+            for op, i in _g.combine_ops(self.states)
+        ]
+
+    def pre_combine(self, table, key_names, mask=None, _sorted=False):
+        """``table``'s own rows, shard by shard, as one partial row a group
+        (stage ``groupby.partial``)."""
+        return table._groupby_segment(
+            key_names, self.state_specs(), _sorted=_sorted,
+            stage=_stages.GROUPBY_PARTIAL,
+            widen=[widen for _op, _j, widen in self.states], mask=mask,
+        )
+
+    def combine(self, partials, key_names):
+        """The partial rows each shard holds (after their exchange: every
+        row of its groups) as the caller's aggregates (stage
+        ``groupby.merge``)."""
+        return partials._groupby_segment(
+            key_names, self.merge_specs(), stage=_stages.GROUPBY_MERGE,
+            merge=self,
+        )
 
 
 def _scalar(x) -> jax.Array:
@@ -2915,8 +2979,9 @@ class Table:
         ``_mask`` (the planner's ``GroupBy(Filter)`` rewrite): a row mask
         as :meth:`filter` takes it; rows whose mask is false or null
         count in no aggregate and found no group, as if filtered first.
-        On the dense path the mask rides the reductions; otherwise the
-        rows are filtered first. ``_dense=False`` (tests and
+        The mask rides the dense path's reductions, and the other path's
+        factorize sort as a padding class; only rows already in key order
+        (no sort to ride) are filtered first. ``_dense=False`` (tests and
         measurements) keeps a call off the dense path."""
         key_names = self._resolve_cols(by)
         specs = _agg_specs(agg)
@@ -2930,10 +2995,36 @@ class Table:
             return self._groupby_dense(key_names, specs, dense, _mask)
         if not key_names:
             raise ValueError(_KEYLESS_OPS)
-        if _mask is not None:
-            return self.filter(_mask).groupby(
-                by, agg, ddof, quantile, _sorted, _dense=False
+        return self._groupby_segment(
+            key_names, specs, ddof, quantile, _sorted, mask=_mask
+        )
+
+    def _groupby_segment(
+        self, key_names, specs, ddof: int = 1, quantile: float = 0.5,
+        _sorted: bool = False, stage: Optional[str] = None,
+        widen: Sequence[bool] = (), merge: Optional["_PartialAgg"] = None,
+        mask=None,
+    ) -> "Table":
+        """The sort-and-segment group-by of every shard's own rows: one
+        program, ``jit_groupby``; ``specs`` are ``(column, op id, name)``.
+
+        The two halves of :meth:`distributed_groupby`'s exchange of
+        partials are this program under a ``stage`` name of their own:
+        the pre-combine (``specs`` the partial states, ``widen[i]`` a
+        mean's sum that adds its column in ``wide_float``) and the
+        combine (``merge``: ``specs`` reduce the received state columns,
+        and the caller's aggregates are finished from them in the same
+        program and name the output).
+
+        ``mask``: a row mask as :meth:`filter` takes it; a row it drops
+        sorts with the padding and founds no group. Rows already in key
+        order run no sort for it to ride and are filtered first."""
+        provably_sorted = _ord.covers_prefix(self._ordering, key_names)
+        if mask is not None and (_sorted or provably_sorted):
+            return self.filter(mask)._groupby_segment(
+                key_names, specs, ddof, quantile, _sorted, stage, widen, merge
             )
+        m = None if mask is None else self._as_mask(mask)
         bump("groupby.factorize_path")
         if not _sorted and provably_sorted:
             # canonical prefix order: run adjacency AND emitted group order
@@ -2969,34 +3060,50 @@ class Table:
         # sync (the count fetch defers to result materialization); selective
         # results compact there.
         cap_out = self.shard_cap
+        # a value column rides the factorize sort once, whatever ops read
+        # it (and once more widened, for a partial mean's sum)
+        # lint: keyed=vals -- val_idx paired with ``widen``: val_idx is a
+        # key component, and widen is all False unless a stage is given,
+        # whose key carries vals whole
+        vals = tuple(zip(val_idx, tuple(widen) or (False,) * len(val_idx)))
+        ride = tuple(dict.fromkeys(vals))
+        finish = None if merge is None else (merge.ops, merge.reads)
         key = (
             "groupby", key_idx, val_idx, ops_t, ddof, quantile, len(flat),
             _sorted, cap_out, gb_fuse,
+        ) + ((stage, vals, finish) if stage else ()) + (
+            ("mask",) if m is not None else ()
         )
-        # a value column rides the factorize sort once, whatever ops read it
-        ride_idx = tuple(dict.fromkeys(val_idx))
+        scope = (
+            (lambda: jax.named_scope(stage)) if stage
+            else contextlib.nullcontext
+        )
 
         def build_emit():
             def kern(dp, rep):
-                (cols, counts) = dp
-                keys, aggs, ng = _g.groupby_aggregate(
-                    [cols[i] for i in key_idx],
-                    [cols[i] for i in ride_idx],
-                    [(oid, ride_idx.index(vi))
-                     for vi, oid in zip(val_idx, ops_t)],
-                    counts[0], cap_out, fuse=gb_fuse, presorted=_sorted,
-                    ddof=ddof, quantile=quantile,
-                )
+                (m, cols, counts) = dp
+                with scope():
+                    keys, aggs, ng = _g.groupby_aggregate(
+                        [cols[i] for i in key_idx],
+                        [_g.widened(cols[i]) if w else cols[i]
+                         for i, w in ride],
+                        [(oid, ride.index(v)) for v, oid in zip(vals, ops_t)],
+                        counts[0], cap_out, fuse=gb_fuse, presorted=_sorted,
+                        ddof=ddof, quantile=quantile, mask=m,
+                    )
+                    if finish is not None:
+                        aggs = _g.finish_states(*finish, aggs)
                 return keys + aggs, _scalar(ng)
 
             return kern
 
         with span("groupby.emit", rows=self._rows_hint()):
             out, nout = get_kernel(self.ctx, key + ("emit",), build_emit)(
-                (flat, self.counts_dev), ()
+                (m, flat, self.counts_dev), ()
             )
         return self._groupby_result(
-            key_names, specs, out, nout, cap_out, out_canonical
+            key_names, specs if merge is None else merge.specs, out, nout,
+            cap_out, out_canonical,
         )
 
     def _groupby_result(
@@ -3167,7 +3274,7 @@ class Table:
         **kw,
     ) -> "Table":
         """Group-by over the whole mesh (reference DistributedHashGroupBy,
-        groupby/groupby.cpp:33-91), one of two ways; the input decides.
+        groupby/groupby.cpp:33-91), one of three ways; the input decides.
 
         **Combined in place.** Where the dense plan applies
         (:meth:`_dense_groupby_plan`: every op one of sum, count, min,
@@ -3183,56 +3290,95 @@ class Table:
         mesh: what moves is slots x aggregates numbers. The groups lie on
         the FIRST shard in key order and the other shards hold no row, so
         ``row_count``, ``to_pydict`` and a following sort see one copy
-        (the ordering descriptor's scope is "global"). The reference
-        pre-combines locally for {SUM, MIN, MAX} (:24-31,57-67) and still
-        shuffles the partial rows; its scalar aggregates
-        (compute/aggregates.cpp:26-137) are this: local reduce, AllReduce.
+        (the ordering descriptor's scope is "global"). The reference's
+        scalar aggregates (compute/aggregates.cpp:26-137) are this: local
+        reduce, AllReduce.
 
-        **Shuffled.** Anything else (a key of many values or no known
-        range, a float key, var / std / nunique / quantile): a hash
-        shuffle on the keys, then the local group-by a shard, each shard
-        emitting its own groups; in front of the shuffle a local
-        pre-combine when every op is associative {SUM, MIN, MAX} and each
-        column has one op.
+        **Partial, shuffle, combine.** The same ops (sum, count, min, max,
+        mean; any number of ops a column) over keys the dense plan
+        declines (many values, no known range, a float key)
+        (:meth:`_groupby_exchange`): every shard reduces its own rows to
+        one partial row a group by the sort-and-segment group-by (stage
+        ``groupby.partial``; a sum as its sum, a count as its count, a
+        minimum and a maximum as themselves, a mean as its sum and its
+        count, each state computed once however many ops read it); the
+        partial rows' count is fetched and they are cut to ``round_cap``
+        of the fullest shard's (they lie first in their shard, in key
+        order: a slice, no compaction sort); a hash shuffle on the keys
+        carries them at that capacity; each shard combines the partial
+        rows it received (sums of sums and of counts, minima of minima,
+        maxima of maxima) and finishes the aggregates (mean = sum over
+        count) in one program (stage ``groupby.merge``). Every shard emits
+        the groups whose keys hash to it, each group once over the mesh,
+        named and typed as :meth:`groupby` names and types them. A group's
+        partial rows are added in source-shard order (the pack's sort by
+        destination and the factorize sort are both stable), so a query
+        gives the same bits run to run. Where every shard holds rows of
+        every group, world x groups rows cross the mesh and not the input.
+        The reference pre-combines for {SUM, MIN, MAX} alone, one op a
+        column (:24-31, 57-67).
+
+        **Raw rows.** var / std / nunique / quantile / median have no
+        partial state here: the input rows are hash-shuffled on the keys
+        and grouped where they land.
 
         ``_mask`` (the planner): a row mask as :meth:`groupby` takes it;
-        it rides the combined reductions, and filters the rows before a
-        shuffle."""
+        it rides the combined reductions and the pre-combine's sort, and
+        filters the rows in front of a shuffle of raw rows. Counters:
+        ``groupby.partial_path`` (the first two routes) /
+        ``groupby.raw_shuffle_path``, ``groupby.precombine.*`` (the
+        second)."""
         if self.world_size == 1:
             return self.groupby(by, agg, _mask=_mask, **kw)
         key_names = self._resolve_cols(by)
         specs = _agg_specs(agg)
-        all_ops = [oid for _c, oid, _n in specs]
         if kw.get("_dense", True) and not kw.get("_sorted", False):
-            dense = self._dense_groupby_plan(key_names, all_ops)
+            dense = self._dense_groupby_plan(
+                key_names, [oid for _c, oid, _n in specs]
+            )
             if dense is not None:
                 return self._groupby_dense(
                     key_names, specs, dense, _mask, combine=True
                 )
         if not key_names:
             raise ValueError(_KEYLESS_OPS)
-        t = self if _mask is None else self.filter(_mask)
-        if all(o in _g.ASSOCIATIVE for o in all_ops):
-            pre = t.groupby(by, agg, **kw)
-            # rename aggregated columns back to the source names so the final
-            # pass re-aggregates them under the same spec
-            ren = {}
-            newagg = {}
-            for col, ops in agg.items():
-                o = ops if isinstance(ops, (str, int)) else (ops[0] if len(ops) == 1 else None)
-                if o is None:
-                    # multiple ops per column can't pre-combine under one name
-                    pre = None
-                    break
-                oname = o if isinstance(o, str) else _agg_name(_g.agg_op_id(o))
-                ren[f"{col}_{oname}"] = col
-                newagg[col] = o
-            if pre is not None:
-                t = pre.rename(ren)
-                shuffled = t._shuffle_impl(kind="hash", key_names=key_names)
-                return shuffled.groupby(by, newagg, **kw)
-        shuffled = t._shuffle_impl(kind="hash", key_names=key_names)
-        return shuffled.groupby(by, agg, **kw)
+        return self._groupby_exchange(key_names, agg, _mask, **kw)
+
+    def _groupby_exchange(self, key_names, agg, _mask=None, **kw) -> "Table":
+        """The group-by of a mesh whose rows of a group must meet: every
+        shard ends up owning the groups whose keys hash to it (what a hash
+        shuffle on the keys and a local group-by give; the planner's
+        lowering calls this for exactly that pair of nodes).
+
+        Ops of ``ops.groupby.PARTIAL_OPS`` alone: pre-combine (stage
+        ``groupby.partial``), count, exchange of the partial rows at
+        ``round_cap`` of the fullest shard's count, combine and finish
+        (stage ``groupby.merge``). Any other op: the rows themselves are
+        shuffled and grouped where they land."""
+        specs = _agg_specs(agg)
+        if not all(oid in _g.PARTIAL_OPS for _c, oid, _n in specs):
+            bump("groupby.raw_shuffle_path")
+            t = self if _mask is None else self.filter(_mask)
+            shuffled = t._shuffle_impl(kind="hash", key_names=key_names)
+            return shuffled.groupby(key_names, agg, **kw)
+        bump("groupby.partial_path")
+        plan = _PartialAgg.of(self, specs)
+        pre = plan.pre_combine(
+            self, key_names, _mask, kw.get("_sorted", False)
+        )
+        # the partial rows lie first in their shards, in key order: the
+        # count (the exchange plans on the host and would fetch it anyway)
+        # and a slice are all it takes to ship them at their own capacity
+        # and not at the input's
+        counts = pre.row_counts
+        fullest = int(counts.max())
+        pre = pre._compact(round_cap(fullest))
+        bump("groupby.precombine.rows_in", rows=self._rows_hint() or 0)
+        bump("groupby.precombine.rows_out", rows=int(counts.sum()))
+        bump("groupby.precombine.fullest", rows=fullest)
+        bump("groupby.precombine.slots", rows=pre.shard_cap)
+        shuffled = pre._shuffle_impl(kind="hash", key_names=key_names)
+        return plan.combine(shuffled, key_names)
 
     def pipeline_groupby(
         self,

@@ -209,6 +209,8 @@ def test_stale_compiled_text_is_reported(how, monkeypatch):
     ("jit(f)/sort_engine/sort", "sort_engine", True),
     ("jit(shuffle_pack)/shard_map/shuffle.pack/semi.sketch/gather", "shuffle.pack", False),
     ("jit(shuffle_reassemble)/shard_map/shuffle.reassemble/dynamic_update_slice", "shuffle.reassemble", False),
+    ("jit(groupby)/shard_map/groupby.partial/groupby.key_ids/sort_engine/sort", "groupby.partial", True),
+    ("jit(groupby)/shard_map/groupby.merge/groupby.segment_sum/add", "groupby.merge", False),
     ("jit(join_spec)/concatenate", None, False),
     ("", None, False),
 ])
@@ -218,7 +220,7 @@ def test_outermost_name_is_the_stage(path, stage, engine_in):
 
 
 def test_vocabulary_is_defined_once():
-    assert len(set(stages.VOCABULARY)) == len(stages.VOCABULARY) == 21
+    assert len(set(stages.VOCABULARY)) == len(stages.VOCABULARY) == 23
     constants = {
         v for k, v in vars(stages).items() if k.isupper() and isinstance(v, str)
     }

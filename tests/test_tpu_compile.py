@@ -720,6 +720,73 @@ def test_groupby_of_many_float64_columns_compiles_in_bounded_time(
 # dictionary keys of 3 and 2 codes, float64 values, a row mask)
 # ----------------------------------------------------------------------
 
+@pytest.mark.parametrize("masked", [False, True], ids=["plain", "masked"])
+def test_groupby_pre_combine_and_combine_compile_for_tpu(one_chip, masked):
+    """The two programs either side of a group-by's exchange of partial
+    rows, in ``h2o-q5-w4``'s shape (int32 id fused into one sort word,
+    int32 and float64 values, x64 on) with the once-a-run state (a count
+    beside a sum, a mean as its sum and count): both take the chip's
+    compiler, stay in sorted space (two sorts, no row-sized scatter), and
+    carry their stage outermost with the sort-and-segment stages inside.
+    A row mask rides the pre-combine's sort as padding: one more operand
+    class, no gather in front."""
+    from cylon_tpu.obs import stages
+
+    fuse = _sort.plan_lane_fusion(
+        [("i32", 23, False, True)], pad_bits=1, prefix_bits=0, allow64=True
+    )
+    assert fuse is not None and fuse.n_words == 1
+    ops = [(_g.SUM, 0), (_g.COUNT, 0), (_g.MEAN, 1)]
+    states, reads = _g.partial_states(ops, [False, True])
+    assert states == ((_g.SUM, 0, False), (_g.COUNT, 0, False),
+                      (_g.SUM, 1, False), (_g.COUNT, 1, False))
+
+    def pre_combine(key, v1, v3, mask, n):
+        with jax.named_scope(stages.GROUPBY_PARTIAL):
+            return _g.groupby_aggregate(
+                [(key, None)], [(v1, None), (v3, None)],
+                [(op, j) for op, j, _w in states], n, ROWS, fuse=fuse,
+                mask=mask if masked else None,
+            )
+
+    def combine(key, s1, c1, s3, c3, n):
+        with jax.named_scope(stages.GROUPBY_MERGE):
+            keys, aggs, ng = _g.groupby_aggregate(
+                [(key, None)], [(x, None) for x in (s1, c1, s3, c3)],
+                _g.combine_ops(states), n, ROWS, fuse=fuse,
+            )
+            return keys, _g.finish_states(ops, reads, aggs), ng
+
+    i32 = _spec((ROWS,), jnp.int32, one_chip)
+    i64 = _spec((ROWS,), jnp.int64, one_chip)
+    f64 = _spec((ROWS,), jnp.float64, one_chip)
+    n = _spec((), jnp.int32, one_chip)
+    programs = {
+        stages.GROUPBY_PARTIAL: _compile(
+            pre_combine, i32, i32, f64, _spec((ROWS,), jnp.bool_, one_chip), n
+        ),
+    }
+    if not masked:
+        programs[stages.GROUPBY_MERGE] = _compile(
+            combine, i32, i64, i64, f64, i64, n
+        )
+    for stage, compiled in programs.items():
+        wide, rows = _wide_ops(compiled)
+        assert wide
+        assert not [shape for shape, opcode in wide if "scatter" in opcode]
+        assert len([shape for shape, opcode in wide if opcode == "sort"]) == 2
+        staged = [op.split("/") for _text, op in rows if op]
+        assert staged and all(
+            stages.stage_of("/".join(path)) == stage
+            for path in staged if stage in path
+        )
+        for inner in (stages.GROUPBY_KEY_IDS, stages.GROUPBY_SEGMENT_SUM):
+            assert any(
+                stage in path and inner in path
+                and path.index(stage) < path.index(inner) for path in staged
+            ), (stage, inner)
+
+
 def test_dense_groupby_compiles_for_tpu_without_sort_scatter_or_gather(one_chip):
     from cylon_tpu.obs import stages
 
