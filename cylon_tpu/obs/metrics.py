@@ -415,6 +415,15 @@ STABLE_METRICS: Dict[str, Tuple[str, str]] = {
     "groupby.precombine.slots": (
         "counter", "slots a shard's partial table crossed the mesh in "
         "(rows= round_cap of the fullest shard's count)"),
+    "groupby.compact.steps": (
+        "counter", "sort-and-segment group-bys (one a shard program: a "
+        "Table.groupby, a pre-combine, a combine) whose run heads reached "
+        "their slots by the log-step compress, ops.sort.step_compact "
+        "(rows= the slots of a shard the moves pass over)"),
+    "groupby.compact.passes": (
+        "counter", "passes those compresses make over their slots (rows= "
+        "len(ops.sort.step_passes(slots)): one a bit of the slot count, "
+        "one a two bits from ops.sort.STEP_TWO_BITS_MIN_SLOTS slots)"),
     "groupby.partial.rows": (
         "counter", "input rows those calls aggregated without an exchange "
         "(rows= the table's host-known row count, 0 while it is deferred); "

@@ -14,14 +14,17 @@ with the value columns riding it as payload operands (and any key column
 that cannot be read back out of the fused sort words), and the group-by
 never leaves that order: every aggregate is a segmented scan over
 the runs of equal keys (:func:`ops.sort.run_reduce`; a run adds only its
-own values, so a float sum is as exact as its group is small), and one
-compaction sort moves each run's first row, which then holds the group's
-keys and totals, to the group's slot. There is no per-element scatter and
-no row-sized gather: on a v5e a scatter-add costs 114 ns a row and a sort
-1.3 ns (PERF.md section 6, PR 28). Many columns ride the two sorts in
-batches of eight 32-bit lanes (:func:`ops.sort.ride_sort`), so the program
-holds the same few sorts, and compiles in the same time, at 32 aggregates
-as at 16. Single dispatch: num_groups <= live
+own values, so a float sum is as exact as its group is small), and each
+run's first row, which then holds the group's keys and totals, reaches
+the group's slot by log-step moves (:func:`ops.sort.step_compact`: the
+order is known and so are the targets, so no second sort finds them out).
+There is no per-element scatter and no row-sized gather: on a v5e a
+scatter-add costs 114 ns a row and a sort 1.3 ns (PERF.md section 6,
+PR 28), and the moves a third to a half of the sort they replaced (PR 46).
+Many columns ride the one sort in batches of eight 32-bit lanes
+(:func:`ops.sort.ride_sort`), so the program holds the same few sorts,
+and compiles in the same time, at 32 aggregates as at 16; the moves take
+every lane as it is. Single dispatch: num_groups <= live
 rows bounds the output statically, so one kernel + one host sync covers
 count AND emit. Keys of few distinct values take the dense path below
 instead (no sort at all).
@@ -37,8 +40,8 @@ import numpy as np
 from ..obs import stages as _stages
 from .factorize import factorize_runs
 from .sort import (
-    KeyCol, flag_compact, flatten_cols, fused_decodable, fused_key_decode,
-    lexsort_indices, orderable_key, run_reduce, scan_identity, unflatten_cols,
+    KeyCol, flatten_cols, fused_decodable, fused_key_decode, lexsort_indices,
+    orderable_key, run_reduce, scan_identity, step_compact, unflatten_cols,
     wide_float, wide_int,
 )
 from .stats import decode_enc, wire_narrowable
@@ -103,10 +106,11 @@ def groupby_aggregate(
     as payload operands, each aggregate is a reduction over the runs of
     equal keys (:func:`ops.sort.run_reduce`), and a run's first row, which
     then holds the group's keys and totals, moves to the group's slot by
-    one compaction sort (stage ``groupby.segment_sum``). No row-sized
-    scatter or gather; ``presorted`` input runs no sort in front either.
-    A key the ``fuse`` plan's sort words hold bit for bit does not ride:
-    the words ride the compaction and the key is decoded at the slots.
+    the log-step compress (:func:`ops.sort.step_compact`; stage
+    ``groupby.segment_sum``). One sort, no row-sized scatter or gather;
+    ``presorted`` input runs no sort at all. A key the ``fuse`` plan's
+    sort words hold bit for bit does not ride: the words go through the
+    compress and the key is decoded at the slots.
 
     ``fuse``: stats-driven sort-word fusion plan for the factorize lanes
     (ops/sort.FusePlan; Table.groupby derives it from the key columns'
@@ -122,7 +126,8 @@ def groupby_aggregate(
         keep = (jnp.arange(cap, dtype=jnp.int32) < n) & mask
         n = jnp.sum(keep, dtype=jnp.int32)
     # a key the fused sort words hold bit for bit is read back out of them
-    # at the groups' slots; any other rides both sorts beside the values
+    # at the groups' slots; any other rides the sort, and moves to the
+    # slots, beside the values
     in_words = (
         fused_decodable(fuse, key_cols)
         if fuse is not None and not presorted else [False] * len(key_cols)
@@ -167,7 +172,8 @@ def _aggregate_runs(
 ):
     """The aggregates of :func:`groupby_aggregate` over rows in sorted
     order: ``start`` marks the live rows that open a run, whose ``carry``
-    arrays go to the group's slot with its totals. Returns (carried
+    arrays go to the group's slot with its totals, by the moves of
+    :func:`ops.sort.step_compact` and by no sort. Returns (carried
     arrays, ``(out, valid)`` an op, each [cap_out]; num_groups)."""
     cap = start.shape[0]
     # the per-row lanes the one scan reduces, by what they hold: ``sum``
@@ -237,7 +243,7 @@ def _aggregate_runs(
     reduced = run_reduce(
         run_end, [v for _k, v in lanes.values()], [k for k, _v in lanes.values()]
     )
-    first, packed = flag_compact(start, list(carry) + reduced)
+    first, packed = step_compact(start, list(carry) + reduced)
     num_groups = jnp.sum(start, dtype=jnp.int32)
     # a group's rows lie between its first row and the next group's
     last = jnp.arange(cap, dtype=jnp.int32) == num_groups - 1
@@ -366,9 +372,9 @@ DENSE_OPS = frozenset({SUM, COUNT, MIN, MAX, MEAN})
 #: most slots (the product of the key columns' spans, a nullable key
 #: taking one more) for which ``Table.groupby`` takes the dense path by
 #: itself. Each aggregate is one masked reduction a slot, so the work a
-#: row grows with the slots, while the sort-and-segment path's (two sorts
-#: and a scan) does not. Measured on a v5e chip at 16,777,216 rows
-#: (PERF.md section 6, PR 27): Q1's eight aggregates take 16.9 / 39.7 /
+#: row grows with the slots, while the sort-and-segment path's (a sort, a
+#: scan and the moves to the slots) does not. Measured on a v5e chip at
+#: 16,777,216 rows (PERF.md section 6, PR 27): Q1's eight aggregates take 16.9 / 39.7 /
 #: 129.8 / 497.6 ms at 4 / 64 / 256 / 1,024 slots, one float64 sum 5.1 /
 #: 9.8 / 110.4 ms at 4 / 64 / 1,024, linear in the slots from 64 on. The
 #: constant was set against the other path's scatter form (14.5 s and

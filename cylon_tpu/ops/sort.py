@@ -701,25 +701,72 @@ def sentinel_compact(key: jax.Array, payloads: Sequence[jax.Array]) -> list:
         return list(out[1:])
 
 
-def flag_compact(
+#: slots from which a pass of :func:`step_compact` moves the rows by two
+#: bits of their distance and not by one. A pass of one bit reads a lane
+#: twice and writes it once; a pass of two bits reads it four times, so two
+#: bits cost five crossings of the lane against six, and selects among
+#: three shifted views against one. Where the lanes stream from HBM the
+#: crossings decide, under that the selects. On a v5e chip
+#: (``benchmarks/compact_bench.py``; PERF.md section 6, PR 46), a uint32
+#: word and a float64 / with two int64 beside them, one bit against two a
+#: pass, ms: 2^20 slots 1.1 - 1.3 / 1.5 - 1.7; 2^22 2.2 - 2.8 / 7.4 - 7.0;
+#: 2^24 29.3 - 26.5 / 59.0 - 49.4; 2^26 158.0 - 139.6 / 298.5 - 255.5 (the
+#: sort they replace: 12.7 / 24.0 at 2^22, 653 for the wider lanes at 2^26).
+STEP_TWO_BITS_MIN_SLOTS = 1 << 24
+
+
+def step_passes(cap: int) -> list:
+    """The passes of :func:`step_compact` over ``cap`` slots, lowest bits
+    first: ``(first bit, bits)`` a pass. What the host counts at dispatch
+    (``groupby.compact.passes``), from the rule the kernel itself
+    follows."""
+    bits = 2 if cap >= STEP_TWO_BITS_MIN_SLOTS else 1
+    return [(at, bits) for at in range(0, (cap - 1).bit_length(), bits)]
+
+
+def _shift_in(x: jax.Array, k: int) -> jax.Array:
+    """``y[i] = x[i + k]``, zeros past the end, as ONE ``pad`` with a
+    negative low edge: the TPU's compiler fuses that into the selects that
+    read it, where the slice of :func:`_shift_up` is a copy of its own."""
+    return jax.lax.pad(x, jnp.zeros((), x.dtype), [(-k, k, 0)])
+
+
+def step_compact(
     keep: jax.Array, payloads: Sequence[jax.Array]
 ) -> Tuple[jax.Array, list]:
     """Move the rows whose ``keep`` flag is set to the front, in their
-    order: one 1-key sort keyed on the row's own position (the sentinel
-    for a dropped row), so no two kept rows tie and the sort need not be
-    stable. Many payloads ride in batches (:func:`ride_sort`). Returns
-    (positions [cap]: the kept rows' original positions, then the sentinel
-    ``cap``; compacted payloads)."""
+    order, without a sort: an order-preserving log-step compress (Hacker's
+    Delight 7-4). A kept row lies as many slots from its target as there
+    are dropped rows to its left; that distance never falls along the kept
+    rows, so moving every row by the bits of its distance, lowest first,
+    never lands two rows on one slot. A pass (:func:`step_passes`) is one
+    round of selects over whole lanes, every payload in the dtype it has:
+    no sort, no gather, no scatter, and no batches however many payloads.
+    Returns (positions [cap]: the kept rows' original positions, then the
+    sentinel ``cap``; compacted payloads, whose slots behind the kept rows
+    hold whatever a pass left there)."""
     cap = keep.shape[0]
-    key = jnp.where(keep, jnp.arange(cap, dtype=jnp.int32), jnp.int32(cap))
-    def compact(pays):
-        with jax.named_scope(_stages.SORT_ENGINE):
-            out = jax.lax.sort(
-                tuple([key] + list(pays)), num_keys=1, is_stable=False
-            )
-        return out[0], list(out[1:])
+    iota = jnp.arange(cap, dtype=jnp.int32)
+    # the kept rows at or after me, by the blocked scan over one run
+    (after,) = run_reduce(iota == cap - 1, [keep.astype(jnp.int32)], ["sum"])
+    dist = jnp.where(keep, iota - (after[0] - after), 0)
+    pos = jnp.where(keep, iota, jnp.int32(cap))
+    lanes = list(payloads)
+    for at, bits in step_passes(cap):
+        hop = (dist >> at) & ((1 << bits) - 1)  # in units of 1 << at slots
+        hops = [j for j in range(1, 1 << bits) if j << at < cap]
+        lands = [_shift_in(hop == j, j << at) for j in hops]
 
-    return ride_sort(compact, payloads)
+        def moved(x, stays):
+            for j, land in zip(hops, lands):
+                stays = jnp.where(land, _shift_in(x, j << at), stays)
+            return stays
+
+        lanes = [moved(x, x) for x in lanes]
+        # a slot its row left and none reached holds no row from here on
+        pos = moved(pos, jnp.where(hop != 0, jnp.int32(cap), pos))
+        dist = moved(dist, jnp.where(hop != 0, 0, dist))
+    return pos, lanes
 
 
 def lexsort_rows(

@@ -3026,6 +3026,11 @@ class Table:
             )
         m = None if mask is None else self._as_mask(mask)
         bump("groupby.factorize_path")
+        # the run heads reach their slots by log-step moves, counted from
+        # the rule the kernel itself follows (ops.sort.step_passes)
+        bump("groupby.compact.steps", rows=self.shard_cap)
+        bump("groupby.compact.passes",
+             rows=len(_sort_mod.step_passes(self.shard_cap)))
         if not _sorted and provably_sorted:
             # canonical prefix order: run adjacency AND emitted group order
             # match the factorize path exactly (factorize_runs(presorted=True))

@@ -2,12 +2,15 @@
 ops/groupby.groupby_aggregate) against numpy and pandas.
 
 Every aggregate of that path is a segmented scan over the runs of the
-factorize sort and one compaction sort; these cases hold its edges: one
+factorize sort, and a run's first row reaches its group's slot by the
+log-step compress (ops/sort.step_compact); these cases hold its edges: one
 run, a run a row, runs of 1 to 100,000 rows, nulls, several keys, no live
 row under a padded capacity, and float64 values whose neighbours differ by
 sixteen orders of magnitude (where a difference of prefix sums is off by
 far more than the 1e-11 the benchmark allows a group's sum).
 """
+import functools
+
 import jax
 import numpy as np
 import pandas as pd
@@ -67,14 +70,85 @@ def test_run_reduce_scans_several_lanes_at_once(rng):
     assert (np.asarray(got[2]) == _numpy_run_reduce(run_end, b, "max")).all()
 
 
-def test_flag_compact_moves_kept_rows_to_the_front(rng):
-    keep = rng.random(300) < 0.3
-    pay = rng.integers(0, 1 << 40, 300)
-    pos, (got,) = jax.jit(lambda k, p: _sort.flag_compact(k, [p]))(keep, pay)
-    m = int(keep.sum())
-    assert (np.asarray(pos)[:m] == np.flatnonzero(keep)).all()
-    assert (np.asarray(pos)[m:] == 300).all()
-    assert (np.asarray(got)[:m] == pay[keep]).all()
+# ----------------------------------------------------------------------
+# the compress: a run's first row to its group's slot, without a sort
+# ----------------------------------------------------------------------
+
+def _kept(rng, cap, share, where):
+    """``keep`` [cap]: ``share`` of the rows of the half (or the whole)
+    that ``where`` names; "one" keeps a single row there."""
+    lo, hi = {
+        "front": (0, max(1, cap // 2)), "back": (cap // 2, cap),
+        "spread": (0, cap),
+    }[where]
+    keep = np.zeros(cap, bool)
+    if share == "one":
+        keep[rng.integers(lo, hi)] = True
+    else:
+        keep[lo:hi] = rng.random(hi - lo) < share
+    return keep
+
+
+def _compress_payloads(rng, cap):
+    """One payload of every kind the group-by carries, then more of them
+    than one sort would take (``RIDE_LANES``)."""
+    pays = [
+        _ride_lane(rng, d, cap)
+        for d in (np.bool_, np.int32, np.uint32, np.int64, np.float32,
+                  np.float64)
+    ]
+    f64 = pays[-1]
+    f64[: min(cap, 2)] = [np.nan, -0.0][: min(cap, 2)]
+    pays += [_ride_lane(rng, np.float64, cap) for _ in range(_sort.RIDE_LANES)]
+    return pays
+
+
+@functools.lru_cache(maxsize=None)
+def _step_compact(bits):
+    """One jitted compress a width of a pass: the constant is read while
+    tracing, so the two widths must not share a cache of programs."""
+    return jax.jit(lambda k, p: _sort.step_compact(k, p))
+
+
+@pytest.fixture(params=[1, 2], ids=["bit-a-pass", "two-bits-a-pass"])
+def step_bits(request, monkeypatch):
+    """Both widths of a pass of ``step_compact``, whatever the slots."""
+    monkeypatch.setattr(
+        _sort, "STEP_TWO_BITS_MIN_SLOTS", 0 if request.param == 2 else 1 << 62
+    )
+    return request.param
+
+
+@pytest.mark.parametrize("where", ["front", "back", "spread"])
+@pytest.mark.parametrize("share", [0.0, "one", 0.037, 0.63, 1.0])
+@pytest.mark.parametrize("cap", [1, 127, 128, 129, 300, 4096, 65_537])
+def test_step_compact_moves_kept_rows_to_the_front(
+    rng, step_bits, cap, share, where
+):
+    """The kept rows' payloads lie first, in their order and bit for bit
+    (``np.flatnonzero`` is the oracle), whatever their dtype and however
+    many; the positions are the kept rows', then the sentinel ``cap``."""
+    keep = _kept(rng, cap, share, where)
+    pays = _compress_payloads(rng, cap)
+    pos, got = _step_compact(step_bits)(keep, pays)
+    at = np.flatnonzero(keep)
+    assert len(_sort.step_passes(cap)) == -(-(cap - 1).bit_length() // step_bits)
+    pos = np.asarray(pos)
+    assert pos.dtype == np.int32
+    assert (pos[: len(at)] == at).all() and (pos[len(at):] == cap).all()
+    assert len(got) == len(pays) > _sort.RIDE_LANES
+    for g, p in zip(got, pays):
+        g = np.asarray(g)
+        assert g.dtype == p.dtype and g.shape == p.shape
+        assert g[: len(at)].tobytes() == p[at].tobytes()
+
+
+def test_step_compact_holds_no_sort_gather_or_scatter():
+    keep = jax.numpy.zeros(5000, bool)
+    pays = [jax.numpy.zeros(5000, d) for d in (np.uint32, np.int64, np.float64)]
+    text = str(jax.make_jaxpr(lambda k, p: _sort.step_compact(k, p))(keep, pays))
+    for prim in (" sort[", "gather[", "scatter", "cumsum[", "while[", "scan["):
+        assert prim not in text, prim
 
 
 # ----------------------------------------------------------------------
@@ -293,7 +367,7 @@ def test_keys_decoded_from_the_sort_words_match_pandas(local_ctx, rng, case):
 
 
 # ----------------------------------------------------------------------
-# many columns: the payloads ride the two sorts in batches
+# many columns: the payloads ride the sort in batches
 # ----------------------------------------------------------------------
 
 _RIDE_DTYPES = [
@@ -307,7 +381,7 @@ def _ride_lane(rng, dtype, n):
         return rng.random(n) < 0.5
     if np.issubdtype(dtype, np.floating):
         x = rng.normal(size=n).astype(dtype)
-        x[:4] = [np.nan, -0.0, np.inf, -np.inf]
+        x[:4] = [np.nan, -0.0, np.inf, -np.inf][: len(x)]
         return x
     info = np.iinfo(dtype)
     return rng.integers(info.min, info.max, n, dtype=dtype, endpoint=True)

@@ -30,6 +30,7 @@ from cylon_tpu.obs import stages
 from cylon_tpu.table import _PartialAgg
 from cylon_tpu.utils import tracing
 
+import compact_cases
 import h2o_groupby_reference as h2o
 
 N, K = 40_000, 20
@@ -143,6 +144,32 @@ def test_the_lazy_group_by_takes_the_same_route():
     # Shuffle placed them
     eager = _table(4).distributed_groupby(by, agg)
     npt.assert_array_equal(lazy.row_counts, eager.row_counts)
+
+
+# -- however the run heads reach their slots, one table (PR 46) -----------
+@pytest.mark.parametrize("way", ["sort", "two-bits-a-pass"])
+@pytest.mark.parametrize("world", [1, 4], ids=lambda w: f"w{w}")
+def test_q5_is_one_table_however_the_heads_move(monkeypatch, world, way):
+    """Question 5 (the benchmark's ``h2o-q5-w4``) through the pre-combine
+    and the combine with the compaction sort that moved the run heads
+    until PR 46, and with the wider pass of the compress: the table of
+    the default pass, bit for bit, shard for shard."""
+    by, agg = h2o.QUESTIONS["q5"]
+    data = _data()
+
+    def q5(patch, how):
+        ctx = compact_cases.fresh_ctx(patch, how, world)
+        table = ct.Table.from_numpy(ctx, list(data), list(data.values()))
+        result = table.distributed_groupby(by, agg)
+        return compact_cases.live_bits(result), list(result.row_counts)
+
+    with pytest.MonkeyPatch.context() as patch:
+        want = q5(patch, "bit-a-pass")
+    assert q5(monkeypatch, way) == want
+    _assert_answer(
+        _in_key_order(_table(world).distributed_groupby(by, agg), by),
+        h2o.answer(data, by, agg),
+    )
 
 
 # -- the capacity the partials travel at ---------------------------------

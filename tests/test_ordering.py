@@ -394,9 +394,9 @@ def test_q3_sort_pass_bytes_reduction(world, devices):
     The bytes are those of the sorts in front of the aggregation: the
     join's, and the factorize sort of the group-by that takes the join's
     rows, which is what a key-ordered join output elides. The aggregation
-    itself (``groupby.segment_sum``) is exactly one compaction sort a
-    local group-by on either path (PR 28; scatters before, which this
-    model of sort passes never counted), and behind the first aggregation
+    itself (``groupby.segment_sum``) sorts nothing on either path (a scan,
+    and the run heads moved to their slots by selects since PR 46; one
+    compaction sort from PR 28 until then), and behind the first aggregation
     (the partials' shuffle and the final group-by of a mesh) both paths
     run the same programs."""
     from cylon_tpu.obs import stages
@@ -440,12 +440,12 @@ def test_q3_sort_pass_bytes_reduction(world, devices):
     fe, agg_e, rest_e = split(eager)
     fo, agg_o, rest_o = split(ordered)
     # the group-by of the join's rows: one factorize sort, none when the
-    # rows come key-ordered; one compaction sort either way and no other
+    # rows come key-ordered; no sort behind it either way
     assert len(_stage_sorts(agg_e, stages.GROUPBY_KEY_IDS)) == 1
     assert len(_stage_sorts(agg_o, stages.GROUPBY_KEY_IDS)) == 0
     for _name, rep in [p for p in eager + ordered if p[0] == "groupby"]:
-        assert len(_stage_sorts(rep, stages.GROUPBY_SEGMENT_SUM)) == 1
-    assert agg_e.sort_count == 2 and agg_o.sort_count == 1
+        assert len(_stage_sorts(rep, stages.GROUPBY_SEGMENT_SUM)) == 0
+    assert agg_e.sort_count == 1 and agg_o.sort_count == 0
     # behind it: the same programs, sort for sort
     assert [(name, rep.sort_count) for name, rep in rest_e] == [
         (name, rep.sort_count) for name, rep in rest_o

@@ -641,9 +641,10 @@ def test_groupby_segment_sum_compiles_for_tpu(one_chip):
 
 def test_groupby_cell_shape_compiles_without_scatter(one_chip):
     """``groupby-w1``'s own shape (int64 key fused into one sort word,
-    float64 value, x64 on): the aggregates ride the factorize sort, so no
-    operation that writes a row-sized array scatters and at most one
-    gathers, and both stages name their operations. The first answer,
+    float64 value, x64 on): the aggregates ride the factorize sort and
+    the run heads move by selects, so no operation that writes a
+    row-sized array scatters and at most one gathers, and both stages
+    name their operations. The first answer,
     without a chip, to whether a 64-bit operand rides ``jax.lax.sort`` on
     a v5e: it compiles, as its two 32-bit halves."""
     from cylon_tpu.obs import stages
@@ -669,9 +670,10 @@ def test_groupby_cell_shape_compiles_without_scatter(one_chip):
     assert wide
     assert not [shape for shape, opcode in wide if "scatter" in opcode]
     assert len([shape for shape, opcode in wide if "gather" in opcode]) <= 1
-    # a 64-bit operand rides either sort as two 32-bit operands
+    # ONE sort, the factorize sort (the run heads reach their slots by
+    # ``step_compact``'s moves); a 64-bit operand rides it as two 32-bit
     sorts = [shape for shape, opcode in wide if opcode == "sort"]
-    assert len(sorts) == 2 and all(shape.count("f32[") >= 2 for shape in sorts)
+    assert len(sorts) == 1 and sorts[0].count("f32[") >= 2
     for name in (stages.GROUPBY_KEY_IDS, stages.GROUPBY_SEGMENT_SUM):
         assert any(name in op.split("/") for _text, op in rows), name
 
@@ -683,10 +685,12 @@ def test_groupby_of_many_float64_columns_compiles_in_bounded_time(
     one_chip, columns
 ):
     """Compile time does not grow with the aggregates: past
-    ``ops.sort.RIDE_LANES`` lanes the payloads ride either sort in batches
-    under one ``jax.lax.map``, so the program holds the same few sorts at
-    16 float64 sums as at 32 (unbatched, 8 sums took 382 s here and the
-    time grew faster than the columns; PERF.md section 6, PR 28)."""
+    ``ops.sort.RIDE_LANES`` lanes the payloads ride the factorize sort in
+    batches under one ``jax.lax.map``, so the program holds the same few
+    sorts at 16 float64 sums as at 32 (unbatched, 8 sums took 382 s here
+    and the time grew faster than the columns; PERF.md section 6, PR 28),
+    and the run heads' moves are selects over every lane at once, which
+    the compiler takes in seconds (PR 46)."""
     import time
 
     fuse = _sort.plan_lane_fusion(
@@ -709,8 +713,8 @@ def test_groupby_of_many_float64_columns_compiles_in_bounded_time(
     )
     seconds = time.monotonic() - t0
     text = compiled.as_text()
-    # the keys' own sort and one sort a stack of batches, at either site
-    assert len(re.findall(r" sort\(", text)) <= 6
+    # the keys' own sort and one sort a stack of batches
+    assert len(re.findall(r" sort\(", text)) <= 3
     assert "scatter(" not in text
     assert seconds < 900, f"{columns} columns compiled in {seconds:.0f} s"
 
@@ -726,7 +730,7 @@ def test_groupby_pre_combine_and_combine_compile_for_tpu(one_chip, masked):
     rows, in ``h2o-q5-w4``'s shape (int32 id fused into one sort word,
     int32 and float64 values, x64 on) with the once-a-run state (a count
     beside a sum, a mean as its sum and count): both take the chip's
-    compiler, stay in sorted space (two sorts, no row-sized scatter), and
+    compiler, stay in sorted space (one sort, no row-sized scatter), and
     carry their stage outermost with the sort-and-segment stages inside.
     A row mask rides the pre-combine's sort as padding: one more operand
     class, no gather in front."""
@@ -774,7 +778,7 @@ def test_groupby_pre_combine_and_combine_compile_for_tpu(one_chip, masked):
         wide, rows = _wide_ops(compiled)
         assert wide
         assert not [shape for shape, opcode in wide if "scatter" in opcode]
-        assert len([shape for shape, opcode in wide if opcode == "sort"]) == 2
+        assert len([shape for shape, opcode in wide if opcode == "sort"]) == 1
         staged = [op.split("/") for _text, op in rows if op]
         assert staged and all(
             stages.stage_of("/".join(path)) == stage
