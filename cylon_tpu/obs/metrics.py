@@ -463,6 +463,13 @@ STABLE_METRICS: Dict[str, Tuple[str, str]] = {
         "ring tail, reassembling its parts in one program (.parts rows= "
         "the blocks written, .rows rows= the live rows placed); a "
         "one-round shuffle bumps neither"),
+    "shuffle.compact.": (
+        "counter", "which front-pack a compact dispatch of a shuffle round "
+        "ran, bumped by the host at the dispatch: .blocks a one-hop "
+        "receive's block writes at the running offsets of the received "
+        "counts (parallel.shuffle.front_pack_chunks; rows= the chunks "
+        "placed, the mesh's size), .by_order the liveness argsort and a "
+        "gather an array that the two-hop receive and the ring relay keep"),
     "shuffle.bincount.": (
         "counter", "which form a count of rows into bins took "
         "(ops.partition.bin_counts: the shuffle's bucket counts, the range "

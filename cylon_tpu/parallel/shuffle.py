@@ -15,9 +15,14 @@ ride HEADER ROWS of the packed lane buffer (:func:`pack_by_sort` /
 :func:`split_header`) — one collective per round moves the payload AND the
 counts, so a distributed join issues 2 collectives, not 4. The pack moves
 rows by a sort keyed by destination, never by a row-sized scatter.
-"Reassembly" is a lane-level compaction argsort
-(:func:`compact_received_lanes`). The round scheduler and double-buffered
-dispatch live in ``table.py _shuffle_many``.
+"Reassembly" is a lane-level front-pack: what one hop leaves is ``P``
+equal chunks, each a live prefix, and each is written as one block at the
+running offset of the received counts (:func:`front_pack_chunks`, handed
+to :func:`compact_received_lanes` as :func:`chunk_front`; no sort, no
+gather). The two-hop receive and the ring relay, whose chunks are not
+equal, keep a compaction argsort and a gather an array
+(:func:`order_front`). The round scheduler and double-buffered dispatch
+live in ``table.py _shuffle_many``.
 
 The scatter chain that the sorted pack replaced stays for two reasons and
 no other: :func:`build_send_slots_round`, :func:`pack_lane_buffer`,
@@ -782,28 +787,73 @@ def received_row_mask(
     return mask, jnp.sum(recv_counts).astype(jnp.int32)
 
 
+def front_pack_chunks(x: jax.Array, recv_counts: jax.Array) -> jax.Array:
+    """Front-pack a received buffer ``x`` ``[P * bucket_cap, *trailing]``
+    that is ``P`` equal chunks (``recv_counts`` is ``[P]``), chunk ``s`` a
+    live prefix of ``recv_counts[s]`` rows and a dead tail (what a one-hop
+    all-to-all leaves): chunk ``s`` is written whole, as ONE block, at the
+    running offset of the counts before it, chunks in rising order. Its
+    dead tail lands where chunk ``s + 1``'s block overwrites it, or past
+    the total;
+    ``off_s + bucket_cap <= (s + 1) * bucket_cap``, so no start is ever
+    clamped and the buffer needs no overhang. Chunk 0 lies where it
+    belongs. The live rows come out source by source in each source's
+    order, as the stable argsort of the liveness mask puts them; rows
+    past the total are whatever the last writes left. No sort, no gather,
+    no scatter: no row is addressed on its own."""
+    num_partitions = recv_counts.shape[0]
+    bucket_cap = x.shape[0] // num_partitions
+    zeros = (jnp.int32(0),) * (x.ndim - 1)
+    off = jnp.int32(0)
+    out = x
+    for s in range(1, num_partitions):
+        off = off + recv_counts[s - 1].astype(jnp.int32)
+        out = jax.lax.dynamic_update_slice(
+            out, x[s * bucket_cap:(s + 1) * bucket_cap], (off, *zeros)
+        )
+    return out
+
+
+def chunk_front(recv_counts: jax.Array):
+    """``front(x)`` of a one-hop receive, for :func:`compact_received_lanes`
+    / :func:`compact_received_wire`: ``x``'s ``P`` equal chunks reach the
+    front by :func:`front_pack_chunks` (the flat mesh's compact,
+    ``table._shuffle_state.build_compact``)."""
+    return lambda x: front_pack_chunks(x, recv_counts)
+
+
+def order_front(mask: jax.Array):
+    """``front(x)`` of a general liveness ``mask``: one stable argsort of
+    the mask and a per-element gather an array. What the receives whose
+    chunks are not equal keep (the two-hop exchange, the ring relay)."""
+    with jax.named_scope(_stages.SHUFFLE_COMPACT):
+        order = jnp.argsort(~mask, stable=True).astype(jnp.int32)
+    return lambda x: x[order]
+
+
 def compact_received_lanes(
     plan,
     lane_rows: Optional[jax.Array],
     pt_cols: dict,
-    mask: jax.Array,
+    front,
 ) -> List[Tuple[jax.Array, Optional[jax.Array]]]:
-    """Receive-side compaction straight at the LANE level: one stable sort
-    by liveness + ONE gather of the already-packed [rows, L] lane matrix
-    (plus one per f64 passthrough column), then unpack. The chunked
-    engine's compact phase uses this instead of :func:`compact_received`,
-    which would re-pack rows that arrived packed."""
+    """Receive-side compaction straight at the LANE level: the
+    already-packed [rows, L] lane matrix and every f64 passthrough column
+    reach the front by ``front`` (:func:`chunk_front`: a block write a
+    source chunk; :func:`order_front`: a liveness sort and a gather an
+    array), then unpack. The chunked engine's compact phase uses this
+    instead of :func:`compact_received`, which would re-pack rows that
+    arrived packed."""
     with jax.named_scope(_stages.SHUFFLE_COMPACT):
-        order = jnp.argsort(~mask, stable=True).astype(jnp.int32)
         out_lanes: List[jax.Array] = []
         if lane_rows is not None and lane_rows.shape[1]:
-            g = lane_rows[order]
+            g = front(lane_rows)
             out_lanes = [g[:, j] for j in range(g.shape[1])]
-        sorted_pt = {ci: d[order] for ci, d in pt_cols.items()}
+        fronted_pt = {ci: front(d) for ci, d in pt_cols.items()}
         out, _ = unpack_cols(
             plan,
             out_lanes,
-            lambda ci: sorted_pt[ci],
+            lambda ci: fronted_pt[ci],
             lambda lane: None if lane is None else lane.astype(jnp.bool_),
         )
         return out
@@ -814,29 +864,27 @@ def compact_received_wire(
     bases: Optional[jax.Array],
     lane_rows: jax.Array,
     pt_cols: dict,
-    mask: jax.Array,
+    front,
     qscale_rows: Optional[jax.Array] = None,
 ) -> List[Tuple[jax.Array, Optional[jax.Array]]]:
     """:func:`compact_received_lanes` for a wire-narrowed exchange: the
-    received rows ARE packed words, so the liveness sort + gather runs on
-    the narrow [rows, n_words] matrix and the bit-unpack happens once, on
-    the compacted rows. ``qscale_rows``: [rows, nq8] per-row block scales
-    of the quantized fields (broadcast from the headers BEFORE this
-    permutation — they ride the same gather so each row dequantizes with
-    its own source chunk's scale)."""
+    received rows ARE packed words, so ``front`` moves the narrow
+    [rows, n_words] matrix and the bit-unpack happens once, on the
+    compacted rows. ``qscale_rows``: [rows, nq8] per-row block scales
+    of the quantized fields (broadcast from the headers BEFORE the rows
+    move: they ride the same ``front``, so each row dequantizes with its
+    own source chunk's scale)."""
     with jax.named_scope(_stages.SHUFFLE_COMPACT):
-        order = jnp.argsort(~mask, stable=True).astype(jnp.int32)
-        g = lane_rows[order]
+        g = front(lane_rows)
         word_lanes = [g[:, j] for j in range(g.shape[1])]
-        sorted_pt = {ci: d[order] for ci, d in pt_cols.items()}
-        qsc = None if qscale_rows is None else qscale_rows[order]
+        fronted_pt = {ci: front(d) for ci, d in pt_cols.items()}
         return wire_unpack_cols(
             word_lanes,
             wire,
             bases,
-            lambda ci: sorted_pt[ci],
+            lambda ci: fronted_pt[ci],
             lambda lane: None if lane is None else lane.astype(jnp.bool_),
-            qscales=qsc,
+            qscales=None if qscale_rows is None else front(qscale_rows),
         )
 
 

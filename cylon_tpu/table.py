@@ -4437,7 +4437,7 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
                 list(plan_sig),
                 lanes_all if has_lanes else None,
                 dict(zip(pt_order, pts_all)),
-                mask_all,
+                _sh.order_front(mask_all),
             )
             return out, _scalar(mask_all.sum().astype(jnp.int32))
 
@@ -4469,14 +4469,16 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
                         ci: jnp.concatenate([ps, p2], axis=0)
                         for ci, ps, p2 in zip(pt_eff, ptsS, pts2)
                     }
+                    # unequal chunks: the liveness sort and gathers stay
+                    front = _sh.order_front(mask)
                     if wire is not None:
                         (bases,) = rep
                         out = _sh.compact_received_wire(
-                            wire, bases, lane_rows, pt_cols, mask
+                            wire, bases, lane_rows, pt_cols, front
                         )
                     else:
                         out = _sh.compact_received_lanes(
-                            list(plan_sig), lane_rows, pt_cols, mask
+                            list(plan_sig), lane_rows, pt_cols, front
                         )
                     return out, _scalar(total)
                 (head, pts) = dp
@@ -4486,41 +4488,42 @@ def _shuffle_state(spec: "_ShuffleSpec") -> dict:
                     lane_rows, recv_counts = _sh.split_header(
                         head, world, n_header
                     )
-                    bc = lane_rows.shape[0] // world
                     nq8 = len(_g_pack.wire_q8_cols(wire))
                     if nq8:
                         # each received row dequantizes with its SOURCE
                         # chunk's block scale, broadcast from the header rows
-                        # before the compaction permutes anything
+                        # before the compaction moves anything
                         qsc_rows = _sh.recv_row_scales(
                             _sh.split_header_scales(
                                 head, world, n_header, nq8
                             ),
-                            world, bc,
+                            world, lane_rows.shape[0] // world,
                         )
                     pt_cols = dict(
                         zip(_g_pack.wire_pt_order(wire, pt_order), pts)
                     )
                 elif has_lanes:
                     lane_rows, recv_counts = _sh.split_header(head, world)
-                    bc = lane_rows.shape[0] // world
                     pt_cols = dict(zip(pt_order, pts))
                 else:
                     lane_rows, recv_counts = None, head
-                    bc = pts[0].shape[0] // world
                     pt_cols = dict(zip(pt_order, pts))
-                mask, total = _sh.received_row_mask(recv_counts, world, bc)
+                # one hop leaves `world` equal chunks, each a live prefix:
+                # every lane reaches the front by a block write a chunk at
+                # the running offsets of `recv_counts`, no liveness mask,
+                # no sort and no gather (parallel/shuffle.front_pack_chunks)
+                front = _sh.chunk_front(recv_counts)
                 if wire is not None:
                     (bases,) = rep
                     out = _sh.compact_received_wire(
-                        wire, bases, lane_rows, pt_cols, mask,
+                        wire, bases, lane_rows, pt_cols, front,
                         qscale_rows=qsc_rows,
                     )
                 else:
                     out = _sh.compact_received_lanes(
-                        list(plan_sig), lane_rows, pt_cols, mask
+                        list(plan_sig), lane_rows, pt_cols, front
                     )
-                return out, _scalar(total)
+                return out, _scalar(jnp.sum(recv_counts).astype(jnp.int32))
 
         return kern
 
@@ -4554,7 +4557,9 @@ def _shuffle_many(specs: Sequence["_ShuffleSpec"]) -> List["Table"]:
     slots + header-fused scatter), COLLECTIVE (the one all_to_all; the
     round's send counts ride the lane buffer's header rows instead of a
     separate count collective, so a distributed join issues 2 collectives,
-    down from 4), COMPACT (header split + lane-level front-pack) — with no
+    down from 4), COMPACT (header split + lane-level front-pack: on a flat
+    mesh a block write a source chunk at the running offsets of the
+    received counts, no sort and no gather) — with no
     host sync anywhere in the loop: while round r's collective is in
     flight the host has already queued round r+1's pack and round r-1's
     compact, and every round's received count comes back in ONE deferred
@@ -5100,6 +5105,8 @@ def _shuffle_many_rounds(states, rows_total) -> List["Table"]:
                         st["ctx"], st["key"] + ("relay", "ring"),
                         st["build_ring"], name="shuffle_ring",
                     )(dp, (jnp.zeros((cap_ri,), jnp.int8), quota) + usef)
+                # the ring compacts what it absorbed in-kernel, by order
+                bump("shuffle.compact.by_order")
                 if st["relay_inter"] is None:
                     continue
             rc = st["sched"].relay_cap()
@@ -5163,6 +5170,13 @@ def _shuffle_many_rounds(states, rows_total) -> List["Table"]:
                         coll_out,
                         (st["bases"],) if st["wire"] is not None else (),
                     )
+                # which front-pack the dispatch ran: the block writes of a
+                # one-hop receive (rows= the chunks placed), or the liveness
+                # sort and gathers the two-hop receive keeps
+                if tp_key is None:
+                    bump("shuffle.compact.blocks", rows=st["world"])
+                else:
+                    bump("shuffle.compact.by_order")
                 if st["tier"] != _spill.TIER_HBM:
                     # tier 1/2: this round's compacted output streams into
                     # the host arena ONE ROUND DEEP — round r is fetched

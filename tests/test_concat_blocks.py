@@ -392,6 +392,74 @@ def test_host_syncs_do_not_grow_with_the_rounds(ctx4, kind, monkeypatch):
 
 
 # ----------------------------------------------------------------------
+# which front-pack a round's compact dispatch ran (PR 47)
+# ----------------------------------------------------------------------
+
+def _compact_counters(run):
+    """The ``shuffle.compact.*`` counters and the compact dispatches of
+    one call (the span ``shuffle.round.compact`` is one a dispatch)."""
+    reset_trace()
+    run()
+    rep = report("shuffle.")
+    return (
+        {k: v for k, v in rep.items() if k.startswith("shuffle.compact.")},
+        rep["shuffle.round.compact"]["count"],
+    )
+
+
+@pytest.mark.parametrize("op", ["sort", "join", "rounds"])
+def test_a_flat_mesh_compacts_by_block_writes_alone(ctx4, op):
+    """``shuffle.compact.blocks``: one bump a compact dispatch, ``rows`` the
+    chunks it placed (the mesh's size); ``shuffle.compact.by_order`` never."""
+    from cylon_tpu.obs import metrics as obs_metrics
+
+    rng = np.random.default_rng(47)
+    a, b = (
+        ct.Table.from_pydict(
+            ctx4,
+            {"k": rng.integers(0, 1000, N_ROWS).astype(np.int64),
+             name: rng.normal(size=N_ROWS)},
+        )
+        for name in ("v", "w")
+    )
+    budget = 4 * CAP_FOR_ROUNDS[4] * _sh.exchange_row_bytes(a._flat_cols())
+    run, dispatches = {
+        "sort": (lambda: a.distributed_sort("k"), 1),
+        "join": (lambda: a.distributed_join(b, on="k", how="inner"), 2),
+        "rounds": (lambda: a._shuffle_impl(
+            kind="hash", key_names=["k"], byte_budget=budget), 4),
+    }[op]
+    counters, compacts = _compact_counters(run)
+    assert compacts == dispatches
+    assert set(counters) == {"shuffle.compact.blocks"}
+    assert counters["shuffle.compact.blocks"]["count"] == dispatches
+    assert int(counters["shuffle.compact.blocks"]["rows"]) == 4 * dispatches
+    for name in ("shuffle.compact.blocks", "shuffle.compact.by_order"):
+        assert obs_metrics.is_declared(name)
+    assert "shuffle.compact." in obs_metrics.STABLE_METRICS
+
+
+def test_a_two_hop_receive_still_compacts_by_order(devices):
+    """The two-hop receive's chunks are not equal: it keeps the liveness
+    sort and the gathers, and says so."""
+    ctx = ct.CylonContext.init_distributed(
+        ct.TPUConfig(devices=devices[:8], mesh_shape="4x2")
+    )
+    rng = np.random.default_rng(48)
+    t = ct.Table.from_pydict(
+        ctx,
+        {"k": rng.integers(0, 1000, N_ROWS).astype(np.int64),
+         "v": rng.normal(size=N_ROWS)},
+    )
+    counters, compacts = _compact_counters(
+        lambda: t._shuffle_impl(kind="hash", key_names=["k"])
+    )
+    assert int(report("shuffle.")["shuffle.coll_bytes.inter"]["rows"]) > 0
+    assert set(counters) == {"shuffle.compact.by_order"}
+    assert counters["shuffle.compact.by_order"]["count"] == compacts == 1
+
+
+# ----------------------------------------------------------------------
 # the program: block writes, nothing addressed by the row
 # ----------------------------------------------------------------------
 

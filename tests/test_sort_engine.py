@@ -427,8 +427,10 @@ _SORT = re.compile(r"\ssort\(")
 
 #: sorts that lie outside the ``sort_engine`` scope today, by program and
 #: stage: ``sort_engine_ms`` does not count them (ROADMAP.md, named debt).
-#: Both are argsorts of a liveness flag: the shuffle's receive side packs
-#: its live rows to the front, the dense group-by its occupied slots.
+#: Both are argsorts of a liveness flag: a two-hop receive (and the ring
+#: relay) packs its live rows to the front, the dense group-by its occupied
+#: slots. A flat mesh's receive side sorts nothing since PR 47 (block
+#: writes: ``tests/test_shuffle_compact.py`` reads its program).
 OUTSIDE_THE_ENGINE = {
     ("shuffle_compact", stages.SHUFFLE_COMPACT),
     ("groupby_dense", stages.GROUPBY_DENSE_AGG),

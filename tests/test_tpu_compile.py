@@ -248,6 +248,53 @@ def test_reassembly_compiles_for_tpu_as_block_writes(one_chip):
 
 
 # ----------------------------------------------------------------------
+# (b3) the receive side of a one-hop round: four chunks of 2^19 slots of a
+# two-lane matrix and a float64 passthrough column (``join-skew-w4``'s and
+# ``h2o-q5-w4``'s 2^21 received slots)
+# ----------------------------------------------------------------------
+
+def test_one_hop_compact_compiles_for_tpu_as_block_writes(one_chip):
+    from cylon_tpu.obs import stages
+    from cylon_tpu.ops import gather as _g
+
+    bc = 1 << 19
+    plan = _g.lane_plan([
+        (_spec((8,), jnp.int64, one_chip), None),
+        (_spec((8,), jnp.float64, one_chip), None),
+    ])
+
+    def compact(head, pt):
+        lane_rows, recv_counts = _sh.split_header(head, WORLD)
+        return _sh.compact_received_lanes(
+            list(plan), lane_rows, {1: pt}, _sh.chunk_front(recv_counts)
+        )
+
+    compiled = _compile(
+        compact,
+        _spec((WORLD * (bc + _sh.HEADER_ROWS), 2), jnp.int32, one_chip),
+        _spec((WORLD * bc,), jnp.float64, one_chip),
+    )
+    text = compiled.as_text()
+    # nothing is sorted and nothing is addressed by the row
+    assert not re.search(r"\s(sort|scatter|gather)\(", text)
+    _module, rows = stages.parse_compiled(text)
+    # (the compiler's own loops for ``split_header``'s reshape of an odd
+    # row count write blocks too, under no name)
+    writes = [
+        op for t, op in rows
+        if " dynamic-update-slice(" in t and "dynamic_update_slice" in op
+    ]
+    # the lane matrix, and the float64 column's two 32-bit halves; the
+    # first chunk lies where it belongs
+    assert len(writes) == (1 + 2) * (WORLD - 1), len(writes)
+    assert all(
+        stages.stage_of(op) == stages.SHUFFLE_COMPACT for op in writes
+    )
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * mem.output_size_in_bytes
+
+
+# ----------------------------------------------------------------------
 # (c) the sort engine
 # ----------------------------------------------------------------------
 
