@@ -122,6 +122,14 @@ DIST_JOIN_PAYLOAD_COLLECTIVES = 2
 DIST_JOIN_SKETCH_COLLECTIVES = 1
 
 
+def replicate_join_collectives(lanes: int) -> int:
+    """The replicate route of a distributed join (PR 48) gathers the small
+    side by one ``all_gather`` a lane (a column's data, a validity lane
+    where it has one) and one of the shards' counts, in ONE program; the
+    big side crosses nothing, so no all_to_all is issued at all."""
+    return lanes + 1
+
+
 def shuffle_collectives(k: int) -> int:
     """A K-round chunked shuffle issues exactly K collectives: the count
     exchange rides the payload collective's header rows (PR 2)."""
@@ -651,6 +659,22 @@ CONTRACTS: Dict[str, CollectiveContract] = {
         all_gather=DIST_JOIN_SKETCH_COLLECTIVES,
         host_syncs=2 * SHUFFLE_HOST_SYNCS_PER_TABLE + 1,
         sync_sites=SHUFFLE_SYNC_SITES + ("join",),
+    ),
+    "dist_join_replicate": CollectiveContract(
+        name="dist_join_replicate",
+        description=(
+            "eager distributed join on the replicate route (one side at "
+            "most 1/REPLICATE_JOIN_MIN_RATIO of a chip's share of the "
+            "other): the small side's lanes and counts all_gathered in one "
+            "program (K passed as its lanes), NO all_to_all, no count "
+            "fetched to decide or to size (the host holds the counts), and "
+            "the ONE speculative-join stats fetch in Table.join"
+        ),
+        collectives=replicate_join_collectives,
+        all_to_all=0,
+        all_gather=replicate_join_collectives,
+        host_syncs=1,
+        sync_sites=("join",),
     ),
     "fused_join_step": CollectiveContract(
         name="fused_join_step",

@@ -15,6 +15,8 @@ Paths covered (the ISSUE-6 registry):
   (ISSUE 13): same collective/sync contract, quant gate engaged;
 - ``dist_join``        — eager distributed inner join, semi filter off;
 - ``dist_join_semi``   — selective pair, sketch all_gather engaged;
+- ``dist_join_replicate`` — a LEFT join against a side 1/250 the size:
+  the replicate route, one all_gather a lane, no all_to_all;
 - ``fused_join_step``  — the fully fused join program (jaxpr census);
 - ``q3_fused_step``    — the fused join->groupby-SUM (q3) program.
 
@@ -273,6 +275,40 @@ def run_dist_join_semi(ctx, rng) -> List[PlanResult]:
     return [res]
 
 
+def run_dist_join_replicate(ctx, rng) -> List[PlanResult]:
+    import cylon_tpu as ct
+    from ..utils.tracing import get_count
+
+    n = 8000
+    lt = ct.Table.from_pydict(
+        ctx,
+        {
+            "k": rng.integers(0, 48, n).astype(np.int32),
+            "v": rng.normal(size=n).astype(np.float32),
+        },
+    )
+    rt = ct.Table.from_pydict(
+        ctx,
+        {
+            "k": rng.permutation(64)[:32].astype(np.int32),
+            "w": rng.normal(size=32).astype(np.float32),
+        },
+    )
+    contract = CONTRACTS["dist_join_replicate"]
+
+    def op():
+        return lt.distributed_join(rt, on="k", how="left")
+
+    before = get_count("join.route.shuffle")
+    res = _measure(op, contract, 2)  # the small side's two lanes
+    if get_count("join.route.shuffle") != before:
+        res.violations.append(
+            "dist_join_replicate: the pair took the shuffle route — the "
+            "plan is not exercising the replicate route"
+        )
+    return [res]
+
+
 def _fused_step_census(ctx, make_step, respill: int, contract) -> PlanResult:
     import jax
     import jax.numpy as jnp
@@ -498,6 +534,7 @@ PLAN_RUNNERS = [
     run_shuffle_quant,
     run_dist_join,
     run_dist_join_semi,
+    run_dist_join_replicate,
     run_fused_join_step,
     run_q3_fused_step,
     run_shuffle_two_hop,

@@ -43,8 +43,19 @@ class Column:
         Strings/objects are dictionary-encoded with a *sorted* dictionary
         (np.unique) so code comparisons are order-equivalent to value
         comparisons. NaN / None / NaT become nulls.
+
+        A column that is dictionary-coded already comes in as its int32
+        codes, the array's dtype carrying the dictionary:
+        ``np.dtype(np.int32, metadata={"dictionary": <sorted unicode
+        array>})``, the numpy spelling of a pandas Categorical or an Arrow
+        DictionaryArray. The codes are loaded as they are and no string is
+        made, sorted or hashed (1e8 rows of a key of 1e8 values load as an
+        integer column does); :func:`_encode_coded` checks what it takes.
         """
         values = np.asarray(values)
+        coded = (values.dtype.metadata or {}).get("dictionary")
+        if coded is not None:
+            return _encode_coded(values, coded)
         if values.dtype == np.dtype("U1"):
             # one-character strings (a flag column) hold no None, NaN or
             # bool, and a value sorts as its code point: no object pass and
@@ -211,6 +222,26 @@ def unify_dictionaries(a: Column, b: Column) -> tuple[np.ndarray, np.ndarray, np
     map_a = np.searchsorted(union, a.dictionary).astype(np.int32)
     map_b = np.searchsorted(union, b.dictionary).astype(np.int32)
     return union, map_a, map_b
+
+
+def _encode_coded(values: np.ndarray, dictionary):
+    """``encode_host`` of int32 codes whose dtype carries their dictionary:
+    a unicode array, sorted and without repeats (code order is value order
+    everywhere a dictionary column is sorted, compared or range-partitioned),
+    every code inside it. One pass over the codes and one over the
+    dictionary; neither is copied."""
+    dictionary = np.asarray(dictionary)
+    if values.dtype != np.int32 or values.ndim != 1:
+        raise ValueError("dictionary codes are a one-dimensional int32 array")
+    if dictionary.dtype.kind != "U" or dictionary.ndim != 1:
+        raise ValueError("a dictionary is a one-dimensional unicode array")
+    if len(dictionary) > 1 and not (dictionary[:-1] < dictionary[1:]).all():
+        raise ValueError("a dictionary is sorted and holds a value once")
+    if len(values) and not (
+        0 <= int(values.min()) and int(values.max()) < len(dictionary)
+    ):
+        raise ValueError("a dictionary code lies outside its dictionary")
+    return values.view(np.int32), None, DataType(Type.STRING), dictionary
 
 
 def _encode_fixed_width(values: np.ndarray, block: int = 1 << 18):

@@ -84,6 +84,30 @@ DEFAULT_SKETCH_BITS = 1 << 21
 # could save.
 SEMI_FILTER_MIN_PAYOFF = 2
 
+# ----------------------------------------------------------------------
+# distributed join: which route (ops/join.replicate_side;
+# table.Table.distributed_join; plan/rules._physicalize)
+# ----------------------------------------------------------------------
+# A distributed join replicates one side to every chip, and leaves the
+# other side where it lies, where the WHOLE of that side (rows x the bytes
+# a row takes on the device) is at most one part in this many of what ONE
+# chip holds of the other side (its rows x row bytes / world); anything
+# else hash-shuffles both sides. Why a share of a chip's part and not of
+# the table: after the gather every chip's local join has the whole small
+# side as its build side, so the route's cost on a chip is the local join
+# of its own part (which the shuffle route pays as well, after packing,
+# exchanging and compacting that part) plus sorting and gathering the
+# small side whole. At 1/16 that addition is under a sixteenth of the
+# rows the chip sorts anyway and cannot change the result's capacity
+# (round_cap(max(cap_l, cap_r))), so the route can only lose what the
+# all_gather itself costs. On four chips the line is a table ratio of
+# 64:1, on eight of 128:1: the H2O join task's medium (1,000:1) and small
+# (1,000,000:1) tables replicate; an equal pair (join-w4, H2O's question 5)
+# and the 16:1 foreign-key pair of join-skew-w4 (4:1 a chip) shuffle as
+# they did. Where between 16:1 and 1,000:1 the true crossover lies has
+# not been measured (PERF.md section 7): the constant is the cautious end.
+REPLICATE_JOIN_MIN_RATIO = 16
+
 
 def sketch_bits(configured: Optional[object] = None) -> int:
     """Resolve the semi-join sketch bit cap: an explicit value wins, then

@@ -372,6 +372,23 @@ STABLE_METRICS: Dict[str, Tuple[str, str]] = {
         "fetch) + the emit census as counters, from numbers the host "
         "holds: emit_slots (rows= slots an emit wrote) and emit_rows "
         "(rows= rows live in them)"),
+    "join.route.replicate": (
+        "counter", "distributed joins on a mesh that took the replicate "
+        "route: one side gathered whole to every chip, the other not moved "
+        "(Table.distributed_join and the planner's Join alike; rows= both "
+        "sides' host-known rows)"),
+    "join.route.shuffle": (
+        "counter", "distributed joins on a mesh that hash-shuffled both "
+        "sides (rows= both sides' rows as far as the host holds them; "
+        "join.replicate.rows and shuffle.coll_rows over the two routes' "
+        "rows is the share of a join's input that crossed the mesh)"),
+    "join.replicate": (
+        "span", "the replicate route's gather of the small side, at "
+        "dispatch (program join_replicate, device stage join.replicate; "
+        "rows= the side's rows; a host event inside a profiler session)"),
+    "join.replicate.rows": (
+        "counter", "rows those gathers brought to chips that did not hold "
+        "them (rows= the side's rows times the other chips)"),
     "join.semi_join": (
         "span", "a semi or anti join as an operator (Table.join how='semi' "
         "/ 'anti'): the keys-only program's dispatch and, where the rows "

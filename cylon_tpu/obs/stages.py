@@ -34,6 +34,10 @@ JOIN_PROBE = "join.probe"
 JOIN_EMIT = "join.emit"
 JOIN_SEMI = "join.semi"
 JOIN_SEMI_MASK = "join.semi_mask"
+#: the replicate route of a distributed join: the small side's live rows
+#: gathered to every chip and front-packed (``parallel/shuffle.
+#: replicate_cols``); the local join's own stages follow it
+JOIN_REPLICATE = "join.replicate"
 SORT_KEYS = "sort.keys"
 SORT_PERM = "sort.perm"
 SORT_TOPK = "sort.topk"
@@ -59,7 +63,7 @@ EXPR_EVAL = "expr.eval"
 
 VOCABULARY = (
     JOIN_KEY_IDS, JOIN_RIGHT_SORT, JOIN_PROBE, JOIN_EMIT, JOIN_SEMI,
-    JOIN_SEMI_MASK,
+    JOIN_SEMI_MASK, JOIN_REPLICATE,
     SORT_KEYS, SORT_PERM, SORT_TOPK, SORT_ENGINE,
     SHUFFLE_COUNT, SHUFFLE_PACK, SHUFFLE_ALL_TO_ALL, SHUFFLE_COMPACT,
     SHUFFLE_REASSEMBLE,

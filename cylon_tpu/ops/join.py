@@ -21,7 +21,7 @@ only host round-trip.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Sequence, Tuple
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -259,6 +259,44 @@ def join_type_id(how: str) -> int:
         return _JOIN_TYPES[how.replace("-", "_").lower()]
     except KeyError:
         raise ValueError(f"unknown join type {how!r}") from None
+
+
+#: the sides a distributed join may hold WHOLE on every chip, by join type
+#: and by nothing else. A replicated side's row meets every chip's part of
+#: the other side, so a row of it that finds no partner would come out
+#: once a chip: only a side whose unmatched rows emit nothing may be
+#: replicated. The right side of inner, left, semi and anti; the left side
+#: of inner and right; neither side of a full outer join.
+REPLICABLE_SIDES = {
+    INNER: ("right", "left"), LEFT: ("right",), RIGHT: ("left",),
+    SEMI: ("right",), ANTI: ("right",), FULL_OUTER: (),
+}
+
+
+def replicate_side(how: str, left, right, world: int) -> Optional[str]:
+    """The route of a distributed join over ``world`` chips: ``"right"`` or
+    ``"left"``, the side that is gathered whole to every chip while the
+    other stays where it lies, or ``None``: both sides are hash-shuffled.
+
+    ``left`` / ``right`` are ``(rows, row_bytes)`` of a side as the host
+    holds them, or ``None`` where its row count is not host-known: then
+    the answer is ``None`` and nothing is fetched to decide. A side is
+    replicated where the join type allows it (:data:`REPLICABLE_SIDES`)
+    and the whole of it is at most one part in
+    ``config.REPLICATE_JOIN_MIN_RATIO`` of one chip's share of the other
+    side; the right side is asked first. The one copy of the rule:
+    ``Table.distributed_join`` and the planner's physicalize pass
+    (``plan.nodes.Join.route``) both ask here."""
+    from ..config import REPLICATE_JOIN_MIN_RATIO
+
+    if world <= 1 or left is None or right is None:
+        return None
+    size = {"left": left[0] * left[1], "right": right[0] * right[1]}
+    for side in REPLICABLE_SIDES[join_type_id(how)]:
+        other = "left" if side == "right" else "right"
+        if size[side] * REPLICATE_JOIN_MIN_RATIO * world <= size[other]:
+            return side
+    return None
 
 
 class _Probe(NamedTuple):
@@ -628,7 +666,10 @@ def _emit_inner_left(
     ``mask_free`` (INNER only; the join of semi-reduced sides asks for it):
     every output row has a row on both sides, so a column that had no
     validity lane gets none (``pack_gather(all_valid=True)``) and a
-    following join on it keeps the single-lane key path."""
+    following join on it keeps the single-lane key path. A LEFT join's
+    LEFT columns get none either: every row it emits repeats a left row
+    (the -1 positions all lie past the total), so its nulls are the right
+    side's alone."""
     mask_free = mask_free and how == INNER
     if emit_impl.startswith("windowed"):
         # VMEM gate: lanes = data lanes (2 for 64-bit) + validity lanes +
@@ -663,7 +704,8 @@ def _emit_inner_left(
         out_pos = jnp.arange(cap_out, dtype=jnp.int32)
         li = jnp.where(out_pos < total_l, li, -1)
         out_l, (base_g, cnt_g) = pack_gather(
-            l_cols, li, extra_lanes=[base, cnt], all_valid=mask_free
+            l_cols, li, extra_lanes=[base, cnt],
+            all_valid=mask_free or how == LEFT,
         )
 
         has_match = (li >= 0) & (cnt_g > 0)

@@ -822,6 +822,34 @@ def chunk_front(recv_counts: jax.Array):
     return lambda x: front_pack_chunks(x, recv_counts)
 
 
+def replicate_cols(cols, count: jax.Array, axis_name: str, out_cap: int):
+    """Every shard's live rows of ``cols`` (``(data, valid | None)`` pairs
+    of ``cap`` slots, the first ``count`` live), on every shard: what the
+    replicate route of a distributed join gathers of its small side
+    (``Table._replicated``). One ``all_gather`` a lane and one of the
+    counts, then :func:`front_pack_chunks`: the gathered buffer IS ``P``
+    equal chunks with a live prefix each, so the rows reach the front
+    shard by shard, each shard's in its own order, by ``P - 1`` block
+    writes. A float64 lane crosses as the two 32-bit halves the chip holds
+    it in (the collective moves bits and adds nothing, as
+    ``ops/groupby.dense_combine``'s gather of its partials). The result is
+    cut (or padded) to ``out_cap`` slots, which must hold the total.
+    Stage ``join.replicate``."""
+    with jax.named_scope(_stages.JOIN_REPLICATE):
+        counts = jax.lax.all_gather(count, axis_name).reshape(-1)
+
+        def whole(x):
+            every = jax.lax.all_gather(x, axis_name)  # [P, cap, ...]
+            flat = every.reshape((-1,) + every.shape[2:])
+            flat = front_pack_chunks(flat, counts)
+            if out_cap <= flat.shape[0]:
+                return flat[:out_cap]
+            pad = [(0, out_cap - flat.shape[0])] + [(0, 0)] * (flat.ndim - 1)
+            return jnp.pad(flat, pad)
+
+        return [(whole(d), None if v is None else whole(v)) for d, v in cols]
+
+
 def order_front(mask: jax.Array):
     """``front(x)`` of a general liveness ``mask``: one stable argsort of
     the mask and a per-element gather an array. What the receives whose

@@ -85,6 +85,7 @@ def detach_scans(root: Node) -> Node:
             stub.table_ordering = n.ordering()  # frozen compile-time claim
             stub.table_stats = dict(n.col_stats())  # frozen likewise
             stub.table_stream_gen = n.stream_gen()  # frozen likewise
+            stub.table_host_rows = n.host_rows()  # frozen likewise
             out: Node = stub
         elif n.children:
             out = n.with_children([walk(c) for c in n.children])
@@ -123,12 +124,18 @@ def _prepare_join_inputs(
     interleaved round dispatch (table._shuffle_pair) — the lazy path picks
     up the same overlap, byte-budget, and semi-join sketch-filter plumbing
     as the eager join (``semi`` = the node's semi_filter annotation)."""
-    from ..table import _promote_key_pair, _shuffle_pair, _unify_dict_pair
+    from ..table import (
+        _bump_join_route,
+        _promote_key_pair,
+        _shuffle_pair,
+        _unify_dict_pair,
+    )
 
     lt, rt = _unify_dict_pair(lt, rt, l_keys, r_keys)
     lt, rt = _promote_key_pair(lt, rt, l_keys, r_keys)
     if lt.world_size > 1:
         if l_shuf and r_shuf:
+            _bump_join_route("shuffle", lt, rt)
             lt, rt = _shuffle_pair(
                 lt, l_keys, rt, r_keys, semi=_SEMI_SIDES.get(semi)
             )
@@ -366,6 +373,21 @@ def _lower_one(node: Node, ex, tables):
         lt = lt.rename({n: node.l_rename[n] for n in lt.column_names})
         rt = rt.rename({n: node.r_rename[n] for n in rt.column_names})
         l_keys, r_keys = list(node.l_key_out), list(node.r_key_out)
+        if node.route is not None and lt.world_size > 1:
+            # the replicate route (rules._physicalize put no Shuffle under
+            # this join): the same table call as the eager join's. A side
+            # under a Filter has no host-known count and takes the shuffle
+            # route, so no mask ever rides this one
+            assert l_mask is None and r_mask is None
+            if node.hit_mask:  # semi_as_mask: the verdicts, nothing moved
+                return _MaskedRows(*lt._join_replicated(
+                    rt, node.route, l_keys, r_keys, node.how, as_mask=True,
+                ))
+            return lt._join_replicated(
+                rt, node.route, l_keys, r_keys, node.how,
+                suffixes=node.suffixes,
+                emit_order="key" if node.emit_key_order else "left",
+            )
         lt, rt = _prepare_join_inputs(
             lt, rt, l_keys, r_keys, l_shuf, r_shuf, semi=node.semi_filter
         )

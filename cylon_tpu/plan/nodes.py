@@ -15,6 +15,8 @@ from __future__ import annotations
 
 from typing import Dict, FrozenSet, List, Optional, Sequence, Tuple
 
+import numpy as np
+
 from .. import ordering as _ord
 from ..ordering import Ordering
 
@@ -74,6 +76,19 @@ class Node:
         quantized widths per node."""
         return {}
 
+    def host_rows(self) -> Optional[Tuple[int, int]]:
+        """``(rows, world size)`` where the node's row count is known on
+        the host before anything runs: a Scan of a table whose counts the
+        host holds, and the nodes that keep every row of one (Project,
+        WithColumns). ``None`` anywhere else, a Filter's output included:
+        what ``Join.pick_route`` weighs, and it fetches nothing."""
+        return None
+
+    def row_bytes(self) -> int:
+        """Bytes a row of the node's output takes on the device, by its
+        schema's physical dtypes (validity lanes left out)."""
+        return sum(np.dtype(p).itemsize for _n, _t, p in self.schema)
+
     def _params(self) -> tuple:
         """Node-local fingerprint parameters (no children, no schema —
         schema is derived and scans carry theirs explicitly)."""
@@ -131,6 +146,7 @@ class Scan(Node):
         self.table_ordering: Optional[Ordering] = None
         self.table_stats: Dict[str, object] = {}
         self.table_stream_gen = None
+        self.table_host_rows: Optional[Tuple[int, int]] = None
         self.schema = tuple(
             (n, int(table._columns[n].dtype.type), str(table._columns[n].data.dtype))
             for n in table.column_names
@@ -158,6 +174,12 @@ class Scan(Node):
             if c.dtype.is_dictionary and n not in stats:
                 stats[n] = dictionary_stat(len(c.dictionary))
         return stats
+
+    def host_rows(self) -> Optional[Tuple[int, int]]:
+        if self.table is None:  # detached stub
+            return self.table_host_rows
+        rows = self.table._rows_hint()  # never a fetch
+        return None if rows is None else (rows, self.table.world_size)
 
     def stream_gen(self):
         """The bound table's streaming identity ``(source_token,
@@ -199,6 +221,9 @@ class Project(Node):
 
     def with_children(self, kids):
         return Project(kids[0], self.cols)
+
+    def host_rows(self) -> Optional[Tuple[int, int]]:
+        return self.children[0].host_rows()
 
     def partitioning(self) -> Partitioning:
         kept = set(self.cols)
@@ -287,6 +312,9 @@ class WithColumns(Node):
     def with_children(self, kids):
         return WithColumns(kids[0], self.exprs)
 
+    def host_rows(self) -> Optional[Tuple[int, int]]:
+        return self.children[0].host_rows()
+
     def partitioning(self) -> Partitioning:
         return [
             s for s in self.children[0].partitioning()
@@ -344,11 +372,19 @@ class Join(Node):
             None, None,
         ),
         hit_mask: bool = False,
+        route: Optional[str] = None,
     ):
         self.children = (left, right)
         self.l_on = tuple(l_on)
         self.r_on = tuple(r_on)
         self.how = _SEMI_SPELLINGS.get(how.replace("-", "_").lower(), how)
+        # set by the physicalize pass on a mesh: the side ("right" /
+        # "left") this join gathers whole to every chip while the other
+        # stays where it lies (``Table.distributed_join``'s replicate
+        # route, by the same rule: :meth:`pick_route`). Such a join gets
+        # no Shuffle under it and claims no placement; None: both sides
+        # are hash-shuffled, or already lie right
+        self.route = route
         # set by the semi_as_mask rewrite on a semi or anti join directly
         # under an aggregate: the join compacts nothing and hands its hit
         # mask, over the left side's rows where they lie, to the aggregate
@@ -398,7 +434,7 @@ class Join(Node):
             "emit_key_order": self.emit_key_order,
             "semi_filter": self.semi_filter,
             "masks": self.masks, "keep": self.keep,
-            "hit_mask": self.hit_mask,
+            "hit_mask": self.hit_mask, "route": self.route,
         }
         notes.update(changes)
         return Join(
@@ -414,8 +450,33 @@ class Join(Node):
     def r_key_out(self) -> Tuple[str, ...]:
         return tuple(self.r_rename[n] for n in self.r_on)
 
+    def pick_route(self) -> Optional[str]:
+        """The side the join would replicate over its mesh, by
+        ``ops.join.replicate_side`` over what the host holds of both
+        inputs (:meth:`Node.host_rows`; a semi or anti join ships the
+        right side's keys alone, as the eager join projects them first).
+        Part of the plan's identity (:meth:`_params`): an executor built
+        for one route never serves a pair of tables that takes the other."""
+        from ..ops.join import replicate_side
+
+        sizes = []
+        for side, child in enumerate(self.children):
+            got = child.host_rows()
+            if got is None:
+                return None
+            if side == 1 and self.how in SEMI_HOWS:
+                phys = {n: p for n, _t, p in child.schema}
+                width = sum(np.dtype(phys[n]).itemsize for n in self.r_on)
+            else:
+                width = child.row_bytes()
+            sizes.append((got[0], width))
+            world = got[1]
+        return replicate_side(self.how, sizes[0], sizes[1], world)
+
     def partitioning(self) -> Partitioning:
         left, right = self.children
+        if self.route is not None:
+            return []  # the big side's rows stay wherever they lay
         if self.how in SEMI_HOWS:
             return left.partitioning()  # a subset of its rows, where they lie
         l_ok = _placed_by(left.partitioning(), self.l_on)
@@ -442,6 +503,10 @@ class Join(Node):
                 nulls_last=True, scope="shard", canonical=True,
                 lexsort_exact=False,
             )
+        if self.route == "left":
+            # the join runs from the big side, the right one: ITS rows
+            # repeat in their order (Table._join_replicated)
+            return _ord.rename(self.children[1].ordering(), self.r_rename)
         if self.how in ("inner", "left") + SEMI_HOWS:
             # the emit repeats left rows in left order (semi, anti: keeps
             # some of them): the left input's descriptor survives, under
@@ -472,12 +537,17 @@ class Join(Node):
             tuple(sorted(self.r_rename.items())),
             self.emit_key_order, self.semi_filter,
             tuple(None if m is None else m.key() for m in self.masks),
-            self.keep, self.hit_mask,
+            self.keep, self.hit_mask, self.route or self.pick_route(),
         )
 
     def label(self) -> str:
         keys = ", ".join(f"{a}={b}" for a, b in zip(self.l_on, self.r_on))
         tail = " emit=key-order" if self.emit_key_order else ""
+        if self.route:
+            tail += (
+                f" route=replicate-{self.route} [the {self.route} side"
+                " gathered to every chip, the other not moved]"
+            )
         if self.semi_filter:
             tail += f" semi-filter={self.semi_filter}"
         for side, m in zip(("left", "right"), self.masks):

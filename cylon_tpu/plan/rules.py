@@ -14,7 +14,15 @@ the rewritten plan plus the ordered list of rule firings (surfaced by
    ABOVE the pushed filters, so filters also shrink every exchange);
 2. physicalize — insert the Shuffle nodes distribution requires (hash
    shuffles under joins/groupbys/unions, a range shuffle under a global
-   sort); mesh of 1 inserts nothing;
+   sort); mesh of 1 inserts nothing. ``join_replicate``: a Join whose
+   inputs' row counts the host holds and one of whose sides is small
+   enough to hold whole on every chip (``Join.pick_route``, the eager
+   ``Table.distributed_join``'s own rule, ``ops.join.replicate_side``)
+   gets NO Shuffle and is marked ``route``: lowering gathers that side
+   and joins where the other side's rows lie. The join then claims no
+   placement (a GroupBy above it keeps its Shuffle), and the rules below
+   that read two Shuffles under a distributed join (``shuffle_elimination``,
+   ``semi_filter``) and the fused join->groupby decline it;
 3. ``shuffle_elimination`` — drop a Shuffle whose input is already placed
    right: a groupby only needs its keys CO-LOCATED (a subset placement
    suffices), while a join/union input must be placed by EXACTLY the same
@@ -101,6 +109,7 @@ TOPK = "topk"
 JOIN_MASK = "join_mask"
 PARTIAL_AGGREGATE = "partial_aggregate"
 SEMI_AS_MASK = "semi_as_mask"
+JOIN_REPLICATE = "join_replicate"
 
 
 def optimize(root: Node, world_size: int) -> Tuple[Node, List[str]]:
@@ -108,7 +117,7 @@ def optimize(root: Node, world_size: int) -> Tuple[Node, List[str]]:
     root = _make_topk(root, fired)
     root = _push_filters(root, fired)
     if world_size > 1:
-        root = _physicalize(root)
+        root = _physicalize(root, fired)
     root = _eliminate_shuffles(root, fired)
     if world_size > 1:
         root = _partial_aggregates(root, fired)
@@ -201,9 +210,19 @@ def _push_filters(node: Node, fired: List[str]) -> Node:
 # ----------------------------------------------------------------------
 # 2. physicalize: insert the shuffles distribution requires
 # ----------------------------------------------------------------------
-def _physicalize(node: Node) -> Node:
-    kids = [_physicalize(c) for c in node.children]
+def _physicalize(node: Node, fired: List[str]) -> Node:
+    kids = [_physicalize(c, fired) for c in node.children]
     if isinstance(node, Join):
+        # the inputs' host-known sizes are the same above and below this
+        # pass: it only puts Shuffles under nodes whose counts are unknown
+        side = node.pick_route()
+        if side is not None:
+            # the replicate route: no Shuffle under either side, and every
+            # rule that reads two Shuffles under a distributed join finds
+            # none here (shuffle_elimination, semi_filter, the fused
+            # join->groupby, which stands over a placed join alone)
+            fired.append(JOIN_REPLICATE)
+            return node.replaced(kids, route=side)
         kids = [
             Shuffle(kids[0], node.l_on, "hash"),
             Shuffle(kids[1], node.r_on, "hash"),
@@ -329,6 +348,8 @@ def _fuse_join_groupby(node: Node, fired: List[str]) -> Node:
     join = node.children[0]
     if not isinstance(join, Join) or join.how != "inner":
         return node
+    if join.route is not None:
+        return node  # the fused kernel needs both sides placed by the key
     if len(node.aggs) != 1 or node.aggs[0][1] != "sum":
         return node
     val_out, _ = node.aggs[0]

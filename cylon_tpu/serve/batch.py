@@ -80,6 +80,9 @@ def _qid_scan_stub(scan: Scan) -> Scan:
     stub.ordinal = scan.ordinal
     stub.table_ordering = None
     stub.table_stats = {}
+    # the stacked table's counts are still on the device: a join over it
+    # takes the shuffle route (Join.pick_route)
+    stub.table_host_rows = None
     stub.schema = tuple(scan.schema) + ((QID, int(Type.INT32), "int32"),)
     return stub
 

@@ -2295,9 +2295,47 @@ class Table:
         **kwargs,
     ) -> "Table":
         """The flagship op (reference DistributedJoin, table.cpp:482-502):
-        hash-shuffle both tables on the join keys over the mesh, then local
-        join per shard. world_size==1 short-circuits to the local join
-        (reference :487-489).
+        both tables' rows that share a key are brought to one chip, then
+        every shard runs the local :meth:`join`. world_size==1
+        short-circuits to the local join (reference :487-489). On a mesh
+        an eager join takes one of two routes, and the rule that picks is
+        ``ops.join.replicate_side`` (no keyword, variable or tuned
+        decision moves it):
+
+        - **shuffle**: both sides are hash-shuffled on the join keys over
+          the mesh (one engine call, the two tables' rounds interleaved,
+          the semi-join sketch filter where the type allows it), then the
+          local join of what each shard received. The result lies where
+          the hash put its keys.
+        - **replicate**: the small side's live rows are gathered to every
+          chip by one program (``join_replicate``: an ``all_gather`` of
+          its columns and counts, front-packed to ``round_cap`` of the
+          total; nothing is fetched, the counts are the host's already),
+          the big side stays where it lies, and every chip joins its own
+          shard against the whole small table. The result is sharded
+          exactly as the big side was: each chip's rows in the big side's
+          row order, its ordering descriptor carried as the local join
+          carries the left side's, ONE copy of the result over the mesh.
+          The gathered columns never leave this call: no table whose
+          shards each hold every row reaches an operation that would
+          count it once a chip.
+
+        Which side MAY be replicated follows from the join type alone
+        (``ops.join.REPLICABLE_SIDES``): the right side of ``inner``,
+        ``left``, ``semi``, ``anti``; the left side of ``inner``,
+        ``right`` (the join is then run from the big side, ``right`` as a
+        left join, and the columns put back in this order); neither side
+        of ``outer``, since an unmatched row of a replicated side would
+        come out once a chip. Whether it IS follows from what the host
+        already holds, each side's row count and the bytes a row takes on
+        the device: a side is replicated where the whole of it is at most
+        one part in ``config.REPLICATE_JOIN_MIN_RATIO`` of one chip's
+        share of the other (64:1 between the tables on four chips). A
+        side whose count is still on the device takes the shuffle route
+        and nothing is fetched to decide. ``join.route.replicate`` /
+        ``join.route.shuffle`` count the routes (``rows=`` both sides'
+        rows), ``join.replicate.rows`` the rows the gather brought to
+        chips that did not hold them.
 
         ``mode='fused'`` runs the whole shuffle->join chain as ONE compiled
         XLA program with static capacities and a single host sync (the
@@ -2347,6 +2385,17 @@ class Table:
             # a semi or anti join reads the right side's keys alone: no
             # other column of it is packed, exchanged or compacted
             other = other.project(r_names)
+        side = _j.replicate_side(
+            kwargs["how"], self._host_size(), other._host_size(),
+            self.world_size,
+        )
+        if side is not None:
+            for k in ("on", "left_on", "right_on"):
+                kwargs.pop(k, None)
+            return self._join_replicated(
+                other, side, l_names, r_names, **kwargs
+            )
+        _bump_join_route("shuffle", self, other)
         left, right = _unify_dict_pair(self, other, l_names, r_names)
         # promote key dtype pairs BEFORE hashing: the shuffle hashes each side
         # independently, and murmur words depend on the physical dtype — an
@@ -2364,6 +2413,97 @@ class Table:
             semi=_sketch.join_filter_sides(kwargs.get("how", "inner")),
         )
         return ls.join(rs, **kwargs)
+
+    def _host_size(self) -> Optional[Tuple[int, int]]:
+        """``(rows, bytes a row takes on the device)`` as the host holds
+        them, ``None`` while the row count is still on the device: what
+        ``ops.join.replicate_side`` weighs. The validity lanes are left
+        out, as the planner's schema has none (``plan.nodes``)."""
+        if self._counts_host is None:
+            return None
+        return (
+            int(self._counts_host.sum()),
+            sum(c.data.dtype.itemsize for c in self._columns.values()),
+        )
+
+    def _replicated(self) -> "Table":
+        """This table's live rows on EVERY shard, front-packed at
+        ``round_cap`` of the total, each shard's count the total: the
+        small side of a distributed join's replicate route. One program
+        (``join_replicate``, ``parallel/shuffle.replicate_cols``) and no
+        fetch. PRIVATE to that route: every other operation would read
+        such a table as ``world`` copies of its rows."""
+        total = int(self._row_counts.sum())
+        cap = round_cap(total)
+        flat = self._flat_cols()
+        axis = self.ctx.axis_name
+
+        def build():
+            def kern(dp, rep):
+                (cols, n) = dp
+                (dummy,) = rep
+                return _sh.replicate_cols(cols, n, axis, dummy.shape[0])
+
+            return kern
+
+        world = self.world_size
+        bump("join.replicate.rows", rows=total * (world - 1))
+        with span("join.replicate", rows=total):
+            out = get_kernel(self.ctx, ("join_replicate", len(flat)), build)(
+                (flat, self.counts_dev), (jnp.zeros((cap,), jnp.int8),)
+            )
+        # a row's values are what they were: the range stats hold; the
+        # shards' runs end to end are in no order
+        return self._rebuild_cols(
+            list(zip(self.column_names, self._columns.values())),
+            out, np.full(world, total, np.int64), cap,
+        )._attach_stats(self._stats)
+
+    def _join_replicated(
+        self, other: "Table", side: str, l_names, r_names, how: str,
+        suffixes: Tuple[str, str] = ("_x", "_y"), as_mask: bool = False,
+        **kwargs,
+    ) -> "Table":
+        """The replicate route of :meth:`distributed_join`: ``side``
+        (``"right"``: ``other``; ``"left"``: this table) is gathered whole
+        to every chip and every chip joins its own shard of the big side
+        against it. The local join always runs FROM the big side, so the
+        result keeps the big side's shards, row order and ordering
+        descriptor: with the left side replicated the sides are swapped
+        (``right`` becomes ``left``, ``inner`` stays; the types that could
+        not be swapped may not replicate their left side) and the columns
+        are put back left first under the names the unswapped join gives
+        them.
+
+        A semi or anti join ships the right side's keys alone. ``as_mask``
+        (the planner's ``semi_as_mask`` over such a join): nothing is
+        compacted, ``(self, mask)`` comes back as from :meth:`_semi_join`."""
+        howi = _j.join_type_id(how)
+        if howi in _j.SEMI_TYPES:
+            other = other.project(r_names)
+        _bump_join_route("replicate", self, other)
+        if as_mask:
+            return self._semi_join(
+                other._replicated(), l_names, r_names, howi, None, None,
+                as_mask=True,
+            )
+        if side == "right":
+            return self.join(
+                other._replicated(), left_on=l_names, right_on=r_names,
+                how=how, suffixes=suffixes, **kwargs,
+            )
+        if howi == _j.RIGHT and kwargs.get("emit_order", "left") == "key":
+            raise ValueError(
+                "emit_order='key' needs how='inner'/'left' (the unmatched-"
+                "right append of right/outer joins has no key-ordered emit)"
+            )
+        res = other.join(
+            self._replicated(), left_on=r_names, right_on=l_names,
+            how="left" if howi == _j.RIGHT else how,
+            suffixes=(suffixes[1], suffixes[0]), **kwargs,
+        )
+        names = _suffix_names(self.column_names, other.column_names, suffixes)
+        return res.project(names)
 
     def _fused_join(
         self,
@@ -5458,6 +5598,16 @@ def _pair_sketches(
     if sides in ("both", "b"):
         probe["b"] = row_of["a"]
     return dict(sketch=gsk, probe=probe, use_range=use_range)
+
+
+def _bump_join_route(route: str, left: "Table", right: "Table") -> None:
+    """One bump a distributed join on a mesh, by the route it took
+    (``rows=`` both sides' rows as far as the host holds them: what
+    ``join.replicate.rows`` and ``shuffle.coll_rows`` are a share of)."""
+    bump(
+        "join.route." + route,
+        rows=(left._rows_hint() or 0) + (right._rows_hint() or 0),
+    )
 
 
 def _shuffle_pair(

@@ -297,6 +297,21 @@ def test_shuffle_contract_runtime(ctx8, rng):
         assert res.sync_sites == ["_shuffle_many", "_shuffle_many_rounds"]
 
 
+def test_replicate_join_contract_runtime(ctx8, rng):
+    """The replicate route of a distributed join: one all_gather a lane of
+    the small side and one of its counts, NO all_to_all, and the local
+    join's one fetch; the shuffle-route contracts stand as they were."""
+    from cylon_tpu.analysis import plans
+
+    assert contracts.replicate_join_collectives(2) == 3
+    assert contracts.CONTRACTS["dist_join"].all_to_all == 2
+    assert contracts.CONTRACTS["dist_join"].all_gather == 0
+    [res] = plans.run_dist_join_replicate(ctx8, rng)
+    assert res.violations == [], res.violations
+    assert res.census.counts.get("all_to_all", 0) == 0
+    assert res.sync_sites == ["join"]
+
+
 @pytest.mark.slow
 def test_full_plan_registry(ctx8, rng):
     """Every representative plan vs the contract table (CI runs this via

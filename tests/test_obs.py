@@ -288,6 +288,37 @@ def test_dispatch_observes_fingerprint_histogram(ctx8, rng, monkeypatch):
 # ----------------------------------------------------------------------
 # stable metric names
 # ----------------------------------------------------------------------
+def test_replicate_route_metrics_all_declared(ctx8, rng):
+    """What a distributed join on the replicate route emits, eager and
+    through the planner, is declared by its own name (not by the
+    ``join.`` family alone) and reads as the route's docstring says."""
+    import cylon_tpu as ct
+
+    n = 4096
+    big = ct.Table.from_pydict(ctx8, {
+        "k": rng.integers(0, 6, n).astype(np.int32), "v": rng.random(n),
+    })
+    small = ct.Table.from_pydict(ctx8, {
+        "k": np.arange(4, dtype=np.int32), "w": rng.random(4),
+    })
+    tracing.reset_trace()
+    big.distributed_join(small, on="k", how="left")
+    big.lazy().join(small.lazy(), on="k", how="left").collect()
+    rep = tracing.get_trace_report()
+    for name in (
+        "join.route.replicate", "join.replicate", "join.replicate.rows",
+    ):
+        assert name in obs_metrics.STABLE_METRICS, name
+        assert rep[name]["count"] == 2, name
+    assert "join.route.shuffle" in obs_metrics.STABLE_METRICS
+    assert "join.route.shuffle" not in rep
+    assert rep["join.replicate.rows"]["rows"] == 2 * 4 * 7
+    assert rep["join.route.replicate"]["rows"] == 2 * (n + 4)
+    assert not [k for k in rep if k.startswith("shuffle.")]
+    undeclared = [k for k in rep if not obs_metrics.is_declared(k)]
+    assert undeclared == [], undeclared
+
+
 def test_q3_metrics_all_declared(ctx8, rng):
     """Everything a q3 run (and a shuffle) emits into the rollup is
     covered by the documented stable-name table."""

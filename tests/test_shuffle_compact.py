@@ -474,3 +474,36 @@ def test_the_one_hop_compact_program_holds_no_sort_and_no_gather():
         assert named and all(
             stages.stage_of(op) == stages.SHUFFLE_COMPACT for op in named
         )
+
+
+# ----------------------------------------------------------------------
+# ROADMAP M17: the CPU mesh past 2^15-slot buckets
+# ----------------------------------------------------------------------
+@pytest.mark.slow
+@pytest.mark.xfail(
+    strict=False,
+    reason="ROADMAP M17: on XLA:CPU the one-hop compact's block writes, "
+    "each an in-place dynamic-update-slice whose source overlaps its "
+    "target, lose rows once a chunk is copied on several threads "
+    "(buckets of 2^15 slots and up; right with "
+    "--xla_cpu_multi_thread_eigen=false, and on the chip)",
+)
+def test_a_shuffle_of_400000_rows_over_four_shards_keeps_every_row():
+    """``Table.shuffle`` of an int32 key and a float64 value, 100,000 rows
+    a shard (buckets of 2^15 slots): the rows that come out are the rows
+    that went in. The witness of M17: it fails on the CPU backend since
+    PR 47 and no tier-1 test reaches the size."""
+    import cylon_tpu as ct
+
+    rows = 400_000
+    ctx = ct.CylonContext.init_distributed(
+        ct.TPUConfig(devices=jax.devices()[:4])
+    )
+    rng = np.random.default_rng(17)
+    k = rng.integers(0, rows, rows).astype(np.int32)
+    v = rng.random(rows)
+    got = ct.Table.from_numpy(ctx, ["k", "v"], [k, v]).shuffle(["k"]).to_pydict()
+    assert len(got["k"]) == rows
+    want, have = np.lexsort((v, k)), np.lexsort((got["v"], got["k"]))
+    np.testing.assert_array_equal(got["k"][have], k[want])
+    np.testing.assert_array_equal(got["v"][have], v[want])

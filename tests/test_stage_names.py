@@ -211,6 +211,8 @@ def test_stale_compiled_text_is_reported(how, monkeypatch):
     ("jit(shuffle_reassemble)/shard_map/shuffle.reassemble/dynamic_update_slice", "shuffle.reassemble", False),
     ("jit(groupby)/shard_map/groupby.partial/groupby.key_ids/sort_engine/sort", "groupby.partial", True),
     ("jit(groupby)/shard_map/groupby.merge/groupby.segment_sum/add", "groupby.merge", False),
+    ("jit(join_replicate)/shard_map/join.replicate/all_gather", "join.replicate", False),
+    ("jit(join_replicate)/shard_map/join.replicate/dynamic_update_slice", "join.replicate", False),
     ("jit(join_spec)/concatenate", None, False),
     ("", None, False),
 ])
@@ -220,7 +222,7 @@ def test_outermost_name_is_the_stage(path, stage, engine_in):
 
 
 def test_vocabulary_is_defined_once():
-    assert len(set(stages.VOCABULARY)) == len(stages.VOCABULARY) == 23
+    assert len(set(stages.VOCABULARY)) == len(stages.VOCABULARY) == 24
     constants = {
         v for k, v in vars(stages).items() if k.isupper() and isinstance(v, str)
     }
@@ -288,3 +290,31 @@ def test_span_and_fetch_are_host_events_in_a_profiler_session(tmp_path, local_ct
         for line in plane.lines for e in line.events
     }
     assert {"unit.stage_span", "host_sync.to_numpy"} <= names
+
+
+# -- (f) the replicate route of a distributed join ------------------------
+def test_replicate_route_dispatches_its_stage_and_no_shuffle():
+    """A LEFT join against a side 1/512 the size on four shards: the
+    program ``join_replicate`` names stage ``join.replicate`` on its
+    collectives and block writes, the local join's program follows, and
+    no shuffle program is dispatched at all."""
+    ctx = _ctx(4)
+    rng = np.random.default_rng(5)
+    big = {"k": rng.integers(0, 6, ROWS).astype(np.int32), "v": rng.random(ROWS)}
+    small = {"k": np.arange(4, dtype=np.int32), "w": rng.random(4)}
+    out = ct.Table.from_numpy(ctx, list(big), list(big.values())).distributed_join(
+        ct.Table.from_numpy(ctx, list(small), list(small.values())),
+        on="k", how="left",
+    )
+    assert out.row_count == ROWS
+    programs = {}
+    for _key, fn, spec in stages.dispatched_programs(ctx):
+        programs[fn.__name__] = stages._compiled_text(fn.lower(*spec))
+    assert {"join_replicate", "join_spec"} <= set(programs)
+    assert not [n for n in programs if n.startswith("shuffle_")]
+    staged = [
+        (opcode, stages.stage_of(path))
+        for opcode, path in _instructions(programs["join_replicate"])
+        if opcode in ("all-gather", "dynamic-update-slice")
+    ]
+    assert staged and all(s == stages.JOIN_REPLICATE for _o, s in staged), staged

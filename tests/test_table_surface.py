@@ -154,3 +154,48 @@ def test_join_config_object(local_ctx, rng):
         ct.JoinConfig("inner", algorithm="quantum")
     with pytest.raises(ValueError):
         ct.JoinConfig("sideways")
+
+
+# ----------------------------------------------------------------------
+# a column that arrives dictionary-coded (PR 48)
+# ----------------------------------------------------------------------
+def _coded(codes, words):
+    kind = np.dtype(np.int32, metadata={"dictionary": np.asarray(words)})
+    return np.asarray(codes, np.int32).astype(kind)
+
+
+def test_codes_whose_dtype_carries_their_dictionary_load_as_strings(world_ctx, rng):
+    """``np.dtype(int32, metadata={"dictionary": ...})``: the codes are
+    loaded as they are, over the dictionary as it is (values that occur or
+    not), and the column is a string column like any other: it decodes,
+    sorts by value and joins a column coded by the loader itself."""
+    words = np.array(["id1", "id10", "id2", "id3", "id7"])
+    codes = rng.integers(0, 4, 60)  # "id7" never occurs
+    t = ct.Table.from_numpy(
+        world_ctx, ["s", "v"], [_coded(codes, words), np.arange(60.0)]
+    )
+    col = t.column("s")
+    assert col.dtype.is_dictionary and col.dictionary is words
+    assert col.data.dtype == np.int32 and col.valid is None
+    got = t.to_pandas()
+    assert (got["s"].to_numpy() == words[codes]).all()
+    by_value = t.distributed_sort("s").to_pandas()["s"].to_numpy().astype(str)
+    assert (by_value == np.sort(words[codes])).all()
+    other = ct.Table.from_pandas(world_ctx, pd.DataFrame(
+        {"s": ["id2", "id7", "id9"], "w": [1.0, 2.0, 3.0]}
+    ))
+    join = t.distributed_join(other, on="s", how="inner")
+    want = got.merge(other.to_pandas(), on="s")
+    assert join.row_count == len(want) == int((codes == 2).sum())
+
+
+@pytest.mark.parametrize("codes,words,why", [
+    ([0, 1], ["b", "a"], "sorted"),
+    ([0, 1], ["a", "a"], "sorted"),
+    ([0, 2], ["a", "b"], "outside"),
+    ([-1, 0], ["a", "b"], "outside"),
+    ([0, 1], [1, 2], "unicode"),
+])
+def test_a_coded_column_is_checked_for_what_it_takes(local_ctx, codes, words, why):
+    with pytest.raises(ValueError, match=why):
+        ct.Table.from_numpy(local_ctx, ["s"], [_coded(codes, words)])

@@ -464,6 +464,107 @@ def test_skewed_joins_program_fits_the_chip_with_its_float64_packed(one_chip):
 
 
 # ----------------------------------------------------------------------
+# (c3) the replicate route of a distributed join, in ``h2o-join-q3-w4``'s
+# shapes: the gather of the small side over the four-chip mesh, and the
+# LEFT OUTER join of a shard of x against the whole of medium
+# ----------------------------------------------------------------------
+
+def _h2o_left_join(one_chip, cap_l, cap_r):
+    """``jit_join_spec`` of question 3's columns at the source's widths
+    (x: six int32 lanes, three ids and their factor twins' codes, and a
+    float64; medium: four int32 lanes and a float64), LEFT, on ``id2``."""
+
+    def join(l1, l2, l3, l4, l5, l6, lv, r1, r2, r4, r5, rv, nl, nr):
+        left = [(c, None) for c in (l1, l2, l3, l4, l5, l6, lv)]
+        right = [(c, None) for c in (r1, r2, r4, r5, rv)]
+        return _j.spec_join(
+            left[1:2], right[1:2], left, right, nl, nr, _j.LEFT, cap_l,
+        )
+
+    i32l = _spec((cap_l,), jnp.int32, one_chip)
+    i32r = _spec((cap_r,), jnp.int32, one_chip)
+    n = _spec((), jnp.int32, one_chip)
+    return _compile(
+        join, *[i32l] * 6, _spec((cap_l,), jnp.float64, one_chip),
+        *[i32r] * 4, _spec((cap_r,), jnp.float64, one_chip), n, n,
+    )
+
+
+def test_replicate_route_compiles_for_four_chips(mesh4):
+    """``join_replicate`` over the 2x2 mesh, medium's five columns: one
+    all-gather a lane (a float64 as the chip holds it) and one of the
+    counts, the chunks front-packed by block writes, every row-sized
+    operation under stage ``join.replicate``, and nothing gathers or
+    scatters a row on its own."""
+    from cylon_tpu.obs import stages
+
+    rows = NamedSharding(mesh4, PartitionSpec("dp"))
+    cap = ROWS >> 2
+
+    def kern(k1, k2, k4, k5, v, counts):
+        return _sh.replicate_cols(
+            [(c, None) for c in (k1, k2, k4, k5, v)], counts, "dp",
+            WORLD * cap,
+        )
+
+    step = jax.jit(jax.shard_map(
+        kern, mesh=mesh4, in_specs=PartitionSpec("dp"),
+        out_specs=PartitionSpec("dp"),
+    ))
+    compiled = step.lower(
+        *[_spec((WORLD * cap,), jnp.int32, rows)] * 4,
+        _spec((WORLD * cap,), jnp.float64, rows),
+        _spec((WORLD,), jnp.int32, rows),
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"\sall-gather(?:-start)?\(", text)) >= 2
+    assert "all-to-all" not in text
+    assert not re.search(r"\s(gather|scatter|sort)\(", text)
+    wide, rows_ = _wide_ops(compiled)
+    staged = [op for _text, op in rows_ if stages.JOIN_REPLICATE in op]
+    assert staged and all(
+        stages.stage_of(op) == stages.JOIN_REPLICATE for op in staged
+    )
+
+
+def test_left_outer_join_compiles_with_validity_on_the_right_alone(one_chip):
+    """Question 3's local join at the small shape (a build side 1/256 of
+    the probe side): the chip's compiler takes the LEFT emit, the right
+    side's five columns come out with a validity lane each and the left
+    side's seven with none."""
+    compiled = _h2o_left_join(one_chip, ROWS, ROWS >> 8)
+    out, _total, _shadow = compiled.out_info
+    assert [v is None for _d, v in out] == [True] * 7 + [False] * 5
+    assert all(v.shape == (ROWS,) and v.dtype == jnp.bool_
+               for _d, v in out[7:])
+    assert [d.dtype for d, _v in out] == (
+        [jnp.int32] * 6 + [jnp.float64] + [jnp.int32] * 4 + [jnp.float64])
+    # the probe's merged sort and the build side's ride sort; the emit's
+    # packed gathers write the output's slots
+    text = compiled.as_text()
+    assert len(re.findall(r"\ssort\(", text)) >= 2
+    wide, _rows = _wide_ops(compiled)
+    assert [shape for shape, opcode in wide if "gather" in opcode or opcode == "fusion"]
+
+
+@pytest.mark.slow  # a minute or two in this sandbox; the cell itself guards it
+@pytest.mark.limit(1800)
+def test_left_outer_join_fits_the_chip_at_the_cells_shapes(one_chip):
+    """At ``h2o-join-q3-w4``'s shard shapes (2^25 probe and output slots of
+    the source's seven and five columns, 2^17 build slots) the LEFT join's
+    program fits a v5e's 15.75 GB, x's 1.07 GB resident counted among its
+    arguments (PERF.md section 6, PR 48). On the chip the cell does not
+    compile where it does not fit."""
+    compiled = _h2o_left_join(one_chip, 1 << 25, 1 << 17)
+    mem = compiled.memory_analysis()
+    held = (
+        mem.temp_size_in_bytes + mem.argument_size_in_bytes
+        + mem.output_size_in_bytes
+    )
+    assert held < 15.75e9 * 0.9, held / 1e9
+
+
+# ----------------------------------------------------------------------
 # (d), (e) the local sort join at both dtype widths; (g) the distributed
 # join step on the four-chip mesh
 # ----------------------------------------------------------------------
