@@ -122,7 +122,7 @@ def main():
         # effort A/B re-uses the backend's executable cache (compile 0.0 s)
         # and measures nothing
         def full_join(a, b, v, w):
-            out, tot, _ = _j.spec_join(
+            out, tot, _, _ = _j.spec_join(
                 [(a, None)], [(b, None)],
                 [(a, None), (v, None)], [(b, None), (w, None)],
                 nl, nr, _j.INNER, cap_out,

@@ -168,7 +168,7 @@ def main():
     def run_join(emit_impl, tag):
         @jax.jit
         def f(a, b, v, w):
-            out, tot, _ = _j.spec_join(
+            out, tot, _, _ = _j.spec_join(
                 [(a, None)], [(b, None)],
                 [(a, None), (v, None)], [(b, None), (w, None)],
                 jnp.int32(n), jnp.int32(n), _j.INNER, cap_j, emit_impl,

@@ -98,7 +98,7 @@ def main():
 
         @jax.jit
         def f(a, b, v):
-            out, total, _ = _j.spec_join(
+            out, total, _, _ = _j.spec_join(
                 [(a, None)], [(b, None)],
                 [(a, None), (v, None)], [(b, None)],
                 jnp.int32(n), jnp.int32(n), _j.INNER, cap_j,

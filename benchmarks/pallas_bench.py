@@ -91,7 +91,7 @@ def main():
 
     @jax.jit
     def sort_join():
-        out, total, _shadow = _j.spec_join(
+        out, total, _shadow, _handed = _j.spec_join(
             [(lk, None)], [(rk, None)],
             [(lk, None)], [(rk, None)],
             nl, nr, _j.INNER, cap_out,

@@ -84,7 +84,7 @@ def main():
     stages["probe+repeat+lgather"] = jax.jit(_lg)
 
     def full(a, b, v, w):
-        out, total, shadow = _j.spec_join(
+        out, total, shadow, _handed = _j.spec_join(
             [(a, None)], [(b, None)],
             [(a, None), (v, None)], [(b, None), (w, None)],
             jnp.int32(n), jnp.int32(n), _j.INNER, cap,
@@ -106,7 +106,7 @@ def main():
 
         r_sorted, _ = pack_gather([(b, None), (w, None)], r_order)
         r_sorted = [(d, None) for d, _v in r_sorted]
-        out_cols, n_out = _j._emit_inner_left(
+        out_cols, n_out, _handed = _j._emit_inner_left(
             lo, cnt, [(a, None), (v, None)],
             r_sorted, jnp.int32(n), _j.INNER, cap, n, w_impl,
         )
@@ -115,7 +115,7 @@ def main():
     stages["probe+windowed_emit"] = jax.jit(_lw)
 
     def full_windowed(a, b, v, w):
-        out, total, shadow = _j.spec_join(
+        out, total, shadow, _handed = _j.spec_join(
             [(a, None)], [(b, None)],
             [(a, None), (v, None)], [(b, None), (w, None)],
             jnp.int32(n), jnp.int32(n), _j.INNER, cap, w_impl,

@@ -372,6 +372,14 @@ STABLE_METRICS: Dict[str, Tuple[str, str]] = {
         "fetch) + the emit census as counters, from numbers the host "
         "holds: emit_slots (rows= slots an emit wrote) and emit_rows "
         "(rows= rows live in them)"),
+    "join.emit.": (
+        "counter", "which form of its left half a speculative INNER/LEFT "
+        "emit took, a shard, read from the flag that rides the totals' "
+        "fetch (ops.join._emit_inner_left): .handthrough (every live left "
+        "row emitted exactly once, the left columns passed as they lie) "
+        "and .gathered (the run expansion and the packed left gather); "
+        "rows= the live output rows of the shards that took it. A counted "
+        "join fetches nothing and bumps neither"),
     "join.route.replicate": (
         "counter", "distributed joins on a mesh that took the replicate "
         "route: one side gathered whole to every chip, the other not moved "

@@ -40,9 +40,9 @@ import numpy as np
 from ..obs import stages as _stages
 from .factorize import factorize_runs
 from .sort import (
-    KeyCol, flatten_cols, fused_decodable, fused_key_decode, lexsort_indices,
-    orderable_key, run_reduce, scan_identity, step_compact, unflatten_cols,
-    wide_float, wide_int,
+    KeyCol, fit_slots, flatten_cols, fused_decodable, fused_key_decode,
+    lexsort_indices, orderable_key, run_reduce, scan_identity, step_compact,
+    unflatten_cols, wide_float, wide_int,
 )
 from .stats import decode_enc, wire_narrowable
 
@@ -71,13 +71,6 @@ def _masked(values: jax.Array, valid: Optional[jax.Array], fill) -> jax.Array:
     if valid is None:
         return values
     return jnp.where(valid, values, jnp.asarray(fill, values.dtype))
-
-
-def _fit(x: jax.Array, cap_out: int) -> jax.Array:
-    """The first ``cap_out`` slots of ``x``, zero-padded when it has fewer."""
-    if x.shape[0] >= cap_out:
-        return x[:cap_out]
-    return jnp.pad(x, (0, cap_out - x.shape[0]))
 
 
 def groupby_aggregate(
@@ -247,9 +240,9 @@ def _aggregate_runs(
     num_groups = jnp.sum(start, dtype=jnp.int32)
     # a group's rows lie between its first row and the next group's
     last = jnp.arange(cap, dtype=jnp.int32) == num_groups - 1
-    size = _fit(jnp.where(last, n, jnp.roll(first, -1)) - first, cap_out)
-    first = _fit(first, cap_out)
-    packed = [_fit(x, cap_out) for x in packed]
+    size = fit_slots(jnp.where(last, n, jnp.roll(first, -1)) - first, cap_out)
+    first = fit_slots(first, cap_out)
+    packed = [fit_slots(x, cap_out) for x in packed]
     slot = dict(zip(lanes, packed[len(carry):]))
     gmask = jnp.arange(cap_out, dtype=jnp.int32) < num_groups
 

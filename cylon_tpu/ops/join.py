@@ -29,7 +29,7 @@ import numpy as np
 
 from ..obs import stages as _stages
 from .factorize import factorize_two
-from .sort import KeyCol
+from .sort import KeyCol, fit_slots
 
 
 def _inv_perm(p: jax.Array) -> jax.Array:
@@ -547,16 +547,21 @@ def emit_gather(
     """Fused emit + payload gather: produce the joined output columns with a
     minimal number of XLA gathers (the TPU bottleneck — see ops/gather.py).
 
-    INNER/LEFT fast path does exactly three big row-addressed operations:
-    the ``jnp.repeat`` for li, one packed left-row gather (payload +
-    base/cnt lanes), and one packed right-row gather against the
-    r_order-permuted right payload (see :func:`_emit_inner_left`). A float64
-    payload is two lanes of those gathers and no gather of its own: for an
-    int64 key and a float64 value a side the two are ``s32[cap_out, 6]``
-    (key 2, base, cnt, value 2) and ``s32[cap_out, 4]`` (key 2, value 2),
-    and the compiled program holds no other gather of ``cap_out`` rows.
-    RIGHT/FULL_OUTER falls back to :func:`emit_from_probe` indices + two
-    packed gathers (the unmatched-right scatter does not fuse).
+    INNER/LEFT fast path (:func:`_emit_inner_left`) holds two forms of its
+    left half under one ``cond`` and one right half. Where some left row
+    emits twice or not at all it does exactly three big row-addressed
+    operations: the ``jnp.repeat`` for li, one packed left-row gather
+    (payload + base/cnt lanes), and one packed right-row gather against the
+    r_order-permuted right payload. A float64 payload is two lanes of those
+    gathers and no gather of its own: for an int64 key and a float64 value a
+    side the two are ``s32[cap_out, 6]`` (key 2, base, cnt, value 2) and
+    ``s32[cap_out, 4]`` (key 2, value 2), and the compiled program holds no
+    other gather of ``cap_out`` rows. Where every live left row emits
+    exactly once (a table joined to another by that one's unique key) the
+    left columns are handed through and the right gather is the one
+    row-addressed operation that runs. RIGHT/FULL_OUTER falls back to
+    :func:`emit_from_probe` indices + two packed gathers (the
+    unmatched-right scatter does not fuse).
 
     Returns (out_cols = left ++ right as (data, valid), n_out scalar).
     """
@@ -581,10 +586,11 @@ def emit_gather(
             (d, None if rv is None else v)
             for (d, v), (_, rv) in zip(r_sorted_cols, r_cols)
         ]
-    return _emit_inner_left(
+    out, n_out, _handed = _emit_inner_left(
         lo, cnt, l_cols, r_sorted_cols, nl, how, cap_out, r_order.shape[0],
         emit_impl,
     )
+    return out, n_out
 
 
 def emit_impl_for(world_size: int, platform: str) -> str:
@@ -652,10 +658,27 @@ def _emit_inner_left(
     nl, how: int, cap_out: int, cap_r: int,
     emit_impl: str = "gather",
     mask_free: bool = False,
-) -> Tuple[list, jax.Array]:
-    """INNER/LEFT emit against an ALREADY key-sorted right payload: the
-    ``jnp.repeat`` for li, one packed left-row gather (payload + base/cnt
-    lanes), one packed right-row gather at the run positions.
+) -> Tuple[list, jax.Array, jax.Array]:
+    """INNER/LEFT emit against an ALREADY key-sorted right payload. Its
+    left half has two forms, and the program holds both under one
+    ``jax.lax.cond`` whose predicate is read from the probe's counts on the
+    device (one reduction over ``cnt``; inside ``shard_map`` a decision a
+    shard; no caller says which):
+
+    - :func:`_left_gathered`, for any counts: the ``jnp.repeat`` for li and
+      one packed left-row gather (payload + base/cnt lanes);
+    - :func:`_left_handed_through`, where every live left row emits exactly
+      once (LEFT: no left row has two partners; INNER: every left row has
+      exactly one; a fact table joined to a dimension table by its key):
+      output row ``k`` is left row ``k``, so the left columns pass as they
+      lie and none of the first form's work runs.
+
+    Live rows agree bit for bit between the forms; rows at or past the
+    total are padding in both. One packed right-row gather at the run
+    positions follows the ``cond`` in either case.
+
+    Returns (out_cols = left ++ right, the emit's total, int32 1 where the
+    left side was handed through and 0 where it was gathered).
 
     ``emit_impl='windowed'``/``'windowed_interp'`` (via
     :func:`emit_impl_for`) swaps the left gather for the Pallas streamed
@@ -688,30 +711,87 @@ def _emit_inner_left(
     from .gather import pack_gather
 
     with jax.named_scope(_stages.JOIN_EMIT):
-        cap_l = lo.shape[0]
-        idx_l = jnp.arange(cap_l, dtype=jnp.int32)
-        live_l = idx_l < nl
+        live_l = jnp.arange(lo.shape[0], dtype=jnp.int32) < nl
         if how == LEFT:
             cnt_adj = jnp.where(live_l & (cnt == 0), 1, cnt)
         else:
             cnt_adj = cnt
-        ends = jnp.cumsum(cnt_adj)
-        offs = ends - cnt_adj
-        total_l = ends[-1].astype(jnp.int32)
-        base = lo - offs
-
-        li = _repeat_ss(ends, cap_out)
-        out_pos = jnp.arange(cap_out, dtype=jnp.int32)
-        li = jnp.where(out_pos < total_l, li, -1)
-        out_l, (base_g, cnt_g) = pack_gather(
-            l_cols, li, extra_lanes=[base, cnt],
-            all_valid=mask_free or how == LEFT,
+        all_valid = mask_free or how == LEFT
+        # one pass over the counts decides, on the device and a shard
+        once = jnp.all(jnp.where(live_l, cnt_adj == 1, True))
+        out_l, rpos, total_l = jax.lax.cond(
+            once,
+            lambda: _left_handed_through(
+                lo, cnt, l_cols, nl, cap_out, cap_r, all_valid
+            ),
+            lambda: _left_gathered(
+                lo, cnt, cnt_adj, l_cols, cap_out, cap_r, all_valid
+            ),
         )
-
-        has_match = (li >= 0) & (cnt_g > 0)
-        rpos = jnp.where(has_match, jnp.clip(base_g + out_pos, 0, cap_r - 1), -1)
+        # the barrier holds the right gather apart from the cond: free to be
+        # scheduled among its neighbours, it shifted the chip compiler's
+        # memory assignment, and two sorts of a join that takes the gather
+        # branch left fast memory (join-w1: +5.1 ms of 243.5 a query;
+        # PERF.md section 6, PR 49)
+        rpos = jax.lax.optimization_barrier(rpos)
         out_r, _ = pack_gather(r_sorted_cols, rpos, all_valid=mask_free)
-        return list(out_l) + list(out_r), total_l
+        return out_l + list(out_r), total_l, once.astype(jnp.int32)
+
+
+def _left_gathered(
+    lo, cnt, cnt_adj, l_cols: Sequence[KeyCol],
+    cap_out: int, cap_r: int, all_valid: bool,
+) -> Tuple[list, jax.Array, jax.Array]:
+    """The left half of :func:`_emit_inner_left` for any counts: left row
+    ``i`` repeated ``cnt_adj[i]`` times (the run expansion ``li``), the
+    columns and the ``base`` / ``cnt`` lanes fetched by ONE packed gather.
+
+    Returns (left output columns, ``rpos`` [cap_out]: each output row's
+    position in the key-sorted right payload or -1, the emit's total)."""
+    from .gather import pack_gather
+
+    ends = jnp.cumsum(cnt_adj)
+    offs = ends - cnt_adj
+    total_l = ends[-1].astype(jnp.int32)
+    base = lo - offs
+
+    li = _repeat_ss(ends, cap_out)
+    out_pos = jnp.arange(cap_out, dtype=jnp.int32)
+    li = jnp.where(out_pos < total_l, li, -1)
+    out_l, (base_g, cnt_g) = pack_gather(
+        l_cols, li, extra_lanes=[base, cnt], all_valid=all_valid,
+    )
+
+    has_match = (li >= 0) & (cnt_g > 0)
+    rpos = jnp.where(has_match, jnp.clip(base_g + out_pos, 0, cap_r - 1), -1)
+    return list(out_l), rpos, total_l
+
+
+def _left_handed_through(
+    lo, cnt, l_cols: Sequence[KeyCol], nl,
+    cap_out: int, cap_r: int, all_valid: bool,
+) -> Tuple[list, jax.Array, jax.Array]:
+    """What :func:`_left_gathered` returns where every live left row emits
+    exactly once, with no gather: ``li`` is then ``0 .. nl-1`` and
+    ``offs[k]`` is ``k``, so output row ``k`` IS left row ``k`` and its
+    right position is ``lo[k]``. Each column is brought to ``cap_out`` slots
+    as it lies (a float64 is never split into lanes); the slots from ``nl``
+    on are padding, as they are in the other form."""
+    total_l = jnp.asarray(nl, jnp.int32)
+    in_out = jnp.arange(cap_out, dtype=jnp.int32) < total_l
+
+    def valid_of(v):
+        if v is None:
+            return None if all_valid else in_out
+        v = fit_slots(v.astype(jnp.bool_), cap_out)
+        return v if all_valid else in_out & v
+
+    out_l = [(fit_slots(d, cap_out), valid_of(v)) for d, v in l_cols]
+    has_match = in_out & (fit_slots(cnt, cap_out) > 0)
+    rpos = jnp.where(
+        has_match, jnp.clip(fit_slots(lo, cap_out), 0, cap_r - 1), -1
+    )
+    return out_l, rpos, total_l
 
 
 def _emit_inner_left_windowed(
@@ -720,9 +800,10 @@ def _emit_inner_left_windowed(
     r_sorted_cols: Sequence[KeyCol],
     nl, how: int, cap_out: int, cap_r: int,
     interpret: bool = False,
-) -> Tuple[list, jax.Array]:
+) -> Tuple[list, jax.Array, jax.Array]:
     """INNER/LEFT emit with the left gather replaced by the Pallas windowed
-    expand (docs/GATHER_DESIGN.md; VERDICT r3 item 1).
+    expand (docs/GATHER_DESIGN.md; VERDICT r3 item 1). It has the one form
+    and hands nothing through: the flag it returns is 0.
 
     The left per-element gather becomes: ONE row scatter compacting emitting
     rows to the front (sorted destinations — for LEFT joins this is the
@@ -798,7 +879,7 @@ def _emit_inner_left_windowed(
             has_match, jnp.clip(lo_g - offs_g + out_pos, 0, cap_r - 1), -1
         )
         out_r, _ = pack_gather(r_sorted_cols, rpos)
-        return list(out_l) + list(out_r), total
+        return list(out_l) + list(out_r), total, jnp.int32(0)
 
 
 def ride_right(r_ids: jax.Array, r_cols: Sequence[KeyCol]) -> list:
@@ -829,7 +910,7 @@ def spec_join(
     emit_key_order: bool = False,
     key_fuse=None,
     mask_free: bool = False,
-) -> Tuple[list, jax.Array, jax.Array]:
+) -> Tuple[list, jax.Array, jax.Array, jax.Array]:
     """Single-dispatch speculative join: probe + count + emit + gather in one
     program with the minimal pass count.
 
@@ -856,9 +937,12 @@ def spec_join(
     and output rows come out GROUPED BY KEY, so downstream ops on the key
     skip their own lexsort.
 
-    Returns (out_cols = left ++ right, exact total, float32 overflow shadow).
-    The caller compares ``total`` against ``cap_out`` on the host and falls
-    back to the exact two-phase path on overflow (table.py speculative join).
+    Returns (out_cols = left ++ right, exact total, float32 overflow shadow,
+    int32 1 where the emit handed its left side through and 0 where it
+    gathered it: :func:`_emit_inner_left`; 0 from the key-order and the
+    RIGHT/FULL_OUTER emits, which have the one form). The caller compares
+    ``total`` against ``cap_out`` on the host and falls back to the exact
+    two-phase path on overflow (table.py speculative join).
     """
     cap_l = l_key_cols[0][0].shape[0]
     cap_r = r_key_cols[0][0].shape[0]
@@ -877,13 +961,13 @@ def spec_join(
                 l_ids, r_ids, l_cols, r_sorted, nl, nr, how, cap_out,
                 cap_l, cap_r,
             )
-            return out_cols, total, shadow
+            return out_cols, total, shadow, jnp.int32(0)
         lo, cnt, r_cnt = _merged_counts(
             l_ids, r_ids, nl, nr, cap_l, cap_r, need_rcnt
         )
         total = count_from_probe(cnt, r_cnt, nl, nr, how)
         shadow = count_overflow_check(cnt, r_cnt)
-        out_cols, n_out = _emit_inner_left(
+        out_cols, _n_out, handed = _emit_inner_left(
             lo, cnt, l_cols, r_sorted, nl, how, cap_out, cap_r, emit_impl,
             mask_free=mask_free,
         )
@@ -897,11 +981,12 @@ def spec_join(
             r_order = jnp.arange(cap_r, dtype=jnp.int32)
         else:
             r_order = _right_order(r_ids)
-        out_cols, n_out = emit_gather(
+        out_cols, _n_out = emit_gather(
             lo, cnt, r_order, r_cnt, l_cols, r_cols, nl, nr, how, cap_out,
             emit_impl,
         )
-    return out_cols, total, shadow
+        handed = jnp.int32(0)
+    return out_cols, total, shadow, handed
 
 
 #: bit of the merged sort's payload that marks a row that is not live (a

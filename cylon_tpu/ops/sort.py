@@ -671,6 +671,13 @@ def sorted_runs_payload(
     return lane_runs_differ(slanes), pays, slanes
 
 
+def fit_slots(x: jax.Array, cap_out: int) -> jax.Array:
+    """The first ``cap_out`` slots of ``x``, zero-padded when it has fewer."""
+    if x.shape[0] >= cap_out:
+        return x[:cap_out]
+    return jnp.pad(x, (0, cap_out - x.shape[0]))
+
+
 def flatten_cols(cols: Sequence[KeyCol]) -> list:
     """The arrays of ``cols``, each column's data then its validity: what
     rides a sort as its payloads."""

@@ -233,7 +233,7 @@ def join_shard(
     # spec_join fuses probe + count + emit with the minimal pass count (the
     # right payload rides the key sort on INNER/LEFT); its exact total both
     # sizes the overflow lane and equals the emitted row count
-    out, needed, shadow = _j.spec_join(
+    out, needed, shadow, _handed = _j.spec_join(
         lk, rk, list(left.cols), list(right.cols),
         left.n, right.n, how, join_cap,
     )

@@ -1877,17 +1877,19 @@ class Table:
                     (lk, rk, lcols, rcols, nl, nr) = dp
                     (dummy,) = rep
                     co = dummy.shape[0]
-                    out, total, shadow = _j.spec_join(
+                    out, total, shadow, handed = _j.spec_join(
                         lk, rk, lcols, rcols, nl[0], nr[0], howi, co,
                         emit_impl, r_presorted=r_presorted,
                         emit_key_order=emit_key, key_fuse=join_fuse,
                         mask_free=counted,
                     )
-                    # pack count + f32 overflow shadow into one [2] i32 lane
-                    # so the host needs a single fetch
-                    stats = jnp.stack(
-                        [total, jax.lax.bitcast_convert_type(shadow, jnp.int32)]
-                    )
+                    # pack count + f32 overflow shadow + the emit's form
+                    # into one [3] i32 lane so the host needs a single fetch
+                    stats = jnp.stack([
+                        total,
+                        jax.lax.bitcast_convert_type(shadow, jnp.int32),
+                        handed,
+                    ])
                     return out, stats
 
                 return kern
@@ -1901,17 +1903,23 @@ class Table:
                     (lflat_k, rflat_k, lflat, rflat, left.counts_dev, right.counts_dev),
                     (jnp.zeros((spec_cap,), jnp.int8),),
                 )
+                handed = None  # a counted join fetches nothing
                 if _totals is None:
-                    stats = _fetch(stats, "join.speculative").reshape(-1, 2)
+                    stats = _fetch(stats, "join.speculative").reshape(-1, 3)
                     totals = stats[:, 0].astype(np.int64)
                     _check_join_count(
                         totals, stats[:, 1].copy().view(np.float32)
                     )
+                    handed = stats[:, 2] != 0
                 else:
                     totals = _totals
             fits = totals.max() <= spec_cap
             bump("join.emit_slots", rows=spec_cap * len(totals))
             bump("join.emit_rows", rows=int(totals.sum()) if fits else 0)
+            if fits and handed is not None:
+                # the emit's form, a shard (ops.join._emit_inner_left)
+                bump("join.emit.handthrough", rows=int(totals[handed].sum()))
+                bump("join.emit.gathered", rows=int(totals[~handed].sum()))
             if fits:
                 res = self._rebuild_cols(
                     list(zip(out_names, src_cols)), out, totals, spec_cap

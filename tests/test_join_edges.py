@@ -1,4 +1,6 @@
-"""Join edge cases: fast-path sentinels, NaN semantics, x64-off mode."""
+"""Join edge cases: fast-path sentinels, NaN semantics, x64-off mode, the
+emit's two forms."""
+import os
 import subprocess
 import sys
 
@@ -327,3 +329,244 @@ def test_joined_columns_come_out_bit_for_bit(devices, rng, schema, how, world):
         "int64": (4, 1), "float64": (4, 1), "nullable-float64": (5, 1),
         "mixed": (7, 1), "wide5": (12, 3),
     }[schema]
+
+
+# ----------------------------------------------------------------------
+# the emit's two forms (PR 49): where every live left row is emitted
+# exactly once the left columns are handed through, else the run expansion
+# and the packed left gather run; one ``cond``, decided from the counts
+# ----------------------------------------------------------------------
+
+_ONCE_NL, _ONCE_CAP_L, _ONCE_NR, _ONCE_CAP_R = 40, 64, 24, 32
+#: (build side, left rows): (a) every left row has its one partner; (b)
+#: some left rows have none; (c) one build key twice, and left rows on it;
+#: (d) one left row has none
+_ONCE_CASES = {
+    # case: (how, the flag)
+    "a-inner": ("inner", 1), "a-left": ("left", 1), "b-left": ("left", 1),
+    "c-inner": ("inner", 0), "c-left": ("left", 0), "d-inner": ("inner", 0),
+}
+
+
+def _once_columns(rng, case):
+    """Left: ``k`` int32, ``a`` int64 (its extremes), ``f`` float64 (values
+    whose halves matter: 1e-30, -0.0, two NaN payloads, a subnormal) and
+    ``n`` int32 under a validity lane. Right: ``k`` and a float64."""
+    rk = rng.permutation(_ONCE_NR).astype(np.int32)
+    lk = rng.integers(0, _ONCE_NR, _ONCE_NL).astype(np.int32)
+    if case[0] == "b":
+        lk[rng.choice(_ONCE_NL, 9, replace=False)] = _ONCE_NR + 5
+    elif case[0] == "c":
+        rk[3] = rk[7]          # one build key twice
+        lk[[2, 11]] = rk[7]    # and two left rows on it
+        lk[lk == rk[3]] = rk[7]
+    elif case[0] == "d":
+        lk[17] = _ONCE_NR + 5
+    f = rng.normal(size=_ONCE_NL)
+    f[:8] = np.concatenate([ride_cases._F64, [1e-30, -1e-30]])
+    a = rng.integers(-(1 << 62), 1 << 62, _ONCE_NL, dtype=np.int64)
+    a[:4] = ride_cases._I64
+    lcols = {
+        "k": (lk, None), "a": (a, None), "f": (f, None),
+        "n": (rng.integers(-99, 99, _ONCE_NL).astype(np.int32),
+              rng.random(_ONCE_NL) < 0.7),
+    }
+    rcols = {"k": (rk, None), "w": (rng.normal(size=_ONCE_NR), None)}
+    return lcols, rcols
+
+
+def _padded(cols, cap):
+    """``[(data, valid)]`` on the device at ``cap`` slots, the padding's
+    values not the live rows' (a slice of it would show)."""
+    import jax.numpy as jnp
+
+    def pad(x, fill):
+        return jnp.asarray(np.concatenate(
+            [x, np.full(cap - len(x), fill, x.dtype)]
+        ))
+
+    return [
+        (pad(d, 77), None if v is None else pad(v, True))
+        for d, v in cols.values()
+    ]
+
+
+def _live(cols, n):
+    return [
+        (np.asarray(d)[:n], None if v is None else np.asarray(v)[:n])
+        for d, v in cols
+    ]
+
+
+def _assert_rows_bit_for_bit(got, want):
+    assert len(got) == len(want)
+    for (gd, gv), (wd, wv) in zip(got, want):
+        assert gd.dtype == wd.dtype and len(gd) == len(wd)
+        gv = np.ones(len(gd), bool) if gv is None else gv
+        wv = np.ones(len(wd), bool) if wv is None else wv
+        assert (gv == wv).all()
+        assert (ride_cases.bits(gd, gv) == ride_cases.bits(wd, wv)).all()
+
+
+@pytest.mark.parametrize("cap_out", [48, 64, 96])  # below, at, above cap_l
+@pytest.mark.parametrize("case", sorted(_ONCE_CASES))
+def test_emit_hands_the_left_side_through_where_each_row_emits_once(
+    rng, case, cap_out
+):
+    """The speculative join's program and the exact path's emit, on a
+    build side unique on its key (and not): the live rows are the numpy
+    join's bit for bit, row for row; the flag says which form ran; and each
+    form called on its own gives the same live rows wherever it may run."""
+    import jax
+    import jax.numpy as jnp
+    from cylon_tpu.ops import join as _j
+
+    how, flag = _ONCE_CASES[case]
+    howi = _j.join_type_id(how)
+    lcols, rcols = _once_columns(rng, case)
+    want = _reference_join(lcols, rcols, how)
+    total = len(want[0][0])
+    assert total <= cap_out
+    l, r = _padded(lcols, _ONCE_CAP_L), _padded(rcols, _ONCE_CAP_R)
+    nl, nr = jnp.int32(_ONCE_NL), jnp.int32(_ONCE_NR)
+
+    out, n_out, _shadow, handed = jax.jit(
+        lambda l, r, nl, nr: _j.spec_join(
+            l[:1], r[:1], l, r, nl, nr, howi, cap_out
+        )
+    )(l, r, nl, nr)
+    assert (int(n_out), int(handed)) == (total, flag)
+    _assert_rows_bit_for_bit(_live(out, total), want)
+    # a LEFT join's left columns carry no validity lane of the emit's own
+    if how == "left":
+        assert [v is None for _d, v in out[:4]] == [True, True, True, False]
+
+    # the exact two-phase path's emit, and the two forms on their own
+    lo, cnt, r_order, r_cnt = jax.jit(
+        lambda lk, rk: _j.probe_arrays(
+            lk, rk, nl, nr, _ONCE_CAP_L, _ONCE_CAP_R, howi
+        )
+    )(l[:1], r[:1])
+    out2, n_out2 = jax.jit(
+        lambda *a: _j.emit_gather(*a, nl, nr, howi, cap_out)
+    )(lo, cnt, r_order, r_cnt, l, r)
+    assert int(n_out2) == total
+    _assert_rows_bit_for_bit(_live(out2, total), want)
+
+    cnt_adj, all_valid = cnt, how == "left"
+    if how == "left":
+        live_l = np.arange(_ONCE_CAP_L) < _ONCE_NL
+        cnt_adj = jnp.where(live_l & (np.asarray(cnt) == 0), 1, cnt)
+    forms = [_j._left_gathered(
+        lo, cnt, cnt_adj, l, cap_out, _ONCE_CAP_R, all_valid)]
+    if flag:
+        forms.append(_j._left_handed_through(
+            lo, cnt, l, nl, cap_out, _ONCE_CAP_R, all_valid))
+    for out_l, rpos, total_l in forms:
+        assert int(total_l) == total
+        _assert_rows_bit_for_bit(_live(out_l, total), want[:4])
+        rpos = np.asarray(rpos)
+        assert (rpos[:total] == np.asarray(forms[0][1])[:total]).all()
+        assert (rpos[total:] == -1).all()
+
+
+def test_the_programs_left_gather_stands_inside_a_branch_alone():
+    """The lowered ``join_spec`` of ``join-w1``'s schema: the run
+    expansion's scatter and the packed left gather (``[rows, 6]``: key 2,
+    base, cnt, value 2) stand in the first branch of ONE ``case``, the
+    other branch gathers, scatters and sorts nothing, and the right gather
+    (``[rows, 4]``) follows the ``case`` behind one barrier."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from cylon_tpu.ops import join as _j
+
+    rows = 4096
+
+    def join(lk, lv, rk, rv, nl, nr):
+        left, right = [(lk, None), (lv, None)], [(rk, None), (rv, None)]
+        return _j.spec_join(
+            left[:1], right[:1], left, right, nl, nr, _j.INNER, rows
+        )
+
+    lines = jax.jit(join).lower(
+        *[jax.ShapeDtypeStruct((rows,), d)
+          for d in (jnp.int64, jnp.float64, jnp.int64, jnp.float64)],
+        *[jax.ShapeDtypeStruct((), jnp.int32)] * 2,
+    ).as_text().split("\n")
+    cases = [i for i, line in enumerate(lines) if '"stablehlo.case"' in line]
+    assert len(cases) == 1
+    start = cases[0]
+    indent = re.match(r" *", lines[start]).group()
+    middle = next(
+        i for i in range(start, len(lines)) if lines[i] == indent + "}, {"
+    )
+    end = next(
+        i for i in range(middle, len(lines))
+        if lines[i].startswith(indent + "}) :")
+    )
+
+    def at(pattern):
+        return [i for i, line in enumerate(lines) if re.search(pattern, line)]
+
+    (left_gather,) = at(rf'"stablehlo\.gather"\(.*:\s*\(tensor<{rows}x6xi32>')
+    (right_gather,) = at(rf'"stablehlo\.gather"\(.*:\s*\(tensor<{rows}x4xi32>')
+    assert start < left_gather < middle and right_gather > end
+    # the barrier that keeps the compiler from mixing the right gather with
+    # the branches (PERF.md section 6, PR 49: two sorts of join-w1)
+    (barrier,) = at(r"stablehlo\.optimization_barrier")
+    assert end < barrier < right_gather
+    expansion = [
+        i for i in at(r'"stablehlo\.scatter"')
+        if f"tensor<{rows + 1}xi32>" in lines[i + 3]
+    ]
+    assert len(expansion) == 1 and start < expansion[0] < middle
+    handed = "\n".join(lines[middle:end])
+    assert not re.search(r"stablehlo\.(gather|scatter|sort)|call @cum", handed)
+
+
+@pytest.mark.parametrize("how", ["inner", "left"])
+def test_the_emits_form_is_counted_a_shard_on_the_mesh(devices, how):
+    """Four shards: a foreign-key join (every probe row has one partner on
+    its shard) bumps ``join.emit.handthrough`` by the output's rows, a
+    join of uniform keys ``join.emit.gathered``, and the fetches a join
+    makes are what they were (the flag rides the totals')."""
+    ctx = ct.CylonContext.init_distributed(ct.TPUConfig(devices=devices[:4]))
+    gen = np.random.default_rng(2147549002)
+    # 256 build keys, each once; 4,096 probe keys drawn from them, skewed
+    build = gen.permutation(1 << 20)[:256].astype(np.int64)
+    fk = {
+        "left": {"k": build[np.minimum(gen.zipf(1.25, 4096), 256) - 1],
+                 "v": gen.random(4096)},
+        "right": {"k": build, "w": gen.random(256)},
+    }
+    uniform = {
+        # half as many partners as rows, so the speculative capacity holds
+        "left": {"k": gen.integers(0, 4096, 2048), "v": gen.random(2048)},
+        "right": {"k": gen.integers(0, 4096, 2048), "w": gen.random(2048)},
+    }
+
+    def counted(data):
+        left, right = (
+            ct.Table.from_numpy(ctx, list(cols), list(cols.values()))
+            for cols in (data["left"], data["right"])
+        )
+        names = ("join.emit.handthrough", "join.emit.gathered")
+        before = [tracing.snapshot().get(n, {}).get("rows", 0) for n in names]
+        syncs = tracing.get_count("host_sync")
+        out = left.distributed_join(right, on="k", how=how)
+        rows = out.row_count
+        syncs = tracing.get_count("host_sync") - syncs
+        after = [tracing.snapshot().get(n, {}).get("rows", 0) for n in names]
+        want = pd.DataFrame(data["left"]).merge(
+            pd.DataFrame(data["right"]), on="k", how=how)
+        assert rows == len(want)
+        return list(np.subtract(after, before)), rows, syncs
+
+    moved, rows, fk_syncs = counted(fk)
+    assert moved == [rows, 0] and rows == 4096
+    moved, rows, uniform_syncs = counted(uniform)
+    assert moved == [0, rows] and rows > 0
+    # both sides shuffled, one speculative join: the same fetches either way
+    assert fk_syncs == uniform_syncs

@@ -79,7 +79,7 @@ def _emit_pair(rng, how, n_l, n_r, keyspace, with_valid=False, with_f64=False):
     ]  # r_cols mask-free: keep mask-free
     outs = {}
     for impl in ("gather", "windowed_interp"):
-        cols, n_out = J._emit_inner_left(
+        cols, n_out, _handed = J._emit_inner_left(
             lo, cnt, l_cols, r_sorted, nl, howi, cap_out, cap_r, impl
         )
         outs[impl] = (
@@ -143,7 +143,7 @@ def test_windowed_emit_wide_table_gate(rng, monkeypatch):
     from cylon_tpu.ops.gather import pack_gather
 
     r_sorted, _ = pack_gather([(jnp.asarray(rk), None)], r_order)
-    cols, n_out = J._emit_inner_left(
+    cols, n_out, _handed = J._emit_inner_left(
         lo, cnt, l_cols, [(r_sorted[0][0], None)],
         jnp.int32(n), J.INNER, 256, cap, "windowed_interp",
     )

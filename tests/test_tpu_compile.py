@@ -421,20 +421,38 @@ def _assert_float64_rides_the_packed_gathers(text, cap_out):
     ``s32[cap_out, 6]`` and ``s32[cap_out, 4]``; the halves' lone
     ``f32[cap_out]`` gathers stand only in the branch the guard takes for a
     table that holds a value whose low half the split would lose
-    (``ops.gather._f64_low_half_may_flush``)."""
-    # (shape, the branch of the guard's cond it was traced in: 0 packed,
-    # 1 the lone gathers) of every gather
+    (``ops.gather._f64_low_half_may_flush``). The left gather with its
+    guard, and the run expansion's scatter, stand inside the gather branch
+    (0) of the emit's own ``cond`` (``ops.join._emit_inner_left``): a join
+    that emits every left row once runs neither."""
+    # (shape, the path of the emit's scope it was traced on, the branch of
+    # the guard's cond, the innermost: 0 packed, 1 the lone gathers) of
+    # every gather
     gathers = re.findall(
-        r"= (\S+?\[[0-9,]*\])\S* gather\(.*?op_name=\"[^\"]*?"
-        r"(?:cond/branch_(\d)_fun)", text,
+        r"= (\S+?\[[0-9,]*\])\S* gather\(.*?op_name=\"[^\"]*?join\.emit/"
+        r"([^\"]*cond/branch_(\d)_fun)/gather\"", text,
     )
-    packed = sorted(shape for shape, branch in gathers if branch == "0")
+    packed = sorted(shape for shape, _path, branch in gathers if branch == "0")
     assert packed == [f"s32[{cap_out},4]", f"s32[{cap_out},6]"], gathers
-    lone = [shape for shape, branch in gathers if branch == "1"]
+    lone = [shape for shape, _path, branch in gathers if branch == "1"]
     assert lone.count(f"f32[{cap_out}]") == 4, gathers
     assert len(gathers) == len(
         re.findall(r"\sgather\(", text)
     ), "a gather outside the guard's branches"
+    # the left side's gathers are the guard's inside the emit's branch 0,
+    # the right side's the guard's alone, after the emit's cond
+    left = [shape for shape, path, _b in gathers if path.count("cond/") == 2]
+    assert sorted(left) == sorted(
+        [f"s32[{cap_out},6]", f"s32[{cap_out},4]"] + [f"f32[{cap_out}]"] * 2
+    ), gathers
+    assert all(
+        path.startswith("cond/branch_0_fun/")
+        for _s, path, _b in gathers if path.count("cond/") == 2
+    ), gathers
+    scatters = re.findall(
+        r"= \S+ scatter\(.*?op_name=\"[^\"]*?join\.emit/([^\"]*)\"", text
+    )
+    assert scatters == ["cond/branch_0_fun/scatter"], scatters
 
 
 def test_skewed_joins_program_gathers_its_float64_packed(one_chip):
@@ -533,7 +551,7 @@ def test_left_outer_join_compiles_with_validity_on_the_right_alone(one_chip):
     side's five columns come out with a validity lane each and the left
     side's seven with none."""
     compiled = _h2o_left_join(one_chip, ROWS, ROWS >> 8)
-    out, _total, _shadow = compiled.out_info
+    out, _total, _shadow, _handed = compiled.out_info
     assert [v is None for _d, v in out] == [True] * 7 + [False] * 5
     assert all(v.shape == (ROWS,) and v.dtype == jnp.bool_
                for _d, v in out[7:])
